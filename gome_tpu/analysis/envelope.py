@@ -167,10 +167,9 @@ def traced_entries(dtype: str = "int32") -> list[dict]:
     jnp.sum's int32→int64 promotion, which the deployment configuration
     never executes."""
     if dtype not in _TRACE_CACHE:
-        from jax.experimental import enable_x64, disable_x64
+        import jax
 
-        ctx = enable_x64 if dtype == "int64" else disable_x64
-        with ctx():
+        with jax.enable_x64(dtype == "int64"):
             _TRACE_CACHE[dtype] = list(_entry_records_x64_scoped(dtype))
     return _TRACE_CACHE[dtype]
 

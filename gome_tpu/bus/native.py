@@ -42,8 +42,7 @@ def _load():
         spec.loader.exec_module(mod)
         path = mod.build()
         if path is None:
-            _lib_err = "g++ unavailable or compile failed"
-            return None
+            raise OSError("g++ unavailable or compile failed")
         lib = ctypes.CDLL(path)
         lib.gq_open.restype = ctypes.c_void_p
         lib.gq_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
@@ -75,6 +74,16 @@ def _load():
         _lib = lib
     except Exception as e:  # pragma: no cover - environment-specific
         _lib_err = str(e)
+    if _lib is None:
+        # Once per process (the outcome is memoized): every caller falls
+        # back to pure Python — same behaviour, ~10x the host time per
+        # order on the frame path — and should not do so without a word.
+        from ..utils.logging import get_logger
+
+        get_logger("native").warning(
+            "native library unavailable (%s): pure-Python fallbacks in use",
+            _lib_err,
+        )
     return _lib
 
 

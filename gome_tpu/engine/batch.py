@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
 import warnings
 
 import jax
@@ -473,6 +474,21 @@ def splice_outs(outs, overrides):
     return outs_at
 
 
+def is_device_fault(exc: BaseException) -> bool:
+    """True for a compile, lowering or device-runtime error: a property of
+    the program and the chip, never of one order's data. Callers that
+    tolerate bad INPUT (the consumer's poison-batch quarantine, the
+    best-effort geometry replay) must let these through — a kernel the
+    chip refuses, swallowed there, looks like a venue that silently drops
+    every order."""
+    if isinstance(exc, jax.errors.JaxRuntimeError):
+        return True
+    # Pallas lowering failures. The class lives in a module only loaded
+    # with the kernel, and none can be raised before it is.
+    pltpu = sys.modules.get("jax.experimental.pallas.tpu")
+    return pltpu is not None and isinstance(exc, pltpu.LoweringException)
+
+
 class CapacityError(RuntimeError):
     """A configured growth ceiling (max_slots / max_cap) was hit. The book
     state is unchanged for the op that tripped it; callers may shed load or
@@ -505,6 +521,17 @@ class EngineStats:
     fill_record_escalations: int = 0
     frame_fallbacks: int = 0  # fast-path frames re-run on the exact path
     lane_growths: int = 0
+    # Grids dispatched and the real (non-padding) ops through them, keyed
+    # by the kernel that ACTUALLY ran (BatchEngine._step): "pallas_full" /
+    # "pallas_dense" (compiled), "interpret_full" / "interpret_dense" (the
+    # Pallas interpreter — CPU tests, the chip_smoke rehearsal),
+    # "scan_full" / "scan_dense".
+    grids_by_kernel: dict[str, int] = dataclasses.field(default_factory=dict)
+    ops_by_kernel: dict[str, int] = dataclasses.field(default_factory=dict)
+    # kernel="pallas" grids that ran on the scan path instead, by reason
+    # (ops.pallas_match.kernel_plan): a deployment that asks for the
+    # kernel can tell whether it got it.
+    scan_giveways: dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 class BatchEngine:
@@ -539,12 +566,15 @@ class BatchEngine:
         (the reference has no such ceiling because Redis pages to disk).
 
         kernel: "scan" (XLA scan x vmap) or "pallas" (VMEM-resident Pallas
-        grid kernel, gome_tpu.ops.pallas_match). "pallas" silently uses the
-        scan path whenever the compiled kernel cannot run (off-TPU, int64
-        books, unblockable lane counts) — identical semantics either way, so
-        the choice is purely a performance one. pallas_interpret=True forces
-        the (slow) Pallas interpreter instead of that fallback; it exists so
-        CPU tests can exercise the kernel's code path.
+        grid kernel, gome_tpu.ops.pallas_match). A "pallas" grid the
+        compiled kernel cannot run (off-TPU, int64 books, unblockable lane
+        counts, book tile over the VMEM budget) gives way to the scan path
+        — identical semantics — and EngineStats counts every grid by the
+        kernel that ran it and every give-way by its reason
+        (ops.pallas_match.kernel_plan). pallas_interpret=True selects the
+        (slow) Pallas interpreter where the compiled kernel is unavailable;
+        it exists so CPU tests and the chip_smoke rehearsal can exercise
+        the kernel's code path.
 
         dense: allow the columnar path to pack batches touching few symbols
         into compact gather/scatter grids over just the live lanes
@@ -1471,7 +1501,7 @@ class BatchEngine:
             # wait); the annotation aligns it with jax.profiler traces.
             with TRACER.stage("device_execute"):
                 new_books, outs = self._step(
-                    books_before, ops, lane_ids, cap_g
+                    books_before, ops, lane_ids, cap_g, n_ops=len(contexts)
                 )
                 self.stats.device_calls += 1
                 host_flags = np.asarray(jax.device_get(outs.book_overflow))
@@ -1555,19 +1585,49 @@ class BatchEngine:
             lane_overrides[row] = jax.device_get(lane_out)
         return outs, lane_overrides
 
+    def _plan_step(self, rows: int, cfg: BookConfig, dense: bool,
+                   n_ops: int | None):
+        """Choose the kernel for one grid of `rows` (per-chip) lanes and
+        attribute the grid to it in EngineStats. Returns kernel_plan's
+        (block_s, interpret): block_s None means the scan path. n_ops is
+        the grid's real op count; None (precompile replay) counts
+        nothing."""
+        block_s, interpret, reason = None, False, None
+        if self.kernel == "pallas":
+            from ..ops import kernel_plan
+
+            block_s, interpret, reason = kernel_plan(
+                rows, cfg.cap, cfg.dtype, self._pallas_interpret
+            )
+        if n_ops is not None:
+            st = self.stats
+            ran = (
+                "scan" if block_s is None
+                else "interpret" if interpret else "pallas"
+            ) + ("_dense" if dense else "_full")
+            st.grids_by_kernel[ran] = st.grids_by_kernel.get(ran, 0) + 1
+            st.ops_by_kernel[ran] = st.ops_by_kernel.get(ran, 0) + n_ops
+            if reason is not None:
+                st.scan_giveways[reason] = st.scan_giveways.get(reason, 0) + 1
+        return block_s, interpret
+
     def _step(self, books: BookState, ops: DeviceOp, lane_ids=None,
-              cap_g: int | None = None):
+              cap_g: int | None = None, n_ops: int | None = None):
         """Run one [R, T] grid with the configured kernel. lane_ids selects
         the dense gather/scatter step (compact grid over live lanes; under
         a mesh the rows are laid out per shard and the gather runs inside
-        shard_map — parallel.mesh.sharded_dense_step). The Pallas path
-        requires S % block_s == 0 (n_slots growth keeps powers of two) and
-        interprets off-TPU; escalation re-runs (lane_scan) stay on the scan
-        path — they are rare and per-lane.
+        shard_map — parallel.mesh.sharded_dense_step). kernel="pallas"
+        grids run the Pallas kernel where ops.kernel_plan finds a blocking
+        and give way to the scan path otherwise (_plan_step counts which);
+        escalation re-runs (lane_scan) stay on the scan path — they are
+        rare and per-lane.
 
         cap_g: the grid's cap class (None/equal = storage cap). Every step
         variant slices the slot axis to it, so the per-step cost tracks
-        this grid's own depth class."""
+        this grid's own depth class.
+
+        n_ops: the grid's real op count, given by live dispatches so
+        EngineStats attributes the grid to the kernel that ran it."""
         cfg = self.config
         if cap_g is not None and cap_g != cfg.cap:
             cfg = dataclasses.replace(cfg, cap=cap_g)
@@ -1582,7 +1642,14 @@ class BatchEngine:
         _dense = dense_batch_step_donating if donate else dense_batch_step
         _densek = dense_kernel_step_donating if donate else dense_kernel_step
         _fullk = full_kernel_step_donating if donate else full_kernel_step
-        if lane_ids is not None and self.mesh is not None:
+        dense = lane_ids is not None
+        # Per-chip rows: under a mesh each chip blocks its own local slice
+        # (parallel.mesh makes the same kernel_plan call at trace time).
+        rows = ops.action.shape[0] // (
+            1 if self.mesh is None else self.mesh.size
+        )
+        block_s, interpret = self._plan_step(rows, cfg, dense, n_ops)
+        if dense and self.mesh is not None:
             from ..parallel.mesh import shard_batch, sharded_dense_step
 
             # Localize: global lane -> shard-local index (each chip's row
@@ -1608,27 +1675,10 @@ class BatchEngine:
                 shard_batch(self.mesh, jnp.asarray(ids_local)),
                 shard_batch(self.mesh, ops),
             )
-        if lane_ids is not None:
+        if dense:
             ids = jnp.asarray(lane_ids, jnp.int32)
-            if self.kernel == "pallas":
-                from ..ops import (
-                    default_block_s,
-                    interpret_block_s,
-                    pallas_available,
-                )
-
-                r = ops.action.shape[0]
-                block_s = default_block_s(r, cfg.cap)
-                if self._pallas_interpret and block_s is None:
-                    block_s = interpret_block_s(r)
-                if block_s is not None and (
-                    pallas_available(cfg.dtype)
-                    or self._pallas_interpret
-                ):
-                    return _densek(
-                        cfg, books, ids, ops, block_s,
-                        not pallas_available(cfg.dtype),
-                    )
+            if block_s is not None:
+                return _densek(cfg, books, ids, ops, block_s, interpret)
             return _dense(cfg, books, ids, ops)
         if self.mesh is not None:
             from ..parallel.mesh import shard_batch, sharded_batch_step
@@ -1643,27 +1693,8 @@ class BatchEngine:
                 )
                 self._sharded_steppers[cfg] = stepper
             return stepper(books, shard_batch(self.mesh, ops))
-        if self.kernel == "pallas":
-            from ..ops import (
-                default_block_s,
-                interpret_block_s,
-                pallas_available,
-            )
-
-            s = ops.action.shape[0]
-            block_s = default_block_s(s, cfg.cap)
-            if self._pallas_interpret and block_s is None:
-                block_s = interpret_block_s(s)
-            if block_s is not None and (
-                pallas_available(cfg.dtype) or self._pallas_interpret
-            ):
-                return _fullk(
-                    cfg, books, ops, block_s,
-                    not pallas_available(cfg.dtype),
-                )
-            # int64 books, off-TPU, or lane counts the kernel cannot block:
-            # the scan path has identical semantics at full speed (the
-            # interpreter is a test vehicle, not a production fallback).
+        if block_s is not None:
+            return _fullk(cfg, books, ops, block_s, interpret)
         return _batch(cfg, books, ops)
 
     # -- snapshot support ----------------------------------------------------

@@ -23,7 +23,7 @@ layer (gome_tpu.persist).
 from __future__ import annotations
 
 from ..types import Action, MatchResult, Order
-from .batch import BatchEngine, EngineStats
+from .batch import BatchEngine, EngineStats, is_device_fault
 from .book import BookConfig
 from .prepool import consume_batch_of, make_prepool
 
@@ -292,7 +292,8 @@ class MatchEngine:
         traced+compiled before live traffic). Returns the number of combos
         replayed (0 with precompile=False or an absent/invalid file —
         loading is best-effort: geometry is a performance hint, never
-        state)."""
+        state). A compile or device error while replaying is not a stale
+        manifest and propagates (engine.batch.is_device_fault)."""
         import json
 
         from . import frames
@@ -333,6 +334,8 @@ class MatchEngine:
                 return 0
             return frames.precompile_combos(self.batch, combos)
         except Exception as e:
+            if is_device_fault(e):
+                raise
             # Best-effort end to end: a stale manifest (combo layout from
             # an older version, shapes recorded before an n_slots growth)
             # must never stop a boot — it is a performance hint, never

@@ -59,10 +59,9 @@ module is the host-CPU mirror of the device profiler (obs.profiler):
 
   * ``host_roofline()`` / ``hostprof_artifact()`` — the committed
     table (``HOSTPROF_r01.json``): measured gateway admit
-    orders/sec/core next to the committed consumer and device numbers,
-    making the ~30x front-door mismatch one artifact instead of a
-    ROADMAP sentence. This is the before/after baseline open item 1's
-    columnar front-door rework will be judged against.
+    orders/sec/core. The committed artifacts also carry consumer and
+    device rows copied from round-5 records that are now deleted; a
+    regenerated artifact leaves those rows to the benchmark (ROADMAP A1).
 
 ``HOSTPROF`` is the process singleton behind the ops ``/hostprof``
 endpoint and the ``gome_hostprof_*`` gauges, armed from the
@@ -728,66 +727,24 @@ def gateway_drill(
 # the host roofline
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)
-    )))
-
-
-def _artifact_value(root: str, name: str, path: tuple):
-    try:
-        with open(os.path.join(root, name), encoding="utf-8") as fh:
-            doc = json.load(fh)
-        for key in path:
-            doc = doc[key]
-        return doc
-    except (OSError, KeyError, TypeError, ValueError):
-        return None
-
-
-def host_roofline(drill: dict, root: str | None = None) -> dict:
-    """The host-vs-device orders/sec table: the drill's measured gateway
-    admit rate next to the committed consumer (BENCH_SERVICE_r05
-    headline, orders/sec/core) and device (BENCH_r05, orders/sec)
-    numbers — ROADMAP open item 1's ~30x front-door mismatch as one
-    committed row set. Missing artifacts degrade to absent rows, never
-    an exception."""
-    root = root or _repo_root()
-    admit = drill.get("admit_orders_per_sec_per_core")
-    out: dict = {
+def host_roofline(drill: dict) -> dict:
+    """The host side of the host-vs-device orders/sec table: the drill's
+    measured gateway admit rate. The consumer and device rows it used to
+    sit beside came from round-5 records taken on a platform that no
+    longer exists; they are absent until the benchmark (ROADMAP A1)
+    measures them on the chip."""
+    return {
         "host_gateway_admit": {
-            "orders_per_sec_per_core": admit,
+            "orders_per_sec_per_core": drill.get(
+                "admit_orders_per_sec_per_core"
+            ),
             "source": "measured (gateway_drill, this artifact)",
         },
+        "note": (
+            "consumer-drain and device-matching rows: not measured on "
+            "this machine yet (ROADMAP A1)"
+        ),
     }
-    consumer = _artifact_value(
-        root, "BENCH_SERVICE_r05.json", ("headline", "value")
-    )
-    if consumer is not None:
-        out["host_consumer_drain"] = {
-            "orders_per_sec_per_core": consumer,
-            "source": "BENCH_SERVICE_r05.json headline (mixed stream)",
-        }
-        if admit:
-            out["front_door_mismatch_consumer_vs_gateway"] = round(
-                consumer / admit, 1
-            )
-    device = _artifact_value(root, "BENCH_r05.json", ("parsed", "value"))
-    if device is not None:
-        out["device_matching"] = {
-            "orders_per_sec": device,
-            "source": "BENCH_r05.json (pallas kernel, device bench)",
-        }
-        if admit:
-            out["front_door_mismatch_device_vs_gateway"] = round(
-                device / admit, 1
-            )
-    out["note"] = (
-        "the gateway's per-order Python admit loop is the system-wide "
-        "bottleneck (ROADMAP open item 1); this table is the measured "
-        "before-baseline the columnar front-door rework cites"
-    )
-    return out
 
 
 def hostprof_artifact(

@@ -23,8 +23,9 @@ a run *achieved*. This module closes that loop in three pieces:
     capture and joins measured device time against the analytic
     flops / bytes-accessed rows: achieved GFLOP/s, achieved GB/s, and
     efficiency vs the machine's roofline ceiling
-    (``min(peak_flops, intensity * peak_bw)``; peaks from
-    ``GOME_PEAK_GFLOPS``/``GOME_PEAK_GBPS`` or a one-shot calibration).
+    (``min(peak_flops, intensity * peak_bw)``; on a TPU the peaks are
+    the published ones from ``TPU_PEAKS``, keyed by ``device_kind``; a
+    CPU run calibrates its own and says so).
 
 ``PROFILER`` is the process singleton behind the ops ``/profile``
 endpoint and the ``gome_profile_*`` gauges, armed from the
@@ -282,14 +283,38 @@ _PEAKS_CACHE: dict = {}
 _PEAKS_LOCK = threading.Lock()
 
 
+#: Published per-chip peaks, keyed by jax's ``device_kind``. Source:
+#: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s
+#: int8, 819 GB/s HBM. A TPU kind that is not here is an error, not a
+#: default: a roofline share against the wrong ceiling is a wrong number.
+TPU_PEAKS = {
+    "TPU v5 lite": {  # v5e, as jax reports it
+        "peak_gflops": 197_000.0,
+        "peak_int8_gops": 393_000.0,
+        "peak_gbps": 819.0,
+        "source": 'table: Google Cloud documentation, "TPU v5e"',
+    },
+}
+
+
 def machine_peaks(refresh: bool = False) -> dict:
-    """Roofline ceilings for THIS machine. ``GOME_PEAK_GFLOPS`` /
-    ``GOME_PEAK_GBPS`` override (source ``env``); otherwise a one-shot
-    memoized calibration (source ``calibrated``): best-of-N f32 matmul
-    for the FLOP/s ceiling, best-of-N saxpy sweep for the bandwidth
-    ceiling. Calibrated ceilings are the practically-achievable ones —
-    exactly the comparison an efficiency%% against a tiny integer scan
-    should use — not datasheet numbers."""
+    """Roofline ceilings for THIS machine. On a TPU backend: the published
+    peaks from ``TPU_PEAKS`` by ``device_kind`` (KeyError for a kind not
+    in the table). On the CPU backend only — tests and self-checks,
+    never a device metric — a one-shot memoized calibration (source
+    ``cpu-calibrated``: best-of-N f32 matmul and saxpy sweep), which
+    ``GOME_PEAK_GFLOPS`` / ``GOME_PEAK_GBPS`` override (source
+    ``cpu-env``)."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        if dev.device_kind not in TPU_PEAKS:
+            raise KeyError(
+                f"no published peaks for device_kind {dev.device_kind!r}; "
+                "add it to gome_tpu.obs.profiler.TPU_PEAKS with its source"
+            )
+        return {**TPU_PEAKS[dev.device_kind], "device_kind": dev.device_kind}
     with _PEAKS_LOCK:
         if _PEAKS_CACHE and not refresh:
             return dict(_PEAKS_CACHE)
@@ -299,7 +324,7 @@ def machine_peaks(refresh: bool = False) -> dict:
             peaks = {
                 "peak_gflops": float(env_f),
                 "peak_gbps": float(env_b),
-                "source": "env",
+                "source": "cpu-env",
             }
         else:
             peaks = _calibrate()
@@ -308,7 +333,7 @@ def machine_peaks(refresh: bool = False) -> dict:
             if env_b:
                 peaks["peak_gbps"] = float(env_b)
             if env_f or env_b:
-                peaks["source"] = "env+calibrated"
+                peaks["source"] = "cpu-env+calibrated"
         _PEAKS_CACHE.clear()
         _PEAKS_CACHE.update(peaks)
         return dict(peaks)
@@ -339,7 +364,7 @@ def _calibrate() -> dict:
     return {
         "peak_gflops": round(peak_gflops, 3),
         "peak_gbps": round(peak_gbps, 3),
-        "source": "calibrated",
+        "source": "cpu-calibrated",
     }
 
 

@@ -2,14 +2,14 @@
 
 from .pallas_match import (
     default_block_s,
-    interpret_block_s,
+    kernel_plan,
     pallas_available,
     pallas_batch_step,
 )
 
 __all__ = [
     "default_block_s",
-    "interpret_block_s",
+    "kernel_plan",
     "pallas_available",
     "pallas_batch_step",
 ]

@@ -78,25 +78,58 @@ def interpret_block_s(s: int) -> int:
     return next(b for b in (8, 4, 2, 1) if s % b == 0)
 
 
+def plan_block_s(s: int, cap: int = 256) -> tuple[int | None, str | None]:
+    """The compiled kernel's lane-blocking policy, in ONE place. Returns
+    (block_s, None), or (None, reason) when no blocking is valid and the
+    caller gives way to the scan path:
+
+      "unblockable_rows" — Mosaic's lane-dim rule (enforced in
+          pallas_batch_step): blocks are 128-multiples, or one
+          sublane-aligned whole-axis block for modest s (s % 8 != 0 hits
+          unsupported relayouts);
+      "tile_over_budget" — the legal block's resident book tiles
+          (~10 x block x 2*cap x 4 B, in/out aliased at ~2x) do not fit
+          the 6 MB share of Mosaic's 16 MB scoped-VMEM stack: cap=1024 at
+          block 128 is a compile-time VMEM OOM."""
+    if s % 128 == 0:
+        block = 128
+    elif s <= 256 and s % 8 == 0:
+        block = s
+    else:
+        return None, "unblockable_rows"
+    if 10 * block * 2 * cap * 4 > 6 << 20:
+        return None, "tile_over_budget"
+    return block, None
+
+
 def default_block_s(s: int, cap: int = 256) -> int | None:
-    """The compiled kernel's lane-blocking policy, in ONE place: 128-lane
-    blocks when the lane count divides, else one sublane-aligned whole-axis
-    block (VMEM-bounded, so only for modest s; s % 8 != 0 hits unsupported
-    Mosaic relayouts). Deep books shrink the block: the resident per-block
-    book tiles are ~10 x block x 2*cap x 4 B, and Mosaic's scoped-VMEM
-    stack is 16 MB — cap=1024 at block 128 is a compile-time VMEM OOM.
-    None means no valid blocking — callers fall back to the scan path."""
-    # Valid blockings are 128-multiples or the whole axis (Mosaic lane-dim
-    # rule enforced in pallas_batch_step); within that, the book tile must
-    # fit the scoped-VMEM stack (~16 MB total; the in/out aliased tiles
-    # cost ~2x the nominal size, so budget the tile at 6 MB).
-    tile = lambda b: 10 * b * 2 * cap * 4
-    limit = 6 << 20
-    if s % 128 == 0 and tile(128) <= limit:
-        return 128
-    if s <= 256 and s % 8 == 0 and tile(s) <= limit:
-        return s
-    return None
+    """plan_block_s without the reason: the block, or None."""
+    return plan_block_s(s, cap)[0]
+
+
+def kernel_plan(
+    rows: int, cap: int, dtype, interpret: bool = False
+) -> tuple[int | None, bool, str | None]:
+    """The dispatch decision for one kernel="pallas" grid of `rows` lanes
+    at cap class `cap` — shared by every call site (engine.batch._step,
+    parallel.mesh) so what EngineStats counts is what ran. Returns
+    (block_s, interpret_mode, None) when the Pallas kernel runs, or
+    (None, False, reason) when the grid gives way to the scan path.
+
+    `interpret` is the caller's pallas_interpret flag (CPU tests, the
+    chip_smoke rehearsal): where the compiled kernel cannot run at all it
+    selects the Pallas interpreter instead of the give-way. The
+    interpreter has no layout rule, so it blocks rows the compiled kernel
+    could not; it keeps the VMEM budget, so a rehearsal gives way exactly
+    where the chip would."""
+    compiled = pallas_available(dtype)
+    if not compiled and not interpret:
+        wide = jnp.dtype(dtype).itemsize > 4
+        return None, False, "int64_books" if wide else "no_tpu_backend"
+    block, reason = plan_block_s(rows, cap)
+    if reason == "unblockable_rows" and not compiled:
+        block, reason = interpret_block_s(rows), None
+    return block, block is not None and not compiled, reason
 
 
 def _kernel(config: BookConfig, t_block: int, *refs):

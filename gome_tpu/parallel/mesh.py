@@ -31,24 +31,11 @@ SYM_AXIS = "sym"
 
 
 def _shard_map_fn(mesh: Mesh):
-    """shard_map bound to `mesh` (replication checking off where
-    supported — spelled check_vma on new jax, check_rep on older: the
-    checker has no rule for pallas_call, whose ShapeDtypeStruct outputs
+    """jax.shard_map bound to `mesh` with the varying-mesh-axis check off:
+    the checker has no rule for pallas_call, whose ShapeDtypeStruct outputs
     carry no varying-mesh-axis annotation, and the bodies here are
-    embarrassingly parallel so the check proves nothing)."""
-    try:
-        from jax import shard_map as _shard_map
-
-        return functools.partial(_shard_map, mesh=mesh, check_vma=False)
-    except ImportError:  # older jax
-        import inspect
-
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        kwargs = {"mesh": mesh}
-        if "check_rep" in inspect.signature(_shard_map).parameters:
-            kwargs["check_rep"] = False
-        return functools.partial(_shard_map, **kwargs)
+    embarrassingly parallel so the check proves nothing."""
+    return functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
 
 def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
@@ -96,30 +83,25 @@ def sharded_batch_step(
     the VMEM-resident kernel runs PER CHIP inside a shard_map over the
     symbol mesh — each chip sees its local [S/D, ...] block and launches
     the same compiled kernel a single-chip engine would, so multi-chip
-    keeps the kernel's ~3x win over the scan path. Falls back to the scan
-    step when the kernel cannot run (off-TPU without pallas_interpret,
-    int64 books, local lane counts with no valid blocking).
+    keeps the kernel's ~3x win over the scan path. Gives way to the scan
+    step where ops.kernel_plan finds no blocking for the local lane count
+    (BatchEngine._step counts the same decision in EngineStats).
     """
     sharding = symbol_sharding(mesh)
 
-    use_pallas = False
-    interpret = False
     if kernel == "pallas":
-        from ..ops import pallas_available
-
-        interpret = not pallas_available(config.dtype)
-        use_pallas = not interpret or pallas_interpret
-
-    if use_pallas:
         shard_map = _shard_map_fn(mesh)
         from ..engine.batch import full_kernel_step
-        from ..ops import default_block_s, interpret_block_s
+        from ..ops import kernel_plan
 
         def stepper(books: BookState, ops: DeviceOp):
-            s_local = ops.action.shape[0] // mesh.size
-            block = default_block_s(s_local, config.cap)
-            if block is None and interpret:
-                block = interpret_block_s(s_local)
+            # The same per-chip decision BatchEngine._step counted.
+            block, interpret, _reason = kernel_plan(
+                ops.action.shape[0] // mesh.size,
+                config.cap,
+                config.dtype,
+                pallas_interpret,
+            )
             if block is None:
                 return batch_step(config, books, ops)
             # full_kernel_step carries the cap-class slice/guard/write-back
@@ -180,14 +162,6 @@ def sharded_dense_step(
         _slice_books_cap,
     )
 
-    use_pallas = False
-    interpret = False
-    if kernel == "pallas":
-        from ..ops import pallas_available
-
-        interpret = not pallas_available(config.dtype)
-        use_pallas = not interpret or pallas_interpret
-
     def per_chip(books, ids, ops):
         import jax.numpy as jnp
 
@@ -200,13 +174,14 @@ def sharded_dense_step(
             base,
         )
         pre_counts = sub.count
-        block = None
-        if use_pallas:
-            from ..ops import default_block_s, interpret_block_s
+        block, interpret = None, False
+        if kernel == "pallas":
+            from ..ops import kernel_plan
 
-            block = default_block_s(ids.shape[0], config.cap)
-            if block is None and interpret:
-                block = interpret_block_s(ids.shape[0])
+            # The same per-chip decision BatchEngine._step counted.
+            block, interpret, _reason = kernel_plan(
+                ids.shape[0], config.cap, config.dtype, pallas_interpret
+            )
         if block is not None:
             from ..ops import pallas_batch_step
 

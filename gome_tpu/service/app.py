@@ -320,16 +320,23 @@ def main(argv=None):
     import sys
 
     from ..config import load_config
+    from ..utils.jaxcache import enable_compile_cache
 
     argv = sys.argv[1:] if argv is None else argv
     config = load_config(argv[0] if argv else None)
+    # Every frame-geometry shape is a compile of seconds on the chip;
+    # cached, a restart pays them once per machine.
+    cache_dir = enable_compile_cache()
     persist = None
     if config.persist.enabled:
         from ..persist import Persister
 
         persist = Persister(config.persist)
     svc = EngineService(config, persist=persist).start()
-    log.info("engine service up (grpc %s:%d)", config.grpc.host, config.grpc.port)
+    log.info(
+        "engine service up (grpc %s:%d, compile cache %s)",
+        config.grpc.host, config.grpc.port, cache_dir,
+    )
     try:
         svc.wait()
     except KeyboardInterrupt:

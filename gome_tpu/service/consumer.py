@@ -17,6 +17,7 @@ from __future__ import annotations
 import threading
 
 from ..bus import QueueBus, decode_orders_batch
+from ..engine.batch import is_device_fault
 from ..engine.orchestrator import MatchEngine
 from ..utils.faults import FAULTS
 from ..utils.logging import get_logger
@@ -130,6 +131,10 @@ class OrderConsumer:
         self.match_seq = 0  # single-writer: the consuming thread
         self._seq_committed = 0  # single-writer: the consuming thread
         self._last_step_failed = False  # single-writer: the consuming thread
+        # repr() of the compile/lowering/device-runtime error that stopped
+        # this consumer (step_with_policy), else None. Health reports it
+        # and the watchdog does not restart past it.
+        self.device_fault: str | None = None  # single-writer: the consuming thread
         self._stop = threading.Event()
         self._life = threading.Lock()  # serializes start()/stop()
         self._thread: threading.Thread | None = None  # guarded by self._life
@@ -450,6 +455,7 @@ class OrderConsumer:
         # Consecutive failures back off (decorrelated jitter) instead of
         # busy-spinning against a dead dependency; any success resets.
         delays = None
+        self.device_fault = None  # an explicit restart is a new attempt
         while not self._stop.is_set():
             self.step_with_policy()
             if self._last_step_failed:
@@ -462,20 +468,27 @@ class OrderConsumer:
     def step_with_policy(self) -> int:
         """One consumer step with the poison-batch policy applied. Returns
         orders processed (0 on a failed or empty step). Never raises — the
-        consumer thread must survive any failure (the reference panics
-        instead; a transient bus outage must not kill matching)."""
+        consumer thread must survive any failure of its INPUT or its bus
+        (the reference panics instead; a transient bus outage must not
+        kill matching). A device fault (engine.batch.is_device_fault) is
+        neither: no order caused it and no retry or bisection cures it,
+        so it stops the consumer (device_fault set, loop ended, /healthz
+        red) with every order still queued behind the committed offset."""
         self._last_step_failed = False
         try:
             n = self.run_once()
             self._fail_count = 0
             return n
-        except Exception:  # keep consuming; reference panics instead
+        except Exception as e:  # keep consuming; reference panics instead
             # Seq rollback to the last durable commit: the replay from the
             # uncommitted offset re-publishes with IDENTICAL seqs, so any
             # double-delivery is detectable (and suppressed) downstream.
             self.match_seq = self._seq_committed
             self._last_step_failed = True
             _step_failures.inc()
+            if is_device_fault(e):
+                self._stop_on_device_fault(e)
+                return 0
             log.exception("order batch failed")
             try:
                 offset = self.bus.order_queue.committed()
@@ -493,9 +506,33 @@ class OrderConsumer:
                         self._pipe.abort()
                         self._pipe_tids.clear()
                     return self.quarantine_once()
-            except Exception:
-                log.exception("poison-batch policy step failed; will retry")
+            except Exception as e2:
+                if is_device_fault(e2):
+                    # The quarantine replay hit the device fault itself
+                    # (_bisect_apply re-raises it): same stop as above.
+                    self._stop_on_device_fault(e2)
+                else:
+                    log.exception(
+                        "poison-batch policy step failed; will retry"
+                    )
             return 0
+
+    def _stop_on_device_fault(self, exc: BaseException) -> None:
+        """End the loop on a compile/lowering/device-runtime error. The
+        failed step already rewound its own frames; abort whatever else is
+        in flight so a restarted process (or consumer) replays from a
+        consistent engine."""
+        if self._pipe is not None:
+            self._pipe.abort()
+            self._pipe_tids.clear()
+        self.device_fault = repr(exc)
+        self._stop.set()
+        log.critical(
+            "device fault — consumer STOPPED, nothing dead-lettered, "
+            "orders stay queued from offset %d",
+            self.bus.order_queue.committed(),
+            exc_info=(type(exc), exc, exc.__traceback__),
+        )
 
     def quarantine_once(self) -> int:
         """Replay the head batch isolating poison ORDERS by bisection:
@@ -547,12 +584,16 @@ class OrderConsumer:
             return True, 0
         try:
             batch = self.engine.process_columnar(orders)
-        except Exception:
+        except Exception as e:
+            if is_device_fault(e):
+                raise  # not a property of these orders: nothing to isolate
             if len(orders) == 1:
                 order = orders[0]
                 try:  # confirm determinism: transient faults retry clean
                     batch = self.engine.process_columnar(orders)
-                except Exception:
+                except Exception as e:
+                    if is_device_fault(e):
+                        raise
                     _poisoned.inc(1)
                     log.exception(
                         "dead-lettering poison order oid=%s symbol=%s",

@@ -75,7 +75,13 @@ class HealthMonitor:
         lane_pressure = len(batch.symbols) / max(batch.max_slots, 1)
         age = time.monotonic() - self._beat
         stalled = consumer_alive and order_lag > 0 and age > self.stall_after_s
-        healthy = consumer_alive and feed_alive and not stalled
+        # A consumer stopped by a compile/lowering/device-runtime error
+        # (consumer.step_with_policy): unhealthy until an operator acts.
+        device_fault = getattr(svc.consumer, "device_fault", None)
+        healthy = (
+            consumer_alive and feed_alive and not stalled
+            and device_fault is None
+        )
         from ..utils.resilience import resilience_snapshot
 
         connections = resilience_snapshot()
@@ -95,6 +101,7 @@ class HealthMonitor:
             lane_pressure=lane_pressure,
             detail={
                 "stalled": stalled,
+                "device_fault": device_fault,
                 "orders_processed": batch.stats.orders,
                 "cap_escalations": batch.stats.cap_escalations,
                 "device_calls": batch.stats.device_calls,
@@ -134,7 +141,13 @@ class Watchdog:
 
     def check_once(self) -> Health:
         h = self.monitor.check()
-        if not h.consumer_alive and self.service.consumer._thread is not None:
+        if (
+            not h.consumer_alive
+            and self.service.consumer._thread is not None
+            # A device fault is not cured by a restart: the same kernel
+            # fails to compile again. Leave it down and red.
+            and h.detail["device_fault"] is None
+        ):
             self._restart("consumer", self.service.consumer)
         if not h.feed_alive and self.service.feed._thread is not None:
             self._restart("feed", self.service.feed)

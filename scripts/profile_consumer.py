@@ -87,14 +87,11 @@ def _consumer_setup():
     """bench.py service_main's setup: pallas engine at the service
     geometry, persisted-manifest precompile, warm-until-stable frames."""
     import bench
-    from bench import (
-        _enable_jax_cache,
-        _svc_columns,
-        _svc_gateway_step,
-        _svc_warmup,
-    )
+    from bench import _svc_columns, _svc_gateway_step, _svc_warmup
 
-    _enable_jax_cache()
+    from gome_tpu.utils.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     if os.environ.get("PROF_PLATFORM"):
         import jax
 
@@ -113,17 +110,13 @@ def _consumer_setup():
         n_slots=S, max_t=32, kernel="pallas",
         dense_t_max=int(os.environ.get("SVC_DENSE_T", 8192)),
     )
-    # Load the service bench's persisted geometry manifest (same default
-    # path) so the profile sees converged shapes, not trace/compile noise.
-    geom = os.environ.get(
-        "SVC_GEOMETRY",
-        os.path.join(
-            os.environ.get("GOME_JAX_CACHE", "/root/.cache/gome_jax"),
-            f"svc_geometry_S{S}_C{CAP}_F{FRAME}.json",
-        ),
-    )
-    n_pre = engine.load_geometry(geom)
-    print(f"precompiled {n_pre} combos from {geom}", file=sys.stderr)
+    # Load the service bench's persisted geometry manifest when its path
+    # is given (SVC_GEOMETRY, as in bench.py --service) so the profile
+    # sees converged shapes, not trace/compile noise.
+    geom = os.environ.get("SVC_GEOMETRY")
+    if geom:
+        n_pre = engine.load_geometry(geom)
+        print(f"precompiled {n_pre} combos from {geom}", file=sys.stderr)
     bus = QueueBus(MemoryQueue("doOrder"), MemoryQueue("matchOrder"))
     consumer = OrderConsumer(
         engine, bus, batch_n=1, batch_wait_s=0, match_wire="frame",

@@ -37,9 +37,11 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import _enable_jax_cache, build_grids
+from bench import build_grids
 
-_enable_jax_cache()
+from gome_tpu.utils.jaxcache import enable_compile_cache
+
+enable_compile_cache()
 
 import jax
 import jax.numpy as jnp
@@ -157,9 +159,8 @@ def measured_table(dtype="int32"):
     device time from the trace events, and divides the analytic work by
     it — achieved GFLOP/s, achieved GB/s, and efficiency vs the
     machine's roofline ceiling (min(peak_flops, intensity * peak_bw);
-    set GOME_PEAK_GFLOPS / GOME_PEAK_GBPS to override the one-shot
-    calibration). Works on any backend the profiler supports, CPU
-    included."""
+    published peaks by device_kind on a TPU, a labelled calibration on
+    the CPU backend — gome_tpu.obs.profiler.machine_peaks)."""
     from gome_tpu.obs.profiler import measured_entry_report
 
     rep = measured_entry_report(

@@ -476,9 +476,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    from bench import _enable_jax_cache
+    from gome_tpu.utils.jaxcache import enable_compile_cache
 
-    _enable_jax_cache()
+    enable_compile_cache()
     args.kernel = "pallas" if jax.default_backend() == "tpu" else "scan"
 
     doc = {

@@ -2,10 +2,15 @@
 real TPU (the pytest suite runs the kernel in interpreter mode on CPU; this
 script closes the compiled-lowering gap). Run on a TPU host:
 
-    python scripts/tpu_parity_check.py [S T CAP K G]
+    python scripts/tpu_parity_check.py [S T CAP K G]    # one full-grid check
+    python scripts/tpu_parity_check.py --suite [S T CAP K G]
 
 Exit 0 on exact equality of every book leaf and every StepOutput leaf
-across chained grids of crossing flow (with cancels and market orders).
+across chained grids of crossing flow (with cancels and market orders);
+1 on a mismatch or when JAX finds no TPU; 2 on a geometry the compiled
+kernel cannot block. `--suite` defaults to the served deployment's own
+geometry (DEPLOYMENT below); chip_smoke.py runs it before it starts the
+service.
 """
 
 from __future__ import annotations
@@ -18,32 +23,52 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 
+#: The served deployment (README, chip_smoke.py): the full grid, and the
+#: dense (R, T, CAP) shapes its flow reaches — the hot lane's deep 8-row
+#: grids at the storage cap and at the escalated classes (512, 1024), the
+#: service bench's dense depth (8192), and a 128-lane-blocked dense grid.
+DEPLOYMENT = dict(S=10240, T=32, CAP=256, K=16, G=2)
+DEPLOYMENT_DENSE = (
+    (8, 1024, 256), (8, 1024, 512), (8, 1024, 1024), (8, 8192, 256),
+    (256, 32, 64),
+)
+
+
+def _block_or_fail(rows, cap, what, log):
+    """(block_s, 0) for a check, or (None, exit code): 1 when JAX finds no
+    TPU (a compiled-kernel check that did not run is a failure, not a
+    skip), 2 when the compiled kernel cannot block the geometry."""
+    import jax.numpy as jnp
+
+    from gome_tpu.ops import kernel_plan
+
+    block_s, _interpret, reason = kernel_plan(rows, cap, jnp.int32)
+    if reason == "no_tpu_backend":
+        log(f"FAIL {what}: no TPU backend (compiled-kernel parity needs one)")
+        return None, 1
+    if block_s is None:
+        log(f"FAIL {what}: rows={rows} cap={cap} gives way to scan "
+            f"({reason}; see gome_tpu.ops.plan_block_s)")
+        return None, 2
+    return block_s, 0
+
+
 def run_parity(S=512, T=16, CAP=128, K=16, G=4, log=print) -> int:
     """Compiled-kernel vs scan parity on the current (TPU) backend.
-    Returns 0 on exact equality of every leaf, 1 on mismatch, 2 on an
-    unblockable S. Importable — bench.py gates every TPU pallas bench on
-    this before reporting numbers."""
+    Returns 0 on exact equality of every leaf, 1 on mismatch or no TPU, 2
+    on an unblockable S. Importable — bench.py gates every TPU pallas
+    bench on this before reporting numbers."""
     import jax
     import jax.numpy as jnp
 
     from gome_tpu.engine import BookConfig, batch_step, init_books
     from gome_tpu.engine.book import DeviceOp
-    from gome_tpu.ops import pallas_available, pallas_batch_step
+    from gome_tpu.ops import pallas_batch_step
 
-    if jax.default_backend() != "tpu":
-        log("SKIP: no TPU backend (compiled-kernel parity needs one)")
-        return 0
-    assert pallas_available(jnp.int32)
-
-    from gome_tpu.ops import default_block_s
-
-    block_s = default_block_s(S, CAP)
-    if block_s is None:
-        log(f"S={S} has no valid compiled-kernel blocking "
-            "(see gome_tpu.ops.default_block_s)")
-        return 2
+    block_s, rc = _block_or_fail(S, CAP, "full", log)
+    if rc:
+        return rc
     config = BookConfig(cap=CAP, max_fills=K, dtype=jnp.int32)
-    rng = np.random.default_rng(7)
 
     def grid(seed):
         r = np.random.default_rng(seed)
@@ -71,8 +96,8 @@ def run_parity(S=512, T=16, CAP=128, K=16, G=4, log=print) -> int:
             return 1
         fills = int(np.asarray(jax.device_get(o_scan.n_fills)).sum())
         log(f"grid {g}: OK ({fills} fills)")
-    log(f"PARITY OK: compiled pallas == scan on {G} grids "
-        f"({S}x{T} ops each, cancels + markets included)")
+    log(f"PARITY OK: pallas == scan on {G} grids ({S}x{T} ops each at "
+        f"cap {CAP}, block_s {block_s}, cancels + markets included)")
     return 0
 
 
@@ -93,22 +118,16 @@ def run_dense_parity(R=8, T=128, CAP=32, K=8, S=64, log=print) -> int:
     """Compiled dense gather/scatter kernel (dense_kernel_step) vs the scan
     dense path on deep time axes — the time-blocked VMEM kernel's block_t
     loop is only exercised with T >> block_t."""
-    import jax
     import jax.numpy as jnp
 
     from gome_tpu.engine import BookConfig, init_books
     from gome_tpu.engine.batch import dense_batch_step, dense_kernel_step
     from gome_tpu.engine.book import DeviceOp
-    from gome_tpu.ops import default_block_s
 
-    if jax.default_backend() != "tpu":
-        log("SKIP dense: no TPU backend")
-        return 0
+    bs, rc = _block_or_fail(R, CAP, "dense", log)
+    if rc:
+        return rc
     config = BookConfig(cap=CAP, max_fills=K, dtype=jnp.int32)
-    bs = default_block_s(R, CAP)
-    if bs is None:
-        log(f"dense: R={R} unblockable")
-        return 2
     r = np.random.default_rng(11)
     lane_ids = np.sort(r.choice(S, R, replace=False)).astype(np.int64)
 
@@ -134,26 +153,24 @@ def run_dense_parity(R=8, T=128, CAP=32, K=8, S=64, log=print) -> int:
             return 1
         if not _leaves_equal(b_scan, b_pall, f"dense grid {g} BookState", log):
             return 1
-    log(f"dense PARITY OK: compiled dense kernel == scan dense path "
-        f"({R}x{T} deep rounds, block_t covered)")
+    log(f"dense PARITY OK: dense kernel == scan dense path ({R}x{T} deep "
+        f"rounds at cap {CAP} over {S} lanes, block_s {bs})")
     return 0
 
 
 def run_edge_price_parity(S=128, T=8, CAP=32, K=8, log=print) -> int:
     """Rebased int32 prices near the +/-2^30 envelope edges (what lane
     rebasing feeds the kernel for BTC-magnitude symbols)."""
-    import jax
     import jax.numpy as jnp
 
     from gome_tpu.engine import BookConfig, batch_step, init_books
     from gome_tpu.engine.book import DeviceOp
-    from gome_tpu.ops import default_block_s, pallas_batch_step
+    from gome_tpu.ops import pallas_batch_step
 
-    if jax.default_backend() != "tpu":
-        log("SKIP edge: no TPU backend")
-        return 0
+    bs, rc = _block_or_fail(S, CAP, "edge", log)
+    if rc:
+        return rc
     config = BookConfig(cap=CAP, max_fills=K, dtype=jnp.int32)
-    bs = default_block_s(S, CAP)
     half = (1 << 30) - 1000
 
     def ops(seed, base):
@@ -189,16 +206,15 @@ def run_engine_escalation_parity(log=print) -> int:
     certified surface includes the escalation replay geometries
     (cap/max_fills doublings) and the frame fast path's rollback — not
     just the steady-state grid shape."""
-    import jax
+    import jax.numpy as jnp
 
     from gome_tpu.engine import BatchEngine, BookConfig
     from gome_tpu.oracle import OracleEngine
     from gome_tpu.types import Order, Side
 
-    if jax.default_backend() != "tpu":
-        log("SKIP escalation: no TPU backend")
-        return 0
-    import jax.numpy as jnp
+    _, rc = _block_or_fail(8, 8, "escalation", log)
+    if rc:
+        return rc
 
     orders = [
         Order(uuid="u", oid=str(i), symbol=f"s{i % 3}", side=Side.SALE,
@@ -225,6 +241,10 @@ def run_engine_escalation_parity(log=print) -> int:
         return 1
     if eng.stats.cap_escalations < 1:
         log("escalation: WARNING — stream did not escalate (geometry drift)")
+    if eng.stats.scan_giveways:
+        log(f"FAIL escalation: grids gave way to scan "
+            f"{eng.stats.scan_giveways}")
+        return 2
     eng.verify_books()
     log(f"escalation PARITY OK: compiled kernel through cap/record "
         f"escalations == oracle ({len(got)} events, "
@@ -236,12 +256,11 @@ def run_fuzz_slice(cases=2, log=print) -> int:
     """A small compiled-mode slice of the differential fuzzer's geometry
     space (the three round-1 Mosaic crashes were all found by randomized
     geometries; CI only runs interpret mode)."""
-    import jax
-
-    if jax.default_backend() != "tpu":
-        log("SKIP fuzz: no TPU backend")
-        return 0
     import jax.numpy as jnp
+
+    _, rc = _block_or_fail(8, 16, "fuzz", log)
+    if rc:
+        return rc
 
     from gome_tpu.engine import BatchEngine, BookConfig
     from gome_tpu.oracle import OracleEngine
@@ -277,29 +296,40 @@ def run_fuzz_slice(cases=2, log=print) -> int:
     return 0
 
 
-def run_suite(S=128, T=8, CAP=256, K=16, G=2, log=print) -> int:
-    """The full certification the bench gates on: every code path _step can
-    select on TPU — full grids (incl. cancels + markets), dense deep
-    rounds (block_t), envelope-edge prices, escalation replays, and a
-    compiled-mode fuzz slice."""
-    for fn in (
+def run_suite(S=128, T=8, CAP=256, K=16, G=2, dense=((8, 128, 32),),
+              log=print) -> int:
+    """The full certification the bench and chip_smoke.py gate on: every
+    code path _step can select on TPU — the full grid (incl. cancels +
+    markets), dense deep rounds at each (R, T, CAP) of `dense` gathered
+    from an S-lane stack (block_t covered), envelope-edge prices,
+    escalation replays, and a compiled-mode fuzz slice. Returns the first
+    non-zero code: a check that could not run (no TPU: 1, unblockable
+    geometry: 2) fails the suite exactly like a mismatch."""
+    checks = [
         lambda: run_parity(S=S, T=T, CAP=CAP, K=K, G=G, log=log),
-        lambda: run_dense_parity(log=log),
+        *(
+            lambda r=r, t=t, cap=cap: run_dense_parity(
+                R=r, T=t, CAP=cap, K=K, S=max(S, r), log=log
+            )
+            for r, t, cap in dense
+        ),
         lambda: run_edge_price_parity(CAP=min(CAP, 32), log=log),
         lambda: run_engine_escalation_parity(log=log),
         lambda: run_fuzz_slice(log=log),
-    ):
+    ]
+    for fn in checks:
         rc = fn()
-        if rc == 1:
-            return 1
+        if rc != 0:
+            return rc
     return 0
 
 
 def main():
-    args = [int(a) for a in sys.argv[1:6] if not a.startswith("--")]
-    S, T, CAP, K, G = args + [512, 16, 128, 16, 4][len(args):]
+    args = [int(a) for a in sys.argv[1:] if not a.startswith("--")][:5]
     if "--suite" in sys.argv or not args:
-        return run_suite(S=128, T=8, CAP=CAP, K=K, G=G)
+        geo = dict(zip(("S", "T", "CAP", "K", "G"), args))
+        return run_suite(**{**DEPLOYMENT, **geo}, dense=DEPLOYMENT_DENSE)
+    S, T, CAP, K, G = args + [512, 16, 128, 16, 4][len(args):]
     return run_parity(S, T, CAP, K, G)
 
 

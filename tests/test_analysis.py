@@ -175,7 +175,7 @@ def test_envelope_flags_float_and_width_creep():
     f32 = jax.make_jaxpr(lambda v: v.astype(jnp.float32) * 2.5)(x)
     assert rules_of(check_jaxpr(f32, "int32", "fixture")) == ["GL202"]
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         i64 = jax.make_jaxpr(
             lambda v: v.astype(jnp.int64) + 1
         )(jnp.zeros((4,), jnp.int32))
@@ -196,7 +196,7 @@ def test_envelope_recurses_into_nested_jaxprs():
 
 
 def test_envelope_int64_engine_allows_int64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         i64 = jax.make_jaxpr(
             lambda v: v + 1
         )(jnp.zeros((4,), jnp.int64))
@@ -225,7 +225,7 @@ def test_envelope_allow_floats_still_flags_strong_f64():
     """The weak-f64 scalar exemption (jax library python literals, e.g.
     inside jax.random under x64) must not exempt STRONG float64 values
     under allow_floats."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         strong = jax.make_jaxpr(
             lambda v: v * 2.0
         )(jnp.zeros((4,), jnp.float64))

@@ -87,8 +87,9 @@ def test_walk_caps_depth_keeping_deepest_frames():
 
     stack = recurse(20)
     assert len(stack) == 4
-    # deepest frames survive the cap: the leaf is _walk's caller
-    assert all(node.endswith(":recurse") for node in stack[:-1])
+    # deepest frames survive the cap: the leaf is _walk's caller (nodes
+    # are module:qualname, so the nested function ends "<locals>.recurse")
+    assert all(node.endswith(".recurse") for node in stack)
 
 
 @pytest.mark.skipif(
