@@ -42,7 +42,7 @@ from ..obs.placement import PLACEMENT
 from ..obs.profiler import PROFILER
 from ..types import KERNELS, Action, MatchResult, Order
 from ..utils.metrics import REGISTRY
-from ..utils.trace import TRACER
+from ..utils.tracing import span
 from .book import (
     BUY,
     BookConfig,
@@ -267,7 +267,8 @@ def _dense_kernel_step_impl(
     )
     pre_counts = sub.count
     sub, outs = pallas_batch_step(
-        config, sub, ops, block_s=block_s, interpret=interpret
+        config, sub, ops, block_s=block_s, interpret=interpret,
+        grid_kind="dense",
     )
     outs = _guard_capped(outs, pre_counts, cap, ops)
     new_books = _scatter_books_cap(books, lane_ids, sub, cap)
@@ -1417,7 +1418,7 @@ class BatchEngine:
     def _one_grid_columnar(self, pending, batches):
         from .events import decode_grid_columnar
 
-        with TRACER.stage("pad_pack"):
+        with span("frame_pack"):
             ops, meta, leftover, lane_ids = self._pack_grid_vectorized(
                 pending
             )
@@ -1430,7 +1431,7 @@ class BatchEngine:
             (int(r), int(tt)): None for r, tt in zip(meta["row"], meta["t"])
         }
         outs, lane_overrides = self._run_exact(ops, contexts, lane_ids)
-        with TRACER.stage("decode"):
+        with span("frame_decode"):
             batches.append(
                 decode_grid_columnar(
                     meta, splice_outs(outs, lane_overrides)
@@ -1496,14 +1497,18 @@ class BatchEngine:
         # grids converge in a few exact replays instead of one wildly
         # oversized jump.
         while True:
-            # One stage span per attempt: dispatch + the blocking overflow
-            # fetch (the fetch drains the step, so this is the device
-            # wait); the annotation aligns it with jax.profiler traces.
-            with TRACER.stage("device_execute"):
+            # Two spans per attempt: the dispatch, then the blocking
+            # overflow fetch (the fetch drains the step, so it is the
+            # device wait: an armed TRACER records it as device_execute).
+            with span("grid_dispatch", rows=ops.action.shape[0],
+                      t=ops.action.shape[1], cap=int(cap_g),
+                      n_ops=len(contexts),
+                      grid="full" if lane_ids is None else "dense"):
                 new_books, outs = self._step(
                     books_before, ops, lane_ids, cap_g, n_ops=len(contexts)
                 )
                 self.stats.device_calls += 1
+            with span("frame_fetch"):
                 host_flags = np.asarray(jax.device_get(outs.book_overflow))
             if not host_flags.any():
                 break

@@ -48,6 +48,7 @@ from ..obs.compile_journal import JOURNAL, frame_combo_detail
 from ..obs.timeline import TIMELINE
 from ..types import Action, OrderType
 from ..utils.trace import TRACER
+from ..utils.tracing import span
 from .batch import (
     BatchEngine,
     _next_pow2,
@@ -484,7 +485,7 @@ def apply_frame(eng: BatchEngine, cols: dict):
     orders. Caller guarantees admission was already applied."""
     from .events import decode_grid_columnar
 
-    with TRACER.stage("pad_pack"):
+    with span("frame_pack"):
         a = _frame_arrays(eng, cols)
         grids = pack_frame_grids(eng, a)
     batches = []
@@ -493,9 +494,10 @@ def apply_frame(eng: BatchEngine, cols: dict):
             (int(r), int(tt)): None for r, tt in zip(meta["row"], meta["t"])
         }
         outs, overrides = eng._run_exact(ops, contexts, lane_ids, cap_g)
-        batches.append(
-            decode_grid_columnar(meta, splice_outs(outs, overrides))
-        )
+        with span("frame_decode"):
+            batches.append(
+                decode_grid_columnar(meta, splice_outs(outs, overrides))
+            )
     # Synchronous path, nothing in flight: re-anchor count_ub exactly so
     # the grow-only ADD increments cannot drift classes upward forever.
     # Only when cap classes are live (a fetch per frame is wasted work
@@ -699,32 +701,39 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
     host-side errors; device budget trips surface at resolve_frame."""
     cp = eng._checkpoint()
     try:
-        with TRACER.stage("pad_pack"):
+        with span("frame_pack", orders=int(cols["n"])) as packed:
             a = _frame_arrays(eng, cols)
             grids = pack_frame_grids(eng, a)
-        books = eng.books
-        items = []
-        compact = None
-        n_kept = int(np.count_nonzero(a["keep"]))
-        if grids:
-            e_fills, e_cancels = _compact_sizes(
-                eng, n_kept, a["dels_total"]
-            )
-            wide = jnp.result_type(jnp.int32, eng.config.dtype)
-            fills_acc = jnp.zeros((len(_FILL_FIELDS), e_fills), wide)
-            cancels_acc = jnp.zeros((len(_CANCEL_FIELDS), e_cancels), wide)
-            totals_acc = jnp.zeros(
-                (max(_next_pow2(len(grids)), 8), 4), jnp.int32
-            )
+            books = eng.books
+            items = []
+            compact = None
+            n_kept = int(np.count_nonzero(a["keep"]))
+            if grids:
+                e_fills, e_cancels = _compact_sizes(
+                    eng, n_kept, a["dels_total"]
+                )
+                wide = jnp.result_type(jnp.int32, eng.config.dtype)
+                fills_acc = jnp.zeros((len(_FILL_FIELDS), e_fills), wide)
+                cancels_acc = jnp.zeros(
+                    (len(_CANCEL_FIELDS), e_cancels), wide
+                )
+                totals_acc = jnp.zeros(
+                    (max(_next_pow2(len(grids)), 8), 4), jnp.int32
+                )
+            packed.note(grids=len(grids))
         for g_i, (ops, meta, lane_ids, cap_g) in enumerate(grids):
             t_disp = TRACER.clock() if TRACER.enabled else 0.0
             t_disp_j = JOURNAL.clock() if JOURNAL.enabled else 0.0
-            with TRACER.annotation("grid_dispatch"):
+            n_rows, t_grid = ops.action.shape
+            with span(
+                "grid_dispatch", rows=n_rows, t=t_grid, cap=int(cap_g),
+                n_ops=len(meta["row"]),
+                grid="full" if lane_ids is None else "dense",
+            ):
                 books, outs = eng._step(
                     books, ops, lane_ids, cap_g, n_ops=len(meta["row"])
                 )
                 eng.stats.device_calls += 1
-                n_rows, t_grid = ops.action.shape
                 fills_acc, cancels_acc, totals_acc = compact_accum(
                     eng.config, outs, fills_acc, cancels_acc, totals_acc,
                     np.int32(g_i),
@@ -826,71 +835,68 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     recovery (rewind to pend.checkpoint, exact-run, resubmit anything
     submitted after); the single-frame wrapper apply_frame_fast and the
     pipelined executor (engine.pipeline.FramePipeline) both do."""
+    global FETCH_SECONDS
     if pend.compact is None:
         return _assemble(eng, pend.arrays, [])
-    global FETCH_SECONDS
-    t0 = time.perf_counter()
-    ts0 = TRACER.clock() if TRACER.enabled else 0.0
-    with TRACER.annotation("frame_fetch_totals"):
+    # One span over both phases of the fetch and the trip check between
+    # them. The totals fetch is the frame's completion barrier: blocking
+    # there drains every dispatched grid, so this IS the device-execute
+    # wait (an armed TRACER records it as that stage).
+    with span("frame_fetch", grids=len(pend.items)):
+        t0 = time.perf_counter()
         totals_dev, fills_dev, cancels_dev = pend.compact[:3]
         totals = jax.device_get(totals_dev)
         counts_max = (
             jax.device_get(pend.compact[3]) if len(pend.compact) > 3
             else None
         )
-    FETCH_SECONDS += time.perf_counter() - t0
-    if TRACER.enabled:
-        # The totals fetch is the frame's completion barrier: blocking
-        # here drains every dispatched grid, so this IS the
-        # device-execute wait. (Span clock = the tracer's, which tests
-        # may script; FETCH_SECONDS stays on perf_counter.)
-        TRACER.observe_span("device_execute", ts0, TRACER.clock())
-    g = len(pend.items)
-    nf_g = totals[:g, 0].astype(np.int64)
-    nc_g = totals[:g, 1].astype(np.int64)
-    total_f = int(nf_g.sum())
-    total_c = int(nc_g.sum())
-    # A fills-buffer overflow ratchets the grow-only floor (keyed by the
-    # FRAME's kept-op class) BEFORE the exact fallback, so the next frame
-    # fits — one slow frame per ratchet step, not a recurring tax. The
-    # totals are TRUE counts (appends past the buffer drop but the mask
-    # sums fully), so one step reaches the right size.
-    tripped = False
-    if total_f > fills_dev.shape[1]:
-        cls = eng._buf_class(pend.n_kept)
-        eng._fills_buf_floor[cls] = max(
-            eng._fills_buf_floor.get(cls, 0), _next_pow2(total_f)
+        FETCH_SECONDS += time.perf_counter() - t0
+        g = len(pend.items)
+        nf_g = totals[:g, 0].astype(np.int64)
+        nc_g = totals[:g, 1].astype(np.int64)
+        total_f = int(nf_g.sum())
+        total_c = int(nc_g.sum())
+        # A fills-buffer overflow ratchets the grow-only floor (keyed by
+        # the FRAME's kept-op class) BEFORE the exact fallback, so the
+        # next frame fits — one slow frame per ratchet step, not a
+        # recurring tax. The totals are TRUE counts (appends past the
+        # buffer drop but the mask sums fully), so one step reaches the
+        # right size.
+        tripped = False
+        if total_f > fills_dev.shape[1]:
+            cls = eng._buf_class(pend.n_kept)
+            eng._fills_buf_floor[cls] = max(
+                eng._fills_buf_floor.get(cls, 0), _next_pow2(total_f)
+            )
+            tripped = True
+        if (
+            tripped
+            or int(totals[:g, 2].sum()) > 0  # book overflow: state is wrong
+            # Records truncated: an op produced more fills than the K
+            # its grid's record arrays were emitted with.
+            or any(
+                int(totals[i, 3]) > shape[1]
+                for i, (_, shape) in enumerate(pend.items)
+            )
+            # Unreachable by construction (cancels <= the frame's DEL
+            # count, which sizes the buffer) — defensive only.
+            or total_c > cancels_dev.shape[1]
+        ):
+            raise _NeedExact()
+        # Phase 2: fetch the used prefixes (pow2-bucketed, clamped to the
+        # buffer) now the true counts are known.
+        t0 = time.perf_counter()
+        f_len = min(_next_pow2(max(total_f, 64)), int(fills_dev.shape[1]))
+        c_len = min(
+            _next_pow2(max(total_c, 64)), int(cancels_dev.shape[1])
         )
-        tripped = True
-    if (
-        tripped
-        or int(totals[:g, 2].sum()) > 0  # book overflow: state is wrong
-        # Records truncated: an op produced more fills than the K its
-        # grid's record arrays were emitted with.
-        or any(
-            int(totals[i, 3]) > shape[1]
-            for i, (_, shape) in enumerate(pend.items)
+        fills_mat = jax.device_get(
+            _prefix_slice_fn(int(fills_dev.shape[0]), f_len)(fills_dev)
         )
-        # Unreachable by construction (cancels <= the frame's DEL count,
-        # which sizes the buffer) — defensive only.
-        or total_c > cancels_dev.shape[1]
-    ):
-        raise _NeedExact()
-    # Phase 2: fetch the used prefixes (pow2-bucketed, clamped to the
-    # buffer) now the true counts are known.
-    t0 = time.perf_counter()
-    ts0 = TRACER.clock() if TRACER.enabled else 0.0
-    f_len = min(_next_pow2(max(total_f, 64)), int(fills_dev.shape[1]))
-    c_len = min(_next_pow2(max(total_c, 64)), int(cancels_dev.shape[1]))
-    fills_mat = jax.device_get(
-        _prefix_slice_fn(int(fills_dev.shape[0]), f_len)(fills_dev)
-    )
-    cancels_mat = jax.device_get(
-        _prefix_slice_fn(int(cancels_dev.shape[0]), c_len)(cancels_dev)
-    )
-    FETCH_SECONDS += time.perf_counter() - t0
-    if TRACER.enabled:
-        TRACER.observe_span("device_execute", ts0, TRACER.clock())
+        cancels_mat = jax.device_get(
+            _prefix_slice_fn(int(cancels_dev.shape[0]), c_len)(cancels_dev)
+        )
+        FETCH_SECONDS += time.perf_counter() - t0
     # Re-anchor count_ub from this frame's true post-frame counts (the
     # pipeline resolves FIFO, so extra minus THIS frame's increments is
     # exactly the still-in-flight sum; a trip above skips this and the
@@ -901,7 +907,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     off_f = np.concatenate(([0], np.cumsum(nf_g)))
     off_c = np.concatenate(([0], np.cumsum(nc_g)))
     batches = []
-    with TRACER.stage("decode"):
+    with span("frame_decode", grids=g):
         for i, (meta, shape) in enumerate(pend.items):
             fills = {
                 f: fills_mat[j, off_f[i] : off_f[i + 1]]

@@ -23,6 +23,7 @@ layer (gome_tpu.persist).
 from __future__ import annotations
 
 from ..types import Action, MatchResult, Order
+from ..utils.tracing import span
 from .batch import BatchEngine, EngineStats, is_device_fault
 from .book import BookConfig
 from .prepool import consume_batch_of, make_prepool
@@ -195,6 +196,10 @@ class MatchEngine:
         — the caller restores `consumed` (pre_pool |= consumed) if the
         batch later fails (at-least-once replay must not drop re-admitted
         ADDs)."""
+        with span("frame_admit", orders=int(cols["n"])):
+            return self._admit_frame(cols)
+
+    def _admit_frame(self, cols: dict) -> tuple[dict, set]:
         import numpy as np
 
         consume_frame = getattr(self.pre_pool, "consume_frame", None)

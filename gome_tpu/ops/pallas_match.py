@@ -220,7 +220,7 @@ def _kernel(config: BookConfig, t_block: int, *refs):
 @functools.partial(
     jax.jit,
     static_argnums=(0,),
-    static_argnames=("block_s", "interpret", "block_t"),
+    static_argnames=("block_s", "interpret", "block_t", "grid_kind"),
 )
 def pallas_batch_step(
     config: BookConfig,
@@ -229,6 +229,7 @@ def pallas_batch_step(
     block_s: int = 128,
     interpret: bool = False,
     block_t: int | None = None,
+    grid_kind: str = "full",
 ) -> tuple[BookState, StepOutput]:
     """Drop-in replacement for engine.batch.batch_step with identical
     semantics (books [S, ...], ops [S, T] -> books', outs [S, T, ...]).
@@ -240,6 +241,11 @@ def pallas_batch_step(
     stay VMEM-resident across the time sweep while op/record windows page
     in t_block-deep blocks, so VMEM cost is O(block_t) and deep time axes
     (hot-symbol dense grids, engine/batch.py) fit at any T.
+
+    grid_kind: "full" (row == lane) or "dense" (gathered live lanes), for
+    the kernel's name only: its device events in a profile read
+    match_<kind>_r<rows>_t<depth>_c<cap class>, so a trace tells the grids
+    of one frame apart.
     """
     s, t_len = ops.action.shape
     if block_t is None:
@@ -344,6 +350,7 @@ def pallas_batch_step(
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name=f"match_{grid_kind}_r{s}_t{t_len}_c{cap}",
     )
     call_args = (*rows_in, books.count, books.next_seq[:, None], op_pack)
     if interpret:

@@ -186,7 +186,8 @@ def sharded_dense_step(
             from ..ops import pallas_batch_step
 
             sub, outs = pallas_batch_step(
-                config, sub, ops, block_s=block, interpret=interpret
+                config, sub, ops, block_s=block, interpret=interpret,
+                grid_kind="dense",
             )
         else:
             sub, outs = jax.vmap(

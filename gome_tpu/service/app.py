@@ -20,6 +20,7 @@ import os
 from ..bus import make_bus
 from ..config import Config
 from ..engine.orchestrator import MatchEngine
+from ..utils import tracing
 from ..utils.logging import configure as configure_logging, get_logger
 from .consumer import OrderConsumer
 from .gateway import OrderGateway, serve_gateway
@@ -260,6 +261,9 @@ class EngineService:
         endpoint when configured); returns self."""
         if self.persist is not None:
             self.persist.restore_latest()
+        # The slow-span lines' absolute counters (throttling, context
+        # switches, CPU time) at a known instant.
+        tracing.log_baseline()
         self._server = serve_gateway(self.gateway, self.config)
         self.consumer.start()
         self.feed.start()
@@ -285,6 +289,7 @@ class EngineService:
             self._server = None
         self.consumer.stop()
         self.feed.stop()
+        tracing.log_totals()
         if self.ops is not None:
             self.ops.stop()
             if self.config.ops.timeline:

@@ -24,7 +24,7 @@ from ..utils.logging import get_logger
 from ..utils.metrics import REGISTRY
 from ..utils.resilience import BackoffPolicy, backoff_delays
 from ..utils.trace import TRACER, decode_context
-from ..utils.tracing import annotate
+from ..utils.tracing import annotate, poll_span, span
 
 log = get_logger("consumer")
 
@@ -122,6 +122,8 @@ class OrderConsumer:
         # queue offset (pipelined mode publishes/completes at resolve
         # time, which can be several steps after the feed).
         self._pipe_tids: dict[int, list] = {}
+        # One consumer_poll span over consecutive empty polls.
+        self._poll = poll_span("consumer_poll")  # single-writer: the consuming thread
         # Matchfeed sequence numbers (ISSUE 11 exactly-once): match_seq is
         # the next seq to stamp — monotonic per book epoch, advanced by
         # _publish. _seq_committed is its value at the last durable
@@ -230,7 +232,9 @@ class OrderConsumer:
         """Drain one micro-batch; returns the number of orders processed."""
         if self.pipeline_depth > 0:
             return self._run_once_pipelined()
-        msgs = self.bus.order_queue.poll_batch(self.batch_n, self.batch_wait_s)
+        msgs = self._poll(
+            self.bus.order_queue.poll_batch, self.batch_n, self.batch_wait_s
+        )
         if not msgs:
             return 0
         from ..bus.colwire import decode_order_frame, is_frame
@@ -246,14 +250,15 @@ class OrderConsumer:
             while i < len(msgs):
                 FAULTS.fire("consumer.frame")
                 if is_frame(msgs[i].body):
-                    with annotate("engine_process_frame"):
+                    with span("frame_unpack"):
                         cols = decode_order_frame(msgs[i].body)
                         tids = self._consume_traces(cols, msgs[i].headers)
-                        with TRACER.batch(tids):
-                            batch = self.engine.process_frame(cols)
-                        count = int(cols["n"])
-                    with annotate("publish_events"), TRACER.batch(tids), \
-                            TRACER.span("publish"):
+                    with annotate("engine_process_frame"), \
+                            TRACER.batch(tids):
+                        batch = self.engine.process_frame(cols)
+                    count = int(cols["n"])
+                    with TRACER.batch(tids), \
+                            span("publish_events", events=len(batch)):
                         self._publish(batch)
                     done_tids += tids
                     n_orders += count
@@ -295,13 +300,12 @@ class OrderConsumer:
         j = i
         while j < len(msgs) and not is_frame(msgs[j].body):
             j += 1
-        with annotate("decode_orders"):
+        with span("decode_orders", orders=j - i):  # frame_unpack's JSON twin
             orders = decode_orders_batch([m.body for m in msgs[i:j]])
-        tids = self._json_traces(orders, msgs[i:j])
+            tids = self._json_traces(orders, msgs[i:j])
         with annotate("engine_process"), TRACER.batch(tids):
             batch = self.engine.process_columnar(orders)
-        with annotate("publish_events"), TRACER.batch(tids), \
-                TRACER.span("publish"):
+        with TRACER.batch(tids), span("publish_events", events=len(batch)):
             self._publish(batch)
         return j, len(orders), len(batch), tids
 
@@ -315,8 +319,7 @@ class OrderConsumer:
         (a consistent cut)."""
         offset, n = token
         tids = self._pipe_tids.pop(offset, None) or []
-        with annotate("publish_events"), TRACER.batch(tids), \
-                TRACER.span("publish"):
+        with TRACER.batch(tids), span("publish_events", events=len(batch)):
             self._publish(batch)
         FAULTS.fire("consumer.commit")
         self.bus.order_queue.commit(offset + 1)
@@ -357,13 +360,18 @@ class OrderConsumer:
         n_orders = 0
         try:
             if len(pipe) == 0:
-                msgs = q.poll_batch(self.batch_n, self.batch_wait_s)
+                msgs = self._poll(
+                    q.poll_batch, self.batch_n, self.batch_wait_s
+                )
                 if not msgs:
                     return 0
             else:
                 # Read cursor: committed offset + one message per in-flight
                 # frame (only whole ORDER-frame messages stay in flight).
-                msgs = q.read_from(q.committed() + len(pipe), self.batch_n)
+                msgs = self._poll(
+                    q.read_from, q.committed() + len(pipe), self.batch_n
+                )
+                self._poll.close()  # empty or not, the oldest frame resolves
             with _batch_latency.time() as timer:
                 if not msgs:
                     # Queue idle: make progress on the in-flight span.
@@ -375,10 +383,11 @@ class OrderConsumer:
                     FAULTS.fire("consumer.frame")
                     m = msgs[i]
                     if is_frame(m.body):
-                        cols = decode_order_frame(m.body)
-                        tids = self._consume_traces(cols, m.headers)
-                        if tids:
-                            self._pipe_tids[m.offset] = tids
+                        with span("frame_unpack"):
+                            cols = decode_order_frame(m.body)
+                            tids = self._consume_traces(cols, m.headers)
+                            if tids:
+                                self._pipe_tids[m.offset] = tids
                         with annotate("pipeline_feed"), TRACER.batch(tids):
                             resolved = pipe.feed(
                                 cols, token=(m.offset, int(cols["n"]))
@@ -464,6 +473,7 @@ class OrderConsumer:
                 self._stop.wait(next(delays, FAULT_BACKOFF.max_s))
             else:
                 delays = None
+        self._poll.close()
 
     def step_with_policy(self) -> int:
         """One consumer step with the poison-batch policy applied. Returns

@@ -35,6 +35,7 @@ from ..types import Action, Order, OrderType, Side
 from ..utils.faults import FAULTS
 from ..utils.logging import get_logger
 from ..utils.trace import TRACER
+from ..utils.tracing import span
 
 log = get_logger("gateway")
 
@@ -581,6 +582,16 @@ class OrderGateway:
         """Amortized ingest: many reference-shaped OrderRequests in one
         RPC, applied in list order (same-batch ADD->DEL sequencing is
         preserved; `cancel[i]` selects DeleteOrder semantics)."""
+        # One span per request: admission verdict, columnar apply, emit
+        # to the bus.
+        with span("gateway_admit", orders=len(request.orders)) as admit:
+            resp = self._do_order_batch(request, context)
+            admit.note(accepted=resp.accepted)
+        return resp
+
+    def _do_order_batch(
+        self, request: pb.OrderBatchRequest, context
+    ) -> pb.OrderBatchResponse:
         n = len(request.orders)
         if request.cancel and len(request.cancel) != n:
             return pb.OrderBatchResponse(

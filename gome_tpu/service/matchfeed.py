@@ -16,6 +16,7 @@ from ..fixed import unscale
 from ..types import MatchResult, OrderSnapshot
 from ..utils.logging import get_logger
 from ..utils.metrics import REGISTRY
+from ..utils.tracing import annotate, poll_span, span
 
 log = get_logger("matchfeed")
 
@@ -119,41 +120,60 @@ class MatchFeed:
         # (a gap after recovery is a durability bug, never expected).
         self.seq = SeqTracker()
         self.suppressed = 0  # single-writer: the feed thread (run_once)
+        self._poll = poll_span("feed_poll")  # owned by the feed thread
 
     def run_once(self) -> int:
-        msgs = self.bus.match_queue.poll_batch(256, 0.002)
+        msgs = self._poll(self.bus.match_queue.poll_batch, 256, 0.002)
         if not msgs:
             return 0
         from ..bus.colwire import decode_event_frame, is_frame
 
-        with self._lock:
-            subs = list(self._subs)
-        for m in msgs:
-            if is_frame(m.body):
-                # Binary EVENT frame (bus.colwire): one message = a whole
-                # batch of MatchResults.
-                results = decode_event_frame(m.body).to_results()
-            else:
-                results = [decode_match_result(m.body)]
-            for mr in results:
-                if mr.seq is not None and not self.seq.observe(mr.seq):
-                    self.suppressed += 1
-                    continue
-                self.events_seen += 1
-                if self.log_events:
-                    # rabbitmq.go:170's util.Info.Printf of the result
-                    log.info(
-                        "match %s: taker=%s maker=%s qty=%d",
-                        "CANCEL" if mr.is_cancel else "FILL",
-                        mr.node.oid,
-                        mr.match_node.oid,
-                        mr.match_volume,
-                    )
-                ev = match_result_to_pb(mr)
-                for q in subs:
-                    q.put(ev)
-        self.bus.match_queue.commit(msgs[-1].offset + 1)
+        with annotate("feed_run_once"):
+            with self._lock:
+                subs = list(self._subs)
+            i = 0
+            while i < len(msgs):
+                # One decode and one fan-out span per EVENT frame (one
+                # message = a whole batch of MatchResults, bus.colwire) or
+                # per run of JSON messages (one event each): never a span
+                # per event.
+                j = i + 1
+                with span("feed_decode"):
+                    if is_frame(msgs[i].body):
+                        results = decode_event_frame(
+                            msgs[i].body
+                        ).to_results()
+                    else:
+                        while j < len(msgs) and not is_frame(msgs[j].body):
+                            j += 1
+                        results = [
+                            decode_match_result(m.body) for m in msgs[i:j]
+                        ]
+                with span("feed_fanout", events=len(results),
+                          subscribers=len(subs)):
+                    self._fan_out(results, subs)
+                i = j
+            self.bus.match_queue.commit(msgs[-1].offset + 1)
         return len(msgs)
+
+    def _fan_out(self, results, subs) -> None:
+        for mr in results:
+            if mr.seq is not None and not self.seq.observe(mr.seq):
+                self.suppressed += 1
+                continue
+            self.events_seen += 1
+            if self.log_events:
+                # rabbitmq.go:170's util.Info.Printf of the result
+                log.info(
+                    "match %s: taker=%s maker=%s qty=%d",
+                    "CANCEL" if mr.is_cancel else "FILL",
+                    mr.node.oid,
+                    mr.match_node.oid,
+                    mr.match_volume,
+                )
+            ev = match_result_to_pb(mr)
+            for q in subs:
+                q.put(ev)
 
     def drain(self) -> int:
         total = 0
@@ -177,9 +197,16 @@ class MatchFeed:
                 if context is not None and not context.is_active():
                     return
                 try:
-                    yield q.get(timeout=0.1)
+                    ev = q.get_nowait()
                 except queue.Empty:
-                    continue
+                    # Only an empty queue opens a span: time outside
+                    # stream_wait is gRPC serialising and sending.
+                    with span("stream_wait"):
+                        try:
+                            ev = q.get(timeout=0.1)
+                        except queue.Empty:
+                            continue
+                yield ev
         finally:
             with self._lock:
                 self._subs.remove(q)
@@ -214,6 +241,7 @@ class MatchFeed:
                 if delays is None:
                     delays = backoff_delays(FAULT_BACKOFF)
                 self._stop.wait(next(delays, FAULT_BACKOFF.max_s))
+        self._poll.close()
 
     def stop(self) -> None:
         # The feed loop never takes _life, so joining under it cannot

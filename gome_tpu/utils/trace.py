@@ -33,10 +33,17 @@ Three cooperating pieces, all dependency-free:
     ops endpoint's ``/trace``.
 
 Hot-path contract: with no recorder installed (the default) every hook is
-a shared no-op — `Tracer.span`/`stage` return a module-level singleton
-context manager and `new_trace` returns None, so the frame hot path pays
-one attribute check and ZERO allocations (asserted by the no-op-recorder
-guard in tests/test_trace.py).
+a shared no-op — `Tracer.span` returns a module-level singleton context
+manager and `new_trace` returns None, so the frame hot path pays one
+attribute check and ZERO allocations (asserted by the no-op-recorder guard
+in tests/test_trace.py).
+
+The tracer keeps what is per ORDER: ids, wire contexts, journeys, and the
+ingress/enqueue/batch_wait/bus_transit spans. The frame-scoped stages
+(pad_pack, device_execute, decode, publish) are timed by
+`utils.tracing.span`, which runs whether or not a recorder is installed and
+hands each closed span to `observe_span` here when one is
+(`utils.tracing.STAGE_OF_SPAN` names the stage each span feeds).
 """
 
 from __future__ import annotations
@@ -275,27 +282,6 @@ class _Span:
         return False
 
 
-class _AnnotatedSpan(_Span):
-    """_Span + a jax.profiler.TraceAnnotation over the same interval, so
-    the host-side stage span lands on the device trace timeline too
-    (utils.tracing.annotate; jax.profiler.trace captures both)."""
-
-    __slots__ = ("_ann",)
-
-    def __enter__(self):
-        from .tracing import annotate
-
-        self._ann = annotate(f"gome:{self.stage}")
-        self._ann.__enter__()
-        return super().__enter__()
-
-    def __exit__(self, exc_type, exc, tb):
-        try:
-            self._ann.__exit__(exc_type, exc, tb)
-        finally:
-            return super().__exit__(exc_type, exc, tb)
-
-
 class _Batch:
     """Context manager attaching a set of trace ids to every batch-scoped
     span closed inside it (thread-local: the consumer thread owns its
@@ -438,24 +424,6 @@ class Tracer:
         if self.recorder is None:
             return NOOP_SPAN
         return _Span(self, stage, trace_id)
-
-    def stage(self, stage: str, trace_id: str | None = None):
-        """span() + jax.profiler TraceAnnotation (host/device timeline
-        alignment) — for stages bracketing device work."""
-        if self.recorder is None:
-            return NOOP_SPAN
-        return _AnnotatedSpan(self, stage, trace_id)
-
-    def annotation(self, name: str):
-        """Bare jax.profiler TraceAnnotation gated on the tracer (no
-        histogram) — for regions whose stage label is only known after
-        the fact (compile miss vs hit: the shape-combo key needs the
-        dispatched outputs' shapes)."""
-        if self.recorder is None:
-            return NOOP_SPAN
-        from .tracing import annotate
-
-        return annotate(f"gome:{name}")
 
     def batch(self, trace_ids):
         """Attach `trace_ids` to batch-scoped spans closed inside the
