@@ -58,8 +58,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CHIP = dict(symbols=10240, cap=256, max_fills=16, max_t=32,
             orders=240_000, batches=6)
 #: --rehearsal defaults: same code, toy sizes (interpret mode is slow).
+#: Requests of 94 orders, so that the first (94 of the listing's 128
+#: symbols) takes the full grid and the rest, live on under half the
+#: lanes, take dense ones: at 128 lanes a full grid may run 1,024 deep
+#: and leaves no dense tail, as the chip's 10,240-lane one (64 deep) does.
 REHEARSAL = dict(symbols=128, cap=16, max_fills=4, max_t=8,
-                 orders=6_000, batches=3)
+                 orders=6_000, batches=64)
 
 CONFIG_YAML = """\
 grpc: {{host: 127.0.0.1, port: 0}}

@@ -753,10 +753,12 @@ def extract_universe() -> dict:
             "deployments); 8 = the Pallas sublane floor"),
         "t_grid": _pow_dim(
             "pow2", 8, t_cap,
-            "_pack_class_train: _next_pow2(need) clamped to [t_floor, "
-            "cap_t]; tail grids snap to {max_t, 8*max_t, cap_t//4, "
-            "cap_t}; full grid = max_t; cap_t <= "
-            "_next_pow2(max(dense_t_max, max_t))"),
+            "BatchEngine._grid_depth: first dense grid = "
+            "_next_pow2(need) clamped to [t_floor, cap_t]; dense tail "
+            "grids and every full grid snap to {max_t, 8*max_t, "
+            "cap_t//4, cap_t} (full grid: no class under max_t, no "
+            "floor); cap_t <= _next_pow2(max(dense_t_max, max_t)), "
+            "row-budgeted by _REC_ELEM_BUDGET // (n_rows * max_fills)"),
         "cap_g": _pow_dim(
             "pow2", 1, max_cap,
             "_cap_ladder: pow4 classes from CAP_CLASS_MIN plus the "
@@ -764,7 +766,7 @@ def extract_universe() -> dict:
         "dense": dict(
             kind="enum", values=[False, True], cardinality=2,
             generator="lane_ids is not None — compact gather/scatter "
-                      "grid vs the full [n_slots, max_t] grid"),
+                      "grid vs the full [n_slots, t_grid] grid"),
         "m_pad": _pow_dim(
             "pow4", 64, pow4_ceil(max_ops),
             "_next_pow4(max(m, 64)) of the grid's packed-op count, "

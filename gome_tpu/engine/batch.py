@@ -203,9 +203,10 @@ def _dense_batch_step_impl(
     symbols) leaves most of a full [S, T] grid as NOP padding — the device
     would spend >99% of its work stepping idle books. This step instead
     gathers the R live lanes' books into a dense [R, ...] sub-stack, scans
-    a compact [R, T] op grid (T can be much deeper than the full-grid
-    max_t, amortizing dispatch for hot symbols — the config 1-2 latency
-    path), and scatters the sub-stack back. Cost: one O(S) copy for the
+    a compact [R, T] op grid (T can be much deeper than max_t,
+    amortizing dispatch for hot symbols — the config 1-2 latency path;
+    depth is BatchEngine._grid_depth's, for full grids too), and scatters
+    the sub-stack back. Cost: one O(S) copy for the
     scatter (XLA preserves the un-donated input) plus O(R·T) matching work,
     vs O(S·T) matching work for the full grid.
 
@@ -426,6 +427,13 @@ def _scatter_books_cap(books: BookState, lane_ids, sub: BookState, cap: int):
     )
 
 
+#: Per-grid record-tensor element budget (T*K*R per record array; 5 record
+#: arrays x 4 B => 16M elements ~ 320 MB of step outputs). Bounds the
+#: rows-x-depth product of every grid (BatchEngine._grid_depth), so deep
+#: time axes are reserved for few-row grids.
+_REC_ELEM_BUDGET = 1 << 24
+
+
 # gomesurface: quantizer
 def _next_pow4(n: int) -> int:
     """Coarser shape bucket for a frame's train grids: every distinct
@@ -579,10 +587,17 @@ class BatchEngine:
 
         dense: allow the columnar path to pack batches touching few symbols
         into compact gather/scatter grids over just the live lanes
-        (dense_batch_step) instead of the full [n_slots, max_t] grid —
+        (dense_batch_step) instead of the full n_slots-row grid —
         throughput then tracks APPLIED ops, not provisioned lanes (Zipf
-        flows), and a hot symbol's stream can run dense_t_max deep per
-        device call (the single-symbol latency path). Semantics identical.
+        flows). Semantics identical. It decides rows only.
+
+        max_t / dense_t_max: the shallowest and the deepest time axis of a
+        columnar or frame grid. Depth is chosen per grid, dense or full,
+        from its row count and its deepest lane (_grid_depth): a hot
+        symbol's stream runs dense_t_max deep per device call where the
+        rows allow it (the single-symbol latency path), and max_t is the
+        depth of a grid whose lanes all fit it — and of every grid of the
+        per-order object path (process / _pack_grid).
 
         mesh: an optional 1-D jax.sharding.Mesh (gome_tpu.parallel.make_mesh)
         partitioning the symbol-lane axis across chips. Matching needs zero
@@ -949,13 +964,17 @@ class BatchEngine:
 
     def _grid_geometry(self, live: np.ndarray, first: bool = True,
                        cls: int | None = None):
-        """Grid geometry decision, shared by the object packer and the
-        frame path (engine.frames): when the batch touches few of the
-        provisioned lanes, pack a compact grid over just the live lanes
-        (row -> lane indirection, executed by dense_batch_step /
-        parallel.mesh.sharded_dense_step); rows bucket to powers of two
-        (min 8 — the Pallas kernel's sublane floor; sentinel padding rows
-        are free) to bound compile shapes.
+        """The ROWS of a grid, shared by the object packer and the frame
+        path (engine.frames). A grid's geometry is two independent
+        decisions: this one (which lanes get a row, and whether rows are
+        indirected) and _grid_depth (how long the time axis is). When the
+        batch touches few of the provisioned lanes, pack a compact grid
+        over just the live lanes (row -> lane indirection, executed by
+        dense_batch_step / parallel.mesh.sharded_dense_step); rows bucket
+        to powers of two (min 8 — the Pallas kernel's sublane floor;
+        sentinel padding rows are free) to bound compile shapes. Once the
+        row bucket reaches n_slots the gather buys nothing and the grid is
+        the full one, row == lane — which says nothing about its depth.
 
         `first` marks the first dense grid of a frame's train. Only it
         consults/advances the grow-only row ratchet: the train's DEEPER
@@ -1036,6 +1055,69 @@ class BatchEngine:
         # and per-shard MAX bucketing under a mesh) costs THIS dispatch.
         _rows_per_live_lane.observe(n_rows / len(live))
         return True, n_rows, lane_ids, row_of
+
+    # gomesurface: quantizer
+    def _grid_depth(self, n_rows: int, need: int, cls: int, first: bool,
+                    dense: bool) -> int:
+        """The DEPTH (time-axis length) of a grid, for either kind of row
+        layout _grid_geometry chose and for both packers: from the grid's
+        row count and the deepest lane it has to carry (`need` ops).
+
+        Depth is budgeted against rows: the step's record tensors are
+        [T, K, R], so a wide grid must stay shallow (2048 rows x 8192 deep
+        x K=16 is a 10+ GB allocation) while a few-row grid can run
+        dense_t_max deep — cap_t below. A full grid of 10,240 lanes at
+        K=16 is held to 64 by that arithmetic, one of 8 lanes gets the
+        ceiling. Under a mesh a full grid's rows are split evenly, so its
+        budget is per chip.
+
+        A dense train's FIRST grid takes _next_pow2(need) over a grow-only
+        floor keyed by cap class `cls` (like the row ratchet: a hot lane's
+        depth hovering at a pow2 boundary must not flip the compiled shape
+        frame to frame). Dense TAIL grids snap to four fixed classes
+        (shallow / 8x-shallow / quarter-ceiling / ceiling): every distinct
+        (rows, depth) is a compiled shape, and per-frame depth noise would
+        otherwise keep minting buckets for the life of the process (~1 s
+        of host re-trace each); the 8x class plugs the geometric hole
+        between max_t and cap_t//4, so padding stays <= 8x.
+
+        A FULL grid's rows are fixed at n_slots, so depth is its only free
+        dimension: it takes the smallest of the same fixed classes that
+        covers `need` and is not shallower than max_t, first grid and tails
+        alike, with NO floor — there is no second dimension for a floor to
+        steady, and one would pad every later small frame to the deepest
+        frame the process ever saw. max_t is the shallowest class: a full
+        grid whose lanes all fit it has the shape it always had."""
+        if not dense and self.mesh is not None:
+            n_rows = n_rows // self.mesh.size
+        t_mem = max(
+            self.max_t,
+            _next_pow2(
+                _REC_ELEM_BUDGET // max(n_rows * self.config.max_fills, 1)
+                + 1
+            )
+            // 2,
+        )
+        cap_t = max(8, min(max(self.dense_t_max, self.max_t), t_mem))
+        if dense and first:
+            t_floor = self._dense_t_floor.get(cls, 8)
+            t_grid = min(max(_next_pow2(need), t_floor), cap_t)
+            # Grow-only; a mem-clamped wide grid leaves the floor for
+            # future narrower (deeper-capable) first grids.
+            self._dense_t_floor[cls] = max(t_floor, t_grid)
+            return t_grid
+        # cap_t >= max(8, max_t), so the shallowest class needs no clamp.
+        classes = sorted({
+            max(8, self.max_t) if dense else self.max_t,
+            min(max(8, 8 * self.max_t), cap_t),
+            min(max(8, cap_t // 4), cap_t),
+            cap_t,
+        })
+        if not dense:
+            # Where the row budget clamps cap_t//4 under max_t (10,240
+            # rows: cap_t 64, quarter 16) that class drops out.
+            classes = [c for c in classes if c >= self.max_t]
+        return next((c for c in classes if c >= min(need, cap_t)), cap_t)
 
     def _admit_lane_range(self, lane: int, l: int, h: int) -> None:
         """Admit the ADD-limit price range [l, h] into `lane`'s grow-only
@@ -1316,31 +1398,11 @@ class BatchEngine:
             else np.zeros(0, np.int64)
         )
         use_dense, n_rows, lane_ids, row_of = self._grid_geometry(live)
-        if use_dense:
-            row = row_of[lanes]
-            from .frames import _REC_ELEM_BUDGET
-
-            # Depth budgeted against rows (record tensors are [T, K, R];
-            # see frames.pack_frame_grids for the rationale).
-            t_mem = max(
-                self.max_t,
-                _next_pow2(
-                    _REC_ELEM_BUDGET
-                    // max(n_rows * self.config.max_fills, 1)
-                    + 1
-                )
-                // 2,
-            )
-            t_floor = self._dense_t_floor.get(self.config.cap, 8)
-            t_grid = min(
-                max(_next_pow2(max(level.values())), t_floor),
-                max(self.dense_t_max, self.max_t),
-                t_mem,
-            )
-            self._dense_t_floor[self.config.cap] = max(t_floor, t_grid)
-        else:
-            row = lanes
-            t_grid = self.max_t
+        row = row_of[lanes] if use_dense else lanes
+        t_grid = self._grid_depth(
+            n_rows, max(level.values(), default=0), self.config.cap, True,
+            use_dense,
+        )
         packed = (t >= 0) & (t < t_grid)
 
         oids, uids = self.oids, self.uids

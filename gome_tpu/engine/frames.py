@@ -70,12 +70,6 @@ MARKET = int(OrderType.MARKET)
 
 _GRID_FIELDS = DeviceOp._fields  # one canonical field list + order
 
-#: Per-grid record-tensor element budget (T*K*R per record array; 5 record
-#: arrays x 4 B => 16M elements ~ 320 MB of step outputs). Bounds the
-#: rows-x-depth product of dense grids so deep time axes are reserved for
-#: few-row (hot-lane) grids.
-_REC_ELEM_BUDGET = 1 << 24
-
 #: Hard per-frame op ceiling (wire contract, enforced in _frame_arrays).
 #: This is what makes the m_pad / e_fills / e_cancels / totals_len combo
 #: dimensions FINITE: every one of them is a quantized function of the
@@ -322,7 +316,15 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
                       cap_g: int, grids: list) -> None:
     """Pack one cap class's grid train (the loop body of the original
     single-train pack_frame_grids, with geometry ratchets keyed by the
-    class)."""
+    class). Each grid's geometry is two decisions made apart, both on
+    BatchEngine so the object packer makes them the same way:
+    _grid_geometry picks the ROWS (a dense grid over the live lanes, or
+    the full grid with row == lane once the row bucket reaches n_slots)
+    and _grid_depth picks the DEPTH from the row count and the deepest
+    lane still to carry, whichever kind the rows are. So a venue
+    provisioned with exactly its live lanes runs a hot lane's frame as a
+    couple of deep full grids, not as a train of max_t-deep ones; max_t
+    is only the shallowest depth class."""
     lanes, t = a["lanes"], a["t"]
     t_off = 0
     while len(active_idx):
@@ -331,57 +333,12 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
         use_dense, n_rows, lane_ids, row_of = eng._grid_geometry(
             live, first=first, cls=cap_g
         )
-        if use_dense:
-            # Depth ratchet, like the row bucket in _grid_geometry — and
-            # like it, only the train's FIRST dense grid consults or
-            # advances the floor (a deep floor would stretch every small
-            # tail grid to the full depth; see _grid_geometry). Depth is
-            # additionally budgeted against the grid's ROW count: the
-            # step's record tensors are [T, K, R], so a wide grid must
-            # stay shallow (2048 rows x 8192 deep x K=16 is a 10+ GB
-            # allocation) while a few-row hot-lane tail can run
-            # dense_t_max deep — the same rows-vs-depth trade the device
-            # bench's packer applies.
-            t_mem = max(
-                eng.max_t,
-                _next_pow2(
-                    _REC_ELEM_BUDGET
-                    // max(n_rows * eng.config.max_fills, 1)
-                    + 1
-                )
-                // 2,
-            )
-            cap_t = max(8, min(max(eng.dense_t_max, eng.max_t), t_mem))
-            need = int(t_sub.max()) - t_off + 1
-            if first:
-                t_floor = eng._dense_t_floor.get(cap_g, 8)
-                t_grid = min(max(_next_pow2(need), t_floor), cap_t)
-                # Grow-only; a mem-clamped wide grid leaves the floor for
-                # future narrower (deeper-capable) first grids.
-                eng._dense_t_floor[cap_g] = max(t_floor, t_grid)
-            else:
-                # Train tails snap to FOUR fixed depth classes (shallow /
-                # 8x-shallow / quarter-ceiling / ceiling): every distinct
-                # (rows, depth) is a compiled shape, and a hot lane's
-                # per-frame depth noise would otherwise keep minting new
-                # buckets for the life of the process (~1s of host
-                # re-trace each). The 8x-shallow class plugs the geometric
-                # hole between max_t and cap_t//4 (padding stays <=8x);
-                # NOP-padded steps on an 8-row tail grid are far cheaper
-                # than re-traces.
-                cands = sorted({
-                    min(max(8, eng.max_t), cap_t),
-                    min(max(8, 8 * eng.max_t), cap_t),
-                    min(max(8, cap_t // 4), cap_t),
-                    cap_t,
-                })
-                t_grid = next(
-                    (c for c in cands if c >= min(need, cap_t)), cap_t
-                )
-        else:
+        if not use_dense:
             # Full grid: row == lane (identity map).
             row_of = np.arange(eng.n_slots, dtype=np.int64)
-            t_grid = eng.max_t
+        t_grid = eng._grid_depth(
+            n_rows, int(t_sub.max()) - t_off + 1, cap_g, first, use_dense
+        )
 
         from . import nativehost
 

@@ -231,9 +231,12 @@ def test_chip_smoke_refuses_the_cpu_and_rehearses_with_the_flag(tmp_path):
     (alone / "chip_smoke.py").write_text(open(smoke).read())
     procs = {
         "rehearsal": run(
-            [smoke, "--rehearsal", "--symbols", "16", "--cap", "8",
+            # 50-order requests on 64 lanes: the listing's first takes
+            # the full grid, the later ones (live on under half the
+            # lanes) dense ones
+            [smoke, "--rehearsal", "--symbols", "64", "--cap", "8",
              "--max-fills", "4", "--max-t", "4", "--orders", "400",
-             "--batches", "2"], REPO_ROOT,
+             "--batches", "8"], REPO_ROOT,
         ),
         "no_flag": run([smoke], REPO_ROOT),
         "sized_without_flag": run([smoke, "--orders", "10"], REPO_ROOT),

@@ -5,6 +5,7 @@ full-grid path."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from gome_tpu.engine import BatchEngine, BookConfig
 from gome_tpu.oracle import OracleEngine
@@ -167,3 +168,219 @@ def test_grid_geometry_ratchets_are_grow_only():
     # Ratchet capped below n_slots: growing past it falls back to full.
     use_dense, n_rows, _, _ = eng._grid_geometry(np.arange(127, dtype=np.int64))
     assert not use_dense and n_rows == eng.n_slots
+
+
+# --- rows and depth are two decisions (full grids run deep too) -----------
+
+def _hot_lane_orders(n, seed=12, symbol="hot", oid0=0):
+    rng = np.random.default_rng(seed)
+    return [
+        Order(
+            uuid="u", oid=str(oid0 + i), symbol=symbol,
+            side=Side(int(rng.integers(0, 2))),
+            price=100 + int(rng.integers(-5, 6)),
+            volume=int(rng.integers(1, 10)),
+        )
+        for i in range(n)
+    ]
+
+
+def _run_frame(eng, orders):
+    """One ORDER frame through the served path's two calls; returns the
+    events and the (rows, depth, dense) of every grid it dispatched."""
+    from gome_tpu.bus import colwire
+    from gome_tpu.engine.frames import resolve_frame, submit_frame
+
+    cols = colwire.decode_order_frame(colwire.encode_orders(orders))
+    before = set(eng.combos())
+    pend = submit_frame(eng, cols)
+    depths = [shape[0] for _, shape in pend.items]
+    new = set(eng.combos()) - before
+    # Every grid of this engine is a full one: row == lane, no lane_ids.
+    assert all(c[0] == eng.n_slots and c[3] is False for c in new), new
+    return resolve_frame(eng, pend).to_results(), depths
+
+
+def _full_classes(eng, n_rows=None):
+    """The fixed depth classes a full grid of this engine may take."""
+    n_rows = eng.n_slots if n_rows is None else n_rows
+    return {
+        eng._grid_depth(n_rows, need, 64, first, False)
+        for need in (1, eng.max_t, eng.max_t + 1, 8 * eng.max_t + 1, 10**6)
+        for first in (True, False)
+    }
+
+
+def test_full_grid_runs_deep_when_n_slots_equals_live_lanes():
+    """An engine provisioned with exactly its live lanes (the row bucket
+    reaches n_slots, so _grid_geometry gives the full grid) still runs a
+    hot lane's 600 ops in at most 2 device calls: the depth of a grid does
+    not hang on whether its rows are indirected. Events equal the oracle's
+    and a max_t-deep, 150-call replay's."""
+    orders = _hot_lane_orders(600)
+    eng = BatchEngine(BookConfig(cap=128, max_fills=16), n_slots=8, max_t=4)
+    got, depths = _run_frame(eng, orders)
+    assert eng.stats.device_calls <= 2
+    assert depths == [1024]
+    assert got == _oracle_events(orders)
+    eng.verify_books()
+
+    shallow = BatchEngine(
+        BookConfig(cap=128, max_fills=16), n_slots=8, max_t=4, dense=False
+    )
+    replay = shallow.process(orders)
+    assert shallow.stats.device_calls == 150  # ceil(600 / max_t)
+    assert got == replay
+
+
+def test_full_grid_depth_is_held_by_the_row_budget():
+    """A full grid wide enough for the record-tensor budget to bind stays
+    at most t_mem deep — by the arithmetic the dense branch always
+    applied, not by a flag: 10,240 lanes x K=16 hold it to 64 (and the
+    quarter class, 16, drops out under max_t), 8 lanes get the ceiling."""
+    wide = BatchEngine(
+        BookConfig(cap=16, max_fills=16), n_slots=10240, max_t=32
+    )
+    assert _full_classes(wide) == {32, 64}
+    assert wide._grid_depth(10240, 32, 64, True, False) == 32
+    assert wide._grid_depth(10240, 1500, 64, True, False) == 64
+    narrow = BatchEngine(BookConfig(cap=16, max_fills=16), n_slots=8,
+                         max_t=32)
+    assert _full_classes(narrow) == {32, 256, 1024}
+
+    # End to end: 256 lanes x max_fills 256 leave t_mem = 256, so a
+    # 300-op lane takes a full grid of 256 and a tail of the quarter
+    # class, never one grid of 1,024. (Sides alternate at one price, so
+    # the book stays a few orders deep and cap 16 never escalates.)
+    orders = [
+        Order(uuid="u", oid=str(i), symbol="hot", side=Side(i % 2),
+              price=100, volume=1 + i % 3)
+        for i in range(300)
+    ]
+    eng = BatchEngine(
+        BookConfig(cap=16, max_fills=256), n_slots=256, max_t=4,
+        dense=False,
+    )
+    got, depths = _run_frame(eng, orders)
+    assert depths == [256, 64]
+    assert got == _oracle_events(orders)
+
+
+def test_full_grid_depth_has_no_floor():
+    """A small frame after a deep one returns to the max_t class: a full
+    grid's rows are fixed, so there is nothing for a grow-only floor to
+    steady, and one would pad every later tick to the deepest frame the
+    process ever saw."""
+    eng = BatchEngine(BookConfig(cap=128, max_fills=16), n_slots=8, max_t=4)
+    deep = _hot_lane_orders(600)
+    small = _hot_lane_orders(3, seed=5, oid0=10_000)
+    got_deep, d_deep = _run_frame(eng, deep)
+    got_small, d_small = _run_frame(eng, small)
+    assert d_deep == [1024] and d_small == [4]
+    assert eng.geometry_floors()["t_floor"] == {}
+    assert got_deep + got_small == _oracle_events(deep + small)
+
+
+def test_full_grid_depths_stay_in_the_fixed_classes():
+    """Over a mixed run (frames of 5 to 700 ops over 8 lanes) every full
+    grid's depth is one of the fixed classes {max_t, 8*max_t, cap_t//4,
+    cap_t}: the class depends on `need` alone, so a warm-up that has met
+    the three shapes has met them all."""
+    eng = BatchEngine(BookConfig(cap=128, max_fills=16), n_slots=8, max_t=4)
+    stream = multi_symbol_stream(
+        n=1700, n_symbols=8, seed=4, zipf_a=1.0, cancel_prob=0.2
+    )
+    seen, got, at = set(), [], 0
+    for n in (5, 40, 700, 12, 300, 3, 640):
+        evs, depths = _run_frame(eng, stream[at : at + n])
+        got.extend(evs)
+        seen.update(depths)
+        at += n
+    assert seen <= {4, 32, 256, 1024} == _full_classes(eng)
+    assert len(seen) >= 3
+    assert got == _oracle_events(stream[:at])
+    eng.verify_books()
+
+
+def _parent_dense_depth(eng, n_rows, need, cls, first):
+    """The dense branch of _pack_class_train as it stood before depth
+    became its own function, verbatim (floors in `eng._dense_t_floor`)."""
+    from gome_tpu.engine.batch import _REC_ELEM_BUDGET, _next_pow2
+
+    t_mem = max(
+        eng.max_t,
+        _next_pow2(
+            _REC_ELEM_BUDGET // max(n_rows * eng.config.max_fills, 1) + 1
+        )
+        // 2,
+    )
+    cap_t = max(8, min(max(eng.dense_t_max, eng.max_t), t_mem))
+    if first:
+        t_floor = eng._dense_t_floor.get(cls, 8)
+        t_grid = min(max(_next_pow2(need), t_floor), cap_t)
+        eng._dense_t_floor[cls] = max(t_floor, t_grid)
+        return t_grid
+    cands = sorted({
+        min(max(8, eng.max_t), cap_t),
+        min(max(8, 8 * eng.max_t), cap_t),
+        min(max(8, cap_t // 4), cap_t),
+        cap_t,
+    })
+    return next((c for c in cands if c >= min(need, cap_t)), cap_t)
+
+
+@pytest.mark.parametrize("max_t,dense_t_max,max_fills", [
+    (32, 1024, 16), (4, 1024, 16), (8, 64, 4), (32, 8192, 16), (64, 16, 8),
+])
+def test_dense_depth_rule_is_what_it_was(max_t, dense_t_max, max_fills):
+    """The dense branch chooses the depths it chose before, ratchets
+    included, on any (rows, need, class, first) sequence."""
+    def mk():
+        return BatchEngine(
+            BookConfig(cap=256, max_fills=max_fills), n_slots=16,
+            max_t=max_t, dense_t_max=dense_t_max,
+        )
+    new, old = mk(), mk()
+    rng = np.random.default_rng(max_t * 7 + max_fills)
+    for _ in range(400):
+        n_rows = 8 << int(rng.integers(0, 12))
+        need = int(rng.integers(1, 5000))
+        cls = (64, 256)[int(rng.integers(0, 2))]
+        first = bool(rng.integers(0, 2))
+        assert new._grid_depth(n_rows, need, cls, first, True) == \
+            _parent_dense_depth(old, n_rows, need, cls, first)
+        assert new._dense_t_floor == old._dense_t_floor
+
+
+def test_dense_combos_of_a_wide_zipf_flow_are_what_they_were():
+    """A spot10k-like flow (4,096 lanes, Zipf(1.3), 4,096-order frames:
+    a wide class-64 grid plus a hot-lane train with a tail) records the
+    shape combos the tree before this change recorded on the same input:
+    none of its grids is a full one, and the dense branch is untouched."""
+    from gome_tpu.bus import colwire
+    from gome_tpu.engine.frames import resolve_frame, submit_frame
+
+    orders = multi_symbol_stream(
+        n=3 * 4096, n_symbols=4096, seed=26, zipf_a=1.3, cancel_prob=0.3
+    )
+    eng = BatchEngine(
+        BookConfig(cap=256, max_fills=16, dtype=jnp.int32),
+        n_slots=4096, max_t=32,
+    )
+    for i in range(0, len(orders), 4096):
+        cols = colwire.decode_order_frame(
+            colwire.encode_orders(orders[i : i + 4096])
+        )
+        resolve_frame(eng, submit_frame(eng, cols))
+    assert eng.stats.device_calls == 9
+    assert eng.combos() == [  # recorded at commit cf9c19d, same script
+        (8, 256, 256, True, 256, 16, 4096, 1024, 8),
+        (8, 256, 256, True, 256, 16, 4096, 2048, 8),
+        (8, 1024, 256, True, 4096, 16, 4096, 1024, 8),
+        (8, 1024, 256, True, 4096, 16, 4096, 2048, 8),
+        (16, 1024, 256, True, 4096, 16, 4096, 2048, 8),
+        (1024, 128, 64, True, 4096, 16, 4096, 1024, 8),
+        (1024, 128, 64, True, 4096, 16, 4096, 2048, 8),
+    ]
+    assert eng.geometry_floors()["t_floor"] == {64: 128, 256: 1024}
+    assert eng.geometry_floors()["rows_floor"] == {64: 1024, 256: 16}

@@ -260,7 +260,11 @@ def test_dense_grids_under_mesh_match_oracle():
     Events must equal the oracle's and the sharded dense stepper must
     actually have run."""
     mesh = make_mesh(8)
-    eng = BatchEngine(CFG, n_slots=128, max_t=8, mesh=mesh)
+    # Lanes are handed out in order, so the 40 symbols crowd the first
+    # shard: r_s reaches 64 and r_s * d = 512, and 1,024 slots keep the
+    # FIRST grid dense. (At 128 slots only the tails of the then
+    # max_t-deep full grid were.)
+    eng = BatchEngine(CFG, n_slots=1024, max_t=8, mesh=mesh)
     orders = _skewed_stream(400, 40, seed=21)
     oracle = OracleEngine()
     expected = []
@@ -281,7 +285,11 @@ def test_dense_frame_path_under_mesh_matches_oracle():
     from gome_tpu.engine.frames import apply_frame_fast
 
     mesh = make_mesh(8)
-    eng = BatchEngine(CFG, n_slots=128, max_t=8, mesh=mesh)
+    # Lanes are handed out in order, so the 40 symbols crowd the first
+    # shard: r_s reaches 64 and r_s * d = 512, and 1,024 slots keep the
+    # FIRST grid dense. (At 128 slots only the tails of the then
+    # max_t-deep full grid were.)
+    eng = BatchEngine(CFG, n_slots=1024, max_t=8, mesh=mesh)
     orders = _skewed_stream(400, 40, seed=22)
     oracle = OracleEngine()
     expected = []
@@ -303,8 +311,8 @@ def test_cap_escalation_under_mesh_dense():
     re-place the stack on the mesh and the replay must stay exact."""
     mesh = make_mesh(8)
     eng = BatchEngine(
-        BookConfig(cap=8, max_fills=4), n_slots=128, max_t=8, mesh=mesh
-    )
+        BookConfig(cap=8, max_fills=4), n_slots=256, max_t=8, mesh=mesh
+    )  # 11 symbols on one shard: r_s 16 x 8 chips < 256, so dense
     from gome_tpu.types import Action, OrderType
 
     # 20 resting asks at distinct prices on one symbol (cap 8 overflows),

@@ -55,8 +55,12 @@ def _compiled_text(one_chip, n_slots, rows, t, cap, dense):
 
 
 @pytest.mark.parametrize("n_slots, rows, t, cap, dense, name", [
-    # hotpair8: 48 full [8 x 32] grids of the 1024-slot class a frame
+    # hotpair8, full grids of the 1024-slot class at its three depth
+    # classes (BatchEngine._grid_depth): a 4,096-order frame runs as two
+    # of the deepest; max_t is the shallowest
     (8, 8, 32, 1024, False, "match_full_r8_t32_c1024"),
+    (8, 8, 256, 1024, False, "match_full_r8_t256_c1024"),
+    (8, 8, 1024, 1024, False, "match_full_r8_t1024_c1024"),
     # spot10k: the wide class-64 dense grid over the live lanes
     (10240, 2048, 256, 64, True, "match_dense_r2048_t256_c64"),
 ])
