@@ -21,7 +21,9 @@ serves the newest order of a level first (time priority broken).
 
 The generator (stream.py) keeps one Book per symbol in its loop, so a stream's
 expected events come with the stream; `run` replays a finished stream, which is
-how the control is computed.
+how the control is computed. A venue with other rules brings a Book of its own
+in configs/<name>_reference.py (a subclass, as a rule); the generator's loop and
+`run(..., Book=...)` then keep that one.
 """
 
 from __future__ import annotations
@@ -125,10 +127,11 @@ class Book:
         return False
 
 
-def run(cols: dict, priority: str = "fifo") -> list:
-    """Events of the stream columns (sym, uid, oid, side, kind, cancel, price,
-    volume: sequences of equal length), processed in order."""
-    books: dict[int, Book] = {}
+def replay(cols: dict, priority: str = "fifo", Book=Book) -> tuple:
+    """(events, books by symbol) of the stream columns (sym, uid, oid, side,
+    kind, cancel, price, volume: sequences of equal length), processed in
+    order by one `Book` per symbol."""
+    books: dict = {}
     events: list = []
     emit = events.append
     for i, (sym, uid, oid, side, kind, cancel, price, volume) in enumerate(zip(
@@ -142,4 +145,9 @@ def run(cols: dict, priority: str = "fifo") -> list:
             book.cancel(i, sym, uid, oid, side, price, emit)
         else:
             book.add(i, sym, uid, oid, side, kind, price, volume, emit)
-    return events
+    return events, books
+
+
+def run(cols: dict, priority: str = "fifo", Book=Book) -> list:
+    """Events of the stream columns; see replay."""
+    return replay(cols, priority, Book)[0]

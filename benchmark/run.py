@@ -3,16 +3,20 @@
 
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-This process imports no JAX. It starts the serving process (benchmark/serve.py:
-the cell's deployment as EngineService(load_config(file)), alone on the chip),
-makes the cell's stream from the seed in worker processes while that boots,
+This process imports no JAX. It empties the run's directory
+(.bench_run/<workload> under the root), starts the serving process
+(benchmark/serve.py: the cell's deployment, alone on the chip, everything it
+keeps on disk inside that directory), makes the cell's stream from the seed in
+worker processes while that boots, the venue's own Book in the generator's loop,
 then is the client: it sends the stream through gRPC DoOrderBatch, reads the
 fills on SubscribeMatches, takes every end-to-end metric on its own clock, and
 after the window compares the events with the plain reference, event for
 event. The last line of standard output is the result: correct, attempted,
 failed, metrics, device (and breakdown with --trace 1). --trace 0 reports the
 cell's end-to-end metrics, --trace 1 its per-layer metrics. Everything else is
-on earlier lines.
+on earlier lines. A configuration with a `restart` block is then killed and
+booted again on its directory, outside every timed number, and held to what it
+acknowledged (restart_check).
 
 Exit codes: 0 a result was printed; 1 the run broke; 2 the repository is not
 around the benchmark; 3 JAX found no TPU, or fewer chips than the cell asks
@@ -28,8 +32,10 @@ import gc
 import json
 import multiprocessing
 import os
+import shutil
 import subprocess
 import sys
+import threading
 import time
 
 T_IMPORT_NS = time.monotonic_ns()
@@ -87,16 +93,26 @@ class Serving:
             bufsize=1, cwd=ROOT, env=env,
         )
 
-    def read(self) -> dict:
-        line = self.proc.stdout.readline()
+    def read(self, timeout_s: float | None = None) -> dict:
+        """The child's next line; with a timeout, a child that has not
+        written one by then is killed (and the read raises ServingExit)."""
+        if timeout_s is None:
+            line = self.proc.stdout.readline()
+        else:
+            timer = threading.Timer(max(timeout_s, 0), self.proc.kill)
+            timer.start()
+            try:
+                line = self.proc.stdout.readline()
+            finally:
+                timer.cancel()
         if not line:
             code = self.proc.wait(timeout=60)
             raise ServingExit(code)
         return json.loads(line)
 
-    def ask(self, line: str) -> dict:
+    def ask(self, line: str, timeout_s: float | None = None) -> dict:
         self.tell(line)
-        return self.read()
+        return self.read(timeout_s)
 
     def tell(self, line: str) -> None:
         self.proc.stdin.write(line + "\n")
@@ -133,20 +149,107 @@ class ServingExit(RuntimeError):
         self.code = code
 
 
+def restart_check(say, n_more: int, timeout_s: float, serving: Serving,
+                  serve_args: dict, sender, loop, sub, n_sent: int,
+                  cum_events, grpc) -> dict:
+    """Kill the serving process at an acknowledgement and boot it again on
+    the same directory: a durable configuration's guarantee, held outside
+    every timed number. `n_more` more requests of the stream go out; at the
+    acknowledgement of the last one the process gets SIGKILL,
+    with events of acknowledged orders still on their way. The second process
+    restores what the first left on disk and replays its log; the client
+    subscribes again and waits until everything owed has arrived. Returns the
+    requests acknowledged in all, and of the second process (None where it
+    did not come up or drain inside timeout_s) the raw events, the
+    seq of the first of them by the match feed's own count and its books'
+    resting counts. `restart_s`, kill to first event delivered, goes on a
+    line of its own."""
+    out = dict(requests=n_sent + n_more, second=None)
+    if out["requests"] > len(sender.requests):
+        say("restart: the stream has no requests left to send before the kill")
+        out["requests"] = n_sent
+        return out
+    for k in range(n_sent, n_sent + n_more):
+        loop.released(k)
+        sender.send(k)
+    deadline = time.monotonic() + timeout_s
+    while not sender.ack_ns[n_sent + n_more - 1]:
+        serving.alive()
+        if time.monotonic() > deadline:
+            say("restart: the requests before the kill were not acknowledged")
+            return out
+        time.sleep(0.0005)
+    t_kill = now_ns()
+    serving.proc.kill()
+    serving.proc.wait(timeout=30)
+    sub.join(timeout=10)  # its stream ended with the process
+    last = n_sent + n_more - 1
+    say(f"restart: SIGKILL at the acknowledgement of request {last}, "
+        f"{len(sub.raw)} of {int(cum_events[last])} events held; booting "
+        f"again on {serve_args['run_dir']}")
+    second = Serving(serve_args)
+    channel = sub2 = None
+    try:
+        left = lambda: deadline - time.monotonic()
+        deadline = time.monotonic() + timeout_s
+        ready = second.read(left())
+        channel = grpc.insecure_channel(f"127.0.0.1:{ready['port']}")
+        grpc.channel_ready_future(channel).result(timeout=max(left(), 0.1))
+        sub2 = client_mod.Subscriber(channel)
+        sub2.start()
+        while True:  # drained, and the client holds all that was fanned out
+            if (second.ask("drained", left())["drained"] and len(sub2.raw)
+                    == second.ask("counters", left())["feed_events"]):
+                break
+            if left() < 0:
+                raise TimeoutError("the second process did not drain")
+            time.sleep(0.02)
+        books = second.ask("books", left())
+        feed = books["feed"]
+        n2 = len(sub2.raw)
+        restart_s = (sub2.stamps[0] - t_kill) / 1e9 if n2 else None
+        out.update(
+            second=list(sub2.raw), counts=books["counts"],
+            invariant_failures=books["invariant_failures"],
+            second_from=(feed["last_seq"] + 1 - n2 - feed["gaps"]) if n2
+            else None)
+        say("restart " + json.dumps(dict(
+            restart_s=restart_s, boot_s=ready["boot_s"], jax_s=ready["jax_s"],
+            events_from_first_process=len(sub.raw), events_from_second=n2,
+            second_from_seq=out["second_from"], feed=feed,
+            what="restart_s: SIGKILL to the first event the second process "
+                 "delivered; a reading, not a metric")))
+    except (ServingExit, TimeoutError, OSError, grpc.FutureTimeoutError) as e:
+        say(f"restart: the second process did not recover: {e!r}")
+    finally:
+        if sub2 is not None and sub2.is_alive():
+            sub2.stop()
+        if channel is not None:
+            channel.close()
+        second.close()
+    return out
+
+
 def result_line(rehearsal: bool, correct: bool, attempted: int, failed: int,
-                metrics: dict, device: dict, breakdown) -> dict:
+                metrics: dict, device: dict, breakdown,
+                compared: dict | None = None) -> dict:
     """The last line of standard output. A rehearsal carries no number under
-    a metric's name: counts only, and its label."""
+    a metric's name: counts only, and its label. `compared`, each number
+    compared with its limit, comes last."""
     if rehearsal:
-        return dict(cpu_rehearsal="CPU REHEARSAL - not a chip result",
-                    correct=bool(correct), attempted=attempted, failed=failed,
-                    metrics_that_a_chip_run_would_report=sorted(metrics),
-                    device=dict(platform=device["platform"],
-                                kind=device["kind"], count=device["count"]))
-    out = dict(correct=bool(correct), attempted=attempted, failed=failed,
-               metrics=metrics, device=device)
-    if breakdown is not None:
-        out["breakdown"] = breakdown
+        out = dict(cpu_rehearsal="CPU REHEARSAL - not a chip result",
+                   correct=bool(correct), attempted=attempted, failed=failed,
+                   metrics_that_a_chip_run_would_report=sorted(metrics),
+                   device=dict(platform=device["platform"],
+                               kind=device["kind"], count=device["count"]))
+    else:
+        out = dict(correct=bool(correct), attempted=attempted, failed=failed,
+                   metrics=metrics, device=device)
+        if breakdown is not None:
+            out["breakdown"] = breakdown
+    if compared is not None:
+        out["compared"] = {name: dict(value=value, limit=0)
+                           for name, value in compared.items()}
     return out
 
 
@@ -200,7 +303,17 @@ def main(argv=None) -> int:
     plan = core_plan()
     say(f"cpu_count {os.cpu_count()} usable {len(os.sched_getaffinity(0))} "
         f"pinning {json.dumps(plan)} loadavg {os.getloadavg()}")
-    run_dir = os.path.join(ROOT, ".bench_run", args.workload)
+    # A run owns its directory: whatever an earlier run of the cell left (a
+    # durable deployment's log and snapshots, a trace) is gone before the
+    # serving process boots.
+    run_dir = os.path.join(args.root, ".bench_run", args.workload)
+    shutil.rmtree(run_dir, ignore_errors=True)
+    os.makedirs(run_dir)
+    say(f"run directory {run_dir}: emptied")
+    restart = config.get("restart")
+    n_more = int(restart.get("requests", 4)) if restart else 0
+    n_requests += n_more  # sent before the kill, after every timed number
+    reference_path = os.path.join(args.root, config["reference"])
     serve_args = dict(
         service=config["service"], rehearsal=args.rehearsal,
         trace=bool(args.trace), chips=cell["chips"], cores=plan["serve"],
@@ -219,7 +332,8 @@ def main(argv=None) -> int:
         with ctx.Pool(n_workers, initializer=_pin,
                       initargs=(plan["workers"],)) as pool:
             made = stream.generate(flow, args.seed, n_requests, R,
-                                   workers=n_workers, pool=pool)
+                                   workers=n_workers, pool=pool,
+                                   reference_path=reference_path)
         # the workers are gone: their cores are the client's now
         _pin((plan["workers"] or []) + (plan["client"] or []))
         cols, events = made["cols"], made["events"]
@@ -230,7 +344,7 @@ def main(argv=None) -> int:
         cum_events = np.cumsum(np.bincount(ev_order // R,
                                            minlength=n_requests))
         say("stream " + json.dumps(dict(
-            stream.facts(made, R), request_orders=R,
+            stream.facts(made, R, flow), request_orders=R,
             request_bytes=len(requests[0]), workers=n_workers,
             generate_s=round(t_ser - t_gen, 3),
             serialise_s=round(time.monotonic() - t_ser, 3),
@@ -342,14 +456,22 @@ def main(argv=None) -> int:
                                 [m["name"] for m in cell["per_layer"]])
         fin = serving.ask(" ".join(
             ["finish", str(acknowledged), str(t0), str(t1)] + spans))
+        n_got = again = None
+        if restart:  # after every timed number; the run's own events end here
+            n_got = len(sub.raw)
+            again = restart_check(
+                say, n_more, float(restart.get("timeout_s", 120)), serving,
+                dict(serve_args, resumed=True, trace=False, sabotage=None),
+                sender, loop, sub, n_sent, cum_events, grpc)
         sub.stop()
         channel.close()
         serving.close()
 
         # -- after the window: the comparison and the numbers ---------------
         t_check = time.monotonic()
-        stamps = np.array(sub.stamps, np.int64)
-        got = wire.decode_events(sub.raw, pb)
+        stamps = np.array(sub.stamps[:n_got], np.int64)
+        got_all = wire.decode_events(sub.raw, pb)
+        got = got_all[:n_got]
         expected = compare.expected_rows(events, n_sent * R)
         numbers = compare.compare_events(expected, got)
         first_diff = numbers.pop("_first_difference")
@@ -360,7 +482,19 @@ def main(argv=None) -> int:
         numbers["client.rpc_errors_or_rejects"] = sender.errors
         numbers["window.warmup_stalled"] = int(stalled)  # then no window was
         numbers["window.not_drained"] = int(not drained)
-        numbers["window.stream_exhausted"] = int(n_sent >= n_requests)
+        numbers["window.stream_exhausted"] = int(n_sent >= n_requests - n_more)
+        if again is not None:
+            n_all = again["requests"] * R
+            recovered = again["second"] is not None
+            numbers.update(compare.restart_numbers(
+                compare.expected_rows(events, n_all), got_all,
+                wire.decode_events(again["second"] or [], pb),
+                again.get("second_from"),
+                compare.resting_counts(cols, n_all,
+                                       stream.book_class(reference_path))
+                if recovered else None,
+                again.get("counts"), again.get("invariant_failures", 0),
+                recovered))
         if args.control:
             ref = spec.load_reference(args.root, config)
             broken = compare.control(cols, n_sent * R, got,
@@ -525,8 +659,12 @@ def main(argv=None) -> int:
                 if m["name"] in e2e:
                     metrics[m["name"]] = dict(value=e2e[m["name"]],
                                               unit=m["unit"])
+        for name, value in numbers.items():  # the record's last lines
+            print(f"{tag}compared {name} = {value} (limit 0)", file=sys.stderr)
+        sys.stderr.flush()
         print(json.dumps(result_line(args.rehearsal, correct, attempted,
-                                     failed, metrics, device, breakdown)),
+                                     failed, metrics, device, breakdown,
+                                     numbers)),
               flush=True)
         return 0
     except ServingExit as e:

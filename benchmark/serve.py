@@ -1,10 +1,20 @@
 """The serving process: the one that holds the chip. It boots the cell's
-deployment as EngineService(load_config(file)) and otherwise only answers the
-supervisor's lines on stdin with JSON lines on stdout: the program's counters,
-the profiler for a traced stretch, and after the window the guarantees it can
-check from inside. No load is made here and nothing of the benchmark's runs on
-this interpreter while the window is open but a sleep (and, in a traced run, a
-10 ms backlog sampler).
+deployment (build_service(load_config(file)): the service with its Persister
+where the file enables one) and otherwise only answers the supervisor's lines
+on stdin with JSON lines on stdout: the program's counters, the profiler for a
+traced stretch, and after the window the guarantees it can check from inside.
+No load is made here and nothing of the benchmark's runs on this interpreter
+while the window is open but a sleep (and, in a traced run, a 10 ms backlog
+sampler).
+
+The run's directory (run.py hands it over empty) holds everything the
+deployment keeps on disk: a relative bus.dir or persist.dir of the service
+block is placed under it. A deployment that keeps anything there (a file or
+cfile bus, a persist section) is durable: its `ready` line says what the
+directory stands on (filesystem type, median fsync of a 64 KB append), and
+when run.py boots it a second time on the same directory (`resumed`, the
+restart check) the match feed is held until the client's subscription has
+registered, since SubscribeMatches has no way to ask for events from a seq.
 
 What the benchmark touches of the program: EngineStats, the bus queues'
 offsets, MatchFeed.seq_state, the metrics registry, jax.monitoring's compile
@@ -41,6 +51,73 @@ def _size(private):
     """Length of a private member of the program that the benchmark reads for
     want of a public one (PERF.md, open questions); None where it is gone."""
     return None if private is None else len(private)
+
+
+def kept_on_disk(service: dict) -> dict:
+    """{block of the service: its directory's default name} for the blocks
+    that keep anything on disk: a file or cfile bus, an enabled persist
+    section. Empty for a deployment that keeps nothing across its death."""
+    kept = {}
+    if service.get("bus", {}).get("backend") in ("file", "cfile"):
+        kept["bus"] = "bus_data"
+    if service.get("persist", {}).get("enabled", "persist" in service):
+        kept["persist"] = "snapshots"
+    return kept
+
+
+def place_under(service: dict, run_dir: str) -> dict:
+    """The service block with what it keeps on disk placed in the run's
+    directory: a relative bus.dir / persist.dir goes under it (the default
+    name where the block names none); an absolute path stays. A block that
+    keeps nothing on disk comes back as it is."""
+    out = json.loads(json.dumps(service))
+    for block, default in kept_on_disk(service).items():
+        out[block]["dir"] = os.path.join(run_dir,
+                                         out[block].get("dir", default))
+    return out
+
+
+def disk_facts(directory: str) -> dict:
+    """What "durable" means here: the filesystem the directory stands on and
+    the median of 32 fsyncs of a 64 KB append to a file in it."""
+    real = os.path.realpath(directory)
+    fs, mount = "unknown", ""
+    try:
+        with open("/proc/mounts") as f:
+            for line in f:
+                _dev, point, kind = line.split()[:3]
+                inside = real == point or real.startswith(
+                    point.rstrip("/") + "/")
+                if inside and len(point) >= len(mount):
+                    fs, mount = kind, point
+    except OSError:
+        pass
+    path = os.path.join(directory, ".fsync_probe")
+    block, took = os.urandom(65536), []
+    with open(path, "ab", buffering=0) as f:
+        for _ in range(32):
+            f.write(block)
+            t0 = now_ns()
+            os.fsync(f.fileno())
+            took.append(now_ns() - t0)
+    os.remove(path)
+    return dict(filesystem=fs, mount=mount,
+                fsync_64k_median_ms=sorted(took)[len(took) // 2] / 1e6)
+
+
+def build_service(config):
+    """The deployment of a loaded Config: the service, with its Persister
+    attached where the file enables one. What gome_tpu.service.app's `main`
+    does by hand; the program owes a public function for it (PERF.md, open
+    questions), and this one goes when it has one."""
+    from gome_tpu.service.app import EngineService
+
+    persist = None
+    if config.persist.enabled:
+        from gome_tpu.persist import Persister
+
+        persist = Persister(config.persist)
+    return EngineService(config, persist=persist)
 
 
 def sleep_until(t_ns: int) -> None:
@@ -133,13 +210,12 @@ class Served:
 
     def boot(self) -> int:
         from gome_tpu.config import load_config
-        from gome_tpu.service.app import EngineService
 
         os.makedirs(self.run_dir, exist_ok=True)
         path = os.path.join(self.run_dir, "config.yaml")
-        with open(path, "w") as f:
-            json.dump(self.args["service"], f)  # JSON is YAML
-        self.svc = EngineService(load_config(path))
+        with open(path, "w") as f:  # JSON is YAML
+            json.dump(place_under(self.args["service"], self.run_dir), f)
+        self.svc = build_service(load_config(path))
         if self.rehearsal:
             self.svc.engine.batch._pallas_interpret = True
         # One INFO line per match event is the reference's behaviour; the
@@ -151,8 +227,28 @@ class Served:
             from benchmark import faults
 
             faults.apply(self.args["sabotage"], self.svc)
+        if self.args.get("resumed"):
+            self._hold_feed_until_subscribed()
         self.svc.start()
         return self.svc._server.bound_port
+
+    def _hold_feed_until_subscribed(self) -> None:
+        """A second boot on the first one's directory replays its log from
+        the moment it starts, and the match feed would hand the events to
+        nobody: SubscribeMatches cannot ask for events from a seq, and the
+        feed's cursor in the durable match queue is the only resume point
+        there is. So the feed stands still until a subscription registers;
+        the events wait in the match queue meanwhile."""
+        feed = self.svc.feed
+        inner = feed.run_once
+
+        def run_once():
+            if _size(getattr(feed, "_subs", None)) == 0:
+                time.sleep(0.002)
+                return 0
+            return inner()
+
+        feed.run_once = run_once
 
     def _wrap_for_trace(self) -> None:
         feed = self.svc.feed
@@ -286,6 +382,25 @@ class Served:
                 and not _size(getattr(svc.consumer, "_pipe", None))
                 and mq.committed() == mq.end_offset())
 
+    def _books_broken(self) -> int:
+        try:
+            self.svc.engine.batch.verify_books()
+        except Exception as e:  # noqa: BLE001 - any failure is the finding
+            print(f"verify_books: {e!r}", file=sys.stderr, flush=True)
+            return 1
+        return 0
+
+    def books(self) -> dict:
+        """After a drain: the resting count of every symbol and side, and
+        whether the books hold their invariants (the restart check)."""
+        eng = self.svc.engine.batch
+        count = eng.lane_books().count
+        return dict(
+            invariant_failures=self._books_broken(),
+            feed=self.svc.feed.seq_state(),
+            counts={name: [int(c) for c in count[eng.symbol_lane(name)]]
+                    for name in eng.symbols.to_list()})
+
     def finish(self, acknowledged: int, t0: int, t1: int, spans) -> dict:
         """After the drain: the guarantees (each held to 0), the peak, and in
         a traced run the trace's rows."""
@@ -295,12 +410,6 @@ class Served:
         st = svc.engine.stats
         feed = svc.feed.seq_state()
         metric = lambda name: int(REGISTRY.counter(name).value())
-        broken = 0
-        try:
-            svc.engine.batch.verify_books()
-        except Exception as e:  # noqa: BLE001 - any failure is the finding
-            print(f"verify_books: {e!r}", file=sys.stderr, flush=True)
-            broken = 1
         grids = dict(st.grids_by_kernel)
         listed = set(self.args.get("scan_giveways_allowed", []))
         numbers = {
@@ -312,7 +421,7 @@ class Served:
                 metric("gome_consumer_step_failures_total"),
             "consumer.poison_orders": metric("gome_poison_orders_total"),
             "consumer.device_fault": int(svc.consumer.device_fault is not None),
-            "books.invariant_failures": broken,
+            "books.invariant_failures": self._books_broken(),
             "kernel.no_compiled_pallas_grid": int(not any(
                 v > 0 for k, v in grids.items()
                 if k.startswith("interpret" if self.rehearsal else "pallas"))),
@@ -389,12 +498,17 @@ def main(argv) -> int:
     served = Served(args, jax)
     try:
         t_jax = now_ns()
+        disk = {}
+        if kept_on_disk(args["service"]):
+            os.makedirs(args["run_dir"], exist_ok=True)
+            disk = dict(run_dir_disk=disk_facts(args["run_dir"]))
         port = served.boot()
         say(dict(ready=True, port=port, platform=devices[0].platform,
                  kind=devices[0].device_kind, count=len(devices),
                  jax_s=(t_jax - t_start) / 1e9,
                  boot_s=(now_ns() - t_jax) / 1e9,
-                 cores=sorted(os.sched_getaffinity(0)), cache_dir=cache_dir))
+                 cores=sorted(os.sched_getaffinity(0)), cache_dir=cache_dir,
+                 **disk))
         for line in sys.stdin:
             cmd, *rest = line.split()
             if cmd == "counters":
@@ -403,6 +517,8 @@ def main(argv) -> int:
                 say(served.window(int(rest[0]), int(rest[1])))
             elif cmd == "drained":
                 say(dict(drained=served.drained()))
+            elif cmd == "books":
+                say(served.books())
             elif cmd == "finish":
                 say(served.finish(int(rest[0]), int(rest[1]), int(rest[2]),
                                   rest[3:]))

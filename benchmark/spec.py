@@ -67,7 +67,7 @@ def load_cell(workload: str, root: str = ROOT, rehearsal: bool = False) -> dict:
     )
 
 
-def _load_module(name: str, path: str):
+def load_module(name: str, path: str):
     spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
@@ -76,15 +76,15 @@ def _load_module(name: str, path: str):
 
 def load_reference(root: str, config: dict):
     """The configuration's plain reference, as a module (run, PRIORITY,
-    CONTROL_PRIORITY)."""
-    return _load_module("benchmark_reference_" + config["name"],
+    CONTROL_PRIORITY and, where the venue has rules of its own, Book)."""
+    return load_module("benchmark_reference_" + config["name"],
                         os.path.join(root, config["reference"]))
 
 
 def load_reader(base: str, metric_name: str):
     """(metric file, read function) of one per-layer metric."""
     meta = _load(os.path.join(base, "metrics", metric_name + ".json"))
-    module = _load_module(
+    module = load_module(
         "benchmark_reader_" + meta["reader"],
         os.path.join(base, "readers", meta["reader"] + ".py"),
     )

@@ -9,9 +9,18 @@ The work a stream asks of the engine is the same in every seed:
     add of the same request) from another: request k has the same count per
     rank and per kind whatever the seed. The seed draws prices, volumes, sides,
     users, which symbol holds which rank, and the order inside each request.
-  * **The reference runs in the loop** (benchmark/reference.py, one Book per
-    symbol), so cancels aim at what is really resting and the expected events
-    come with the stream.
+  * **The reference runs in the loop**, one Book per symbol: the `Book` of the
+    configuration's own reference file where it defines one
+    (configs/<name>_reference.py, found by path so that worker processes can
+    load it), else benchmark/reference.py's. So cancels aim at what is really
+    resting under the venue's own rules, and the expected events come with
+    the stream.
+  * **The flow names its add kinds** (flow.add_kinds, optional): each entry
+    {name, kind, share_of_adds, pricing} is a kind of add that is not a market
+    order: the byte that goes into the `kind` column and onto the wire, its
+    share of those adds (the shares sum to 1) and how it is priced: `passive`,
+    `marketable`, or `steered` by the side's resting count. Without the key
+    there is one, the limit add (kind 0, steered).
   * **Depth is steered.** Every lane has a band [lo, hi] for each side's
     resting count (flow.bands, by rank; chosen from the engine's cap ladder in
     the configuration's file). Inside it the generator picks passive or
@@ -35,11 +44,14 @@ import random
 
 import numpy as np
 
-from . import reference
+from . import reference, spec
 
 BUY, SALE = 0, 1
 LIMIT, MARKET = 0, 1
-K_LIMIT, K_MARKET, K_CANCEL, K_CANCEL_SAME = 0, 1, 2, 3
+PRICINGS = ("steered", "passive", "marketable")
+#: The add kinds of a flow that names none: the limit add alone.
+LIMIT_ADD = dict(name="limit", kind=LIMIT, share_of_adds=1.0,
+                 pricing="steered")
 PHI = (math.sqrt(5.0) - 1.0) / 2.0
 PHI2 = math.sqrt(2.0) - 1.0
 COLUMNS = ("sym", "uid", "oid", "side", "kind", "cancel", "price", "volume")
@@ -52,13 +64,44 @@ def popularity(flow: dict) -> np.ndarray:
     return p / p.sum()
 
 
+def add_kinds(flow: dict) -> list[dict]:
+    """The flow's kinds of add other than the market order, checked."""
+    kinds = flow.get("add_kinds") or [LIMIT_ADD]
+    for entry in kinds:
+        if entry["pricing"] not in PRICINGS:
+            raise ValueError(f"add kind {entry['name']!r}: pricing "
+                             f"{entry['pricing']!r} is not one of {PRICINGS}")
+        if not 0 <= int(entry["kind"]) <= 127 or int(entry["kind"]) == MARKET:
+            raise ValueError(f"add kind {entry['name']!r}: kind "
+                             f"{entry['kind']!r} is not a byte of its own")
+    total = sum(float(entry["share_of_adds"]) for entry in kinds)
+    if abs(total - 1.0) > 1e-9:
+        raise ValueError(f"flow.add_kinds: share_of_adds sum to {total}, not 1")
+    return kinds
+
+
 def kind_shares(flow: dict) -> np.ndarray:
-    """[limit, market, cancel, cancel_same] shares of all orders."""
+    """[each add kind..., market, cancel, cancel_same] shares of all orders."""
     c = float(flow["cancel_share"])
     same = c * float(flow["cancel_same_request_share"])
     adds = 1.0 - c
     market = adds * float(flow["market_share_of_adds"])
-    return np.array([adds - market, market, c - same, same])
+    return np.array(
+        [(adds - market) * float(entry["share_of_adds"])
+         for entry in add_kinds(flow)] + [market, c - same, same])
+
+
+def book_class(reference_path: str | None):
+    """The Book the generator's loop keeps per symbol: the one the
+    configuration's reference file defines, else reference.Book. It has
+    reference.Book's interface: add and cancel (True when the order rested,
+    when the cancel hit; events through `emit` in EVENT_FIELDS order), count
+    (resting orders per side) and prices (occupied prices per side,
+    ascending)."""
+    if reference_path is None:
+        return reference.Book
+    module = spec.load_module("benchmark_stream_reference", reference_path)
+    return getattr(module, "Book", reference.Book)
 
 
 def _guard(lo: int, hi: int) -> int:
@@ -118,12 +161,13 @@ def _lane_rng(seed: int, rank: int) -> random.Random:
 
 
 def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
-             n_list: int, out, events, trace):
+             n_list: int, out, events, trace, Book=reference.Book):
     """One lane's orders at stream indices `pos` (ascending), written into the
     column arrays `out` at those indices; its events appended to `events`.
     n_list is the listing's length (listing_plan). `trace`, when not None, is
     an int32 array [requests, 6] that receives
-    per request (min, max, end) of each side's resting count."""
+    per request (min, max, end) of each side's resting count. `Book` is the
+    venue's book (book_class)."""
     lo, hi = band_of(flow, rank)
     guard = _guard(lo, hi)
     lo_t, hi_t = lo + guard, hi - guard
@@ -138,6 +182,9 @@ def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
     n_open = opening_requests(flow, request_orders) * request_orders
     rng_open = _lane_rng(int(opening.get("seed", 0)), rank)
     rng_seed = _lane_rng(seed, rank)
+    adds = add_kinds(flow)
+    k_market = len(adds)  # then cancel, then cancel aimed at the same request
+    k_cancel, k_cancel_same = k_market + 1, k_market + 2
     cum = np.cumsum(kind_shares(flow))
     cum[-1] = 1.0
     phase = (rank * 0.3819660112501051) % 1.0
@@ -145,7 +192,7 @@ def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
         cum, ((np.arange(len(pos)) + 0.5) * PHI2 + phase) % 1.0, side="right"
     ).tolist()
 
-    book = reference.Book()
+    book = Book()
     d = book.count
     alive: dict[int, tuple] = {}   # oid -> (side, price, uid)
     lists = ([], [])               # resting oids per side, oldest first
@@ -187,7 +234,7 @@ def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
                 t_row[0] = t_row[1] = d[0]
                 t_row[3] = t_row[4] = d[1]
         rng = rng_open if g < n_open else rng_seed
-        kind = K_LIMIT if g < n_list else kinds[j]
+        kind = -1 if g < n_list else kinds[j]  # a listing slot: no plan kind
         uid = rng.randrange(n_users)
         side = rng.randrange(2)
         vol = rng.randint(v_lo, v_hi)
@@ -195,12 +242,12 @@ def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
         x1 = min(max((d[1] - lo_t) / span, 0.0), 1.0)
         x = (x0, x1)
         cancel, okind, price, oid = False, LIMIT, 0, g
-        if kind >= K_CANCEL:
+        if kind >= k_cancel:
             cancel, vol = True, 1
             t = side if d[side] > lo_t else 1 - side
             target = None
             if d[t] > lo_t and rng.random() < x[t]:
-                if kind == K_CANCEL_SAME and req_adds:
+                if kind == k_cancel_same and req_adds:
                     cand = req_adds[rng.randrange(len(req_adds))]
                     info = alive.get(cand)
                     if info is not None and d[info[0]] > lo_t:
@@ -217,7 +264,7 @@ def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
             side = j % 2
             off = tick * rng.randrange(band)
             price = mid - 1 - off if side == BUY else mid + 1 + off
-        elif kind == K_MARKET:
+        elif kind == k_market:
             okind = MARKET
             if d[1 - side] <= lo_t:
                 if d[side] > lo_t:
@@ -225,7 +272,11 @@ def run_lane(flow: dict, seed: int, rank: int, sym: int, pos, request_orders,
                 else:
                     vol = 1
         else:
-            want_pass = rng.random() < 1.0 - 0.5 * x[side]
+            okind, pricing = adds[kind]["kind"], adds[kind]["pricing"]
+            if pricing == "steered":
+                want_pass = rng.random() < 1.0 - 0.5 * x[side]
+            else:
+                want_pass = pricing == "passive"
             if want_pass and d[side] < hi_t:
                 passive = True
             elif d[1 - side] > lo_t:
@@ -311,7 +362,8 @@ def traced_ranks(flow: dict) -> list[int]:
 
 def work(args) -> dict:
     """One worker's share: the lanes in `groups` (rank, symbol, positions)."""
-    flow, seed, n_requests, request_orders, groups = args
+    flow, seed, n_requests, request_orders, groups, reference_path = args
+    Book = book_class(reference_path)
     n = n_requests * request_orders
     traced = set(traced_ranks(flow))
     n_list = len(listing_plan(flow))
@@ -323,7 +375,7 @@ def work(args) -> dict:
         if rank in traced:
             trace = traces[rank] = np.zeros((n_requests, 6), np.int32)
         run_lane(flow, seed, rank, sym, pos, request_orders, n_list, out,
-                 events, trace)
+                 events, trace, Book)
         if trace is not None:  # requests without an order of the lane
             seen = np.zeros(n_requests, bool)
             seen[np.unique(pos // request_orders)] = True
@@ -373,14 +425,15 @@ def merge(parts: list, n: int) -> dict:
 
 
 def generate(flow: dict, seed: int, n_requests: int, request_orders: int,
-             workers: int = 1, pool=None) -> dict:
+             workers: int = 1, pool=None, reference_path=None) -> dict:
     """The stream of n_requests x request_orders orders: {"cols": columns by
     stream index, "events": int64 [n_events, 13] in reference.EVENT_FIELDS
     order, "traces": {rank: [requests, 6] depth (min, max, end per side)},
     "sym_of_rank"}. `pool` is a multiprocessing pool of at least `workers`
-    processes; without one the lanes run here."""
+    processes; without one the lanes run here. `reference_path` is the
+    configuration's reference file, whose Book the loop keeps (book_class)."""
     ranks, sym_of_rank = layout(flow, seed, n_requests, request_orders)
-    jobs = [(flow, seed, n_requests, request_orders, g)
+    jobs = [(flow, seed, n_requests, request_orders, g, reference_path)
             for g in split(ranks, sym_of_rank, workers)]
     parts = pool.map(work, jobs, chunksize=1) if pool is not None else \
         [work(j) for j in jobs]
@@ -390,8 +443,17 @@ def generate(flow: dict, seed: int, n_requests: int, request_orders: int,
     return out
 
 
-def facts(stream: dict, request_orders: int) -> dict:
-    """What a stream is made of, for the earlier-line report and the tests."""
+def facts(stream: dict, request_orders: int, flow: dict | None = None) -> dict:
+    """What a stream is made of, for the earlier-line report and the tests.
+    With a flow that names its add kinds, the share of each among all
+    orders."""
+    named = {}
+    if flow is not None and flow.get("add_kinds"):
+        adds = ~stream["cols"]["cancel"]
+        named = dict(add_kind_shares={
+            entry["name"]: float(
+                (adds & (stream["cols"]["kind"] == entry["kind"])).mean())
+            for entry in add_kinds(flow)})
     cols = stream["cols"]
     n = len(cols["sym"])
     cancel = cols["cancel"]
@@ -406,5 +468,5 @@ def facts(stream: dict, request_orders: int) -> dict:
         top_rank_share=float(counts.max() / n),
         symbols_touched=int((counts > 0).sum()),
         events=int(len(ev)), events_per_order=float(len(ev) / max(n, 1)),
-        cancel_events=int((ev[:, 12] == 0).sum()),
+        cancel_events=int((ev[:, 12] == 0).sum()), **named,
     )

@@ -32,23 +32,30 @@ REHEARSALS = {
 
 
 @pytest.fixture(scope="module")
-def rehearsals():
-    """Every rehearsal this file reads, started side by side."""
+def rehearsals(linked_root, finish):
+    """Every rehearsal this file reads, started side by side, each in a root
+    of its own. Something is left in the first one's run directory, as an
+    earlier run of the cell would leave it."""
+    roots = {key: linked_root(key) for key in REHEARSALS}
+    stale = os.path.join(roots["sat"], ".bench_run", "hotpair8.sat", "trace")
+    os.makedirs(stale)
+    with open(os.path.join(stale, "left_by_an_earlier_run"), "w") as f:
+        f.write("x")
     procs = {
         key: subprocess.Popen(
             [sys.executable, RUN, "--workload", wl, "--seed", "2147483659",
-             "--seconds", seconds, "--rehearsal", *more],
+             "--seconds", seconds, "--rehearsal", "--root", roots[key], *more],
             cwd=ROOT, env=ENV, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True)
         for key, (wl, seconds, *more) in REHEARSALS.items()
     }
     out = {}
     for key, p in procs.items():
-        stdout, stderr = p.communicate(timeout=300)
-        assert p.returncode == 0, (key, stderr[-2000:])
-        lines = stdout.strip().splitlines()
+        result, lines, stderr = finish(key, p)
         assert all(ln.startswith("[CPU REHEARSAL") for ln in lines[:-1]), key
-        out[key] = (json.loads(lines[-1]), lines)
+        out[key] = (result, lines)
+        out[key + ".stderr"] = stderr
+    out["roots"] = roots
     return out
 
 
@@ -59,8 +66,9 @@ def sat(rehearsals):
 
 def test_a_rehearsal_is_labelled_and_carries_no_number_under_a_metrics_name(sat):
     out, lines = sat
-    assert set(out) == {"cpu_rehearsal", "correct", "attempted", "failed",
-                        "metrics_that_a_chip_run_would_report", "device"}
+    assert list(out) == ["cpu_rehearsal", "correct", "attempted", "failed",
+                         "metrics_that_a_chip_run_would_report", "device",
+                         "compared"]
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
     assert out["metrics_that_a_chip_run_would_report"] == [
         "orders_per_s", "setup_s"]
@@ -77,6 +85,32 @@ def test_every_number_compared_is_printed_beside_its_limit(sat):
             "matchfeed.gaps", "matchfeed.dupes", "books.invariant_failures",
             "kernel.no_compiled_pallas_grid", "consumer.step_failures",
             "consumer.poison_orders"} <= names
+
+
+def test_the_numbers_compared_end_the_result_line_and_standard_error(
+        sat, rehearsals):
+    out, lines = sat
+    printed = {ln.split("compare hotpair8 ")[1].split(" = ")[0]: ln
+               for ln in lines if "] compare hotpair8 " in ln}
+    assert list(out["compared"]) == list(printed)
+    assert all(v == {"value": 0, "limit": 0} for v in out["compared"].values())
+    last = rehearsals["sat.stderr"].strip().splitlines()[-len(printed):]
+    assert [ln.split("compared ")[1] for ln in last] == [
+        f"{name} = 0 (limit 0)" for name in printed]
+
+
+def test_a_run_empties_its_directory_before_the_serving_process_boots(
+        sat, rehearsals):
+    """What an earlier run of the cell left there is gone, and what this run
+    kept on disk lies under it."""
+    _out, lines = sat
+    run_dir = os.path.join(rehearsals["roots"]["sat"], ".bench_run",
+                           "hotpair8.sat")
+    assert any(f"run directory {run_dir}: emptied" in ln for ln in lines)
+    assert os.listdir(run_dir) == ["config.yaml"]
+    at = [i for i, ln in enumerate(lines)
+          if "emptied" in ln or "serving ready" in ln]
+    assert len(at) == 2 and "emptied" in lines[at[0]]
 
 
 def test_the_report_line_carries_the_witnesses(sat):
@@ -102,8 +136,10 @@ def test_the_result_line_of_a_chip_run_has_exactly_the_contracts_keys():
     assert list(out) == ["correct", "attempted", "failed", "metrics", "device"]
     traced = result_line(False, True, 10, 0, metrics,
                          dict(device, busy_s=1.0, window_s=2.0),
-                         dict(device_ops=[], idle_gaps=[]))
-    assert set(traced) == set(out) | {"breakdown"}
+                         dict(device_ops=[], idle_gaps=[]),
+                         {"events.missing": 2})
+    assert list(traced) == list(out) + ["breakdown", "compared"]
+    assert traced["compared"] == {"events.missing": dict(value=2, limit=0)}
 
 
 def test_a_traced_paced_rehearsal_names_the_cells_per_layer_metrics(rehearsals):

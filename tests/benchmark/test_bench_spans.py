@@ -31,11 +31,11 @@ SPAN_METRICS = {
     "stream_wait_share": ("span_share", "stream_wait", "share"),
 }
 CELLS = {"sat": (["spot10k.sat", "hotpair8.sat"], "orders_per_s"),
-         "paced": (["spot10k.paced"], "fill_latency_p50_ms")}
+         "paced": (["spot10k.paced", "hotpair8.paced"], "fill_latency_p50_ms")}
 
 
 def _read(reader, run, meta):
-    return spec._load_module(
+    return spec.load_module(
         "reader_" + reader, os.path.join(BASE, "readers", reader + ".py")
     ).read(run, meta)
 
@@ -105,29 +105,31 @@ def test_a_span_metrics_entry_and_file_agree(name, kind):
 
 
 @pytest.fixture(scope="module")
-def traced_rehearsals():
-    """A traced rehearsal of every cell, started side by side."""
+def traced_rehearsals(linked_root, finish):
+    """A traced rehearsal of every cell, started side by side, in a root of
+    their own (another file's rehearsals of the same cells run beside
+    them)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         cells = [w["name"] for w in json.load(f)["workloads"]]
+    root = linked_root("traced")
     procs = {
         cell: subprocess.Popen(
             [sys.executable, os.path.join(BASE, "run.py"), "--workload", cell,
              "--seed", "2147483777", "--seconds", "3", "--trace", "1",
-             "--rehearsal"],
+             "--rehearsal", "--root", root],
             cwd=ROOT, env=ENV, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True)
         for cell in cells
     }
     out = {}
     for cell, p in procs.items():
-        stdout, stderr = p.communicate(timeout=300)
-        assert p.returncode == 0, (cell, stderr[-2000:])
-        out[cell] = (json.loads(stdout.strip().splitlines()[-1]), stderr)
+        line, _lines, stderr = finish(cell, p)
+        out[cell] = (line, stderr)
     return out
 
 
 @pytest.mark.parametrize("cell", ["spot10k.sat", "hotpair8.sat",
-                                  "spot10k.paced"])
+                                  "spot10k.paced", "hotpair8.paced"])
 def test_a_traced_rehearsal_names_the_cells_span_metrics(cell,
                                                          traced_rehearsals):
     line, stderr = traced_rehearsals[cell]

@@ -179,3 +179,48 @@ def test_the_control_comes_out_not_correct(seed, streams):
     assert compare.compare_events(sound, sound)["events.mismatched"] == 0
     broken = compare.control(m["cols"], n, sound, "lifo")
     assert broken["events.mismatched"] > 100
+
+
+#: sha256 over the columns (stream.COLUMNS order) and the events of streams
+#: made by the parent commit's generator (ba9ae75, before a configuration
+#: could bring its own Book and add kinds; PR 27's builder ran the function
+#: below on an unpacked `git archive` of it): venue, rehearsal or the cell's
+#: own flow, request_orders, requests, seed. The accepted venues' streams may
+#: not move by a byte.
+PARENT_DIGESTS = {
+    ("spot10k", True, 128, 40, 2):
+        "f046f6a63b8a675653214df642907225151eecbb0f2fbe4e64feef4d3be16cbf",
+    ("spot10k", True, 128, 40, 2147483659):
+        "66900f3214b681254a27a564a5a4a314fbb9894d51b1502d6e61a0338debb478",
+    ("spot10k", False, 4096, 24, 1873402117):
+        "5fa656fc4229b97feb2a90e8a78190e5567938c0c0beb9e708708cfa776602ef",
+    ("spot10k", False, 62, 1400, 771203945): 
+        "37999b9c39428518dea6afb9974997b13163614b55135ee2ea7e8bf7c1de233a",
+    ("hotpair8", True, 128, 40, 2):
+        "d49a922b9424b1052ae0af31b8407ff1e536b2953bf84d39233cdeaad1028973",
+    ("hotpair8", True, 128, 40, 2147483659):
+        "8c1e11e7abda877f18fc46f393d23feb46fe9d02c34ff0ec30930e4cddbbd9f3",
+    ("hotpair8", False, 4096, 12, 1873402117):
+        "ad7d0c3061919c3a2ddc4024101744599689f09b94fe0bfa5737bca0e45e3eb6",
+}
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("case", sorted(PARENT_DIGESTS), ids=str)
+def test_the_accepted_venues_streams_are_the_parents_byte_for_byte(case,
+                                                                   workers):
+    import hashlib
+
+    venue, rehearsal, request_orders, n_requests, seed = case
+    with open(os.path.join(spec.HERE, "configs", venue + ".json")) as f:
+        config = json.load(f)
+    if rehearsal:
+        spec._merge(config, config["rehearsal"])
+    made = stream.generate(
+        config["flow"], seed, n_requests, request_orders, workers=workers,
+        reference_path=os.path.join(spec.ROOT, config["reference"]))
+    h = hashlib.sha256()
+    for col in stream.COLUMNS:
+        h.update(np.ascontiguousarray(made["cols"][col]).tobytes())
+    h.update(np.ascontiguousarray(made["events"], dtype=np.int64).tobytes())
+    assert h.hexdigest() == PARENT_DIGESTS[case]
