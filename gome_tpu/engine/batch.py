@@ -43,6 +43,7 @@ from ..obs.profiler import PROFILER
 from ..types import KERNELS, Action, MatchResult, Order
 from ..utils.metrics import REGISTRY
 from ..utils.tracing import span
+from . import placement
 from .book import (
     BUY,
     BookConfig,
@@ -50,7 +51,6 @@ from .book import (
     DeviceOp,
     StepOutput,
     grow_books,
-    grow_lanes,
     init_books,
 )
 from .host import Interner, OpContext, decode_events, encode_op
@@ -606,7 +606,14 @@ class BatchEngine:
         multiples of the mesh size (growth rounds up). kernel="pallas"
         under a mesh runs the compiled VMEM kernel per chip inside a
         shard_map (gome_tpu.parallel.mesh.sharded_batch_step), preserving
-        the kernel's throughput win at multi-chip scale."""
+        the kernel's throughput win at multi-chip scale. Under a mesh a
+        symbol's lane (its row of the book stack, what _lane returns and
+        every packer and per-lane array indexes) is its PLACEMENT: symbols
+        are dealt round-robin over the shards in arrival order
+        (engine.placement), so a Zipf head that arrives first spreads
+        evenly. What leaves the engine keeps the one-chip order, lane =
+        interner id - 1: the events' symbol_id, export_state / import_state,
+        lane_books and symbol_lane."""
         if kernel not in KERNELS:
             raise ValueError(f"kernel must be one of {KERNELS}, got {kernel!r}")
         if config.cap > max_cap:
@@ -715,14 +722,43 @@ class BatchEngine:
 
         return shard_batch(self.mesh, books)
 
+    # -- placement under a mesh (engine.placement) --------------------------
+    def _lane_of(self, arrival):
+        """Lane (row of the book stack) of the k-th symbol to arrive, int or
+        array; the identity without a mesh."""
+        if self.mesh is None:
+            return arrival
+        return placement.lane_of(arrival, self.n_slots, self.mesh.size)
+
+    def _symbol_ids(self, lanes):
+        """Inverse of _lane_of: the one-chip lane (interner id - 1) that
+        events and snapshots name a symbol by."""
+        if self.mesh is None:
+            return lanes
+        return placement.arrival_of(lanes, self.n_slots, self.mesh.size)
+
+    def _relayout(self, a, n_slots: int, xp=np):
+        """A per-lane array laid out for len(a) lanes, laid out for n_slots:
+        padded (or cut) at the end without a mesh; under one every shard's
+        block widens, so every lane moves, through arrival order. Host
+        arrays, or with xp=jnp a leaf of the device book stack."""
+        old = len(a)
+        if old == n_slots:
+            return a
+        if self.mesh is not None:
+            d = self.mesh.size
+            a = a[placement.lane_of(np.arange(old), old, d)]
+        a = a[:n_slots] if old > n_slots else xp.pad(
+            a, [(0, n_slots - old)] + [(0, 0)] * (a.ndim - 1)
+        )
+        if self.mesh is not None:
+            a = a[placement.arrival_of(np.arange(n_slots), n_slots, d)]
+        return a
+
     def _grow_base_arrays(self, new_slots: int) -> None:
-        pad = new_slots - len(self.price_base)
-        self.price_base = np.pad(self.price_base, (0, pad))
-        self._base_set = np.pad(self._base_set, (0, pad))
-        self._env_lo = np.pad(self._env_lo, (0, pad))
-        self._env_hi = np.pad(self._env_hi, (0, pad))
-        self._ub_base = np.pad(self._ub_base, (0, pad))
-        self._ub_extra = np.pad(self._ub_extra, (0, pad))
+        for name in ("price_base", "_base_set", "_env_lo", "_env_hi",
+                     "_ub_base", "_ub_extra"):
+            setattr(self, name, self._relayout(getattr(self, name), new_slots))
 
     # -- resting-count upper bound (cap-class selection) -------------------
     def count_ub(self) -> np.ndarray:
@@ -743,16 +779,15 @@ class BatchEngine:
         is exactly the still-in-flight sum); None asserts nothing is in
         flight and zeroes extra."""
         n = self.n_slots
-        base = np.zeros(n, np.int64)
-        m = min(len(counts_max), n)
-        base[:m] = np.asarray(counts_max[:m], np.int64)
-        self._ub_base = base
+        # (a fetch or a pack from before a lane growth is laid out for the
+        # stack as it was then)
+        self._ub_base = self._relayout(np.array(counts_max, np.int64), n)
         if resolved_adds is None:
             self._ub_extra = np.zeros(n, np.int64)
         else:
-            extra = self._ub_extra.copy()
-            m = min(len(resolved_adds), n)
-            extra[:m] -= np.asarray(resolved_adds[:m], np.int64)
+            extra = self._ub_extra - self._relayout(
+                np.asarray(resolved_adds, np.int64), n
+            )
             np.maximum(extra, 0, out=extra)
             self._ub_extra = extra
 
@@ -1167,29 +1202,49 @@ class BatchEngine:
             price=self.books.price.at[lane].add(d)
         )
 
-    def _lane(self, symbol: str) -> int:
-        lane = self.symbols.intern(symbol) - 1  # Interner ids start at 1
-        if lane >= self.n_slots:
+    def _arrival(self, symbol: str) -> int:
+        """The symbol's place in arrival order (interner id - 1: the lane a
+        one-chip engine gives it), interning a new symbol and growing the
+        book stack to hold it."""
+        k = self.symbols.intern(symbol) - 1  # Interner ids start at 1
+        if k >= self.n_slots:
             if not self.auto_grow:
                 raise CapacityError(
-                    f"symbol {symbol!r} needs lane {lane} but engine has "
+                    f"symbol {symbol!r} needs lane {k} but engine has "
                     f"n_slots={self.n_slots} (auto_grow disabled)"
                 )
-            new_slots = min(max(self.n_slots * 2, lane + 1), self.max_slots)
+            new_slots = min(max(self.n_slots * 2, k + 1), self.max_slots)
             if self.mesh is not None:
                 m = self.mesh.size
                 new_slots = min(((new_slots + m - 1) // m) * m, self.max_slots)
-            if lane >= new_slots:
+            if k >= new_slots:
                 raise CapacityError(
-                    f"symbol {symbol!r} needs lane {lane} but max_slots="
+                    f"symbol {symbol!r} needs lane {k} but max_slots="
                     f"{self.max_slots}; raise max_slots or shard symbols "
                     "across more engines"
                 )
-            self.books = self._place(grow_lanes(self.books, new_slots))
-            self._grow_base_arrays(new_slots)
-            self.n_slots = new_slots
-            self.stats.lane_growths += 1
-        return lane
+            self._grow_lanes(new_slots)
+        return k
+
+    def _grow_lanes(self, new_slots: int) -> None:
+        # (under a mesh the one gather across chips the engine ever does)
+        self.books = self._place(jax.tree.map(
+            lambda a: self._relayout(a, new_slots, jnp), self.books
+        ))
+        self._grow_base_arrays(new_slots)
+        self.n_slots = new_slots
+        self.stats.lane_growths += 1
+
+    def _lane(self, symbol: str) -> int:
+        """The symbol's lane: its row of the book stack (_lane_of)."""
+        return self._lane_of(self._arrival(symbol))
+
+    def _lanes(self, symbols) -> np.ndarray:
+        """Lanes of a sequence of symbols. Interns them all first: a growth
+        on the way moves the lanes of a mesh engine, so a lane is read off
+        only once the stack has its final size."""
+        ks = np.fromiter((self._arrival(s) for s in symbols), np.int64)
+        return self._lane_of(ks)
 
     def _checkpoint(self):
         """Everything a failed batch must roll back: the device book stack
@@ -1279,7 +1334,7 @@ class BatchEngine:
         # grid is allocated once at the final lane count and newly created
         # lanes pack into THIS grid rather than deferring to an extra
         # device call.
-        lanes = [self._lane(order.symbol) for _, order in pending]
+        lanes = self._lanes(o.symbol for _, o in pending).tolist()
         drop = self._prepare_bases(pending, lanes)
         grid = _nop_grid(self.config, self.n_slots, self.max_t)
         contexts: dict[tuple[int, int], tuple[int, Order]] = {}
@@ -1372,9 +1427,7 @@ class BatchEngine:
         from ..types import OrderType
 
         n = len(pending)
-        lanes = np.fromiter(
-            (self._lane(o.symbol) for _, o in pending), np.int64, n
-        )
+        lanes = self._lanes(o.symbol for _, o in pending)
         drop = self._prepare_bases(pending, lanes)
         bases = self.price_base[lanes]  # [N] int64
         # Slot within the lane = occurrence index (FIFO by construction:
@@ -1459,7 +1512,7 @@ class BatchEngine:
                 )
             grid[name][pl, pt] = vals
         meta = {
-            "lane": lanes[packed],
+            "lane": self._symbol_ids(lanes[packed]),  # the events' symbol_id
             "row": pl,
             "t": pt,
             "arrival": np.fromiter(
@@ -1737,11 +1790,17 @@ class BatchEngine:
                     pallas_interpret=self._pallas_interpret,
                 )
                 self._sharded_dense_steppers[cfg] = stepper
-            return stepper(
-                books,
-                shard_batch(self.mesh, jnp.asarray(ids_local)),
-                shard_batch(self.mesh, ops),
-            )
+            # (live: each shard's live rows of this grid, for the trace's
+            # reader; every shard is padded to the largest one's bucket)
+            with span(
+                "shard_put", rows=len(ids_np) // self.mesh.size,
+                live="/".join(map(str, (
+                    ids_np.reshape(self.mesh.size, -1) < self.n_slots
+                ).sum(axis=1))),
+            ):
+                ids_local = shard_batch(self.mesh, jnp.asarray(ids_local))
+                ops = shard_batch(self.mesh, ops)
+            return stepper(books, ids_local, ops)
         if dense:
             ids = jnp.asarray(lane_ids, jnp.int32)
             if block_s is not None:
@@ -1759,7 +1818,9 @@ class BatchEngine:
                     pallas_interpret=self._pallas_interpret,
                 )
                 self._sharded_steppers[cfg] = stepper
-            return stepper(books, shard_batch(self.mesh, ops))
+            with span("shard_put"):
+                ops = shard_batch(self.mesh, ops)
+            return stepper(books, ops)
         if block_s is not None:
             return _fullk(cfg, books, ops, block_s, interpret)
         return _batch(cfg, books, ops)
@@ -1769,8 +1830,14 @@ class BatchEngine:
         """Host-side copy of all mutable engine state (books + interners +
         geometry) for the durability layer (gome_tpu.persist)."""
         books = jax.device_get(self.books)
+        # The one-chip order, whatever row of whatever chip a lane is
+        # stored in: a snapshot restores into any mesh, or none.
+        by_arrival = np.asarray
+        if self.mesh is not None:
+            rows = self._lane_of(np.arange(self.n_slots))
+            by_arrival = lambda a: np.asarray(a)[rows]
         return {
-            "books": {k: np.asarray(v) for k, v in books._asdict().items()},
+            "books": {k: by_arrival(v) for k, v in books._asdict().items()},
             "symbols": self.symbols.to_list(),
             "oids": self.oids.to_list(),
             "uids": self.uids.to_list(),
@@ -1781,10 +1848,10 @@ class BatchEngine:
             "max_t": self.max_t,
             # JSON-safe lists: the durability layer folds everything but
             # "books" into its (JSON) manifest.
-            "price_base": self.price_base.tolist(),
-            "base_set": self._base_set.astype(int).tolist(),
-            "env_lo": self._env_lo.tolist(),
-            "env_hi": self._env_hi.tolist(),
+            "price_base": by_arrival(self.price_base).tolist(),
+            "base_set": by_arrival(self._base_set).astype(int).tolist(),
+            "env_lo": by_arrival(self._env_lo).tolist(),
+            "env_hi": by_arrival(self._env_hi).tolist(),
         }
 
     def import_state(self, state: dict) -> None:
@@ -1811,7 +1878,13 @@ class BatchEngine:
                 "engine or re-snapshot from a mesh-aligned one"
             )
         self.max_t = int(state["max_t"])
-        b = state["books"]
+        # A snapshot is in the one-chip order (export_state): each lane
+        # goes to the row this engine's placement gives it.
+        placed = np.array  # (a copy: the state stays the caller's)
+        if self.mesh is not None:
+            rows = self._symbol_ids(np.arange(self.n_slots))
+            placed = lambda a: np.asarray(a)[rows]
+        b = {k: placed(v) for k, v in state["books"].items()}
         books = BookState(**b)
         # _place device_puts with the mesh sharding directly from host
         # arrays; an inner device_put first would materialize the whole
@@ -1833,10 +1906,10 @@ class BatchEngine:
         self._ub_base = np.asarray(b["count"], np.int64).max(axis=1)
         self._ub_extra = np.zeros(n, np.int64)
         if "price_base" in state:
-            self.price_base = np.asarray(state["price_base"], np.int64).copy()
-            self._base_set = np.asarray(state["base_set"], bool).copy()
-            self._env_lo = np.asarray(state["env_lo"], np.int64).copy()
-            self._env_hi = np.asarray(state["env_hi"], np.int64).copy()
+            self.price_base = placed(np.asarray(state["price_base"], np.int64))
+            self._base_set = placed(np.asarray(state["base_set"], bool))
+            self._env_lo = placed(np.asarray(state["env_lo"], np.int64))
+            self._env_hi = placed(np.asarray(state["env_hi"], np.int64))
         else:
             # Pre-rebasing snapshot: stored prices are absolute, i.e. base 0.
             # Lanes holding resting orders MUST be marked base-set at 0 —
@@ -1902,18 +1975,24 @@ class BatchEngine:
     def lane_books(self) -> BookState:
         """Host copy of the books with ABSOLUTE prices (per-lane rebasing
         offsets added back; the price leaf widens to int64 when bases are in
-        play). Consumers of raw device state use export_state instead."""
+        play), in the one-chip order: row k is the k-th symbol to arrive
+        (symbol_lane), under a mesh too. Consumers of raw device state use
+        export_state instead."""
         books = jax.device_get(self.books)
         if self._rebase and self._base_set.any():
             price = np.asarray(books.price).astype(np.int64)
             price = price + self.price_base[:, None, None]
             books = books._replace(price=price)
+        if self.mesh is not None:
+            rows = self._lane_of(np.arange(self.n_slots))
+            books = jax.tree.map(lambda a: np.asarray(a)[rows], books)
         return books
 
     def symbol_lane(self, symbol: str) -> int:
-        """Read-only lookup: the lane owning `symbol`. Raises KeyError for a
-        symbol the engine has never processed (unlike _lane, this never
-        interns or grows device state)."""
+        """Read-only lookup: the lane owning `symbol` in the one-chip order
+        (its row of lane_books and of a snapshot; interner id - 1). Raises
+        KeyError for a symbol the engine has never processed (unlike _lane,
+        this never interns or grows device state)."""
         i = self.symbols.get(symbol)
         if i is None:
             raise KeyError(f"unknown symbol {symbol!r}")

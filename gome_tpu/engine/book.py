@@ -209,21 +209,6 @@ def grow_books(books: BookState, new_cap: int) -> BookState:
     )
 
 
-def grow_lanes(books: BookState, n_lanes: int) -> BookState:
-    """Append empty symbol lanes to a stacked [S, ...] book pytree (used when
-    more distinct symbols arrive than the engine was provisioned for —
-    the reference has no such limit because Redis keys are dynamic)."""
-    s = books.count.shape[0]
-    if n_lanes < s:
-        raise ValueError(f"cannot shrink lanes {s} -> {n_lanes}")
-    if n_lanes == s:
-        return books
-    return jax.tree.map(
-        lambda a: jnp.pad(a, [(0, n_lanes - s)] + [(0, 0)] * (a.ndim - 1)),
-        books,
-    )
-
-
 def book_depth(book: BookState, side: int, max_levels: int):
     """Aggregate [price, volume] depth view, best-first — the observable
     equivalent of the reference's S:BUY/S:SALE zset + S:depth hash

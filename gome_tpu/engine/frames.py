@@ -105,9 +105,11 @@ def _lane_map(eng: BatchEngine, symbols) -> np.ndarray:
 
     The wire decoder (bus.colwire) returns the SAME list object for a
     dictionary region it has seen before, so a stable symbol universe
-    resolves its per-unique interner walk once, not once per frame. Lane
-    ids are permanent (the interner is grow-only), BUT a cached map is
-    only usable while every lane fits the CURRENT book stack: _lane()'s
+    resolves its per-unique interner walk once, not once per frame. What
+    is cached is each symbol's place in arrival order, which is permanent
+    (the interner is grow-only); its lane follows from the engine's
+    placement (the same number without a mesh). BUT a cached map is
+    only usable while every lane fits the CURRENT book stack: _arrival()'s
     side effect is auto-growing n_slots, and a transactional rollback
     (_restore after a failed/overflowed frame) shrinks n_slots back — a
     blind cache hit on the retry would skip the re-growth and index past
@@ -116,13 +118,13 @@ def _lane_map(eng: BatchEngine, symbols) -> np.ndarray:
     resets when the engine's interners are replaced (import_state)."""
     ent = eng._lane_map_cache.get(symbols)
     if ent is not None and ent[1] < eng.n_slots:
-        return ent[0]
-    lane_of_sym = np.empty(len(symbols), np.int64)
+        return eng._lane_of(ent[0])
+    arrival_of_sym = np.empty(len(symbols), np.int64)
     for i, s in enumerate(symbols):
-        lane_of_sym[i] = eng._lane(s)  # may auto-grow the book stack
-    max_lane = int(lane_of_sym.max()) if len(lane_of_sym) else -1
-    eng._lane_map_cache.put(symbols, (lane_of_sym, max_lane))
-    return lane_of_sym
+        arrival_of_sym[i] = eng._arrival(s)  # may auto-grow the book stack
+    max_lane = int(arrival_of_sym.max()) if len(arrival_of_sym) else -1
+    eng._lane_map_cache.put(symbols, (arrival_of_sym, max_lane))
+    return eng._lane_of(arrival_of_sym)
 
 
 def intern_column(interner, uniques) -> np.ndarray:
@@ -389,6 +391,9 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
                 "oid_id": a["oid_ids"][sel],
                 "uid_id": a["uid_ids"][sel],
             }
+        # The events' symbol_id: the one-chip lane, wherever a mesh
+        # engine's placement stores the symbol (the same array without one).
+        meta["lane"] = eng._symbol_ids(meta["lane"])
         ops = _scatter_grid_fn(
             np.dtype(eng.config.dtype).name, n_rows, t_grid
         )(cols, flat)

@@ -8,8 +8,12 @@ Cross-shard traffic exists only here, at dispatch (DCN between hosts, PCIe
 to chips); matching never communicates.
 
 Topology:
-  ShardRouter      — stable symbol -> shard mapping (fnv1a hash; adding
-                     hosts is a controlled resharding, never implicit).
+  ShardRouter      — symbol -> shard by engine.placement, the rule the
+                     mesh engine places its lanes by: symbols dealt
+                     round-robin in arrival order (adding hosts is a
+                     controlled resharding, never implicit). fnv1a stays
+                     here for the fleet tier (fleet/router.py), whose
+                     partitions are named across processes.
   ShardedEngine    — N MatchEngine shards behind the single-engine facade:
                      mark/process split per shard, events merged back into
                      arrival order. In-process stand-in for N per-host
@@ -24,7 +28,9 @@ Topology:
 
 from __future__ import annotations
 
+from ..engine import placement
 from ..engine.book import BookConfig
+from ..engine.host import Interner
 from ..engine.orchestrator import MatchEngine
 from ..types import MatchResult, Order
 
@@ -40,13 +46,21 @@ def fnv1a(s: str) -> int:
 
 
 class ShardRouter:
+    """symbol -> shard, by the placement rule of the mesh engine
+    (engine.placement.shard_of): the k-th symbol this router meets goes to
+    shard k mod n_shards. The arrival order is the router's state, as the
+    interner is the engine's; one router fronts one set of shards."""
+
     def __init__(self, n_shards: int):
         if n_shards <= 0:
             raise ValueError("n_shards must be positive")
         self.n_shards = n_shards
+        self._arrivals = Interner()
 
     def route(self, symbol: str) -> int:
-        return fnv1a(symbol) % self.n_shards
+        return placement.shard_of(
+            self._arrivals.intern(symbol) - 1, self.n_shards
+        )
 
 
 class ShardedEngine:

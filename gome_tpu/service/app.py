@@ -318,6 +318,17 @@ class EngineService:
         return n
 
 
+def build_service(config) -> EngineService:
+    """The deployment of a loaded Config: the service, with its Persister
+    attached where the file enables one (not started)."""
+    persist = None
+    if config.persist.enabled:
+        from ..persist import Persister
+
+        persist = Persister(config.persist)
+    return EngineService(config, persist=persist)
+
+
 def main(argv=None):
     """CLI entry: `python -m gome_tpu.service.app [config.yaml]` — the
     single-binary replacement for the reference's three `go run` processes
@@ -332,12 +343,7 @@ def main(argv=None):
     # Every frame-geometry shape is a compile of seconds on the chip;
     # cached, a restart pays them once per machine.
     cache_dir = enable_compile_cache()
-    persist = None
-    if config.persist.enabled:
-        from ..persist import Persister
-
-        persist = Persister(config.persist)
-    svc = EngineService(config, persist=persist).start()
+    svc = build_service(config).start()
     log.info(
         "engine service up (grpc %s:%d, compile cache %s)",
         config.grpc.host, config.grpc.port, cache_dir,

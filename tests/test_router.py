@@ -1,6 +1,7 @@
 """Shard-routing tests: stable hashing, sharded-engine parity vs both the
 single engine and the oracle, per-shard isolation."""
 
+import numpy as np
 import pytest
 
 from gome_tpu.engine import BookConfig, MatchEngine
@@ -95,3 +96,27 @@ def test_shards_isolated():
     for i, shard in enumerate(eng.shards):
         count = int(shard.batch.lane_books().count.sum())
         assert count == (1 if i == owner else 0)
+
+
+def test_router_deals_symbols_as_the_mesh_engine_places_them():
+    """One placement rule (engine.placement): the k-th symbol a router meets
+    goes to shard k mod D, which is the shard whose block of the book stack a
+    D-device mesh engine gives the k-th symbol it meets."""
+    from gome_tpu.engine import BatchEngine, placement
+    from gome_tpu.parallel import make_mesh
+
+    names = [f"sym{i}" for i in (7, 3, 11, 0, 5, 9, 2, 8, 1)]
+    router = ShardRouter(4)
+    assert [router.route(s) for s in names] == [k % 4 for k in range(9)]
+    assert [router.route(s) for s in reversed(names)] == [
+        k % 4 for k in reversed(range(9))]  # a symbol stays where it was put
+    eng = BatchEngine(BookConfig(cap=16, max_fills=4), n_slots=16,
+                      mesh=make_mesh(4))
+    local = eng.n_slots // 4
+    assert [eng._lane(s) // local for s in names] == [
+        router.route(s) for s in names]
+    k = np.arange(16)
+    lanes = placement.lane_of(k, 16, 4)
+    assert sorted(lanes.tolist()) == list(range(16))
+    assert (placement.arrival_of(lanes, 16, 4) == k).all()
+    assert (placement.lane_of(k, 16, 1) == k).all()

@@ -524,3 +524,25 @@ class TestBatchIngestRpc:
         assert "aborted at entry 0" in resp.message
         # The aborted entry's mark was undone.
         assert len(engine.pre_pool) == 0
+
+
+def test_build_service_attaches_the_persister_the_file_enables(tmp_path):
+    """gome_tpu.service.app.build_service(config): the deployment of a
+    loaded Config, which `main` starts: EngineService with its Persister
+    where persist.enabled, without one otherwise."""
+    from gome_tpu.config import PersistConfig
+    from gome_tpu.service.app import build_service
+
+    engine = EngineConfig(cap=32, n_slots=8, max_t=8)
+    durable = build_service(Config(
+        grpc=GrpcConfig(host="127.0.0.1", port=0), engine=engine,
+        persist=PersistConfig(enabled=True, dir=str(tmp_path / "snaps"),
+                              every_n_batches=1)))
+    assert isinstance(durable, EngineService)
+    assert durable.persist is not None
+    assert durable.persist.consumer is durable.consumer
+    assert durable.consumer.on_batch is not None
+    plain = build_service(Config(
+        grpc=GrpcConfig(host="127.0.0.1", port=0), engine=engine,
+        persist=PersistConfig(dir=str(tmp_path / "never"))))
+    assert plain.persist is None and plain.consumer.on_batch is None

@@ -6,6 +6,7 @@ compiles with the expected zero-collective partitioning."""
 
 import jax
 import numpy as np
+import pytest
 
 from gome_tpu.engine import BatchEngine, BookConfig, batch_step, init_books
 from gome_tpu.engine.book import DeviceOp
@@ -385,3 +386,315 @@ def test_fill_record_escalation_under_mesh_dense():
     got = eng.process_columnar(orders).to_results()
     assert got == expected
     assert eng.stats.fill_record_escalations >= 1
+
+
+# ---- lane placement under a mesh: the deal in arrival order (PR 28) -------
+
+
+def _listed_venue_stream(n_symbols, n_head, head_depth, n_flow, seed):
+    """A venue whose first listings are its majors: the stream opens with a
+    listing, round after round and symbol after symbol, hottest first (so
+    symbols ARRIVE in rank order), `head_depth` passive quotes a side on each
+    of the `n_head` hottest symbols and 2 on the rest; then a Zipf(1) flow of
+    limit adds, market adds and cancels."""
+    from gome_tpu.types import Action, OrderType
+
+    rng = np.random.default_rng(seed)
+    orders, resting = [], {k: [] for k in range(n_symbols)}
+
+    def add(k, side, price, kind=OrderType.LIMIT):
+        oid = f"o{len(orders)}"
+        orders.append(Order(
+            uuid=f"u{int(rng.integers(8))}", oid=oid, symbol=f"r{k:04d}",
+            side=side, price=price, volume=int(rng.integers(1, 5)),
+            action=Action.ADD, order_type=kind,
+        ))
+        if kind is OrderType.LIMIT:
+            resting[k].append((oid, side, price))
+
+    per_side = np.full(n_symbols, 2)
+    per_side[:n_head] = head_depth
+    for r in range(int(per_side.max())):
+        for k in np.flatnonzero(per_side > r):
+            add(k, Side.BUY, 990 - r % 20)
+            add(k, Side.SALE, 1010 + r % 20)
+    p = 1.0 / np.arange(1, n_symbols + 1)
+    ranks = rng.choice(n_symbols, size=n_flow, p=p / p.sum())
+    for k in ranks.tolist():
+        u = rng.random()
+        if u < 0.35 and resting[k]:
+            oid, side, price = resting[k].pop(int(rng.integers(len(resting[k]))))
+            orders.append(Order(
+                uuid="u0", oid=oid, symbol=f"r{k:04d}", side=side,
+                price=price, volume=1, action=Action.DEL,
+                order_type=OrderType.LIMIT,
+            ))
+        elif u < 0.5:
+            add(k, Side(int(rng.integers(2))), 0, OrderType.MARKET)
+        else:
+            side = Side(int(rng.integers(2)))
+            add(k, side, (985 if side is Side.BUY else 1005) + int(rng.integers(10)))
+    return orders
+
+
+def _frame_cols(orders, chunk):
+    from gome_tpu.bus import colwire
+
+    return [
+        colwire.decode_order_frame(colwire.encode_orders(orders[i:i + chunk]))
+        for i in range(0, len(orders), chunk)
+    ]
+
+
+def _served(engine_kw, frames_cols, orders, depth=2, watch=None):
+    """Frames through the served path (MatchEngine.admit_frame,
+    submit_frame / resolve_frame, `depth` frames in flight): the events of
+    each frame as an EventBatch, in order."""
+    from gome_tpu.engine.orchestrator import MatchEngine
+    from gome_tpu.engine.pipeline import FramePipeline
+
+    engine = MatchEngine(**engine_kw)
+    if watch is not None:
+        watch(engine.batch)
+    for o in orders:
+        engine.mark(o)
+    pipe = FramePipeline(engine, depth=depth)
+    out = []
+    for i, cols in enumerate(frames_cols):
+        out.extend(pipe.feed(cols, token=i))
+    out.extend(pipe.flush())
+    assert [token for token, _ in out] == list(range(len(frames_cols)))
+    return engine, [batch for _, batch in out]
+
+
+def _watch_shard_counts(seen):
+    """Note, for every dense grid packed, (cap class, first of its train,
+    live lanes of each shard)."""
+
+    def watch(eng):
+        inner = eng._grid_geometry
+
+        def geometry(live, first=True, cls=None):
+            dense, n_rows, lane_ids, row_of = inner(live, first=first, cls=cls)
+            if dense:
+                d = eng.mesh.size
+                seen.append((cls, first, (
+                    lane_ids.reshape(d, n_rows // d) < eng.n_slots
+                ).sum(axis=1)))
+            return dense, n_rows, lane_ids, row_of
+
+        eng._grid_geometry = geometry
+
+    return watch
+
+
+VENUE_KW = dict(
+    config=BookConfig(cap=256, max_fills=8, dtype=np.int32),
+    n_slots=512, max_t=8,
+)
+
+
+@pytest.fixture(scope="module")
+def venue():
+    orders = _listed_venue_stream(
+        n_symbols=128, n_head=8, head_depth=70, n_flow=3000, seed=28
+    )
+    oracle = OracleEngine()
+    expected = [ev for o in orders for ev in oracle.process(o)]
+    return orders, _frame_cols(orders, 1024), expected
+
+
+@pytest.fixture(scope="module")
+def served_on_four(venue):
+    orders, frames_cols, _ = venue
+    seen = []
+    engine, batches = _served(
+        dict(VENUE_KW, mesh=make_mesh(4)), frames_cols, orders,
+        watch=_watch_shard_counts(seen),
+    )
+    return engine, batches, seen
+
+
+@pytest.fixture(scope="module")
+def served_on_one(venue):
+    orders, frames_cols, _ = venue
+    return _served(VENUE_KW, frames_cols, orders)
+
+
+def test_served_frame_path_under_four_shards_matches_the_oracle(
+        venue, served_on_four):
+    _, _, expected = venue
+    engine, batches, _ = served_on_four
+    got = [ev for b in batches for ev in b.to_results()]
+    assert got == expected
+    assert engine.batch._sharded_dense_steppers, "never dense under the mesh"
+    assert engine.batch.stats.frame_fallbacks == 0
+    engine.batch.verify_books()
+
+
+def test_symbols_that_arrive_hottest_first_are_dealt_evenly(served_on_four):
+    """The per-shard live counts the packer saw: the deep head (8 lanes over
+    64 a side, the 256-slot class) lies 2 to a shard in every grid that
+    carries all of it, and the wide class-64 grid's largest shard holds at
+    most 1.25 times the mean. Block placement in arrival order put all 8 and
+    every hot lane on shard 0."""
+    engine, _, seen = served_on_four
+    eng = engine.batch
+    head = [c for cls, first, c in seen if cls == 256 and c.sum() == 8]
+    assert head and all((c == 2).all() for c in head), head[:3]
+    wide = [c for cls, first, c in seen if cls == 64 and first and c.sum() >= 64]
+    assert len(wide) >= 3
+    for c in wide:
+        assert c.max() <= 1.25 * c.mean(), c
+    # the placement itself: the k-th symbol to arrive on shard k mod 4, as
+    # that shard's (k // 4)-th lane
+    local = eng.n_slots // 4
+    for k in (0, 1, 2, 3, 4, 9, 127):
+        lane = eng._lane(f"r{k:04d}")
+        assert (lane // local, lane % local) == (k % 4, k // 4)
+        assert eng.symbol_lane(f"r{k:04d}") == k
+
+
+def test_sharding_changes_no_event_and_no_snapshot(served_on_four,
+                                                   served_on_one):
+    """Same frames, with and without a mesh: every column of every frame's
+    EventBatch (symbol_id among them), lane_books and export_state are those
+    of the one-chip engine, whatever row of whatever chip holds a lane."""
+    four, batches4, _ = served_on_four
+    one, batches1 = served_on_one
+    assert len(batches4) == len(batches1)
+    for b4, b1 in zip(batches4, batches1):
+        assert b4.symbols == b1.symbols
+        for name in b1.columns:
+            np.testing.assert_array_equal(b4.columns[name], b1.columns[name],
+                                          err_msg=name)
+    for a, b in zip(jax.tree.leaves(four.batch.lane_books()),
+                    jax.tree.leaves(one.batch.lane_books())):
+        np.testing.assert_array_equal(a, b)
+    s4, s1 = four.batch.export_state(), one.batch.export_state()
+    assert s4.keys() == s1.keys()
+    for key in s1:
+        if key == "books":
+            for leaf in s1["books"]:
+                np.testing.assert_array_equal(s4["books"][leaf],
+                                              s1["books"][leaf], err_msg=leaf)
+        else:
+            assert s4[key] == s1[key], key
+
+
+def test_snapshot_round_trips_across_mesh_and_no_mesh(venue, served_on_four):
+    """export_state under a mesh restores into an engine without one, that
+    one's snapshot back into a mesh engine (of another size), and all three
+    then match the same further flow alike."""
+    orders, _, _ = venue
+    four, _, _ = served_on_four
+    state = four.batch.export_state()
+    kw = dict(VENUE_KW)
+    cfg = kw.pop("config")
+    plain = BatchEngine(cfg, **kw)
+    plain.import_state(state)
+    again = BatchEngine(cfg, mesh=make_mesh(8), **kw)
+    again.import_state(plain.export_state())
+    back = BatchEngine(cfg, mesh=make_mesh(4), **kw)
+    back.import_state(again.export_state())
+    more = [
+        Order(uuid="u1", oid=f"m{i}", symbol=f"r{k:04d}", side=Side(i % 2),
+              price=(1030, 970)[i % 2], volume=3)  # both cross the book
+        for i, k in enumerate([0, 1, 2, 3, 5, 8, 13, 21, 34, 55, 89, 127] * 4)
+    ]
+    from gome_tpu.engine.frames import apply_frame_fast
+
+    results = []
+    for eng in (plain, again, back):
+        eng.verify_books()
+        got = []
+        for cols in _frame_cols(more, 16):
+            got.extend(apply_frame_fast(eng, cols).to_results())
+        results.append(got)
+    assert results[0] and results[0] == results[1] == results[2]
+    books = [jax.tree.leaves(e.lane_books()) for e in (plain, again, back)]
+    for a, b, c in zip(*books):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, c)
+
+
+def test_lane_growth_under_a_mesh_moves_every_lane_and_loses_nothing():
+    """8 provisioned lanes on 4 shards, 40 symbols: every growth widens each
+    shard's block, so every lane moves; events equal the oracle's and the
+    books those of an engine without a mesh, with a frame in flight across
+    each growth."""
+    orders = _listed_venue_stream(
+        n_symbols=40, n_head=2, head_depth=6, n_flow=400, seed=5
+    )
+    frames_cols = _frame_cols(orders, 48)
+    kw = dict(config=BookConfig(cap=64, max_fills=8, dtype=np.int32),
+              n_slots=8, max_t=8)
+    four, batches4 = _served(dict(kw, mesh=make_mesh(4)), frames_cols, orders)
+    one, batches1 = _served(kw, frames_cols, orders)
+    oracle = OracleEngine()
+    expected = [ev for o in orders for ev in oracle.process(o)]
+    assert [ev for b in batches4 for ev in b.to_results()] == expected
+    assert four.batch.stats.lane_growths >= 2
+    assert four.batch.n_slots % 4 == 0 and four.batch.n_slots >= 40
+    for b4, b1 in zip(batches4, batches1):
+        np.testing.assert_array_equal(b4.columns["symbol_id"],
+                                      b1.columns["symbol_id"])
+    n = min(four.batch.n_slots, one.batch.n_slots)
+    for a, b in zip(jax.tree.leaves(four.batch.lane_books()),
+                    jax.tree.leaves(one.batch.lane_books())):
+        np.testing.assert_array_equal(a[:n], b[:n])
+    four.batch.verify_books()
+
+
+def _packed_grid_digest():
+    """sha256 over every grid the frame packer makes of a seeded frame train
+    on an engine without a mesh: each grid's op fields, row -> lane ids, cap
+    class and decode metadata, frame after frame through the fast path (so
+    count_ub, the floors and the cap classes evolve as they do when served)."""
+    import hashlib
+
+    from gome_tpu.engine import frames
+
+    orders = _listed_venue_stream(
+        n_symbols=96, n_head=4, head_depth=70, n_flow=1500, seed=2028
+    )
+    kw = dict(VENUE_KW)
+    eng = BatchEngine(kw.pop("config"), **kw)
+    h = hashlib.sha256()
+    inner = frames.pack_frame_grids
+
+    def pack(e, a):
+        grids = inner(e, a)
+        for ops, meta, lane_ids, cap_g in grids:
+            h.update(repr((tuple(ops.action.shape), int(cap_g),
+                           lane_ids is None)).encode())
+            for field in ops._fields:
+                h.update(np.ascontiguousarray(getattr(ops, field)).tobytes())
+            if lane_ids is not None:
+                h.update(np.asarray(lane_ids, np.int64).tobytes())
+            for key in sorted(meta):
+                h.update(key.encode())
+                h.update(np.asarray(meta[key], np.int64).tobytes())
+        return grids
+
+    frames.pack_frame_grids = pack
+    try:
+        n_events = 0
+        for cols in _frame_cols(orders, 512):
+            n_events += len(frames.apply_frame_fast(eng, cols))
+    finally:
+        frames.pack_frame_grids = inner
+    return h.hexdigest(), n_events, eng.stats.device_calls
+
+
+def test_without_a_mesh_the_packed_grids_are_the_parents():
+    """The timed path of the one-chip cells: the same grids, byte for byte,
+    as the tree before the placement (digest taken from an unpacked
+    `git archive a4fbb9d` with this same function)."""
+    assert _packed_grid_digest() == PARENT_PACKED_GRIDS
+
+
+#: (digest, events, device calls) of _packed_grid_digest() on commit a4fbb9d.
+PARENT_PACKED_GRIDS = (
+    "1c3097caf37ec952395aa5d09105bc6d77f1c6f03fd07482225d2e0cd91a2a08", 728, 9
+)

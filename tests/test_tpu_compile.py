@@ -19,17 +19,22 @@ from gome_tpu.ops.pallas_match import plan_block_s
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 - no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -71,3 +76,44 @@ def test_the_kernel_compiles_for_v5e_under_its_geometrys_name(
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1
     assert calls[0].startswith(f"%{name}."), calls[0][:120]
+
+
+@pytest.mark.parametrize("rows, t, cap, name", [
+    # spot10k_mesh4: the wide class-64 grid and the deep band's class-256
+    # grid, the lane axis split over four chips; the kernel's name holds a
+    # chip's rows (a quarter of the grid's)
+    (2048, 16, 64, "match_dense_r512_t16_c64"),
+    (32, 512, 256, "match_dense_r8_t512_c256"),
+])
+def test_the_sharded_dense_step_compiles_for_four_v5e_chips_without_collectives(
+        topo, monkeypatch, rows, t, cap, name):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from gome_tpu.ops import pallas_match
+    from gome_tpu.parallel.mesh import SYM_AXIS, sharded_dense_step
+
+    # kernel_plan asks the default backend, which is the CPU here; the step
+    # is compiled for the described chips, so the test answers for them.
+    monkeypatch.setattr(pallas_match, "pallas_available",
+                        lambda dtype=jnp.int32: True)
+    mesh = Mesh(np.asarray(topo.devices), (SYM_AXIS,))
+    split = NamedSharding(mesh, PartitionSpec(SYM_AXIS))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=split)
+    store = BookConfig(cap=256, max_fills=16, dtype=jnp.int32)
+    books = jax.tree.map(place, jax.eval_shape(
+        lambda: jax.vmap(lambda _: init_book(store))(jnp.arange(10240))
+    ))
+    cell = jax.ShapeDtypeStruct((rows, t), jnp.int32, sharding=split)
+    ops = DeviceOp(**{f: cell for f in DeviceOp._fields})
+    ids = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=split)
+    cfg = BookConfig(cap=cap, max_fills=16, dtype=jnp.int32)
+    with jax.enable_x64(False):
+        text = sharded_dense_step(cfg, mesh, kernel="pallas").lower(
+            books, ids, ops).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and calls[0].startswith(f"%{name}."), calls
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in text, collective
