@@ -34,7 +34,8 @@ def add_order_servicer(server: grpc.Server, servicer) -> None:
         "SubscribeMatches": grpc.unary_stream_rpc_method_handler(
             servicer.SubscribeMatches,
             request_deserializer=pb.SubscribeRequest.FromString,
-            response_serializer=pb.MatchEvent.SerializeToString,
+            # MatchFeed.subscribe yields each MatchEvent already serialised.
+            response_serializer=None,
         ),
         "DoOrderBatch": grpc.unary_unary_rpc_method_handler(
             servicer.DoOrderBatch,

@@ -685,7 +685,9 @@ class OrderGateway:
             context.abort(
                 grpc.StatusCode.UNIMPLEMENTED, "no match feed attached"
             )
-        yield from self._match_feed.subscribe(context)
+        # The feed's own generator of serialised messages (api/service.py
+        # registers this method with the identity serializer).
+        return self._match_feed.subscribe(context)
 
 
 def serve_gateway(

@@ -3,17 +3,26 @@
 "matchOrder" queue, logs each MatchResult (rabbitmq.go:162-171), and — where
 the reference leaves a "your code..." stub (rabbitmq.go:169) — fans events
 out to in-process subscribers (the gateway's SubscribeMatches stream).
+
+The unit handed from the feed thread to a subscriber is the match message
+(one EVENT frame, or one run of JSON messages as a poll brought them): a
+list of serialised MatchEvent messages, one per surviving event, put on the
+subscriber's queue once. The wire stays one MatchEvent per gRPC message; the
+handler's thread only yields bytes that are already made.
 """
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
+
+import numpy as np
 
 from ..api import order_pb2 as pb
 from ..bus import QueueBus, decode_match_result
 from ..fixed import unscale
-from ..types import MatchResult, OrderSnapshot
+from ..types import MatchResult
 from ..utils.logging import get_logger
 from ..utils.metrics import REGISTRY
 from ..utils.tracing import annotate, poll_span, span
@@ -27,6 +36,16 @@ _dupes_total = REGISTRY.counter(
 _gaps_total = REGISTRY.counter(
     "gome_matchfeed_gaps_total",
     "missing matchfeed seqs observed (events lost upstream)",
+)
+# events over hand-offs = the size of the unit the subscribers are handed:
+# a frame's events on the frame wire, 1 where JSON messages come one by one.
+_handoffs_total = REGISTRY.counter(
+    "gome_matchfeed_handoffs_total",
+    "match messages (EVENT frames, runs of JSON messages) fanned out",
+)
+_events_total = REGISTRY.counter(
+    "gome_matchfeed_events_total",
+    "match events fanned out (after duplicate suppression)",
 )
 
 
@@ -74,6 +93,28 @@ class SeqTracker:
         self.last_seq = seq
         return True
 
+    def observe_run(self, seqs: range) -> int:
+        """A frame's consecutive seqs at once, with the counts that
+        ``observe`` on each in turn gives: returns how many leading seqs
+        were already seen (the caller delivers only the tail after them)."""
+        n = len(seqs)
+        if not n:
+            return 0
+        self.observed += n
+        last = self.last_seq
+        seen = 0
+        if last is not None:
+            seen = min(max(last - seqs.start + 1, 0), n)
+            if seen:
+                self.dupes += seen
+                _dupes_total.inc(seen)
+            elif seqs.start > last + 1:
+                self.gaps += seqs.start - last - 1
+                _gaps_total.inc(seqs.start - last - 1)
+        if seen < n:
+            self.last_seq = seqs[-1]
+        return seen
+
     def state(self) -> dict:
         return {
             "last_seq": self.last_seq,
@@ -83,25 +124,67 @@ class SeqTracker:
         }
 
 
-def snapshot_to_pb(s: OrderSnapshot) -> pb.OrderSnapshot:
+def _row_of(mr: MatchResult) -> tuple:
+    """A MatchResult as the 13 fields of its MatchEvent, in wire order."""
     # Wire doubles carry the reference's observable values: the scaled
     # float64 (SURVEY §2.2 — events serialize post-scaling nodes).
-    return pb.OrderSnapshot(
-        uuid=s.uuid,
-        oid=s.oid,
-        symbol=s.symbol,
-        transaction=int(s.side),
-        price=unscale(s.price),
-        volume=unscale(s.volume),
+    n, m = mr.node, mr.match_node
+    return (
+        n.uuid, n.oid, n.symbol, int(n.side),
+        unscale(n.price), unscale(n.volume),
+        m.uuid, m.oid, m.symbol, int(m.side),
+        unscale(m.price), unscale(m.volume),
+        float(mr.match_volume),
     )
 
 
-def match_result_to_pb(mr: MatchResult) -> pb.MatchEvent:
-    return pb.MatchEvent(
-        node=snapshot_to_pb(mr.node),
-        match_node=snapshot_to_pb(mr.match_node),
-        match_volume=float(mr.match_volume),
-    )
+def _frame_rows(batch) -> list[tuple]:
+    """An EVENT frame's events as ``_row_of(mr)`` gives them for
+    ``batch.to_results()``, without the objects: each column is read once,
+    the id tables are indexed once, and a cancel's match_node is its node
+    (engine.events.EventBatch.to_results is the reference; a test holds the
+    two together field by field)."""
+    c = batch.columns
+    cancel, side = c["is_cancel"], c["taker_side"]
+
+    def names(table, ids):
+        return [table[i] for i in ids.tolist()]
+
+    def maker(col, taker_col):
+        return np.where(cancel, taker_col, c[col])
+
+    def scaled(col):
+        return list(map(unscale, col.tolist()))
+
+    symbol = names(batch.symbols, c["symbol_id"])
+    return list(zip(
+        names(batch.uid_table, c["taker_uid"]),
+        names(batch.oid_table, c["taker_oid"]),
+        symbol,
+        side.tolist(),
+        scaled(c["taker_price"]),
+        scaled(c["taker_volume"]),
+        names(batch.uid_table, maker("maker_uid", c["taker_uid"])),
+        names(batch.oid_table, maker("maker_oid", c["taker_oid"])),
+        symbol,
+        np.where(cancel, side, 1 - side).tolist(),
+        scaled(maker("fill_price", c["taker_price"])),
+        scaled(maker("maker_volume", c["taker_volume"])),
+        map(float, np.where(cancel, 0, c["match_volume"]).tolist()),
+    ))
+
+
+def match_result_to_pb(mr) -> pb.MatchEvent:
+    """The one place a MatchEvent is built, from a MatchResult or from the
+    row of one (``_row_of``, ``_frame_rows``). The feed looks it up through
+    the module at every call, once per delivered event, so whoever replaces
+    this attribute sees, and may alter, everything that is delivered."""
+    ev = pb.MatchEvent()
+    n, m = ev.node, ev.match_node
+    (n.uuid, n.oid, n.symbol, n.transaction, n.price, n.volume,
+     m.uuid, m.oid, m.symbol, m.transaction, m.price, m.volume,
+     ev.match_volume) = mr if type(mr) is tuple else _row_of(mr)
+    return ev
 
 
 class MatchFeed:
@@ -136,44 +219,62 @@ class MatchFeed:
                 # One decode and one fan-out span per EVENT frame (one
                 # message = a whole batch of MatchResults, bus.colwire) or
                 # per run of JSON messages (one event each): never a span
-                # per event.
+                # per event. `seqs` is a range for a stamped frame, whose
+                # duplicates and gaps are decided on its ends.
                 j = i + 1
                 with span("feed_decode"):
                     if is_frame(msgs[i].body):
-                        results = decode_event_frame(
-                            msgs[i].body
-                        ).to_results()
+                        batch = decode_event_frame(msgs[i].body)
+                        rows = _frame_rows(batch)
+                        seqs = (
+                            None if batch.seq0 is None
+                            else range(batch.seq0, batch.seq0 + len(rows))
+                        )
                     else:
                         while j < len(msgs) and not is_frame(msgs[j].body):
                             j += 1
                         results = [
                             decode_match_result(m.body) for m in msgs[i:j]
                         ]
-                with span("feed_fanout", events=len(results),
+                        rows = [_row_of(mr) for mr in results]
+                        seqs = [mr.seq for mr in results]
+                with span("feed_fanout", events=len(rows),
                           subscribers=len(subs)):
-                    self._fan_out(results, subs)
+                    self._fan_out(rows, seqs, subs)
                 i = j
             self.bus.match_queue.commit(msgs[-1].offset + 1)
         return len(msgs)
 
-    def _fan_out(self, results, subs) -> None:
-        for mr in results:
-            if mr.seq is not None and not self.seq.observe(mr.seq):
-                self.suppressed += 1
-                continue
-            self.events_seen += 1
-            if self.log_events:
-                # rabbitmq.go:170's util.Info.Printf of the result
+    def _fan_out(self, rows, seqs, subs) -> None:
+        """One match message's events, as rows, to every subscriber as ONE
+        queue item: the serialised MatchEvent of each event not seen before,
+        in order."""
+        n = len(rows)
+        if type(seqs) is range:
+            rows = rows[self.seq.observe_run(seqs):]
+        elif seqs is not None:
+            observe = self.seq.observe
+            rows = [
+                row for row, seq in zip(rows, seqs)
+                if seq is None or observe(seq)
+            ]
+        self.suppressed += n - len(rows)
+        if not rows:
+            return
+        self.events_seen += len(rows)
+        _handoffs_total.inc()
+        _events_total.inc(len(rows))
+        # rabbitmq.go:170's util.Info.Printf of each result, for a
+        # deployment that logs at INFO; the level is looked at once here.
+        if self.log_events and log.isEnabledFor(logging.INFO):
+            for row in rows:  # wire order: 1, 7 the oids, 12 the volume
                 log.info(
                     "match %s: taker=%s maker=%s qty=%d",
-                    "CANCEL" if mr.is_cancel else "FILL",
-                    mr.node.oid,
-                    mr.match_node.oid,
-                    mr.match_volume,
+                    "FILL" if row[12] else "CANCEL", row[1], row[7], row[12],
                 )
-            ev = match_result_to_pb(mr)
-            for q in subs:
-                q.put(ev)
+        chunk = [match_result_to_pb(row).SerializeToString() for row in rows]
+        for q in subs:
+            q.put(chunk)
 
     def drain(self) -> int:
         total = 0
@@ -186,9 +287,10 @@ class MatchFeed:
         return {**self.seq.state(), "suppressed": self.suppressed}
 
     def subscribe(self, context=None):
-        """Generator of pb.MatchEvent for one subscriber (gateway streaming
-        handler). Ends when the gRPC context goes inactive or the feed
-        stops."""
+        """Generator of serialised pb.MatchEvent messages (bytes) for one
+        subscriber (the gateway's streaming handler sends them as they
+        are). Ends when the gRPC context goes inactive or the feed stops;
+        both are looked at once per queue item, not per event."""
         q: queue.Queue = queue.Queue()
         with self._lock:
             self._subs.append(q)
@@ -197,16 +299,16 @@ class MatchFeed:
                 if context is not None and not context.is_active():
                     return
                 try:
-                    ev = q.get_nowait()
+                    chunk = q.get_nowait()
                 except queue.Empty:
                     # Only an empty queue opens a span: time outside
-                    # stream_wait is gRPC serialising and sending.
+                    # stream_wait is gRPC sending.
                     with span("stream_wait"):
                         try:
-                            ev = q.get(timeout=0.1)
+                            chunk = q.get(timeout=0.1)
                         except queue.Empty:
                             continue
-                yield ev
+                yield from chunk
         finally:
             with self._lock:
                 self._subs.remove(q)

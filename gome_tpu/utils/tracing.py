@@ -32,7 +32,7 @@ gap of the device, and a stall, has a name per thread:
                                frame_admit, frame_pack, grid_dispatch (one per
                                grid), frame_fetch, frame_decode, publish_events
   match feed                   feed_poll, [feed_run_once:] feed_decode,
-                               feed_fanout
+                               feed_fanout (one per match message)
   gRPC handler, SubscribeMatches   stream_wait (only while its queue is empty)
 
 Wall minus thread CPU is the time a thread held a span open without running:

@@ -112,8 +112,9 @@ def main(argv=None) -> int:
                 rejected[i] += 1
 
     def subscriber() -> None:
-        # Real fan-out consumer: the generator's queue handoff is the
-        # SubscribeMatches path; it ends when the feed stops.
+        # Real fan-out consumer: the generator's queue handoff (one item
+        # per match message, yielded as one serialised MatchEvent per
+        # event) is the SubscribeMatches path; it ends when the feed stops.
         for _ in svc.feed.subscribe():
             sub_events[0] += 1
 
