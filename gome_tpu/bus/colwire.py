@@ -183,20 +183,24 @@ def _read_dict_column_v1(buf: memoryview, off: int, n: int):
     return values, idx, off
 
 
-def _pack_padded_column(strs) -> bytes:
-    """strs: list[str] (or np 'S' array). Pads to the batch max width.
-    str inputs are encoded to UTF-8 bytes FIRST — np.array(dtype='S') on
-    str objects is ASCII-only and would crash on in-contract non-ASCII
-    ids."""
+def _padded_array(strs) -> np.ndarray:
+    """strs: list[str] (or np 'S' array) -> the 'S{width}' array a padded
+    column holds, width the batch's longest. str inputs are encoded to
+    UTF-8 bytes FIRST — np.array(dtype='S') on str objects is ASCII-only
+    and would crash on in-contract non-ASCII ids."""
     if isinstance(strs, np.ndarray) and strs.dtype.kind == "S":
-        arr = np.ascontiguousarray(strs)
-    else:
-        arr = np.array(
-            [s if isinstance(s, bytes) else s.encode() for s in strs],
-            dtype="S",
-        )
-        if arr.dtype.itemsize == 0:  # all-empty edge
-            arr = arr.astype("S1")
+        return np.ascontiguousarray(strs)
+    arr = np.array(
+        [s if isinstance(s, bytes) else s.encode() for s in strs],
+        dtype="S",
+    )
+    if arr.dtype.itemsize == 0:  # all-empty edge
+        arr = arr.astype("S1")
+    return arr
+
+
+def _pack_padded_column(strs) -> bytes:
+    arr = _padded_array(strs)
     return struct.pack("<H", arr.dtype.itemsize) + arr.tobytes()
 
 
@@ -283,9 +287,12 @@ def encode_order_frame_blocks(blocks: list[bytes]) -> bytes:  # gomelint: hotpat
     )
 
 
-def encode_orders(orders) -> bytes:
-    """Convenience: a list of Order objects -> one ORDER frame (what a
-    batching gateway produces; shared by tests, the fuzzer, and examples)."""
+def orders_to_cols(orders) -> dict:
+    """A list of Order objects -> the column dict decode_order_frame
+    returns for the same orders (same keys and dtypes, dictionaries in
+    first-occurrence order, `trace` only when an order carries one),
+    built without bytes: how a list of Orders enters the engine's frame
+    path (engine.frames)."""
     n = len(orders)
     syms: list[str] = []
     uuids: list[str] = []
@@ -314,12 +321,24 @@ def encode_orders(orders) -> bytes:
             uuids.append(o.uuid)
         uuid_idx[i] = uuid_ix[o.uuid]
         oids.append(o.oid)
-    traces = None
+    cols = dict(
+        n=n, action=action, side=side, kind=kind, price=price,
+        volume=volume, symbols=syms, symbol_idx=sym_idx, uuids=uuids,
+        uuid_idx=uuid_idx, oids=_padded_array(oids),
+    )
     if any(o.trace is not None for o in orders):
-        traces = [o.trace or "" for o in orders]
+        cols["trace"] = _padded_array([o.trace or "" for o in orders])
+    return cols
+
+
+def encode_orders(orders) -> bytes:
+    """Convenience: a list of Order objects -> one ORDER frame (what a
+    batching gateway produces; shared by tests, the fuzzer, and examples)."""
+    c = orders_to_cols(orders)
     return encode_order_frame(
-        n, action, side, kind, price, volume, syms, sym_idx, uuids,
-        uuid_idx, oids, traces=traces,
+        c["n"], c["action"], c["side"], c["kind"], c["price"], c["volume"],
+        c["symbols"], c["symbol_idx"], c["uuids"], c["uuid_idx"], c["oids"],
+        traces=c.get("trace"),
     )
 
 

@@ -40,7 +40,7 @@ import numpy as np
 
 from ..obs.placement import PLACEMENT
 from ..obs.profiler import PROFILER
-from ..types import KERNELS, Action, MatchResult, Order
+from ..types import KERNELS, MatchResult, Order
 from ..utils.metrics import REGISTRY
 from ..utils.tracing import span
 from . import placement
@@ -53,7 +53,7 @@ from .book import (
     grow_books,
     init_books,
 )
-from .host import Interner, OpContext, decode_events, encode_op
+from .host import Interner
 from .step import ACTION_ADD, _Side, step_rows_impl
 
 # The donating twins donate the whole ops pytree; XLA reuses most of its
@@ -170,12 +170,13 @@ def _batch_step_impl(
 #     the transactional rollback (the "double-buffer" the GL6xx audit
 #     flags IS the transaction mechanism — see ARCHITECTURE.md);
 #   * the `_donating` twin donates the ops-grid transfer buffers. _step
-#     dispatches to it exactly when the grid is HOST-sourced (numpy —
-#     the object-path packers): every dispatch then re-transfers, so the
-#     device copy is provably dead and XLA reuses it for the [S, T]
-#     outputs instead of allocating fresh ones. Device-built scatter
-#     grids (frames.pack_frame_grids) stay undonated: the escalation
-#     path re-dispatches the same arrays.
+#     dispatches to it exactly when the grid is HOST-sourced (numpy):
+#     every dispatch then re-transfers, so the device copy is provably
+#     dead and XLA reuses it for the [S, T] outputs instead of allocating
+#     fresh ones. The one packer (frames.pack_frame_grids) builds its
+#     grids on the device and they stay undonated: the escalation path
+#     re-dispatches the same arrays. No caller hands _step a host grid
+#     any more (ROADMAP C5: the twins and the switch go together).
 batch_step = functools.partial(  # gomelint: disable=GL601 — see note above
     jax.jit, static_argnums=0
 )(_batch_step_impl)
@@ -462,7 +463,7 @@ def splice_outs(outs, overrides):
     """Build the `outs_at(field, rows, ts)` accessor decode_grid_columnar
     needs: reads StepOutput columns at packed (row, t) coordinates and
     splices in per-row escalation re-runs (each with its own record budget
-    K', padded to align). Shared by the object packer and the frame path."""
+    K', padded to align)."""
 
     def outs_at(field, rows, ts):
         base = np.asarray(getattr(outs, field))[rows, ts]
@@ -546,9 +547,10 @@ class EngineStats:
 class BatchEngine:
     """Host-side driver for the batched device engine.
 
-    Owns the device-resident [S] book stack, the symbol->lane mapping, and
-    the id interners; packs order lists into op grids and decodes StepOutputs
-    back into the global MatchResult event stream.
+    Owns the device-resident [S] book stack, the symbol->lane mapping, the
+    id interners and the grid geometry, and runs one op grid exactly
+    (_run_exact) or without a host sync (_step). How a batch of orders
+    becomes grids, and grids' outputs events, is engine.frames.
 
     This layer assumes orders already passed admission (pre-pool checks live
     in the orchestrator above — gome_tpu.engine.orchestrator); every ADD
@@ -585,19 +587,18 @@ class BatchEngine:
         it exists so CPU tests and the chip_smoke rehearsal can exercise
         the kernel's code path.
 
-        dense: allow the columnar path to pack batches touching few symbols
+        dense: allow the packer to put batches touching few symbols
         into compact gather/scatter grids over just the live lanes
         (dense_batch_step) instead of the full n_slots-row grid —
         throughput then tracks APPLIED ops, not provisioned lanes (Zipf
         flows). Semantics identical. It decides rows only.
 
         max_t / dense_t_max: the shallowest and the deepest time axis of a
-        columnar or frame grid. Depth is chosen per grid, dense or full,
-        from its row count and its deepest lane (_grid_depth): a hot
-        symbol's stream runs dense_t_max deep per device call where the
-        rows allow it (the single-symbol latency path), and max_t is the
-        depth of a grid whose lanes all fit it — and of every grid of the
-        per-order object path (process / _pack_grid).
+        grid. Depth is chosen per grid, dense or full, from its row count
+        and its deepest lane (_grid_depth): a hot symbol's stream runs
+        dense_t_max deep per device call where the rows allow it (the
+        single-symbol latency path), and max_t is the depth of a grid
+        whose lanes all fit it.
 
         mesh: an optional 1-D jax.sharding.Mesh (gome_tpu.parallel.make_mesh)
         partitioning the symbol-lane axis across chips. Matching needs zero
@@ -791,54 +792,6 @@ class BatchEngine:
             np.maximum(extra, 0, out=extra)
             self._ub_extra = extra
 
-    def _prepare_bases(self, pending, lanes) -> np.ndarray:
-        """Set / recenter per-lane price bases so every ADMITTED price in
-        `pending` is representable on device. Runs before packing;
-        recentering shifts the lane's resting prices on device (rare — only
-        when flow drifts more than REBASE_LIMIT ticks from the current
-        base).
-
-        Returns a boolean drop mask aligned with `pending`: True marks a
-        DEL whose price is unrepresentable under the lane's (possibly just
-        recentred) base. Only ADD limit prices feed the grow-only envelope —
-        a DEL price is a lookup key, not an admission (a wrong-price cancel
-        is in-contract and must miss, engine.go:92-98; the stock delorder
-        client hardcodes price 0.5). Since every RESTING price always fits
-        the window, an unrepresentable DEL provably matches nothing, so it
-        is dropped host-side as a missed cancel instead of widening the
-        envelope and poisoning the lane forever."""
-        n = len(pending)
-        drop = np.zeros(n, bool)
-        if not self._rebase:
-            return drop
-        from ..types import OrderType
-
-        lo: dict[int, int] = {}
-        hi: dict[int, int] = {}
-        for (_, o), lane in zip(pending, lanes):
-            if o.action is not Action.ADD or o.order_type is OrderType.MARKET:
-                # MARKET prices are documented-ignored (encoded 0); DEL/NOP
-                # prices never admit a resting order. Neither may widen the
-                # envelope.
-                continue
-            p = o.price
-            l = lo.get(lane)
-            if l is None:
-                lo[lane] = hi[lane] = p
-            else:
-                if p < l:
-                    lo[lane] = p
-                elif p > hi[lane]:
-                    hi[lane] = p
-        for lane, l in lo.items():
-            self._admit_lane_range(lane, l, hi[lane])
-        for i, ((_, o), lane) in enumerate(zip(pending, lanes)):
-            if o.action is Action.DEL and (
-                abs(o.price - int(self.price_base[lane])) > self._INT32_SAFE
-            ):
-                drop[i] = True
-        return drop
-
     # Buffer-floor helpers (shared with frames._compact_sizes): floors
     # are {pow2 op-class: slot count}; an int means "this size, in its
     # own class".
@@ -999,10 +952,10 @@ class BatchEngine:
 
     def _grid_geometry(self, live: np.ndarray, first: bool = True,
                        cls: int | None = None):
-        """The ROWS of a grid, shared by the object packer and the frame
-        path (engine.frames). A grid's geometry is two independent
-        decisions: this one (which lanes get a row, and whether rows are
-        indirected) and _grid_depth (how long the time axis is). When the
+        """The ROWS of a grid, for the frame packer (engine.frames). A
+        grid's geometry is two independent decisions: this one (which
+        lanes get a row, and whether rows are indirected) and
+        _grid_depth (how long the time axis is). When the
         batch touches few of the provisioned lanes, pack a compact grid
         over just the live lanes (row -> lane indirection, executed by
         dense_batch_step / parallel.mesh.sharded_dense_step); rows bucket
@@ -1095,8 +1048,8 @@ class BatchEngine:
     def _grid_depth(self, n_rows: int, need: int, cls: int, first: bool,
                     dense: bool) -> int:
         """The DEPTH (time-axis length) of a grid, for either kind of row
-        layout _grid_geometry chose and for both packers: from the grid's
-        row count and the deepest lane it has to carry (`need` ops).
+        layout _grid_geometry chose: from the grid's row count and the
+        deepest lane it has to carry (`need` ops).
 
         Depth is budgeted against rows: the step's record tensors are
         [T, K, R], so a wide grid must stay shallow (2048 rows x 8192 deep
@@ -1156,10 +1109,10 @@ class BatchEngine:
 
     def _admit_lane_range(self, lane: int, l: int, h: int) -> None:
         """Admit the ADD-limit price range [l, h] into `lane`'s grow-only
-        envelope, seeding or recentering the base as needed. Shared by the
-        object packer (_prepare_bases) and the vectorized frame path
-        (engine.frames). Raises CapacityError — committing NOTHING — when
-        the admitted envelope cannot fit an int32 window."""
+        envelope, seeding or recentering the base as needed (the scalar
+        step of frames._prepare_bases_vec). Raises CapacityError —
+        committing NOTHING — when the admitted envelope cannot fit an
+        int32 window."""
         if not self._base_set[lane]:
             nb = (l + h) // 2
             if max(h - nb, nb - l) > self._INT32_SAFE:
@@ -1239,13 +1192,6 @@ class BatchEngine:
         """The symbol's lane: its row of the book stack (_lane_of)."""
         return self._lane_of(self._arrival(symbol))
 
-    def _lanes(self, symbols) -> np.ndarray:
-        """Lanes of a sequence of symbols. Interns them all first: a growth
-        on the way moves the lanes of a mesh engine, so a lane is read off
-        only once the stack has its final size."""
-        ks = np.fromiter((self._arrival(s) for s in symbols), np.int64)
-        return self._lane_of(ks)
-
     def _checkpoint(self):
         """Everything a failed batch must roll back: the device book stack
         (immutable on device — retaining the reference is free) plus the
@@ -1278,305 +1224,24 @@ class BatchEngine:
         self._ub_extra = ub_extra.copy()
 
     def process(self, orders: list[Order]) -> list[MatchResult]:
-        """Apply a micro-batch. Symbols with more than max_t ops are drained
-        over several device calls (order preserved); returns all events in
-        original arrival order. Device-budget overflows are escalated
-        internally (see module docstring) — results are always exact.
+        """process_columnar() as MatchResult objects, in the reference's
+        global emission order."""
+        return self.process_columnar(orders).to_results()
 
-        Transactional: a raised batch rolls the engine back to its pre-batch
-        state (multi-grid batches commit device books per grid — without the
-        rollback, replaying a batch that failed on grid 2 would double-apply
-        grid 1's orders)."""
-        return [
-            ev
-            for _, evs in self.process_indexed(list(enumerate(orders)))
-            for ev in evs
-        ]
+    def process_columnar(self, orders: list[Order]):
+        """Apply a micro-batch given as Order objects: a convenience over
+        the frame path (engine.frames.process_frame on the orders' columns
+        — the exact, synchronous form). Returns a columnar EventBatch
+        (gome_tpu.engine.events) in original arrival order; device-budget
+        overflows are escalated internally (see module docstring), so
+        results are always exact. Transactional: a raised batch rolls the
+        engine back to its pre-batch state (multi-grid batches commit
+        device books per grid — without the rollback, replaying a batch
+        that failed on grid 2 would double-apply grid 1's orders)."""
+        from ..bus.colwire import orders_to_cols
+        from . import frames
 
-    # gomelint: hotpath
-    def process_indexed(
-        self, indexed: list[tuple[int, Order]]
-    ) -> list[tuple[int, list[MatchResult]]]:
-        """process() keyed by caller-assigned arrival tags: each input item
-        is (tag, order) and the result is (tag, events) groups sorted by
-        tag. The sharded engine (gome_tpu.parallel.router) passes GLOBAL
-        arrival indices here so per-shard results merge back into the exact
-        single-FIFO emission order of the reference consumer
-        (rabbitmq.go:116-125). Same transactional rollback as process()."""
-        cp = self._checkpoint()
-        try:
-            return self._process_indexed(indexed)
-        except Exception:
-            self._restore(cp)
-            raise
-
-    def _process_indexed(self, indexed):
-        pending = list(indexed)
-        decoded: list[tuple[int, list[MatchResult]]] = []
-        while pending:
-            pending = self._one_grid(pending, decoded)
-        decoded.sort(key=lambda kv: kv[0])
-        self.stats.orders += len(indexed)
-        for _, evs in decoded:
-            for ev in evs:
-                if ev.is_cancel:
-                    self.stats.cancels += 1
-                else:
-                    self.stats.fills += 1
-        return decoded
-
-    def _pack_grid(self, pending):
-        """Pack a pending (arrival, order) list into one [S, max_t] op grid.
-        Returns (ops, contexts, leftover): contexts maps (lane, t) -> the
-        packed (arrival, order); leftover holds deferred ops from lanes
-        whose time axis filled (FIFO within a symbol is never split)."""
-        # Resolve lanes first (this may auto-grow the book stack), so the
-        # grid is allocated once at the final lane count and newly created
-        # lanes pack into THIS grid rather than deferring to an extra
-        # device call.
-        lanes = self._lanes(o.symbol for _, o in pending).tolist()
-        drop = self._prepare_bases(pending, lanes)
-        grid = _nop_grid(self.config, self.n_slots, self.max_t)
-        contexts: dict[tuple[int, int], tuple[int, Order]] = {}
-        fill_level: dict[int, int] = {}
-        leftover: list[tuple[int, Order]] = []
-        blocked: set[int] = set()  # lanes whose FIFO order must not be broken
-
-        for (arrival, order), lane, dropped in zip(pending, lanes, drop):
-            if dropped:
-                # Unrepresentable DEL price (see _prepare_bases): provably a
-                # miss; never reaches the device.
-                self.stats.cancels_missed += 1
-                continue
-            t = fill_level.get(lane, 0)
-            if lane in blocked or t >= self.max_t:
-                # Lane's time axis is full: defer, and block the lane so
-                # same-symbol ops never reorder (SURVEY §5.2).
-                blocked.add(lane)
-                leftover.append((arrival, order))
-                continue
-            op = encode_op(
-                order,
-                self.oids,
-                self.uids,
-                self.config.dtype,
-                price_base=int(self.price_base[lane]),
-            )
-            for name, arr in grid.items():
-                arr[lane, t] = getattr(op, name)
-            contexts[(lane, t)] = (arrival, order)
-            fill_level[lane] = t + 1
-            if order.action is Action.ADD and not op.is_market:
-                self._ub_extra[lane] += 1  # count_ub upper-bound upkeep
-        return DeviceOp(**grid), contexts, leftover
-
-    def process_columnar(self, orders: list[Order]):  # gomelint: hotpath
-        """Apply a micro-batch and return events as a columnar EventBatch
-        (gome_tpu.engine.events) instead of MatchResult objects — the
-        vectorized decode path that keeps the host in step with the device
-        kernel's throughput. Identical event content and global order to
-        process(); stats are updated the same way. Transactional like
-        process(): a raised batch rolls back to pre-batch state."""
-        cp = self._checkpoint()
-        try:
-            return self._process_columnar(orders)
-        except Exception:
-            self._restore(cp)
-            raise
-
-    def _process_columnar(self, orders: list[Order]):
-        from .events import EventBatch, empty_batch
-
-        pending = [(i, o) for i, o in enumerate(orders)]
-        dels = sum(1 for o in orders if o.action is Action.DEL)
-        batches: list[dict] = []  # per-grid column dicts
-        while pending:
-            pending = self._one_grid_columnar(pending, batches)
-        self.stats.orders += len(orders)
-
-        tables = dict(
-            symbols=self.symbols.to_list(),
-            oid_table=self.oids.table,
-            uid_table=self.uids.table,
-        )
-        if not batches:
-            # Nothing reached the device (e.g. every op was a dropped
-            # unrepresentable DEL): they are all missed cancels.
-            self.stats.cancels_missed += dels
-            return empty_batch(**tables)
-        cols = {
-            n: np.concatenate([b[n] for b in batches]) for n in batches[0]
-        }
-        # Leftover grids hold deferred ops whose arrivals interleave with
-        # the first grid's: restore the global emission order.
-        order_ix = np.argsort(cols["arrival"], kind="stable")
-        cols = {n: v[order_ix] for n, v in cols.items()}
-        batch = EventBatch(columns=cols, **tables)
-        cancels = int(batch.columns["is_cancel"].sum())
-        self.stats.cancels += cancels
-        self.stats.fills += len(batch) - cancels
-        self.stats.cancels_missed += dels - cancels
-        return batch
-
-    def _pack_grid_vectorized(self, pending):
-        """Columnar-path packing: one Python pass extracts per-op fields into
-        a [N, 8] int table; lane/slot assignment and the grid writes are
-        numpy scatters. ~10x cheaper per op than _pack_grid's per-field
-        scalar stores (the decode side is vectorized too, so packing would
-        otherwise dominate the host budget)."""
-        from ..types import OrderType
-
-        n = len(pending)
-        lanes = self._lanes(o.symbol for _, o in pending)
-        drop = self._prepare_bases(pending, lanes)
-        bases = self.price_base[lanes]  # [N] int64
-        # Slot within the lane = occurrence index (FIFO by construction:
-        # occurrence order == arrival order, and every op past the grid's
-        # time depth defers, so a lane's stream never reorders or splits
-        # across grids). Dropped DELs (unrepresentable price,
-        # _prepare_bases) consume no slot and are neither packed nor
-        # deferred — the columnar missed-cancel accounting (dels - cancel
-        # events) covers them.
-        t = np.full(n, -1, np.int64)
-        level: dict[int, int] = {}
-        for i, lane in enumerate(lanes):
-            if drop[i]:
-                continue
-            c = level.get(lane, 0)
-            t[i] = c
-            level[lane] = c + 1
-
-        live = (
-            np.unique(lanes[~drop]) if bool((~drop).any())
-            else np.zeros(0, np.int64)
-        )
-        use_dense, n_rows, lane_ids, row_of = self._grid_geometry(live)
-        row = row_of[lanes] if use_dense else lanes
-        t_grid = self._grid_depth(
-            n_rows, max(level.values(), default=0), self.config.cap, True,
-            use_dense,
-        )
-        packed = (t >= 0) & (t < t_grid)
-
-        oids, uids = self.oids, self.uids
-        table = np.empty((n, 7), np.int64)
-        for i, (_, o) in enumerate(pending):
-            rec = table[i]
-            rec[0] = int(o.action)
-            rec[1] = int(o.side)
-            rec[2] = o.order_type is OrderType.MARKET
-            rec[3] = o.price
-            rec[4] = o.volume
-            rec[5] = oids.intern(o.oid)
-            rec[6] = uids.intern(o.uuid)
-        adds = packed & (table[:, 0] == int(Action.ADD))
-        # Keep count_ub an upper bound across paths: every packed limit ADD
-        # may rest once (the frame path's increments live in
-        # frames._frame_arrays; this is the object-path equivalent).
-        rest_candidates = adds & (table[:, 2] == 0)
-        if rest_candidates.any():
-            self._ub_extra += np.bincount(
-                lanes[rest_candidates], minlength=self.n_slots
-            )
-        bad = adds & (table[:, 4] <= 0)
-        if bad.any():
-            i = int(np.nonzero(bad)[0][0])
-            raise ValueError(
-                f"volume must be positive, got {table[i, 4]} "
-                f"(oid={pending[i][1].oid}); volume<=0 is out of contract"
-            )
-        if np.dtype(self.config.dtype).itemsize <= 4:
-            from .step import LOT_MAX32
-
-            over = adds & (table[:, 4] > LOT_MAX32)
-            if over.any():
-                i = int(np.nonzero(over)[0][0])
-                raise ValueError(
-                    f"volume {table[i, 4]} exceeds the int32-mode per-order "
-                    f"lot ceiling {LOT_MAX32} (oid={pending[i][1].oid}); "
-                    "use coarser lot units or an int64 BookConfig"
-                )
-
-        grid = _nop_grid(self.config, n_rows, t_grid)
-        pl, pt = row[packed], t[packed]
-        for col, name in enumerate(
-            ("action", "side", "is_market", "price", "volume", "oid", "uid")
-        ):
-            vals = table[packed, col]
-            if name == "price":
-                # Device sees rebased ticks; MARKET prices are documented-
-                # ignored and encode as 0 (they are excluded from the
-                # envelope, so rebasing them could overflow).
-                vals = np.where(
-                    table[packed, 2] != 0, 0, vals - bases[packed]
-                )
-            grid[name][pl, pt] = vals
-        meta = {
-            "lane": self._symbol_ids(lanes[packed]),  # the events' symbol_id
-            "row": pl,
-            "t": pt,
-            "arrival": np.fromiter(
-                (a for (a, _), p in zip(pending, packed) if p),
-                np.int64,
-            ),
-            "action": table[packed, 0],
-            "side": table[packed, 1],
-            "is_market": table[packed, 2],
-            "price": table[packed, 3],  # absolute (events carry these)
-            "price_base": bases[packed],
-            "oid_id": table[packed, 5],
-            "uid_id": table[packed, 6],
-        }
-        leftover = [pending[i] for i in np.nonzero(~packed & ~drop)[0]]
-        return DeviceOp(**grid), meta, leftover, lane_ids
-
-    def _one_grid_columnar(self, pending, batches):
-        from .events import decode_grid_columnar
-
-        with span("frame_pack"):
-            ops, meta, leftover, lane_ids = self._pack_grid_vectorized(
-                pending
-            )
-        if len(meta["arrival"]) == 0:
-            # Everything dropped (unrepresentable DELs): nothing to run.
-            return leftover
-        # _run_exact keys escalation bookkeeping by (row, t); give it the
-        # packed coordinates.
-        contexts = {
-            (int(r), int(tt)): None for r, tt in zip(meta["row"], meta["t"])
-        }
-        outs, lane_overrides = self._run_exact(ops, contexts, lane_ids)
-        with span("frame_decode"):
-            batches.append(
-                decode_grid_columnar(
-                    meta, splice_outs(outs, lane_overrides)
-                )
-            )
-        return leftover
-
-    def _one_grid(self, pending, decoded):
-        ops, contexts, leftover = self._pack_grid(pending)
-        if not contexts:
-            # Everything dropped (unrepresentable DELs): nothing to run.
-            return leftover
-        outs, lane_overrides = self._run_exact(ops, contexts)
-        for (lane, t), (arrival, order) in contexts.items():
-            src = lane_overrides.get(lane)
-            if src is not None:
-                out = jax.tree.map(lambda a: a[t], src)
-            else:
-                out = jax.tree.map(lambda a: a[lane, t], outs)
-            events = decode_events(
-                OpContext(order),
-                out,
-                self.oids,
-                self.uids,
-                price_base=int(self.price_base[lane]),
-            )
-            if order.action is Action.DEL and not events:
-                self.stats.cancels_missed += 1
-            decoded.append((arrival, events))
-        return leftover
+        return frames.process_frame(self, orders_to_cols(orders))
 
     def _run_exact(self, ops: DeviceOp, contexts, lane_ids=None,
                    cap_g: int | None = None):
@@ -1751,11 +1416,11 @@ class BatchEngine:
         cfg = self.config
         if cap_g is not None and cap_g != cfg.cap:
             cfg = dataclasses.replace(cfg, cap=cap_g)
-        # Donation policy (GL6xx): a HOST-sourced grid (numpy — the
-        # object-path packers) re-transfers on every dispatch, so its
-        # device buffers are dead after the call and the donating twins
-        # let XLA reuse them for the outputs. Device-built grids
-        # (frames._scatter_grid_fn) must NOT donate: escalation replays
+        # Donation policy (GL6xx): a HOST-sourced grid (numpy)
+        # re-transfers on every dispatch, so its device buffers are dead
+        # after the call and the donating twins let XLA reuse them for
+        # the outputs. Device-built grids (frames._scatter_grid_fn, all
+        # the packer makes) must NOT donate: escalation replays
         # re-dispatch the same arrays (_run_exact's phase-1 loop).
         donate = isinstance(ops.action, np.ndarray)
         _batch = batch_step_donating if donate else batch_step

@@ -1,18 +1,18 @@
-"""Columnar event batches: the high-throughput decode path.
+"""Columnar event batches: the decode of the exact path.
 
-The object decoder (host.decode_events) builds one MatchResult dataclass per
-fill — exact, but Python-object construction caps end-to-end throughput at
-a few hundred thousand events/sec, far below what the device side sustains
-(gome_tpu.ops.pallas_match). This module decodes a whole grid's StepOutputs
-into numpy columns in O(vector ops), deferring (or skipping) object
-construction:
+Building one MatchResult dataclass per fill (as the single-op harness
+host.decode_events does) is exact, but Python-object construction caps
+end-to-end throughput at a few hundred thousand events/sec, far below what
+the device side sustains (gome_tpu.ops.pallas_match). This module decodes a
+whole grid's StepOutputs into numpy columns in O(vector ops), deferring (or
+skipping) object construction:
 
   * `EventBatch` — one numpy column per MatchResult field, in the exact
     reference emission order (arrival order of the taker op; best level
     first, FIFO within level, within an op — SURVEY §3.4).
-  * `EventBatch.to_results()` — materialize the same `list[MatchResult]`
-    the object decoder produces (used by the compatibility wrapper and the
-    parity tests that pin the two paths together).
+  * `EventBatch.to_results()` — materialize the `list[MatchResult]` the
+    oracle produces (what process() returns, and what the parity tests
+    compare).
   * `EventBatch.to_json_lines()` — serialize straight from columns in the
     matchOrder wire shape, never constructing per-event objects.
 
@@ -67,8 +67,7 @@ class EventBatch:
         return len(self.columns["arrival"])
 
     def to_results(self) -> list[MatchResult]:
-        """Materialize MatchResult objects (identical to the per-op object
-        decoder's output, same order)."""
+        """Materialize MatchResult objects (the oracle's, same order)."""
         c = self.columns
         out: list[MatchResult] = []
         oid_t, uid_t, syms = self.oid_table, self.uid_table, self.symbols

@@ -1,38 +1,40 @@
-"""Frame path: apply a columnar order batch with ZERO per-order Python.
+"""The frame packer: how a batch of orders becomes op grids, and the grids'
+outputs events, with ZERO per-order Python.
 
-The object path (BatchEngine.process_columnar) builds one `Order` per
-message and walks a Python loop per op to intern ids and fill the device
-grid — ~1-2 µs/order of host time, a 10x gap to the 1M orders/sec
-north-star once the device no longer bottlenecks. This module applies a
-decoded ORDER frame (gome_tpu.bus.colwire) straight from numpy columns:
+The engine's one way in is a decoded ORDER frame's columns
+(gome_tpu.bus.colwire: decode_order_frame on the wire, orders_to_cols for
+a list of Order objects); everything here works on them as numpy arrays:
 
-  * interning is vectorized: `np.unique` reduces each string column to its
-    per-batch uniques, the interner dict is touched once per UNIQUE value,
-    and a take() broadcasts ids back to all N orders;
-  * the rebasing envelope, the unrepresentable-DEL drop mask, and the
-    per-lane time-slot assignment are all numpy (sort/segment tricks);
-  * grid packing reuses the object path's geometry decision
-    (BatchEngine._grid_geometry: dense gather/scatter grids vs full
-    grids) and the SAME _run_exact / decode_grid_columnar machinery, so
-    escalations and event decoding are shared — the frame path changes
-    how ops get INTO a grid, nothing about what a grid means.
+  * interning is vectorized: the interner dict is touched once per UNIQUE
+    symbol and uuid of the frame's dictionaries, and a take() broadcasts
+    ids back to all N orders;
+  * the rebasing envelope (_prepare_bases_vec), the unrepresentable-DEL
+    drop mask, and the per-lane time-slot assignment are all numpy
+    (sort/segment tricks) or one native pass (nativehost);
+  * grid packing splits the frame into per-cap-class grid trains, takes
+    each grid's rows and depth from BatchEngine (_grid_geometry: dense
+    gather/scatter grids vs full grids; _grid_depth) and builds the
+    padded grid on the device from O(ops) bytes (_scatter_grid_fn).
 
-Two execution strategies:
+Two ways to run a frame's grids, on the same BatchEngine state:
 
-  * `apply_frame` — exact, synchronous: each grid runs through
-    BatchEngine._run_exact (device budgets escalate in-line). One device
-    round trip per grid.
-  * `apply_frame_fast` — the production hot path: every grid of the frame
-    is DISPATCHED back-to-back with a device-side event-compaction kernel
-    (compact_accum) appended, then ONE async fetch resolves the
-    whole frame. The compaction reduces the transfer from O(S*T*K) record
-    tensors (~500 B/order) to O(events)
+  * `apply_frame` / `process_frame` — exact, synchronous: each grid runs
+    through BatchEngine._run_exact (device budgets escalate in-line) and
+    decodes through events.decode_grid_columnar. One device round trip
+    per grid. The list-of-Order conveniences (BatchEngine.process /
+    process_columnar, MatchEngine's) are this form.
+  * `apply_frame_fast` (submit_frame + resolve_frame; across frames,
+    engine.pipeline.FramePipeline) — the production hot path: every grid
+    of the frame is DISPATCHED back-to-back with a device-side
+    event-compaction kernel (compact_accum) appended, then ONE async
+    fetch resolves the whole frame. The compaction reduces the transfer
+    from O(S*T*K) record tensors (~500 B/order) to O(events)
     (~30 B/order). If any device budget tripped (book overflow, record
     truncation, compaction buffer), the frame transactionally rolls back
     and re-runs on the exact path — rare by construction, never wrong.
 
-Event content and ordering are pinned to the object path by differential
-tests (tests/test_frames.py).
+Event content and ordering of both are pinned to the oracle's by
+differential tests (tests/test_frames.py).
 """
 
 from __future__ import annotations
@@ -319,14 +321,13 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
     """Pack one cap class's grid train (the loop body of the original
     single-train pack_frame_grids, with geometry ratchets keyed by the
     class). Each grid's geometry is two decisions made apart, both on
-    BatchEngine so the object packer makes them the same way:
-    _grid_geometry picks the ROWS (a dense grid over the live lanes, or
-    the full grid with row == lane once the row bucket reaches n_slots)
-    and _grid_depth picks the DEPTH from the row count and the deepest
-    lane still to carry, whichever kind the rows are. So a venue
-    provisioned with exactly its live lanes runs a hot lane's frame as a
-    couple of deep full grids, not as a train of max_t-deep ones; max_t
-    is only the shallowest depth class."""
+    BatchEngine: _grid_geometry picks the ROWS (a dense grid over the
+    live lanes, or the full grid with row == lane once the row bucket
+    reaches n_slots) and _grid_depth picks the DEPTH from the row count
+    and the deepest lane still to carry, whichever kind the rows are. So
+    a venue provisioned with exactly its live lanes runs a hot lane's
+    frame as a couple of deep full grids, not as a train of max_t-deep
+    ones; max_t is only the shallowest depth class."""
     lanes, t = a["lanes"], a["t"]
     t_off = 0
     while len(active_idx):
@@ -443,8 +444,8 @@ def _assemble(eng, a, batches):
 
 def apply_frame(eng: BatchEngine, cols: dict):
     """Exact synchronous frame application (one _run_exact per grid);
-    returns an EventBatch identical to process_columnar on the same
-    orders. Caller guarantees admission was already applied."""
+    returns the frame's EventBatch. Caller guarantees admission was
+    already applied."""
     from .events import decode_grid_columnar
 
     with span("frame_pack"):
@@ -473,7 +474,8 @@ def apply_frame(eng: BatchEngine, cols: dict):
 
 
 def process_frame(eng: BatchEngine, cols: dict):
-    """Transactional wrapper (same rollback contract as process_columnar)."""
+    """apply_frame, transactional: a raised frame rolls the engine back
+    to its pre-frame state."""
     cp = eng._checkpoint()
     try:
         return apply_frame(eng, cols)
@@ -1062,10 +1064,10 @@ class _NeedExact(Exception):
 
 
 def orders_from_frame(cols: dict):
-    """Decode an ORDER frame into Order objects — the compatibility path
-    for engines without a native frame pipeline (e.g. the in-process
-    ShardedEngine facade; sharded deployments route frames per shard
-    upstream instead, so this loop is never on a hot path)."""
+    """Decode an ORDER frame into Order objects (the inverse of
+    colwire.orders_to_cols) — for the consumer's poison bisect and the
+    recovery scan, which handle orders one by one
+    (bus.decode_message_orders); never on a hot path."""
     from ..types import Action, Order, OrderType, Side
 
     syms, uuids = cols["symbols"], cols["uuids"]
@@ -1095,10 +1097,24 @@ def orders_from_frame(cols: dict):
 
 
 def _prepare_bases_vec(eng, lanes, action, kind, price) -> np.ndarray:
-    """Vectorized _prepare_bases: same semantics as the object path
-    (ADD-limit-only grow-only envelope; commit after checks; unrepresentable
-    DELs dropped as misses), with numpy segment min/max and a Python loop
-    only over the UNIQUE lanes admitting prices this batch."""
+    """Set / recenter per-lane price bases so every ADMITTED price of the
+    batch is representable on device. Runs before packing; recentering
+    shifts the lane's resting prices on device (rare — only when flow
+    drifts more than REBASE_LIMIT ticks from the current base). Numpy
+    segment min/max, and a Python loop only over the UNIQUE lanes that
+    seed or recenter this batch (BatchEngine._admit_lane_range, which
+    commits a lane's envelope only after every check passed).
+
+    Returns a boolean drop mask aligned with the batch: True marks a DEL
+    whose price is unrepresentable under the lane's (possibly just
+    recentred) base. Only ADD limit prices feed the grow-only envelope —
+    MARKET prices are documented-ignored (encoded 0), and a DEL price is
+    a lookup key, not an admission (a wrong-price cancel is in-contract
+    and must miss, engine.go:92-98; the stock delorder client hardcodes
+    price 0.5). Since every RESTING price always fits the window, an
+    unrepresentable DEL provably matches nothing, so it is dropped
+    host-side as a missed cancel instead of widening the envelope and
+    poisoning the lane forever."""
     n = len(lanes)
     drop = np.zeros(n, bool)
     if not eng._rebase:

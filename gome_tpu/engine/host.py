@@ -83,7 +83,7 @@ def encode_op(
     """Order -> scalar DeviceOp (numpy scalars; cheap to batch later).
     dtype must match BookConfig.dtype so the device writeback needs no cast.
     price_base: the lane's rebasing offset (32-bit books store prices
-    relative to it; see BatchEngine._prepare_bases)."""
+    relative to it; see frames._prepare_bases_vec)."""
     if order.action is Action.ADD and order.volume <= 0:
         raise ValueError(
             f"volume must be positive, got {order.volume} (oid={order.oid}); "

@@ -22,11 +22,31 @@ layer (gome_tpu.persist).
 
 from __future__ import annotations
 
+import numpy as np
+
 from ..types import Action, MatchResult, Order
 from ..utils.tracing import span
 from .batch import BatchEngine, EngineStats, is_device_fault
 from .book import BookConfig
 from .prepool import consume_batch_of, make_prepool
+
+
+def _kept_rows(cols: dict, keep) -> dict:
+    """An ORDER frame's columns without the rows admission dropped (the
+    dictionaries stay whole: the index columns still point into them)."""
+    if keep.all():
+        return cols
+    return dict(
+        cols,
+        n=int(keep.sum()),
+        **{
+            k: np.ascontiguousarray(cols[k][keep])
+            for k in (
+                "action", "side", "kind", "price", "volume",
+                "symbol_idx", "uuid_idx", "oids",
+            )
+        },
+    )
 
 
 class MatchEngine:
@@ -93,69 +113,20 @@ class MatchEngine:
         event stream in the reference's global emission order. Admission
         (the pre-pool check, engine.go:58-62) drops ADDs cancelled before
         consumption without touching the book."""
-        return [
-            ev
-            for _, evs in self.process_indexed(list(enumerate(orders)))
-            for ev in evs
-        ]
-
-    def process_indexed(
-        self, indexed: list[tuple[int, Order]]
-    ) -> list[tuple[int, list[MatchResult]]]:
-        """process() keyed by caller-assigned arrival tags (see
-        BatchEngine.process_indexed) — admission applies identically; tags
-        of dropped ADDs simply emit no group."""
-        admitted, consumed = self._admit(indexed)
-        try:
-            return self.batch.process_indexed(admitted)
-        except Exception:
-            self.pre_pool |= consumed
-            raise
+        return self.process_columnar(orders).to_results()
 
     def process_one(self, order: Order) -> list[MatchResult]:
         return self.process([order])
 
     def process_columnar(self, orders: list[Order]):
-        """process() with the vectorized decode path: same admission, same
-        event content/order, but returns a columnar EventBatch
-        (gome_tpu.engine.events) — the shape the consumer publishes from
-        without building per-event objects."""
-        admitted, consumed = self._admit(list(enumerate(orders)))
-        try:
-            return self.batch.process_columnar([o for _, o in admitted])
-        except Exception:
-            self.pre_pool |= consumed
-            raise
+        """process() as a columnar EventBatch (gome_tpu.engine.events) —
+        the shape the consumer publishes from without building per-event
+        objects. A convenience over process_frame on the orders' columns
+        (the exact, synchronous form): same admission, and a raised batch
+        restores the pre-pool marks it consumed."""
+        from ..bus.colwire import orders_to_cols
 
-    def _admit(
-        self, indexed: list[tuple[int, Order]]
-    ) -> tuple[list[tuple[int, Order]], set]:
-        """Apply admission over (tag, order) items; also returns the
-        pre-pool keys this batch consumed so a FAILED batch can restore them
-        (process/_columnar do) — the at-least-once consumer replays failed
-        batches, and a replayed ADD must not die as unmarked just because
-        the failed attempt already popped its key."""
-        sel: list[tuple[int, Order]] = []
-        keys: list[tuple[str, str, str]] = []
-        for item in indexed:
-            action = item[1].action
-            if action is Action.ADD or action is Action.DEL:
-                sel.append(item)
-                keys.append(self._prekey(item[1]))
-            # NOP padding never reaches the device.
-        existed = consume_batch_of(self.pre_pool, keys)
-        admitted: list[tuple[int, Order]] = []
-        consumed: set[tuple[str, str, str]] = set()
-        for item, key, ex in zip(sel, keys, existed):
-            if item[1].action is Action.ADD:
-                if not ex:
-                    self.stats.dropped_no_prepool += 1
-                    continue
-                consumed.add(key)
-            elif ex:
-                consumed.add(key)
-            admitted.append(item)
-        return admitted, consumed
+        return self.process_frame(orders_to_cols(orders), fast=False)
 
     # -- views -------------------------------------------------------------
     @property
@@ -174,13 +145,17 @@ class MatchEngine:
 
     def process_frame(self, cols: dict, fast: bool = True):
         """Columnar-frame ingestion (bus.colwire ORDER frames): admission
-        semantics identical to process() — unmarked ADDs drop, DELs clear
-        their marks — applied by filtering the columns, then the
-        zero-per-order-Python frame path (engine.frames) runs the batch.
-        Returns an EventBatch. fast=True uses the device-side
-        event-compaction path (one fetch per frame; transparently falls
-        back to the exact escalating path when a device budget trips).
-        For cross-frame pipelining use engine.pipeline.FramePipeline."""
+        (the pre-pool check, engine.go:58-62: unmarked ADDs drop, DELs
+        clear their marks) applied by filtering the columns, then the
+        frame packer (engine.frames) runs the batch. Returns an
+        EventBatch. fast=True uses the device-side event-compaction path
+        (one fetch per frame; transparently falls back to the exact
+        escalating path when a device budget trips); fast=False the
+        exact path itself. A raised frame restores the marks it consumed
+        — the at-least-once consumer replays failed batches, and a
+        replayed ADD must not die as unmarked just because the failed
+        attempt already popped its key. For cross-frame pipelining use
+        engine.pipeline.FramePipeline."""
         from . import frames
 
         cols, consumed = self.admit_frame(cols)
@@ -200,8 +175,6 @@ class MatchEngine:
             return self._admit_frame(cols)
 
     def _admit_frame(self, cols: dict) -> tuple[dict, set]:
-        import numpy as np
-
         consume_frame = getattr(self.pre_pool, "consume_frame", None)
         if consume_frame is not None:
             # Fused native pass: compose keys + pop markers + masks in C++.
@@ -210,19 +183,7 @@ class MatchEngine:
                 ((cols["action"] == int(Action.ADD)) & ~keep).sum()
             )
             self.stats.dropped_no_prepool += dropped
-            if not keep.all():
-                cols = dict(
-                    cols,
-                    n=int(keep.sum()),
-                    **{
-                        k: np.ascontiguousarray(cols[k][keep])
-                        for k in (
-                            "action", "side", "kind", "price", "volume",
-                            "symbol_idx", "uuid_idx", "oids",
-                        )
-                    },
-                )
-            return cols, consumed
+            return _kept_rows(cols, keep), consumed
 
         n = int(cols["n"])
         action = cols["action"].tolist()
@@ -257,19 +218,7 @@ class MatchEngine:
                 if ex:
                     consumed.add(keys[i])
         self.stats.dropped_no_prepool += dropped
-        if not keep.all():
-            cols = dict(
-                cols,
-                n=int(keep.sum()),
-                **{
-                    k: np.ascontiguousarray(cols[k][keep])
-                    for k in (
-                        "action", "side", "kind", "price", "volume",
-                        "symbol_idx", "uuid_idx", "oids",
-                    )
-                },
-            )
-        return cols, consumed
+        return _kept_rows(cols, keep), consumed
 
     # -- geometry persistence ----------------------------------------------
     def save_geometry(self, path: str) -> None:

@@ -5,7 +5,7 @@ from .mesh import (
     sharded_batch_step,
     symbol_sharding,
 )
-from .router import ShardedEngine, ShardRouter, fnv1a, multihost_mesh
+from .router import ShardRouter, fnv1a, multihost_mesh
 
 __all__ = [
     "make_mesh",
@@ -14,7 +14,6 @@ __all__ = [
     "sharded_batch_step",
     "symbol_sharding",
     "ShardRouter",
-    "ShardedEngine",
     "fnv1a",
     "multihost_mesh",
 ]

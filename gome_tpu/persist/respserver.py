@@ -127,6 +127,11 @@ class FakeRedisServer:
 
         port, store = self.port, self.store
         self.stop()
+        # Join before rebinding _stop: an accept thread that has not yet
+        # seen the old event set would read the new one, loop on into the
+        # NEW listener, and hold it open through the next restart.
+        for t in self._threads:
+            t.join(timeout=2.0)
         self._stop = threading.Event()
         self._threads = []
         self._conns = []

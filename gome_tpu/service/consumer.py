@@ -203,15 +203,13 @@ class OrderConsumer:
         return tids
 
     def _publish(self, batch) -> None:
-        # Frame publishing needs real EventBatch columns; the sharded
-        # facade's compatibility wrapper (router._ResultsBatch) publishes
-        # reference JSON instead. Every event is stamped with the next
-        # matchfeed seq (GCE2 header / JSON "Seq" / AMQP x-seq);
-        # match_seq only advances once the publish SUCCEEDED, so a failed
-        # publish replays with the same seqs.
+        # Every event is stamped with the next matchfeed seq (GCE2 header
+        # / JSON "Seq" / AMQP x-seq); match_seq only advances once the
+        # publish SUCCEEDED, so a failed publish replays with the same
+        # seqs.
         seq0 = self.match_seq
         n = len(batch)
-        if self.match_wire == "frame" and hasattr(batch, "columns"):
+        if self.match_wire == "frame":
             from ..bus.colwire import encode_event_frame
 
             if n:

@@ -1,9 +1,10 @@
 """Differential fuzz: device engine vs the pure-Python oracle on randomized
 streams under adversarial engine geometries (tiny caps -> constant cap
 escalation, tiny max_fills -> record escalations, max_t=1 -> per-op grids,
-lane growth, int32 rebasing at extreme price bases, and all three decode
-paths: object, columnar, and ORDER frames through MatchEngine admission +
-the cross-frame device pipeline).
+lane growth, int32 rebasing at extreme price bases, and the three ways to
+run a batch through the one frame packer: the list form (exact), the
+encoded frame on the compacted fast path, and ORDER frames through
+MatchEngine admission + the cross-frame device pipeline).
 
     python scripts/fuzz.py [n_cases] [seed0] [--tpu]
 
@@ -57,10 +58,12 @@ def run_case(seed: int) -> str:
     max_t = int(rng.choice([1, 3, 16]))
     n_slots = int(rng.choice([1, 2, 8, 16]))
     dtype = jnp.int32 if rng.random() < 0.5 else jnp.int64
-    # object: per-order path; columnar: vectorized decode; frame: ORDER
-    # frames through MatchEngine admission + the cross-frame device
-    # pipeline (random depth) — the native host ops' differential target.
-    mode = str(rng.choice(["object", "columnar", "frame"]))
+    # list: Order lists through the exact path (process); fast: the
+    # encoded frame through apply_frame_fast (device-side compaction,
+    # exact fallback); frame: ORDER frames through MatchEngine admission +
+    # the cross-frame device pipeline (random depth) — the native host
+    # ops' differential target.
+    mode = str(rng.choice(["list", "fast", "frame"]))
     n_symbols = int(rng.choice([1, 3, 7]))
     base_price = int(
         rng.choice(
@@ -148,10 +151,7 @@ def run_case(seed: int) -> str:
         got = []
         for i in range(0, len(orders), chunk):
             part = orders[i : i + chunk]
-            if mode == "columnar":
-                got.extend(engine.process_columnar(part).to_results())
-            else:
-                got.extend(engine.process(part))
+            got.extend(_run_part(engine, part, mode))
     from gome_tpu.ops import default_block_s, pallas_available
 
     effective = (
@@ -185,6 +185,19 @@ def run_case(seed: int) -> str:
         f"{engine.stats.cap_escalations}"
         f"/{engine.stats.fill_record_escalations}"
     )
+
+
+def _run_part(engine, part, mode: str) -> list:
+    """One chunk through a BatchEngine: "list" is the exact list form,
+    "fast" the same orders as an encoded frame on the compacted path —
+    two executions of one packer, both held to the oracle."""
+    if mode == "list":
+        return engine.process(part)
+    from gome_tpu.bus.colwire import decode_order_frame, encode_orders
+    from gome_tpu.engine.frames import apply_frame_fast
+
+    cols = decode_order_frame(encode_orders(part))
+    return apply_frame_fast(engine, cols).to_results()
 
 
 def run_sim_case(seed: int) -> str:
@@ -238,7 +251,7 @@ def run_sim_case(seed: int) -> str:
     max_t = int(rng.choice([1, 3, 16]))
     n_slots = int(rng.choice([1, 2, flow.n_lanes]))
     dtype = jnp.int32 if rng.random() < 0.5 else jnp.int64
-    mode = str(rng.choice(["object", "columnar"]))
+    mode = str(rng.choice(["list", "fast"]))
     chunk = int(rng.choice([1, 17, 64]))
     engine = BatchEngine(
         BookConfig(cap=cap, max_fills=max_fills, dtype=dtype),
@@ -247,10 +260,7 @@ def run_sim_case(seed: int) -> str:
     got = []
     for i in range(0, len(orders), chunk):
         part = orders[i : i + chunk]
-        if mode == "columnar":
-            got.extend(engine.process_columnar(part).to_results())
-        else:
-            got.extend(engine.process(part))
+        got.extend(_run_part(engine, part, mode))
     desc = (
         f"seed={seed} SIM lanes={flow.n_lanes} t_bins={flow.t_bins} "
         f"grids={n_grids} n={len(orders)} cap={cap} K={max_fills} "

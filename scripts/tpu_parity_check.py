@@ -255,14 +255,18 @@ def run_engine_escalation_parity(log=print) -> int:
 def run_fuzz_slice(cases=2, log=print) -> int:
     """A small compiled-mode slice of the differential fuzzer's geometry
     space (the three round-1 Mosaic crashes were all found by randomized
-    geometries; CI only runs interpret mode)."""
+    geometries; CI only runs interpret mode). Cases alternate between the
+    two ways to run a frame's grids: the list form (exact path) and the
+    encoded frame on the compacted fast path."""
     import jax.numpy as jnp
 
     _, rc = _block_or_fail(8, 16, "fuzz", log)
     if rc:
         return rc
 
+    from gome_tpu.bus.colwire import decode_order_frame, encode_orders
     from gome_tpu.engine import BatchEngine, BookConfig
+    from gome_tpu.engine.frames import apply_frame_fast
     from gome_tpu.oracle import OracleEngine
     from gome_tpu.utils.streams import multi_symbol_stream
 
@@ -279,18 +283,23 @@ def run_fuzz_slice(cases=2, log=print) -> int:
             BookConfig(cap=cap, max_fills=k, dtype=jnp.int32),
             n_slots=8, max_t=8, kernel="pallas",
         )
+        run = "fast" if c % 2 else "list"
         got = []
         for i in range(0, len(orders), 50):
-            got.extend(
-                eng.process_columnar(orders[i : i + 50]).to_results()
-            )
+            part = orders[i : i + 50]
+            if run == "list":
+                got.extend(eng.process(part))
+            else:
+                cols = decode_order_frame(encode_orders(part))
+                got.extend(apply_frame_fast(eng, cols).to_results())
         oracle = OracleEngine()
         want = [r for o in orders for r in oracle.process(o)]
         if got != want:
-            log(f"MISMATCH fuzz case {c} (cap={cap} K={k} syms={n_sym})")
+            log(f"MISMATCH fuzz case {c} ({run}, cap={cap} K={k} "
+                f"syms={n_sym})")
             return 1
         eng.verify_books()
-        log(f"fuzz case {c} OK (cap={cap} K={k} syms={n_sym}, "
+        log(f"fuzz case {c} OK ({run}, cap={cap} K={k} syms={n_sym}, "
             f"{len(got)} events)")
     log(f"fuzz PARITY OK: {cases} compiled-mode randomized geometries")
     return 0

@@ -1147,7 +1147,7 @@ def test_hot_path_seeds_reach_the_engine():
     graph = callgraph.build(Project(mods))
     hot = {fn.name for fn in graph.hot_functions()}
     for must in ("run_once", "_run_exact", "submit_frame", "resolve_frame",
-                 "_pack_grid_vectorized", "feed"):
+                 "pack_frame_grids", "feed"):
         assert must in hot, f"{must} fell off the hot path"
 
 

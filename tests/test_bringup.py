@@ -18,6 +18,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from gome_tpu.engine import BatchEngine, BookConfig
@@ -82,8 +83,12 @@ def test_engine_stats_count_every_grid_by_the_kernel_that_ran_it():
     # 3 symbols -> an 8-row dense grid: the whole-axis block fits at any cap.
     few = [_add(f"a{i}", 100 + i, symbol=f"s{i % 3}") for i in range(9)]
     eng.process_columnar(few)
-    # 100 symbols -> rows bucket to n_slots: a full [128, 4] grid at the
-    # storage cap 1024, whose 128-lane book tile is over the budget.
+    # 100 symbols -> rows bucket to n_slots: a full [128, 4] grid. The
+    # packer takes a grid's cap class from its lanes' resting-count bound;
+    # with the bound past the 256 class (as after 300 ADDs a lane) the grid
+    # runs at the storage cap 1024, whose 128-lane book tile is over the
+    # budget.
+    eng.note_packed_adds(np.full(eng.n_slots, 300))
     wide = [_add(f"b{i}", 100, symbol=f"w{i}") for i in range(100)]
     eng.process_columnar(wide)
 

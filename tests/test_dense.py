@@ -228,8 +228,10 @@ def test_full_grid_runs_deep_when_n_slots_equals_live_lanes():
     shallow = BatchEngine(
         BookConfig(cap=128, max_fills=16), n_slots=8, max_t=4, dense=False
     )
-    replay = shallow.process(orders)
-    assert shallow.stats.device_calls == 150  # ceil(600 / max_t)
+    replay = []
+    for i in range(0, len(orders), 4):  # a frame of max_t ops: one [8 x 4]
+        replay += shallow.process(orders[i : i + 4])
+    assert shallow.stats.device_calls == 150  # 600 / max_t
     assert got == replay
 
 

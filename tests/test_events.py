@@ -1,5 +1,6 @@
 """Columnar decode path (engine/events.py): exact equivalence with the
-per-op object decoder and with the oracle, plus wire-format byte parity."""
+oracle, as columns and as MatchResult objects, plus wire-format byte
+parity."""
 
 
 from gome_tpu.bus.codec import encode_match_result
@@ -17,7 +18,9 @@ def _fresh_engines(**kw):
 
 def test_columnar_equals_object_decode():
     """Same mixed stream (fills, partial fills, cancels, market orders)
-    through both decode paths -> identical MatchResult lists."""
+    through both list forms -> the oracle's MatchResult list, whether the
+    engine materialises the objects (process) or the caller does
+    (process_columnar().to_results())."""
     orders = mixed_stream(n=220, seed=13, cancel_prob=0.25, market_prob=0.1)
     obj_engine, col_engine = _fresh_engines()
     obj_events, col_events = [], []
@@ -25,7 +28,9 @@ def test_columnar_equals_object_decode():
         chunk = orders[i : i + 50]
         obj_events.extend(obj_engine.process(chunk))
         col_events.extend(col_engine.process_columnar(chunk).to_results())
-    assert obj_events == col_events
+    oracle = OracleEngine()
+    expected = [ev for o in orders for ev in oracle.process(o)]
+    assert obj_events == col_events == expected
     assert len(obj_events) > 0
 
 

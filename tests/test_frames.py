@@ -1,6 +1,10 @@
 """Frame path (engine.frames + bus.colwire): wire codec round-trips and
-differential parity — the vectorized frame path must produce the identical
-EventBatch the object path produces for the same orders."""
+differential parity — every way in (an encoded frame on the exact or the
+compacted path, a list of Orders through the list forms) runs the one frame
+packer and produces the oracle's events."""
+
+import dataclasses
+import hashlib
 
 import jax.numpy as jnp
 import numpy as np
@@ -38,7 +42,7 @@ def run_frames(eng, orders, chunk, fast=False):
     return out
 
 
-def run_objects(eng, orders, chunk):
+def run_lists(eng, orders, chunk):
     out = []
     for i in range(0, len(orders), chunk):
         out.extend(eng.process_columnar(orders[i : i + chunk]).to_results())
@@ -57,22 +61,26 @@ def _oracle(orders):
     "n_slots,chunk,fast",
     [(64, 97, False), (8, 50, False), (64, 97, True), (8, 50, True)],
 )
-def test_frame_path_matches_object_path_and_oracle(n_slots, chunk, fast):
+def test_frame_path_matches_list_form_and_oracle(n_slots, chunk, fast):
     orders = multi_symbol_stream(n=400, n_symbols=6, seed=21, cancel_prob=0.2)
     a = BatchEngine(BookConfig(cap=32, max_fills=8), n_slots=n_slots, max_t=8)
     b = BatchEngine(BookConfig(cap=32, max_fills=8), n_slots=n_slots, max_t=8)
     got_f = run_frames(a, orders, chunk, fast=fast)
-    got_o = run_objects(b, orders, chunk)
-    assert got_f == got_o == _oracle(orders)
+    got_l = run_lists(b, orders, chunk)
+    assert got_f == got_l == _oracle(orders)
     a.verify_books()
+    _assert_same_books(a, b)
+
+
+def _assert_same_books(a, b):
+    """Two engines' books equal leaf for leaf. The oid/uid leaves hold
+    interner ids, which depend on the order a dictionary lists its
+    strings in (a producer's business): compared through the tables."""
     ba, bb = a.lane_books(), b.lane_books()
     for name in ("price", "lots", "seq", "count", "next_seq"):
         np.testing.assert_array_equal(
             np.asarray(getattr(ba, name)), np.asarray(getattr(bb, name))
         )
-    # oid/uid leaves hold interner ids, and the frame path interns in
-    # sorted-unique order (np.unique) vs the object path's first-occurrence
-    # order — compare through the tables.
     for leaf, ta, tb in (
         ("oid", a.oids.table, b.oids.table),
         ("uid", a.uids.table, b.uids.table),
@@ -83,6 +91,244 @@ def test_frame_path_matches_object_path_and_oracle(n_slots, chunk, fast):
         sb = np.array(tb, dtype=object)[xb]
         active = np.asarray(ba.lots) > 0
         assert (sa[active] == sb[active]).all(), leaf
+
+
+# --- one way in: the list forms are conveniences over the frame path --------
+
+
+def _wire_stream(traced):
+    orders = multi_symbol_stream(n=120, n_symbols=5, seed=30, cancel_prob=0.2)
+    orders.append(
+        Order(uuid="ü", oid="mkt-é", symbol="s0", side=Side.BUY, price=0,
+              volume=7, order_type=OrderType.MARKET)
+    )
+    if traced:
+        orders = [
+            dataclasses.replace(o, trace=f"{i:04x}@{i}.5") if i % 3 == 0
+            else o
+            for i, o in enumerate(orders)
+        ]
+    return orders
+
+
+@pytest.mark.parametrize(
+    "traced,digest",
+    [
+        (False,
+         "8b72f572b1c18f982b567b71afb3e45492c43c842d1bf284301dc92730987d1f"),
+        (True,
+         "73c90a7e49fd0d058fccab6f80b1f618a148925c6a2221c41656ab30676c850f"),
+    ],
+)
+def test_orders_to_cols_equals_decoded_frame(traced, digest):
+    """orders_to_cols builds, without bytes, the dict decode_order_frame
+    returns for the same orders — key for key, dtype for dtype — and
+    encode_orders still writes the bytes it wrote before it was split
+    (digests taken on the parent commit, 76cd419)."""
+    orders = _wire_stream(traced)
+    payload = colwire.encode_orders(orders)
+    assert hashlib.sha256(payload).hexdigest() == digest
+    want = colwire.decode_order_frame(payload)
+    got = colwire.orders_to_cols(orders)
+    assert list(got) == list(want)
+    assert ("trace" in got) == traced
+    for key, w in want.items():
+        g = got[key]
+        if isinstance(w, np.ndarray):
+            assert isinstance(g, np.ndarray) and g.dtype == w.dtype, key
+            np.testing.assert_array_equal(g, w)
+        else:
+            assert type(g) is type(w) and g == w, key
+    empty = colwire.orders_to_cols([])
+    back = colwire.decode_order_frame(colwire.encode_orders([]))
+    assert empty["n"] == back["n"] == 0
+    assert empty["oids"].dtype == back["oids"].dtype
+
+
+_LIST_FORMS = {
+    # name -> (engine class is MatchEngine?, chunk, call)
+    "BatchEngine.process": (False, 97, lambda e, part: e.process(part)),
+    "BatchEngine.process_columnar": (
+        False, 97, lambda e, part: e.process_columnar(part).to_results()),
+    "MatchEngine.process": (True, 97, lambda e, part: e.process(part)),
+    "MatchEngine.process_columnar": (
+        True, 97, lambda e, part: e.process_columnar(part).to_results()),
+    "MatchEngine.process_one": (
+        True, 1, lambda e, part: e.process_one(part[0])),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.int64], ids=["i32", "i64"])
+@pytest.mark.parametrize("form", sorted(_LIST_FORMS))
+def test_list_forms_are_the_frame_path(form, dtype, monkeypatch):
+    """Every list-of-Order entry packs its batch with the frame packer
+    (one pack_frame_grids call a batch) and gives the oracle's events and,
+    leaf for leaf, the books of frames.process_frame on the encoded
+    frames of the same stream."""
+    from gome_tpu.engine import frames
+    from gome_tpu.engine.orchestrator import MatchEngine
+
+    is_match, chunk, call = _LIST_FORMS[form]
+    orders = multi_symbol_stream(n=300, n_symbols=5, seed=17, cancel_prob=0.2)
+    orders.append(
+        Order(uuid="t", oid="mkt", symbol=orders[0].symbol, side=Side.BUY,
+              price=0, volume=9, order_type=OrderType.MARKET)
+    )
+    cfg = BookConfig(cap=32, max_fills=8, dtype=dtype)
+    ref = BatchEngine(cfg, n_slots=16, max_t=8)
+    want = run_frames(ref, orders, chunk)
+    assert want == _oracle(orders)
+
+    packs = []
+    real_pack = frames.pack_frame_grids
+
+    def counting_pack(eng, a):
+        packs.append(a["n"])
+        return real_pack(eng, a)
+
+    monkeypatch.setattr(frames, "pack_frame_grids", counting_pack)
+    if is_match:
+        eng = MatchEngine(config=cfg, n_slots=16, max_t=8)
+        for o in orders:
+            eng.mark(o)
+        batch_eng = eng.batch
+    else:
+        eng = batch_eng = BatchEngine(cfg, n_slots=16, max_t=8)
+    got = []
+    for i in range(0, len(orders), chunk):
+        got.extend(call(eng, orders[i : i + chunk]))
+    assert got == want
+    assert packs == [
+        len(orders[i : i + chunk]) for i in range(0, len(orders), chunk)
+    ]
+    assert batch_eng.stats.orders == len(orders)
+    batch_eng.verify_books()
+    _assert_same_books(batch_eng, ref)
+
+
+def test_list_form_failure_restores_marks():
+    """A raised batch through a list form leaves the pre-pool and the books
+    as they were before it: the at-least-once consumer replays a failed
+    batch, and a replayed ADD must not die as unmarked because the failed
+    attempt popped its key."""
+    from gome_tpu.engine.orchestrator import MatchEngine
+
+    orders = multi_symbol_stream(n=120, n_symbols=4, seed=3, cancel_prob=0.2)
+    head, tail = orders[:60], orders[60:]
+    eng = MatchEngine(
+        config=BookConfig(cap=32, max_fills=8, dtype=jnp.int32),
+        n_slots=8, max_t=8,
+    )
+    for o in orders:
+        eng.mark(o)
+    got = eng.process(head)
+    poison = Order(uuid="p", oid="poison", symbol=tail[0].symbol,
+                   side=Side.BUY, price=tail[0].price, volume=0)
+    eng.mark(poison)
+    marks = set(eng.pre_pool)
+    books = eng.batch.export_state()["books"]
+    stats = dataclasses.asdict(eng.stats)
+    bad = tail[:30] + [poison] + tail[30:]
+    for form in (eng.process_columnar, eng.process):
+        with pytest.raises(ValueError, match="volume must be positive"):
+            form(bad)
+        assert set(eng.pre_pool) == marks
+        after = eng.batch.export_state()["books"]
+        for leaf, before in books.items():
+            np.testing.assert_array_equal(after[leaf], before, leaf)
+    assert dataclasses.asdict(eng.stats) == stats
+    # The replay without the poison order is the stream the oracle saw.
+    eng.unmark(poison)
+    got += eng.process_columnar(tail).to_results()
+    assert got == _oracle(orders)
+    assert set(eng.pre_pool) == set()
+
+
+def test_every_entry_dispatches_device_grids():
+    """No public entry hands BatchEngine._step a grid built on the host:
+    the frame packer scatters every grid on the device, so `_step`'s
+    donate switch (numpy ops -> the donating twins) is never true. What
+    ROADMAP C5 rests on."""
+    import jax
+
+    from gome_tpu.engine import frames
+    from gome_tpu.engine.orchestrator import MatchEngine
+    from gome_tpu.engine.pipeline import FramePipeline
+
+    orders = multi_symbol_stream(n=240, n_symbols=6, seed=8, cancel_prob=0.2)
+    # A sweep over more resting orders than cap and max_fills: the exact
+    # path's escalation replays go through _step too.
+    orders += [
+        Order(uuid="u", oid=f"deep{i}", symbol="deep", side=Side.SALE,
+              price=100 + i, volume=1)
+        for i in range(24)
+    ] + [Order(uuid="u", oid="sweep", symbol="deep", side=Side.BUY,
+               price=300, volume=1000)]
+    seen = {}
+
+    def recording(eng, entry):
+        real = eng._step
+
+        def step(books, ops, *a, **k):
+            seen.setdefault(entry, []).append(type(ops.action))
+            return real(books, ops, *a, **k)
+
+        eng._step = step
+
+    def cols_of(part):
+        return colwire.decode_order_frame(orders_to_frame(part))
+
+    cfg = BookConfig(cap=8, max_fills=4)
+
+    def mk_batch(part):
+        return BatchEngine(cfg, n_slots=16, max_t=4)
+
+    def mk_match(part):
+        eng = MatchEngine(config=cfg, n_slots=16, max_t=4)
+        for o in part:
+            eng.mark(o)
+        return eng
+
+    def pipeline(e, part):
+        pipe = FramePipeline(e, depth=2)
+        for i in range(0, len(part), 50):
+            pipe.feed(cols_of(part[i : i + 50]))
+        pipe.flush()
+
+    def precompile(e, part):
+        e.process(part[:40])  # exact path: records no combo
+        frames.apply_frame_fast(e, cols_of(part[40:90]))
+        assert frames.precompile_combos(e, e.combos()) >= 1
+
+    entries = {
+        "BatchEngine.process": (mk_batch, lambda e, p: e.process(p)),
+        "BatchEngine.process_columnar": (
+            mk_batch, lambda e, p: e.process_columnar(p)),
+        "frames.process_frame": (
+            mk_batch, lambda e, p: frames.process_frame(e, cols_of(p))),
+        "frames.apply_frame_fast": (
+            mk_batch, lambda e, p: frames.apply_frame_fast(e, cols_of(p))),
+        "frames.precompile_combos": (mk_batch, precompile),
+        "MatchEngine.process": (mk_match, lambda e, p: e.process(p)),
+        "MatchEngine.process_columnar": (
+            mk_match, lambda e, p: e.process_columnar(p)),
+        "MatchEngine.process_one": (
+            mk_match, lambda e, p: [e.process_one(o) for o in p[:20]]),
+        "MatchEngine.process_frame(fast=True)": (
+            mk_match, lambda e, p: e.process_frame(cols_of(p), fast=True)),
+        "MatchEngine.process_frame(fast=False)": (
+            mk_match, lambda e, p: e.process_frame(cols_of(p), fast=False)),
+        "FramePipeline": (mk_match, pipeline),
+    }
+    for entry, (make, run) in entries.items():
+        eng = make(orders)
+        recording(getattr(eng, "batch", eng), entry)
+        run(eng, orders)
+    assert set(seen) == set(entries)
+    for entry, kinds in seen.items():
+        assert kinds, entry
+        assert all(issubclass(k, jax.Array) for k in kinds), (entry, kinds)
+        assert not any(issubclass(k, np.ndarray) for k in kinds), entry
 
 
 def test_frame_path_int32_rebasing_and_dropped_dels():
