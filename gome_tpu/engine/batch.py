@@ -523,6 +523,12 @@ class EngineStats:
     cancels_missed: int = 0
     dropped_no_prepool: int = 0  # incremented by the orchestrator facade
     device_calls: int = 0
+    # Fast-path frames dispatched with device grids (frames.submit_frame);
+    # of them, those whose event buffers were an earlier frame's, and those
+    # whose events came back with their totals (frames.ONE_PHASE_MAX_BYTES).
+    fast_frames: int = 0
+    fast_frames_reused: int = 0
+    fast_frames_one_phase: int = 0
     cap_escalations: int = 0
     # Confined escalations: one GRID's cap class deepened (re-sliced from
     # the same storage) without growing the [S]-wide stack — the cheap
@@ -671,6 +677,11 @@ class BatchEngine:
         # trace cost (which the XLA persistent cache does NOT cover: it
         # caches compiles, not traces) moves off every hot path.
         self._seen_combos: set[tuple] = set()
+        # Resolved frames' event buffers, handed to the next frame of the
+        # same shapes: (e_fills, e_cancels, totals_len) -> sets of
+        # (fills, cancels, totals) device arrays no frame in flight holds
+        # (frames._take_buffers / _give_buffers).
+        self._event_buffers: dict[tuple, list] = {}
         if mesh is not None:
             # Every place n_slots can be set (init, growth, restore) must
             # produce a mesh multiple; enforcing the two static bounds here

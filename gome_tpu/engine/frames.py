@@ -26,12 +26,20 @@ Two ways to run a frame's grids, on the same BatchEngine state:
   * `apply_frame_fast` (submit_frame + resolve_frame; across frames,
     engine.pipeline.FramePipeline) — the production hot path: every grid
     of the frame is DISPATCHED back-to-back with a device-side
-    event-compaction kernel (compact_accum) appended, then ONE async
-    fetch resolves the whole frame. The compaction reduces the transfer
-    from O(S*T*K) record tensors (~500 B/order) to O(events)
-    (~30 B/order). If any device budget tripped (book overflow, record
-    truncation, compaction buffer), the frame transactionally rolls back
-    and re-runs on the exact path — rare by construction, never wrong.
+    event-compaction kernel (compact_accum) appended, and the frame
+    resolves from its own fetch. A frame costs the device one scatter,
+    one step and one compaction per grid (and the count_ub reduction),
+    nothing else: its event buffers are an earlier frame's, handed back
+    at resolve and donated into the first compaction, which reads the
+    totals as zero (_take_buffers), so no op makes or clears them. It
+    costs the host ONE wait when its event matrices are small
+    (ONE_PHASE_MAX_BYTES): their copy starts with the totals' at submit;
+    a large frame fetches the totals first and then the used prefixes
+    (resolve_frame). The compaction reduces the transfer from O(S*T*K)
+    record tensors (~500 B/order) to O(events) (~30 B/order). If any
+    device budget tripped (book overflow, record truncation, compaction
+    buffer), the frame transactionally rolls back and re-runs on the
+    exact path — rare by construction, never wrong.
 
 Event content and ordering of both are pinned to the oracle's by
 differential tests (tests/test_frames.py).
@@ -583,10 +591,17 @@ def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
     accumulators are donated, so the train appends in place with no
     host sync; totals_acc[g] records this grid's TRUE
     fill/cancel counts (+ overflow flag + max n_fills), which is also
-    how the host later splits the flat buffers back into grids."""
+    how the host later splits the flat buffers back into grids. The
+    grid with g == 0 opens the frame: it is handed whatever buffers of
+    the right shapes the engine holds and reads the totals as zero."""
     e_fills = fills_acc.shape[1]
     e_cancels = cancels_acc.shape[1]
     wide = fills_acc.dtype
+    # A frame's first grid starts from zero totals whatever the buffers
+    # held: they are an earlier frame's, handed back (_take_buffers). The
+    # event matrices need no clearing, only the prefix the totals name is
+    # ever read.
+    totals_acc = jnp.where(g == 0, 0, totals_acc)
     off_f = jnp.sum(totals_acc[:, 0])
     off_c = jnp.sum(totals_acc[:, 1])
     fq = outs.fill_qty  # [R, T, K]
@@ -641,18 +656,109 @@ class PendingFrame:
     makes a tripped budget or failure transactionally recoverable."""
 
     __slots__ = ("cols", "arrays", "checkpoint", "items", "compact",
-                 "n_kept")
+                 "n_kept", "one_phase")
 
-    def __init__(self, cols, arrays, checkpoint, items, compact, n_kept):
+    def __init__(self, cols, arrays, checkpoint, items, compact, n_kept,
+                 one_phase):
         self.cols = cols
         self.arrays = arrays  # incl. add_counts for the count_ub handoff
         self.checkpoint = checkpoint
         self.items = items  # [(meta, (t_grid, K))]
         # (totals_acc, fills_acc, cancels_acc, counts_max)|None — counts_max
         # is the post-frame per-lane max-side resting count, riding the
-        # frame's single fetch to re-anchor count_ub (cap classes).
+        # frame's totals fetch to re-anchor count_ub (cap classes).
         self.compact = compact
         self.n_kept = n_kept
+        # The event matrices' copy to the host started with the totals'
+        # (ONE_PHASE_MAX_BYTES): resolve_frame takes all in one fetch.
+        self.one_phase = one_phase
+
+
+#: The one-phase rule: a frame whose two event matrices together hold at
+#: most this many bytes has them copied to the host whole, with its
+#: totals, and resolves with one wait; a larger one fetches the totals
+#: first and then the used prefixes (resolve_frame). A frame of 60-80
+#: orders has 2-8 KB of them, one of 4,096 orders 115 KB and up, of which
+#: a seventh is used; an extra round trip costs the host what a transfer
+#: far larger than either does, so the rule only has to keep the small
+#: frames' fetch small.
+ONE_PHASE_MAX_BYTES = 1 << 15
+
+
+def _one_phase(itemsize: int, e_fills: int, e_cancels: int) -> bool:
+    """The one-phase rule on a frame's buffer widths."""
+    return (
+        len(_FILL_FIELDS) * e_fills + len(_CANCEL_FIELDS) * e_cancels
+    ) * itemsize <= ONE_PHASE_MAX_BYTES
+
+
+def export_metrics(eng: BatchEngine) -> None:
+    """The fast path's frame counters on /metrics, read from eng.stats at
+    scrape time (nothing on the frame's way; registering again rebinds to
+    the newest engine's, as services are rebuilt across tests). Reused over
+    frames and one-phase over frames are both near 1 where small frames
+    flow steadily; a large-frame flow reads reuse near 1 and one-phase 0."""
+    from ..utils.metrics import REGISTRY
+
+    stats = eng.stats  # the counters only: a gauge outlives its engine
+    for name, help_, field in (
+        ("gome_fast_frames_total",
+         "frames dispatched on the fast path with device grids",
+         "fast_frames"),
+        ("gome_fast_frames_buffers_reused_total",
+         "fast-path frames whose event buffers were an earlier frame's",
+         "fast_frames_reused"),
+        ("gome_fast_frames_one_phase_total",
+         "fast-path frames whose events came back with their totals",
+         "fast_frames_one_phase"),
+    ):
+        REGISTRY.callback_gauge(
+            name, help_, lambda field=field: getattr(stats, field)
+        )
+
+
+def _zero_buffers(eng: BatchEngine, e_fills: int, e_cancels: int,
+                  totals_len: int):
+    """A fresh set of event buffers (fills, cancels, totals): three host
+    arrays put on the device as compact_accum returns them (replicated
+    over a mesh), so a fresh set and a handed-back one run one program. A
+    transfer, not a device op; a steady flow makes none (_take_buffers)."""
+    wide = np.promote_types(np.int32, np.dtype(eng.config.dtype))
+    where = None
+    if eng.mesh is not None:
+        where = jax.sharding.NamedSharding(
+            eng.mesh, jax.sharding.PartitionSpec()
+        )
+    return tuple(jax.device_put((
+        np.zeros((len(_FILL_FIELDS), e_fills), wide),
+        np.zeros((len(_CANCEL_FIELDS), e_cancels), wide),
+        np.zeros((totals_len, 4), np.int32),
+    ), where))
+
+
+def _take_buffers(eng: BatchEngine, e_fills: int, e_cancels: int,
+                  totals_len: int):
+    """The frame's event buffers (fills, cancels, totals) and whether they
+    are an earlier frame's: a resolved frame hands its set back
+    (_give_buffers), keyed by its shapes, and the next frame of those
+    shapes appends into it — compact_accum donates them and reads the
+    totals as zero at g == 0, so no device op makes or clears them. A set
+    is handed out once: the pop takes it from the engine, the donation
+    kills its handles, and only a frame that resolved without a trip
+    returns its own (a rewound frame's set goes with it). With none to
+    hand out (the first frames of a shape: up to depth + 1 sets are alive
+    at once) the frame starts on a fresh set."""
+    sets = eng._event_buffers.get((e_fills, e_cancels, totals_len))
+    if sets:
+        return sets.pop(), True
+    return _zero_buffers(eng, e_fills, e_cancels, totals_len), False
+
+
+def _give_buffers(eng: BatchEngine, fills, cancels, totals) -> None:
+    """Hand a resolved frame's event buffers back to the engine for the
+    next frame of their shapes (_take_buffers)."""
+    key = (fills.shape[1], cancels.shape[1], totals.shape[0])
+    eng._event_buffers.setdefault(key, []).append((fills, cancels, totals))
 
 
 # gomesurface: combo(build)
@@ -671,19 +777,22 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
             books = eng.books
             items = []
             compact = None
+            one_phase = False
             n_kept = int(np.count_nonzero(a["keep"]))
             if grids:
                 e_fills, e_cancels = _compact_sizes(
                     eng, n_kept, a["dels_total"]
                 )
-                wide = jnp.result_type(jnp.int32, eng.config.dtype)
-                fills_acc = jnp.zeros((len(_FILL_FIELDS), e_fills), wide)
-                cancels_acc = jnp.zeros(
-                    (len(_CANCEL_FIELDS), e_cancels), wide
+                (fills_acc, cancels_acc, totals_acc), reused = _take_buffers(
+                    eng, e_fills, e_cancels, max(_next_pow2(len(grids)), 8)
                 )
-                totals_acc = jnp.zeros(
-                    (max(_next_pow2(len(grids)), 8), 4), jnp.int32
+                one_phase = _one_phase(
+                    fills_acc.dtype.itemsize, e_fills, e_cancels
                 )
+                eng.stats.fast_frames += 1
+                eng.stats.fast_frames_reused += int(reused)
+                eng.stats.fast_frames_one_phase += int(one_phase)
+                packed.note(reused=int(reused))
             packed.note(grids=len(grids))
         for g_i, (ops, meta, lane_ids, cap_g) in enumerate(grids):
             t_disp = TRACER.clock() if TRACER.enabled else 0.0
@@ -757,16 +866,20 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
                 # but only multi-class engines ever read it; single-class
                 # ones skip the [S]-wide reduction and transfer.
                 compact += (jnp.max(books.count, axis=-1),)
-            # Phase-1 fetch starts now: totals (+counts_max) are tiny and
-            # resolve needs them FIRST — the event matrices are fetched
-            # as used-prefix slices sized from the totals (resolve_frame),
-            # so the transfer scales with the frame's EVENTS, not with
-            # the pow2-margined buffer capacity (7-8x the events on a
-            # margined mixed flow).
+            # The fetch starts now. Totals (+counts_max) are tiny and
+            # resolve needs them FIRST; small event matrices come whole
+            # with them (ONE_PHASE_MAX_BYTES), large ones are fetched as
+            # used-prefix slices sized from the totals (resolve_frame), so
+            # that transfer scales with the frame's EVENTS, not with the
+            # pow2-margined buffer capacity (7-8x the events on a margined
+            # mixed flow).
             compact[0].copy_to_host_async()
             if len(compact) > 3:
                 compact[3].copy_to_host_async()
-        return PendingFrame(cols, a, cp, items, compact, n_kept)
+            if one_phase:
+                compact[1].copy_to_host_async()
+                compact[2].copy_to_host_async()
+        return PendingFrame(cols, a, cp, items, compact, n_kept, one_phase)
     except Exception:
         eng._restore(cp)
         raise
@@ -787,7 +900,11 @@ def _prefix_slice_fn(n_fields: int, length: int):
 
 
 def resolve_frame(eng: BatchEngine, pend: PendingFrame):
-    """Fetch + decode a submitted frame — TWO-phase device->host fetch:
+    """Fetch + decode a submitted frame. A small frame (pend.one_phase,
+    ONE_PHASE_MAX_BYTES) resolves with ONE wait: totals, the count_ub
+    re-anchor and both event matrices whole, all in flight since submit;
+    the used prefix is sliced on the host. A large one keeps the TWO-phase
+    device->host fetch:
 
       1. the [G, 4] totals (+ the [S] count_ub re-anchor), tiny and
          already in flight since submit;
@@ -795,10 +912,14 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
          from the totals — a margined mixed-flow buffer is 7-8x its
          actual events.
 
+    Either way nothing is decoded before the trip check on the totals.
     Raises _NeedExact when a device budget tripped — the CALLER owns the
     recovery (rewind to pend.checkpoint, exact-run, resubmit anything
     submitted after); the single-frame wrapper apply_frame_fast and the
-    pipelined executor (engine.pipeline.FramePipeline) both do."""
+    pipelined executor (engine.pipeline.FramePipeline) both do. A frame
+    that resolves hands its event buffers back to the engine for the next
+    frame of their shapes (_take_buffers); a tripped or failed one does
+    not."""
     global FETCH_SECONDS
     if pend.compact is None:
         return _assemble(eng, pend.arrays, [])
@@ -806,14 +927,17 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     # them. The totals fetch is the frame's completion barrier: blocking
     # there drains every dispatched grid, so this IS the device-execute
     # wait (an armed TRACER records it as that stage).
-    with span("frame_fetch", grids=len(pend.items)):
+    with span("frame_fetch", grids=len(pend.items),
+              phases=1 if pend.one_phase else 2):
         t0 = time.perf_counter()
         totals_dev, fills_dev, cancels_dev = pend.compact[:3]
-        totals = jax.device_get(totals_dev)
-        counts_max = (
-            jax.device_get(pend.compact[3]) if len(pend.compact) > 3
-            else None
-        )
+        if pend.one_phase:
+            totals, fills_mat, cancels_mat, *rest = jax.device_get(
+                pend.compact
+            )
+        else:
+            totals, *rest = jax.device_get((totals_dev,) + pend.compact[3:])
+        counts_max = rest[0] if rest else None
         FETCH_SECONDS += time.perf_counter() - t0
         g = len(pend.items)
         nf_g = totals[:g, 0].astype(np.int64)
@@ -847,20 +971,23 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
             or total_c > cancels_dev.shape[1]
         ):
             raise _NeedExact()
-        # Phase 2: fetch the used prefixes (pow2-bucketed, clamped to the
-        # buffer) now the true counts are known.
-        t0 = time.perf_counter()
-        f_len = min(_next_pow2(max(total_f, 64)), int(fills_dev.shape[1]))
-        c_len = min(
-            _next_pow2(max(total_c, 64)), int(cancels_dev.shape[1])
-        )
-        fills_mat = jax.device_get(
-            _prefix_slice_fn(int(fills_dev.shape[0]), f_len)(fills_dev)
-        )
-        cancels_mat = jax.device_get(
-            _prefix_slice_fn(int(cancels_dev.shape[0]), c_len)(cancels_dev)
-        )
-        FETCH_SECONDS += time.perf_counter() - t0
+        if not pend.one_phase:
+            # Phase 2: fetch the used prefixes (pow2-bucketed, clamped to
+            # the buffer) now the true counts are known.
+            t0 = time.perf_counter()
+            f_len = min(
+                _next_pow2(max(total_f, 64)), int(fills_dev.shape[1])
+            )
+            c_len = min(
+                _next_pow2(max(total_c, 64)), int(cancels_dev.shape[1])
+            )
+            fills_mat, cancels_mat = jax.device_get((
+                _prefix_slice_fn(int(fills_dev.shape[0]), f_len)(fills_dev),
+                _prefix_slice_fn(int(cancels_dev.shape[0]), c_len)(
+                    cancels_dev
+                ),
+            ))
+            FETCH_SECONDS += time.perf_counter() - t0
     # Re-anchor count_ub from this frame's true post-frame counts (the
     # pipeline resolves FIFO, so extra minus THIS frame's increments is
     # exactly the still-in-flight sum; a trip above skips this and the
@@ -886,7 +1013,10 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
                     eng, meta, shape, (totals[i], fills, cancels)
                 )
             )
-        return _assemble(eng, pend.arrays, batches)
+        batch = _assemble(eng, pend.arrays, batches)
+    # Decoded: nothing reads the frame's buffers any more.
+    _give_buffers(eng, fills_dev, cancels_dev, totals_dev)
+    return batch
 
 
 def apply_frame_fast(eng: BatchEngine, cols: dict):
@@ -1002,11 +1132,9 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
                 np.full(n_rows, eng.n_slots, np.int64) if dense else None
             )
             _books, outs = eng._step(eng.books, ops, lane_ids, cap_g)
-            fills_acc = jnp.zeros((len(_FILL_FIELDS), e_fills), wide)
-            cancels_acc = jnp.zeros((len(_CANCEL_FIELDS), e_cancels), wide)
-            totals_acc = jnp.zeros((totals_len, 4), jnp.int32)
             out = compact_accum(
-                eng.config, outs, fills_acc, cancels_acc, totals_acc,
+                eng.config, outs,
+                *_zero_buffers(eng, e_fills, e_cancels, totals_len),
                 np.int32(0),
             )
             # Serialize: each replay holds a transient books-sized output;
@@ -1032,12 +1160,14 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
         # The count_ub re-anchor reduction that rides every frame fetch.
         jax.block_until_ready(jnp.max(eng.books.count, axis=-1))
     # Phase-2 prefix-slice kernels (resolve_frame): warm the plausible
-    # pow2 lengths for every recorded buffer size so a boundary-crossing
-    # event count never compiles mid-traffic. Tiny graphs, but a compile
-    # is a compile.
+    # pow2 lengths for every recorded buffer size that is fetched in two
+    # phases, so a boundary-crossing event count never compiles
+    # mid-traffic. Tiny graphs, but a compile is a compile.
     wide_zeros = {}
     for combo in combos:
         try:  # same per-combo isolation as the replay loop above
+            if _one_phase(wide.itemsize, combo[6], combo[7]):
+                continue
             for n_fields, e in (
                 (len(_FILL_FIELDS), combo[6]),
                 (len(_CANCEL_FIELDS), combo[7]),

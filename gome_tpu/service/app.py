@@ -71,6 +71,9 @@ class EngineService:
             kernel=e.kernel,
             mesh=mesh,
         )
+        from ..engine import frames
+
+        frames.export_metrics(self.engine.batch)
         if self.config.store.enabled:
             # A `redis:` config section puts the pre-pool markers in the
             # (Redis-compatible) store under the reference's exact schema —
@@ -290,6 +293,14 @@ class EngineService:
         self.consumer.stop()
         self.feed.stop()
         tracing.log_totals()
+        # Beside the spans' totals, at their level: how the frames those
+        # spans timed were handed their buffers and fetched.
+        st = self.engine.stats
+        (log.warning if tracing.slow() else log.info)(
+            "fast-path frames: %d dispatched, %d on reused event buffers, "
+            "%d fetched in one phase",
+            st.fast_frames, st.fast_frames_reused, st.fast_frames_one_phase,
+        )
         if self.ops is not None:
             self.ops.stop()
             if self.config.ops.timeline:

@@ -6,6 +6,7 @@ packer and produces the oracle's events."""
 import dataclasses
 import hashlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -688,3 +689,200 @@ def test_geometry_manifest_stale_or_oversized_is_best_effort(tmp_path):
 
     with pytest.raises(ValueError, match="devices"):
         make_mesh(64)  # only 8 virtual devices exist
+
+
+# --- the event buffers' life and the one-phase fetch (ISSUE 33) -------------
+
+
+def _engine(mesh_devices, **kw):
+    mesh = None
+    if mesh_devices:
+        from gome_tpu.parallel import make_mesh
+
+        mesh = make_mesh(mesh_devices)
+    kw.setdefault("n_slots", 16)
+    kw.setdefault("max_t", 8)
+    return BatchEngine(BookConfig(cap=128, max_fills=8, dtype=jnp.int32),
+                       mesh=mesh, **kw)
+
+
+@pytest.fixture
+def device_work(monkeypatch):
+    """Two lists that fill while JAX works: the name of every primitive
+    dispatched eagerly (apply_primitive asks xla_primitive_callable for its
+    program at each call) and every lowering or backend compile
+    (jax.monitoring; a retrace alone is not one)."""
+    from jax._src import dispatch, monitoring
+
+    eager, lowered = [], []
+    real = dispatch.xla_primitive_callable
+
+    def counting(prim, **params):
+        eager.append(prim.name)
+        return real(prim, **params)
+
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counting)
+
+    def on_event(name, _secs, **_kw):
+        if name.endswith(("jaxpr_to_mlir_module_duration",
+                          "backend_compile_duration")):
+            lowered.append(name)
+
+    monitoring.register_event_duration_secs_listener(on_event)
+    yield eager, lowered
+    monitoring.unregister_event_duration_listener(on_event)
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one_chip", "mesh4"])
+def test_steady_frames_make_no_eager_op_and_lower_nothing(
+    mesh_devices, device_work, monkeypatch
+):
+    """After warm-up a steady stream's frames cost the device its
+    programs and the host one wait: no primitive is dispatched eagerly by
+    submit_frame or resolve_frame (the parent made six a frame, the three
+    jnp.zeros of the event buffers), nothing is lowered, every frame's
+    buffers are an earlier frame's and every frame resolves in one phase,
+    with one device_get (the parent made four)."""
+    from collections import deque
+
+    from gome_tpu.engine.frames import resolve_frame, submit_frame
+
+    orders = multi_symbol_stream(
+        n=60 * 34, n_symbols=12, seed=5, cancel_prob=0.3
+    )
+    eng = _engine(mesh_devices)
+    eager, lowered = device_work
+    fetches = []
+    real_get = jax.device_get
+
+    def counting_get(tree):
+        fetches.append(len(tree))
+        return real_get(tree)
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    in_flight = deque()
+    for k in range(34):
+        if k == 14:  # warm: every shape met, three sets in rotation
+            eager.clear(), lowered.clear(), fetches.clear()
+            before = dataclasses.replace(eng.stats)
+        in_flight.append(
+            submit_frame(eng, colwire.orders_to_cols(orders[k * 60:][:60]))
+        )
+        if len(in_flight) > 2:
+            resolve_frame(eng, in_flight.popleft())
+    assert eager == []
+    assert lowered == []
+    # One blocking fetch a frame: totals, both matrices and counts_max.
+    assert fetches == [4] * 20
+    frames = eng.stats.fast_frames - before.fast_frames
+    assert frames == 20
+    assert eng.stats.fast_frames_reused - before.fast_frames_reused == 20
+    assert eng.stats.fast_frames_one_phase - before.fast_frames_one_phase == 20
+    assert eng.stats.frame_fallbacks == before.frame_fallbacks
+    # Depth 2: three sets of the one shape exist, one waits with the engine.
+    assert [len(v) for v in eng._event_buffers.values()] == [1]
+    while in_flight:
+        resolve_frame(eng, in_flight.popleft())
+    eng.verify_books()
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one_chip", "mesh4"])
+def test_one_and_two_phase_frames_equal_the_exact_path(
+    mesh_devices, monkeypatch
+):
+    """One stream whose frames lie just under and just over the one-phase
+    rule (64 kept ops: the two matrices hold exactly the rule's bytes;
+    65: the next buffer class), two in flight: the same EventBatch
+    columns, books and counters as process_frame, frame for frame."""
+    from collections import deque
+
+    from gome_tpu.engine import frames
+
+    wide = 4  # int32 books
+    small = (len(frames._FILL_FIELDS) + len(frames._CANCEL_FIELDS)) * 64 * wide
+    monkeypatch.setattr(frames, "ONE_PHASE_MAX_BYTES", small)
+    orders = multi_symbol_stream(
+        n=129 * 8, n_symbols=12, seed=9, cancel_prob=0.25
+    )
+    chunks, i = [], 0
+    while i < len(orders):
+        n = 64 if len(chunks) % 2 == 0 else 65
+        chunks.append(orders[i : i + n])
+        i += n
+    fast, exact = _engine(mesh_devices), _engine(mesh_devices)
+    in_flight, got, phases = deque(), [], []
+    for chunk in chunks + [None, None]:
+        if chunk is not None:
+            pend = frames.submit_frame(fast, colwire.orders_to_cols(chunk))
+            phases.append(pend.one_phase)
+            in_flight.append(pend)
+        if len(in_flight) > 2 or (chunk is None and in_flight):
+            got.append(frames.resolve_frame(fast, in_flight.popleft()))
+    want = [process_frame(exact, colwire.orders_to_cols(c)) for c in chunks]
+    assert phases[:4] == [True, False, True, False]
+    assert 0 < fast.stats.fast_frames_one_phase < fast.stats.fast_frames
+    assert fast.stats.fast_frames_reused > 0
+    assert fast.stats.frame_fallbacks == 0
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.columns.keys() == w.columns.keys()
+        for name in w.columns:
+            np.testing.assert_array_equal(g.columns[name], w.columns[name])
+    for name in ("orders", "fills", "cancels", "cancels_missed"):
+        assert getattr(fast.stats, name) == getattr(exact.stats, name), name
+    fast.verify_books()
+    _assert_same_books(fast, exact)
+
+
+def test_precompiled_manifest_leaves_a_live_run_nothing_to_lower(
+    tmp_path, monkeypatch, device_work
+):
+    """precompile_combos lowers exactly what a live frame dispatches: a
+    fresh engine that loaded a recorded manifest runs the recorded flow,
+    cold buffers and reused ones, one-phase frames and two-phase ones,
+    with 0 lowerings and 0 backend compiles."""
+    from gome_tpu.engine import frames
+    from gome_tpu.engine.orchestrator import MatchEngine
+
+    monkeypatch.setattr(frames, "ONE_PHASE_MAX_BYTES", 9 * 64 * 8)
+
+    def mk():
+        return MatchEngine(
+            config=BookConfig(cap=32, max_fills=8, dtype=jnp.int64),
+            n_slots=64, max_t=8,
+        )
+
+    orders = multi_symbol_stream(
+        n=1200, n_symbols=24, seed=5, zipf_a=1.2, cancel_prob=0.3
+    )
+    # Small frames and large ones, so both fetches and several buffer
+    # shapes are in the manifest; each size three times, so sets are
+    # reused.
+    sizes = [60, 60, 60, 60, 300, 300, 300, 60]
+    chunks, i = [], 0
+    for n in sizes:
+        chunks.append(colwire.orders_to_cols(orders[i : i + n]))
+        i += n
+
+    def run(engine):
+        for o in orders[:i]:
+            engine.mark(o)
+        return [
+            engine.process_frame(c, fast=True).to_results() for c in chunks
+        ]
+
+    e1 = mk()
+    ev1 = run(e1)
+    assert 0 < e1.stats.fast_frames_one_phase < e1.stats.fast_frames
+    path = str(tmp_path / "geometry.json")
+    e1.save_geometry(path)
+
+    e2 = mk()
+    assert e2.load_geometry(path) == e1.batch.combo_count()
+    _eager, lowered = device_work
+    lowered.clear()
+    ev2 = run(e2)
+    assert lowered == []
+    assert ev2 == ev1
+    assert e2.stats.fast_frames_reused > 0
+    assert e2.stats.frame_fallbacks == e1.stats.frame_fallbacks

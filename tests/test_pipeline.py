@@ -389,3 +389,95 @@ def test_pipelined_soak_with_persist_crash_restore(tmp_path):
     got = [m.body for m in svc2.bus.match_queue.read_from(0, 1 << 20)]
     assert got == ref_events
     svc2.engine.batch.verify_books()
+
+
+def test_rewind_never_hands_out_a_held_or_donated_buffer_set(monkeypatch):
+    """The event buffers' life across a rewind (ISSUE 33). Depth 2, so
+    three frames are in flight when the oldest resolves; the fifth frame's
+    fills overflow its buffer (120 fills into 64 columns), which ratchets
+    the floor and raises _NeedExact with two later frames in flight. At
+    every hand-out, before and after the rewind: the set's handles are
+    alive (a donated set is never handed out again) and no frame in flight
+    holds any of them; the tripped frame's own set and the two discarded
+    frames' never come back. Events, books and counters equal the
+    synchronous run's."""
+    kw = dict(config=BookConfig(cap=256, max_fills=4), n_slots=8, max_t=8)
+
+    def sells(tag, n):
+        return [
+            Order(uuid="m", oid=f"{tag}{i}", symbol="s", side=Side.SALE,
+                  price=100, volume=1)
+            for i in range(n)
+        ]
+
+    def quotes(tag, n):  # rest far from each other: no event
+        return [
+            Order(uuid="q", oid=f"{tag}{i}", symbol="s2",
+                  side=Side(i % 2), price=300 if i % 2 else 50, volume=1)
+            for i in range(n)
+        ]
+
+    orders = []
+    for f in range(4):
+        orders += sells(f"r{f}-", 50)
+    orders += [
+        Order(uuid="t", oid=f"sweep{i}", symbol="s", side=Side.BUY,
+              price=100, volume=3)
+        for i in range(40)
+    ] + quotes("q4-", 10)  # frame 4: 120 fills from 50 ops
+    for f in range(5, 12):
+        orders += sells(f"r{f}-", 20) + quotes(f"q{f}-", 30)
+
+    sync_eng, _, sync_events = _run(kw, orders, 50, 0)
+
+    engine, bus, consumer = _make(kw, 2)
+    for o in orders:
+        engine.mark(o)
+    for p in _frames_for(orders, 50):
+        bus.order_queue.publish(p)
+
+    real_take = engine_frames._take_buffers
+    handed, retired, keep = [], set(), []  # keep: an id stays its array's
+
+    def checked_take(eng, *shape):
+        bufs, reused = real_take(eng, *shape)
+        if reused:
+            assert not any(b.is_deleted() for b in bufs)
+            held = {
+                id(arr)
+                for pend, _c, _t in consumer._pipe._q
+                for arr in (pend.compact or ())
+            }
+            assert not held & set(map(id, bufs))
+            assert not retired & set(map(id, bufs))
+        handed.append(reused)
+        return bufs, reused
+
+    real_resolve = engine_frames.resolve_frame
+
+    def watched_resolve(eng, pend):
+        try:
+            return real_resolve(eng, pend)
+        except engine_frames._NeedExact:
+            # The tripped frame's set and those of the frames submitted on
+            # top of it (rewound with it) must never be handed out.
+            for p in [pend] + [q[0] for q in consumer._pipe._q]:
+                keep.extend(p.compact[:3])
+                retired.update(map(id, p.compact[:3]))
+            assert len(consumer._pipe._q) == 2  # three were in flight
+            raise
+
+    monkeypatch.setattr(engine_frames, "_take_buffers", checked_take)
+    monkeypatch.setattr(engine_frames, "resolve_frame", watched_resolve)
+    assert consumer.drain() == len(orders)
+    got = [m.body for m in bus.match_queue.read_from(0, 1 << 20)]
+    assert got == sync_events == _oracle_lines(orders)
+    st = engine.stats
+    assert st.frame_fallbacks == 1 and len(retired) == 9
+    assert engine.batch.geometry_floors()["fills_buf"][64] == 128
+    # The first three frames start on fresh sets (nothing has resolved);
+    # so do the two resubmitted after the rewind took three sets with it.
+    assert handed[:3] == [False] * 3 and handed.count(False) <= 6
+    assert st.fast_frames_reused == handed.count(True) >= 6
+    _assert_books_equal(engine, sync_eng)
+    engine.batch.verify_books()
