@@ -64,7 +64,7 @@ def build_grids(s, t, g, seed=0, dtype=np.int64):
         grids.append(
             dict(
                 action=action, side=side,
-                is_market=np.zeros((s, t), np.int32),
+                kind=np.zeros((s, t), np.int32),
                 price=price, volume=volume, oid=oid, uid=uid,
             )
         )
@@ -90,7 +90,7 @@ def build_config_grids(cfg, s, t, g, seed=0, dtype=np.int64):
         d = dict(
             action=np.zeros((s, t), np.int32),
             side=np.zeros((s, t), np.int32),
-            is_market=np.zeros((s, t), np.int32),
+            kind=np.zeros((s, t), np.int32),
             price=np.zeros((s, t), dtype),
             volume=np.zeros((s, t), dtype),
             oid=np.zeros((s, t), dtype),
@@ -134,7 +134,7 @@ def build_config_grids(cfg, s, t, g, seed=0, dtype=np.int64):
             d["oid"][cm] = rng.integers(1, max(oid_base, 2), int(cm.sum()))
         if cfg == 5:
             mm = mask & (rng.random((s, t)) < 0.25) & (d["action"] == 1)
-            d["is_market"][mm] = 1
+            d["kind"][mm] = 1
         fresh = d["action"] == 1
         d["oid"][fresh] = oid_base + np.arange(int(fresh.sum()))
         oid_base += int(fresh.sum())
@@ -284,7 +284,7 @@ def _jit_cache_sizes(**fns):
     return out
 
 
-FIELDS = ("action", "side", "is_market", "price", "volume", "oid", "uid")
+FIELDS = ("action", "side", "kind", "price", "volume", "oid", "uid")
 
 
 def pack_dense_rounds(grids, t_dense, s_total, cap=None, depth_bound=None):
@@ -354,7 +354,7 @@ def pack_dense_rounds(grids, t_dense, s_total, cap=None, depth_bound=None):
             ops = {
                 f: np.zeros(
                     (s_total, depth),
-                    np.int32 if f in ("action", "side", "is_market")
+                    np.int32 if f in ("action", "side", "kind")
                     else merged[lanes[0]][f].dtype,
                 )
                 for f in FIELDS
@@ -379,7 +379,7 @@ def pack_dense_rounds(grids, t_dense, s_total, cap=None, depth_bound=None):
         ops = {
             f: np.zeros(
                 (rows, depth),
-                np.int32 if f in ("action", "side", "is_market")
+                np.int32 if f in ("action", "side", "kind")
                 else merged[lanes[0]][f].dtype,
             )
             for f in FIELDS

@@ -195,7 +195,12 @@ def _entry_records_x64_scoped(dtype: str):
         lane_scan,
         lane_scan_donating,
     )
-    from ..engine.book import BookConfig, DeviceOp, init_books
+    from ..engine.book import (
+        GRID_I32_FIELDS,
+        BookConfig,
+        DeviceOp,
+        init_books,
+    )
     from ..engine.step import step_impl
 
     config = BookConfig(cap=8, max_fills=4, dtype=jnp.dtype(dtype))
@@ -204,7 +209,7 @@ def _entry_records_x64_scoped(dtype: str):
 
     books = init_books(config, s)
     op_grid = DeviceOp(**{
-        f: jnp.zeros((s, t), jnp.int32 if f in ("action", "side", "is_market")
+        f: jnp.zeros((s, t), jnp.int32 if f in GRID_I32_FIELDS
                      else dt)
         for f in DeviceOp._fields
     })
@@ -266,7 +271,7 @@ def _entry_records_x64_scoped(dtype: str):
     outs = StepOutput(**{
         f: (jnp.zeros((s, t), jnp.int32)
             if f in ("n_fills", "fill_overflow", "rested", "book_overflow",
-                     "cancel_found")
+                     "cancel_found", "expired")
             else jnp.zeros((s, t), dt)
             if f in ("taker_remaining", "cancel_volume")
             else jnp.zeros((s, t, k), dt))
@@ -274,7 +279,7 @@ def _entry_records_x64_scoped(dtype: str):
     })
     fills_acc = jnp.zeros((len(fr._FILL_FIELDS), 64), wide)
     cancels_acc = jnp.zeros((len(fr._CANCEL_FIELDS), 64), wide)
-    totals_acc = jnp.zeros((8, 4), jnp.int32)
+    totals_acc = jnp.zeros((8, fr.N_TOTALS), jnp.int32)
     yield dict(
         context="engine/frames.py:compact_accum",
         closed=jax.make_jaxpr(

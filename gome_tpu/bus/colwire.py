@@ -85,6 +85,8 @@ _EVENT_NUM = (
     ("fill_price", np.int64),
     ("maker_volume", np.int64),
     ("match_volume", np.int64),
+    # the event's taker was a MARKET order; not the order's kind: an IOC,
+    # FOK or POST_ONLY taker reads 0 (engine/events.py)
     ("is_market", np.uint8),
 )
 

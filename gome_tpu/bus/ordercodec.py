@@ -13,13 +13,18 @@ import ctypes
 
 import numpy as np
 
-from ..types import Action, Order, OrderType, Side
+from ..types import ORDER_KINDS, Action, Order, OrderType, Side, known_kinds
 from .codec import decode_order
 
 # Index tables beat Enum.__call__ (~10x) on the per-message hot path.
 _SIDES = (Side.BUY, Side.SALE)
 _ACTIONS = (Action.NOP, Action.ADD, Action.DEL)
-_KINDS = (OrderType.LIMIT, OrderType.MARKET)
+#: By the kind's own number; None at the unassigned ones (2, 5), which the
+#: range check below sends to the json path like any unknown kind.
+_KINDS = tuple(
+    OrderType(k) if k in ORDER_KINDS else None
+    for k in range(max(ORDER_KINDS) + 1)
+)
 
 _fn = None
 _fn_err = False
@@ -81,7 +86,7 @@ def decode_orders_batch(bodies: list[bytes]) -> list[Order]:
     ok = (
         (transaction[:parsed] >= 0) & (transaction[:parsed] <= 1)
         & (action[:parsed] >= 0) & (action[:parsed] <= 2)
-        & (kind[:parsed] >= 0) & (kind[:parsed] <= 1)
+        & known_kinds(kind[:parsed])
     )
     if not ok.all():
         parsed = int(np.argmin(ok))

@@ -217,19 +217,26 @@ def main(argv=None):
     import json
     import sys
 
-    argv = sys.argv[1:] if argv is None else argv
+    argv = list(sys.argv[1:] if argv is None else argv)
+    kwargs = {}
+    if "--kind" in argv:  # every order's kind, by its name in order.proto
+        at = argv.index("--kind")
+        try:
+            kwargs["kind"] = pb.OrderKind.Value(argv[at + 1].upper())
+        except (IndexError, ValueError):
+            sys.exit(f"--kind takes one of {pb.OrderKind.keys()}")
+        del argv[at : at + 2]
     target = argv[0] if argv else "127.0.0.1:8088"
     n = int(argv[1]) if len(argv) > 1 else 2000
     concurrency = int(argv[2]) if len(argv) > 2 else 1
     n_symbols = int(argv[3]) if len(argv) > 3 else 0
-    kwargs = {}
     if n_symbols:
         kwargs["symbols"] = [f"sym{i}" for i in range(n_symbols)]
     if len(argv) > 4:  # crossing price band for sustained benches
         if len(argv) < 7:
             sys.exit(
-                "usage: doorder TARGET [N [CONCURRENCY [N_SYMBOLS "
-                "[PRICE_LO PRICE_HI DECIMALS [SEED]]]]]"
+                "usage: doorder [--kind NAME] TARGET [N [CONCURRENCY "
+                "[N_SYMBOLS [PRICE_LO PRICE_HI DECIMALS [SEED]]]]]"
             )
         kwargs["price_lo"] = float(argv[4])
         kwargs["price_hi"] = float(argv[5])

@@ -321,7 +321,7 @@ def _nop_grid(config: BookConfig, n_slots: int, t: int) -> dict[str, np.ndarray]
     i32 = lambda: np.zeros((n_slots, t), np.int32)
     val = lambda: np.zeros((n_slots, t), np.dtype(config.dtype))
     return dict(
-        action=i32(), side=i32(), is_market=i32(),
+        action=i32(), side=i32(), kind=i32(),
         price=val(), volume=val(), oid=val(), uid=val(),
     )
 
@@ -536,6 +536,15 @@ class EngineStats:
     grid_cap_escalations: int = 0
     fill_record_escalations: int = 0
     frame_fallbacks: int = 0  # fast-path frames re-run on the exact path
+    # Adds applied, by the kind's wire number (types.OrderType), and those
+    # of them that expired by their kind's rule (oracle/book.py docstring):
+    # an IOC add whose remainder was dropped, a FOK add killed, a POST_ONLY
+    # add blocked. Counted on the device (StepOutput.expired) and summed
+    # into the totals a frame already fetches.
+    adds_by_kind: dict[int, int] = dataclasses.field(default_factory=dict)
+    expired_ioc: int = 0
+    fok_killed: int = 0
+    post_only_blocked: int = 0
     lane_growths: int = 0
     # Grids dispatched and the real (non-padding) ops through them, keyed
     # by the kernel that ACTUALLY ran (BatchEngine._step): "pallas_full" /

@@ -82,7 +82,7 @@ class DeviceOp(NamedTuple):
 
     action: jax.Array  # i32: 0=NOP, 1=ADD, 2=DEL (gomengine/main.go:14-18)
     side: jax.Array  # i32: 0=BUY, 1=SALE (api/order.proto:4-7)
-    is_market: jax.Array  # i32 bool: MARKET extension (BASELINE config 5)
+    kind: jax.Array  # i32: types.OrderType, the wire's number (0 on a DEL/NOP)
     price: jax.Array  # dtype ticks
     volume: jax.Array  # dtype lots
     oid: jax.Array  # dtype interned order id
@@ -93,7 +93,7 @@ class DeviceOp(NamedTuple):
 #: Grid packers (the numpy path in engine.frames and the native
 #: nativehost.pack_grid) share this rule so both produce identically
 #: typed DeviceOp grids.
-GRID_I32_FIELDS = ("action", "side", "is_market")
+GRID_I32_FIELDS = ("action", "side", "kind")
 
 
 class StepOutput(NamedTuple):
@@ -120,6 +120,10 @@ class StepOutput(NamedTuple):
     book_overflow: jax.Array  # i32 bool: rest dropped, side full
     cancel_found: jax.Array  # i32 bool: DEL matched a resting order
     cancel_volume: jax.Array  # lots remaining at cancel (engine.go:100)
+    # i32: 0, or the kind of an add that expired by its kind's rule (IOC:
+    # a remainder was dropped; FOK: killed; POST_ONLY: blocked). The kind
+    # and not a flag, so that the frame's totals count each by a compare.
+    expired: jax.Array
 
 
 def ensure_dtype_usable(dtype) -> None:

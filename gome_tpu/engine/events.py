@@ -76,6 +76,10 @@ class EventBatch:
             seq = None if seq0 is None else seq0 + i
             symbol = syms[c["symbol_id"][i]]
             side = Side(int(c["taker_side"][i]))
+            # The event's `is_market` column describes the taker of an
+            # event as the event wire always has: MARKET or not. It is not
+            # the op's kind: an IOC, FOK or POST_ONLY taker reads 0 here
+            # (LIMIT), since nothing a snapshot shows depends on it.
             kind = (
                 OrderType.MARKET if c["is_market"][i] else OrderType.LIMIT
             )

@@ -56,7 +56,7 @@ import numpy as np
 
 from ..obs.compile_journal import JOURNAL, frame_combo_detail
 from ..obs.timeline import TIMELINE
-from ..types import Action, OrderType
+from ..types import Action, OrderType, may_rest
 from ..utils.trace import TRACER
 from ..utils.tracing import span
 from .batch import (
@@ -67,7 +67,7 @@ from .batch import (
     splice_outs,
 )
 from .book import GRID_I32_FIELDS, DeviceOp
-from .step import ACTION_ADD, LOT_MAX32
+from .step import ACTION_ADD, LOT_MAX32, TAKER_PRICE_MAX32
 
 #: Cumulative wall-clock seconds apply_frame_fast spent BLOCKED on the
 #: device->host fetch of compacted events. Blocking there also drains the
@@ -77,6 +77,16 @@ FETCH_SECONDS = 0.0
 
 ACTION_DEL = int(Action.DEL)
 MARKET = int(OrderType.MARKET)
+#: Kinds whose expiry the device counts, in the order of the totals'
+#: columns 4.. (compact_accum) and of a frame's `expired` array, and the
+#: EngineStats field that counts each.
+_EXPIRING_KINDS = (
+    int(OrderType.IOC), int(OrderType.FOK), int(OrderType.POST_ONLY)
+)
+_EXPIRED_FIELDS = ("expired_ioc", "fok_killed", "post_only_blocked")
+#: Columns of a frame's per-grid totals: fills, cancels, book overflows,
+#: max n_fills, then the expired adds of each of _EXPIRING_KINDS.
+N_TOTALS = 4 + len(_EXPIRING_KINDS)
 
 _GRID_FIELDS = DeviceOp._fields  # one canonical field list + order
 
@@ -229,14 +239,18 @@ def _frame_arrays(eng: BatchEngine, cols: dict) -> dict:
             occ = np.arange(len(sorted_lanes)) - group_start
             t[ki[order]] = occ
 
-    # count_ub upkeep (cap-class selection, batch.py): every kept limit
-    # ADD may rest at most once. The increment happens at PACK time — the
+    # count_ub upkeep (cap-class selection, batch.py): every kept ADD of
+    # a kind that can rest (LIMIT, POST_ONLY) may rest at most once; a
+    # MARKET, IOC or FOK add never does, so a taker-heavy lane climbs no
+    # cap class it never needs. The increment happens at PACK time — the
     # classes chosen below then cover this frame's own worst case.
-    rest_mask = keep & is_add & (kind != MARKET)
+    kept_add = keep & is_add
+    rest_mask = kept_add & may_rest(kind)
     add_counts = np.bincount(
         lanes[rest_mask], minlength=eng.n_slots
     ).astype(np.int64)
     eng.note_packed_adds(add_counts)
+    adds_by_kind = np.bincount(kind[kept_add])
 
     return dict(
         n=n, action=action, side=side, kind=kind, price=price,
@@ -244,6 +258,12 @@ def _frame_arrays(eng: BatchEngine, cols: dict) -> dict:
         keep=keep, t=t, bases=bases,
         dels_total=int((action == ACTION_DEL).sum()),
         add_counts=add_counts,
+        # EngineStats upkeep (_assemble): kept adds by kind, of them those
+        # that cannot rest, and the expired adds per _EXPIRING_KINDS, which
+        # the frame's totals (fast path) or outputs (exact path) add up.
+        adds_by_kind=adds_by_kind,
+        takers=int(kept_add.sum()) - int(add_counts.sum()),
+        expired=np.zeros(len(_EXPIRING_KINDS), np.int64),
     )
 
 
@@ -370,17 +390,26 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
             flat = np.full(m_pad, n_rows * t_grid, np.int32)
             pr, pt = row_of[lanes[sel]], t[sel] - t_off
             flat[:m] = (pr * t_grid + pt).astype(np.int32)
-            is_mkt = (a["kind"][sel] == MARKET) & (
-                a["action"][sel] == ACTION_ADD
+            # The kind word: the wire's number on an ADD, 0 on a cancel
+            # (which ignores its kind). The rebased price is clamped as the
+            # native packer clamps it (step.TAKER_PRICE_MAX32).
+            op_kind = np.where(
+                a["action"][sel] == ACTION_ADD, a["kind"][sel], 0
             )
+            is_mkt = op_kind == MARKET
+            op_price = np.where(
+                is_mkt, 0, a["price"][sel] - a["bases"][sel]
+            )
+            if dt.itemsize <= 4:
+                op_price = np.clip(
+                    op_price, -TAKER_PRICE_MAX32, TAKER_PRICE_MAX32
+                )
             for i, (_name, val) in enumerate(
                 (
                     ("action", a["action"][sel]),
                     ("side", a["side"][sel]),
-                    ("is_market", is_mkt),
-                    ("price", np.where(
-                        is_mkt, 0, a["price"][sel] - a["bases"][sel]
-                    )),
+                    ("kind", op_kind),
+                    ("price", op_price),
                     ("volume", a["volume"][sel]),
                     ("oid", a["oid_ids"][sel]),
                     ("uid", a["uid_ids"][sel]),
@@ -432,7 +461,14 @@ def _assemble(eng, a, batches):
     # frame count cannot double on an exact-path fallback. Disabled
     # sampler = one attribute check, zero allocations.
     TIMELINE.note_frame(a["n"])
-    eng.stats.orders += a["n"]
+    st = eng.stats
+    st.orders += a["n"]
+    for k in np.flatnonzero(a["adds_by_kind"]).tolist():
+        st.adds_by_kind[k] = st.adds_by_kind.get(k, 0) + int(
+            a["adds_by_kind"][k]
+        )
+    for field, n in zip(_EXPIRED_FIELDS, a["expired"].tolist()):
+        setattr(st, field, getattr(st, field) + n)
     if not batches:
         eng.stats.cancels_missed += a["dels_total"]
         return empty_batch(**_tables(eng))
@@ -465,6 +501,10 @@ def apply_frame(eng: BatchEngine, cols: dict):
             (int(r), int(tt)): None for r, tt in zip(meta["row"], meta["t"])
         }
         outs, overrides = eng._run_exact(ops, contexts, lane_ids, cap_g)
+        expired = np.asarray(outs.expired)[meta["row"], meta["t"]]
+        a["expired"] += [
+            int(np.count_nonzero(expired == k)) for k in _EXPIRING_KINDS
+        ]
         with span("frame_decode"):
             batches.append(
                 decode_grid_columnar(meta, splice_outs(outs, overrides))
@@ -590,7 +630,8 @@ def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
     frame's grid TRAIN is dozens of grids: 3*G arrays -> 3. The
     accumulators are donated, so the train appends in place with no
     host sync; totals_acc[g] records this grid's TRUE
-    fill/cancel counts (+ overflow flag + max n_fills), which is also
+    fill/cancel counts (+ overflow flag + max n_fills + the adds that
+    expired, per kind of _EXPIRING_KINDS), which is also
     how the host later splits the flat buffers back into grids. The
     grid with g == 0 opens the frame: it is handed whatever buffers of
     the right shapes the engine holds and reads the totals as zero."""
@@ -644,6 +685,10 @@ def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
                 jnp.sum(cmask.astype(jnp.int32)),
                 jnp.sum(outs.book_overflow).astype(jnp.int32),
                 jnp.max(outs.n_fills).astype(jnp.int32),
+            ]
+            + [
+                jnp.sum((outs.expired == k).astype(jnp.int32))
+                for k in _EXPIRING_KINDS
             ]
         ).astype(jnp.int32)  # x64 promotes int32 sums to int64
     )
@@ -715,6 +760,22 @@ def export_metrics(eng: BatchEngine) -> None:
         REGISTRY.callback_gauge(
             name, help_, lambda field=field: getattr(stats, field)
         )
+    # Adds applied and adds expired, by kind (types.OrderType's names).
+    for kind in OrderType:
+        REGISTRY.callback_gauge(
+            "gome_orders_admitted_total",
+            "adds applied by the engine, by order kind",
+            lambda k=int(kind): stats.adds_by_kind.get(k, 0),
+            labels={"kind": kind.name.lower()},
+        )
+    for kind, field in zip(_EXPIRING_KINDS, _EXPIRED_FIELDS):
+        REGISTRY.callback_gauge(
+            "gome_orders_expired_total",
+            "adds that expired by their kind's rule: an IOC remainder "
+            "dropped, a FOK add killed, a POST_ONLY add blocked",
+            lambda field=field: getattr(stats, field),
+            labels={"kind": OrderType(kind).name.lower()},
+        )
 
 
 def _zero_buffers(eng: BatchEngine, e_fills: int, e_cancels: int,
@@ -732,7 +793,7 @@ def _zero_buffers(eng: BatchEngine, e_fills: int, e_cancels: int,
     return tuple(jax.device_put((
         np.zeros((len(_FILL_FIELDS), e_fills), wide),
         np.zeros((len(_CANCEL_FIELDS), e_cancels), wide),
-        np.zeros((totals_len, 4), np.int32),
+        np.zeros((totals_len, N_TOTALS), np.int32),
     ), where))
 
 
@@ -793,7 +854,7 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
                 eng.stats.fast_frames_reused += int(reused)
                 eng.stats.fast_frames_one_phase += int(one_phase)
                 packed.note(reused=int(reused))
-            packed.note(grids=len(grids))
+            packed.note(grids=len(grids), takers=a["takers"])
         for g_i, (ops, meta, lane_ids, cap_g) in enumerate(grids):
             t_disp = TRACER.clock() if TRACER.enabled else 0.0
             t_disp_j = JOURNAL.clock() if JOURNAL.enabled else 0.0
@@ -906,7 +967,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     the used prefix is sliced on the host. A large one keeps the TWO-phase
     device->host fetch:
 
-      1. the [G, 4] totals (+ the [S] count_ub re-anchor), tiny and
+      1. the [G, N_TOTALS] totals (+ the [S] count_ub re-anchor), tiny and
          already in flight since submit;
       2. the USED PREFIX of the fill/cancel event matrices, pow2-bucketed
          from the totals — a margined mixed-flow buffer is 7-8x its
@@ -928,7 +989,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     # there drains every dispatched grid, so this IS the device-execute
     # wait (an armed TRACER records it as that stage).
     with span("frame_fetch", grids=len(pend.items),
-              phases=1 if pend.one_phase else 2):
+              phases=1 if pend.one_phase else 2) as fetched:
         t0 = time.perf_counter()
         totals_dev, fills_dev, cancels_dev = pend.compact[:3]
         if pend.one_phase:
@@ -944,6 +1005,8 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
         nc_g = totals[:g, 1].astype(np.int64)
         total_f = int(nf_g.sum())
         total_c = int(nc_g.sum())
+        expired = totals[:g, 4:].sum(axis=0, dtype=np.int64)
+        fetched.note(expired=int(expired.sum()))
         # A fills-buffer overflow ratchets the grow-only floor (keyed by
         # the FRAME's kept-op class) BEFORE the exact fallback, so the
         # next frame fits — one slow frame per ratchet step, not a
@@ -995,6 +1058,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     # single-class engines, which never read count_ub.
     if counts_max is not None:
         eng._note_exact_counts(counts_max, pend.arrays["add_counts"])
+    pend.arrays["expired"] += expired
     off_f = np.concatenate(([0], np.cumsum(nf_g)))
     off_c = np.concatenate(([0], np.cumsum(nc_g)))
     batches = []
@@ -1237,8 +1301,11 @@ def _prepare_bases_vec(eng, lanes, action, kind, price) -> np.ndarray:
 
     Returns a boolean drop mask aligned with the batch: True marks a DEL
     whose price is unrepresentable under the lane's (possibly just
-    recentred) base. Only ADD limit prices feed the grow-only envelope —
-    MARKET prices are documented-ignored (encoded 0), and a DEL price is
+    recentred) base. Only the prices of ADDs that can rest (LIMIT,
+    POST_ONLY: types.may_rest) feed the grow-only envelope — MARKET prices
+    are documented-ignored (encoded 0), the limit of an IOC or FOK add
+    never rests (the packers clamp it, step.TAKER_PRICE_MAX32, so it
+    widens nothing), and a DEL price is
     a lookup key, not an admission (a wrong-price cancel is in-contract
     and must miss, engine.go:92-98; the stock delorder client hardcodes
     price 0.5). Since every RESTING price always fits the window, an
@@ -1249,7 +1316,7 @@ def _prepare_bases_vec(eng, lanes, action, kind, price) -> np.ndarray:
     drop = np.zeros(n, bool)
     if not eng._rebase:
         return drop
-    adm = (action == ACTION_ADD) & (kind != MARKET)
+    adm = (action == ACTION_ADD) & may_rest(kind)
     if adm.any():
         al = lanes[adm]
         ap = price[adm]

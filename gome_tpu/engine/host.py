@@ -17,7 +17,7 @@ import numpy as np
 
 from ..types import Action, MatchResult, Order, OrderType, snapshot_of
 from .book import DeviceOp, StepOutput
-from .step import LOT_MAX32
+from .step import LOT_MAX32, TAKER_PRICE_MAX32
 
 
 class Interner:
@@ -96,14 +96,23 @@ def encode_op(
             "units or an int64 BookConfig"
         )
     val = np.dtype(dtype).type
-    is_market = order.order_type is OrderType.MARKET
+    is_add = order.action is Action.ADD
     # MARKET price is documented-ignored: encode 0 so an arbitrary client
-    # price can never overflow the lane's rebased int32 window.
+    # price can never overflow the lane's rebased int32 window. Any other
+    # price is clamped as the frame packers clamp it
+    # (step.TAKER_PRICE_MAX32): only an IOC or FOK limit can lie outside.
+    if is_add and order.order_type is OrderType.MARKET:
+        price = 0
+    else:
+        price = order.price - price_base
+        if np.dtype(dtype).itemsize <= 4:
+            price = max(-TAKER_PRICE_MAX32, min(price, TAKER_PRICE_MAX32))
     return DeviceOp(
         action=np.int32(int(order.action)),  # Action values == device codes
         side=np.int32(int(order.side)),
-        is_market=np.int32(is_market),
-        price=val(0 if is_market else order.price - price_base),
+        # the wire's number on an ADD; a cancel ignores its kind
+        kind=np.int32(int(order.order_type) if is_add else 0),
+        price=val(price),
         volume=val(order.volume),
         oid=val(oids.intern(order.oid)),
         uid=val(uids.intern(order.uuid)),

@@ -42,7 +42,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..types import Action
+from ..types import Action, OrderType, may_rest
 from .book import BUY, BookConfig, BookState, DeviceOp, StepOutput
 
 # Device-side action codes are the types.Action values (single source of
@@ -50,6 +50,12 @@ from .book import BUY, BookConfig, BookState, DeviceOp, StepOutput
 ACTION_NOP = int(Action.NOP)
 ACTION_ADD = int(Action.ADD)
 ACTION_DEL = int(Action.DEL)
+# The op's kind word holds the wire's number (types.OrderType).
+KIND_LIMIT = int(OrderType.LIMIT)
+KIND_MARKET = int(OrderType.MARKET)
+KIND_IOC = int(OrderType.IOC)
+KIND_FOK = int(OrderType.FOK)
+KIND_POST_ONLY = int(OrderType.POST_ONLY)
 
 
 def _bsel(c, a, b):
@@ -71,6 +77,14 @@ def _bsel(c, a, b):
 # and partials below the clamp are exact.
 SAT32_MAX = (1 << 30) - 1
 LOT_MAX32 = SAT32_MAX  # documented int32-mode per-order lot ceiling
+
+# Where the packers clamp the rebased limit price of an add in int32 mode.
+# An add that can rest keeps its price inside the lane's admitted envelope
+# (|price - base| <= BatchEngine._INT32_SAFE = 2^31 - 2), so the clamp only
+# ever moves the limit of an add that cannot rest (IOC, FOK; types.may_rest),
+# which feeds no envelope: one past the farthest representable resting price,
+# it crosses exactly the resting orders the true limit crosses.
+TAKER_PRICE_MAX32 = (1 << 31) - 1
 
 
 def _prefix_sum(a):
@@ -152,15 +166,22 @@ class _Side(NamedTuple):
 
 
 def _match(
-    config: BookConfig, opp: _Side, opp_count, side, price, volume, is_market
+    config: BookConfig, opp: _Side, opp_count, side, price, volume, kind
 ):
-    """Fill the crossing prefix of the opposing side.
+    """Fill the crossing prefix of the opposing side, as the add's kind
+    allows.
 
     Crossing rule (nodepool.go:86-115): BUY taker hits asks with price <=
     limit; SALE taker hits bids with price >= limit; MARKET (extension)
     hits every active order. Because the side is priority-sorted, crossing
     slots are a contiguous prefix, so "walk levels best-first, FIFO within
     level" (engine.go:118-136) degenerates to elementwise arithmetic.
+
+    Two kinds fill all or nothing (oracle/book.py has the rules): a FOK
+    add that the whole crossing prefix cannot fill, and a POST_ONLY add
+    that would take anything, leave the book as it was. Both are decided
+    from the fill itself and blended in as an i32 mask (as _bsel does): no
+    branch, no new reduction. Returns `killed` (bool) beside the usual.
     """
     cap = config.cap
     k = config.max_fills
@@ -171,7 +192,7 @@ def _match(
     # a vector has no Mosaic relayout.
     le = (opp.price <= price).astype(jnp.int32)
     ge = (opp.price >= price).astype(jnp.int32)
-    mkt = (is_market != 0).astype(jnp.int32)
+    mkt = (kind == KIND_MARKET).astype(jnp.int32)
     crosses = jnp.maximum(_bsel(side == BUY, le, ge), mkt)
     crossing = active & (crosses != 0)
 
@@ -182,7 +203,20 @@ def _match(
     # would under-report the depth ahead of a slot.
     cum_excl = _prefix_sum(_shr1_last(clots))
     fill = jnp.clip(volume - cum_excl, 0, clots)
+    # What the whole crossing prefix C can give this add: min(volume,
+    # avail), exactly. Where the 32-bit prefix sum saturated it read at
+    # least SAT32_MAX >= volume (volume <= LOT_MAX32), so the slots behind
+    # fill nothing, as under the true sum; below the clamp it is exact.
     total = jnp.sum(fill)
+    # FOK: avail >= volume  <=>  total == volume. POST_ONLY: C is not
+    # empty  <=>  total > 0 (resting lots and volumes are positive, so the
+    # first crossing slot always fills something).
+    killed = ((kind == KIND_FOK) & (total < volume)) | (
+        (kind == KIND_POST_ONLY) & (total > 0)
+    )
+    live = 1 - killed.astype(fill.dtype)
+    fill = fill * live
+    total = total * live
     remaining = volume - total
 
     new_lots = opp.lots - fill
@@ -206,7 +240,7 @@ def _match(
     )
 
     compacted = opp._replace(lots=new_lots).shift_left(n_removed, cap)
-    return compacted, opp_count - n_removed, remaining, out
+    return compacted, opp_count - n_removed, remaining, killed, out
 
 
 def _insert(config: BookConfig, own: _Side, own_count, entry: _Side, side):
@@ -286,13 +320,18 @@ def step_rows_impl(
     opp_count0 = jnp.where(is_buy, sale_count, buy_count)
 
     # --- ADD: match against the opposing side -------------------------------
-    opp1, opp_count1, remaining, fills = _match(
-        config, opp0, opp_count0, s, op.price, op.volume, op.is_market
+    opp1, opp_count1, remaining, killed, fills = _match(
+        config, opp0, opp_count0, s, op.price, op.volume, op.kind
     )
 
-    # --- ADD: rest the remainder (limit only; market remainder is dropped —
-    # MARKET is our extension, the reference has no market orders) ----------
-    do_rest = is_add & (remaining > 0) & (op.is_market == 0)
+    # --- ADD: rest the remainder: a LIMIT add's, or a POST_ONLY add that
+    # took nothing (types.may_rest); a MARKET or IOC remainder is dropped
+    # and a killed FOK leaves nothing to rest (extensions: the reference
+    # has limit orders only) ------------------------------------------------
+    do_rest = is_add & (remaining > 0) & may_rest(op.kind) & ~killed
+    expired = is_add & (
+        killed | ((op.kind == KIND_IOC) & (remaining > 0))
+    )
     entry = _Side(
         price=op.price,
         lots=remaining,
@@ -355,6 +394,7 @@ def step_rows_impl(
         book_overflow=(do_rest & overflow).astype(jnp.int32),
         cancel_found=(is_del & found).astype(jnp.int32),
         cancel_volume=jnp.where(is_del, cancel_volume, zero),
+        expired=jnp.where(expired, op.kind, 0).astype(jnp.int32),
     )
     return new_buy, new_sale, new_buy_count, new_sale_count, new_next_seq, out
 

@@ -230,9 +230,10 @@ def frame_combo_detail(dtype_name: str, combo: tuple) -> dict:
         # step record tensors [R, T, K] x 5 (dominant step output)
         "record_bytes": int(cells * k_rec * _RECORD_TENSORS * itemsize),
         # frame-level compaction buffers (fills[7, e_f] + cancels[2, e_c]
-        # + totals[len, 4]) — the device->host fetch ceiling
+        # + totals[len, 7]: frames.N_TOTALS) — the device->host fetch
+        # ceiling
         "fetch_buffer_bytes": int(
-            (7 * e_fills + 2 * e_cancels) * wide + totals_len * 4 * 4
+            (7 * e_fills + 2 * e_cancels) * wide + totals_len * 7 * 4
         ),
         "scatter_jaxpr_eqns": _scatter_eqn_count(
             dtype_name, int(n_rows), int(t_grid)

@@ -204,7 +204,12 @@ def donation_report(dtype: str = "int32") -> list[dict]:
         lane_scan,
         lane_scan_donating,
     )
-    from ..engine.book import BookConfig, DeviceOp, init_books
+    from ..engine.book import (
+        GRID_I32_FIELDS,
+        BookConfig,
+        DeviceOp,
+        init_books,
+    )
 
     cap, s, t = _DONATION_GEOMETRY
     out: list[dict] = []
@@ -215,7 +220,7 @@ def donation_report(dtype: str = "int32") -> list[dict]:
         op_grid = DeviceOp(**{
             f: jnp.zeros(
                 (s, t),
-                jnp.int32 if f in ("action", "side", "is_market") else dt,
+                jnp.int32 if f in GRID_I32_FIELDS else dt,
             )
             for f in DeviceOp._fields
         })

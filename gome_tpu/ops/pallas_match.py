@@ -24,7 +24,7 @@ allows unaligned dynamic offsets on the major dim):
   * the 7 op fields ship packed in ONE [T, 8, S] int32 array (row 7 spare);
     each step reads the [8, B] slab at its (major-dim, unaligned-ok) time
     index and peels rows.
-  * the 7 per-op scalar outputs come back the same way: one [T, 8, S] pack.
+  * the 8 per-op scalar outputs come back the same way: one [T, 8, S] pack.
   * the 5 non-derivable per-op fill-record arrays come back time-leading as
     [T, K, S]; the step's [B, K] records are transposed in-VMEM so the lane
     dim stays the (dense) symbol block (fill_qty / taker_after are
@@ -47,7 +47,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..engine.book import BookConfig, BookState, DeviceOp, StepOutput
+from ..engine.book import (
+    GRID_I32_FIELDS,
+    BookConfig,
+    BookState,
+    DeviceOp,
+    StepOutput,
+)
 from ..engine.step import _Side, step_rows_impl
 
 # Only the 5 non-derivable record fields cross the kernel boundary:
@@ -60,9 +66,9 @@ _REC_FIELDS = (
 )
 _SCALAR_FIELDS = (
     "n_fills", "fill_overflow", "taker_remaining", "rested",
-    "book_overflow", "cancel_found", "cancel_volume",
+    "book_overflow", "cancel_found", "cancel_volume", "expired",
 )
-_OP_FIELDS = ("action", "side", "is_market", "price", "volume", "oid", "uid")
+_OP_FIELDS = ("action", "side", "kind", "price", "volume", "oid", "uid")
 
 
 def pallas_available(dtype=jnp.int32) -> bool:
@@ -183,7 +189,7 @@ def _kernel(config: BookConfig, t_block: int, *refs):
             **{
                 f: (
                     slab[i].astype(jnp.int32)
-                    if f in ("action", "side", "is_market")
+                    if f in GRID_I32_FIELDS
                     else slab[i]
                 )
                 for i, f in enumerate(_OP_FIELDS)
@@ -195,13 +201,10 @@ def _kernel(config: BookConfig, t_block: int, *refs):
         # fill records: [B, K] -> transpose -> slot t of [T, K, B]
         for ref, f in zip(rec_refs, _REC_FIELDS):
             ref[pl.ds(t, 1)] = jnp.transpose(getattr(out, f))[None]
-        # per-op scalars: one [8, B] slab (row 7 zero) in config.dtype, so
-        # int64 taker_remaining/cancel_volume survive the pack intact
+        # per-op scalars: one [8, B] slab in config.dtype, so int64
+        # taker_remaining/cancel_volume survive the pack intact
         dt = config.dtype
-        s = jnp.stack(
-            [getattr(out, f).astype(dt) for f in _SCALAR_FIELDS]
-            + [jnp.zeros_like(out.n_fills).astype(dt)]
-        )
+        s = jnp.stack([getattr(out, f).astype(dt) for f in _SCALAR_FIELDS])
         scal[pl.ds(t, 1)] = s[None]
         return buy, sale, nb[:, None], ns[:, None], nq[:, None]
 

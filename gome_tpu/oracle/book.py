@@ -23,6 +23,23 @@ Deliberate behavioral choices (SURVEY §2.3):
 Extensions beyond the reference (flagged explicitly):
   * MARKET orders (BASELINE.json config 5): cross the book ignoring price;
     any remainder is dropped (never rests) and emits no event.
+  * Orders that carry a time in force (PR 34; types.OrderType has the
+    numbers). For an add of `volume` at limit `price` on `side`, let C be
+    the crossing prefix of the opposite side (asks with price <= limit for
+    BUY, bids with price >= limit for SALE; best price first, first in
+    first out inside a level) and `avail` the sum of its lots:
+      - IOC: fills down C as a limit add does, min(volume, avail) lots;
+        what is left is dropped: it never rests, makes no event and is no
+        cancel target (as a MARKET remainder).
+      - FOK: if avail >= volume it fills down C as a limit add does and
+        nothing is left; otherwise nothing happens: no fill, no event, the
+        book untouched. The test is on all of C.
+      - POST_ONLY: if C is not empty (an equal price crosses) nothing
+        happens: no fill, no rest, no event; otherwise it rests at `price`
+        at the tail of its level, as a limit add does.
+    A cancel ignores the kind; a cancel aimed at an order that never rested
+    misses. StepStats counts what expired: expired_ioc (an IOC remainder
+    dropped), fok_killed, post_only_blocked.
 
 Out-of-contract inputs (deliberate divergences on degenerate streams):
   * volume <= 0 ADDs: the reference emits a MatchVolume=0 pseudo-event when
@@ -48,6 +65,7 @@ from ..types import (
     OrderType,
     Side,
     StepStats,
+    may_rest,
     snapshot_of,
 )
 
@@ -224,17 +242,31 @@ class OracleEngine:
         self.pre_pool.discard(key)
 
         book = self.book(order.symbol)
-        limit = None if order.order_type is OrderType.MARKET else order.price
+        kind = order.order_type
+        limit = None if kind is OrderType.MARKET else order.price
+        crossing = book.crossing_levels(order.side, limit)
+        if kind is OrderType.POST_ONLY and crossing:
+            self.stats.post_only_blocked += 1  # would take: nothing happens
+            return
+        if kind is OrderType.FOK:
+            opp = order.side.opposite
+            avail = sum(book.level_volume(opp, p) for p in crossing)
+            if avail < order.volume:
+                self.stats.fok_killed += 1  # all of C cannot fill it
+                return
         remaining = order.volume
-        for level_price in book.crossing_levels(order.side, limit):
+        for level_price in crossing:
             remaining = self._match_level(book, order, level_price, remaining)
             if remaining <= 0:
                 break
 
-        if remaining > 0 and order.order_type is OrderType.LIMIT:
+        if remaining > 0 and may_rest(kind):
             # Remainder rests at its own limit price (engine.go:69-83).
             book.rest(order, remaining)
-        # MARKET remainder is dropped (extension; reference has no markets).
+        elif remaining > 0 and kind is OrderType.IOC:
+            self.stats.expired_ioc += 1
+        # A MARKET or IOC remainder is dropped (extensions; the reference
+        # has neither): no event.
 
     def _match_level(
         self, book: SymbolBook, taker: Order, level_price: int, remaining: int

@@ -20,6 +20,7 @@ import os
 from ..bus import make_bus
 from ..config import Config
 from ..engine.orchestrator import MatchEngine
+from ..types import OrderType
 from ..utils import tracing
 from ..utils.logging import configure as configure_logging, get_logger
 from .consumer import OrderConsumer
@@ -300,6 +301,13 @@ class EngineService:
             "fast-path frames: %d dispatched, %d on reused event buffers, "
             "%d fetched in one phase",
             st.fast_frames, st.fast_frames_reused, st.fast_frames_one_phase,
+        )
+        (log.warning if tracing.slow() else log.info)(
+            "orders: %d applied; adds by kind %s; expired: %d IOC "
+            "remainders dropped, %d FOK killed, %d POST_ONLY blocked",
+            st.orders,
+            {OrderType(k).name: n for k, n in sorted(st.adds_by_kind.items())},
+            st.expired_ioc, st.fok_killed, st.post_only_blocked,
         )
         if self.ops is not None:
             self.ops.stop()

@@ -31,7 +31,7 @@ from ..config import Config
 from ..fixed import scale
 from ..obs.hostprof import HOSTPROF
 from ..obs.placement import PLACEMENT
-from ..types import Action, Order, OrderType, Side
+from ..types import Action, Order, OrderType, Side, known_kinds
 from ..utils.faults import FAULTS
 from ..utils.logging import get_logger
 from ..utils.trace import TRACER
@@ -270,7 +270,7 @@ class OrderGateway:
                 f"volume {order.volume} exceeds the engine's per-order "
                 f"lot ceiling {self._max_volume}"
             )
-        if order.order_type is OrderType.LIMIT and order.price <= 0:
+        if order.order_type is not OrderType.MARKET and order.price <= 0:
             raise ValueError("limit price must be positive")
         return order
 
@@ -504,14 +504,15 @@ class OrderGateway:
         volume, vol_ok, vol_sus = _vector_scale(vol_f, self._accuracy)
         ok = (
             (trans >= 0) & (trans <= 1)
-            & (kind >= 0) & (kind <= 1)
+            & known_kinds(kind)
             & price_ok & vol_ok
         )
         add_ok = volume > 0
         if self._max_volume is not None:
             add_ok &= volume <= self._max_volume
-        # MARKET adds skip the price check, like _validate_add.
-        add_ok &= (kind != 0) | (price > 0)
+        # MARKET adds skip the price check, like _validate_add; every
+        # other kind's price is a limit.
+        add_ok &= (kind == 1) | (price > 0)  # 1: OrderType.MARKET
         ok &= cancel | add_ok  # cancels skip the ADD-only checks
         flagged = ~ok | price_sus | vol_sus
         if flagged.any():

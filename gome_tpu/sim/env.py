@@ -68,7 +68,7 @@ class AgentAction(NamedTuple):
     lane: jax.Array  # i32 symbol lane
     action: jax.Array  # i32 0=NOP, 1=ADD, 2=DEL
     side: jax.Array  # i32 0=BUY, 1=SALE
-    is_market: jax.Array  # i32 bool
+    kind: jax.Array  # i32 types.OrderType number (0 LIMIT, 1 MARKET, ...)
     price: jax.Array  # book dtype ticks (absolute)
     volume: jax.Array  # book dtype lots
     oid: jax.Array  # book dtype order-id handle
@@ -122,7 +122,7 @@ def null_action(config: EnvConfig) -> AgentAction:
     z32 = jnp.zeros((a,), jnp.int32)
     zdt = jnp.zeros((a,), dt)
     return AgentAction(
-        lane=z32, action=z32, side=z32, is_market=z32,
+        lane=z32, action=z32, side=z32, kind=z32,
         price=zdt, volume=zdt, oid=zdt,
     )
 
@@ -177,7 +177,7 @@ def _agent_grid(config: EnvConfig, act: AgentAction) -> DeviceOp:
     fields = {
         "action": (act.action * on32, jnp.int32),
         "side": (act.side * on32, jnp.int32),
-        "is_market": (act.is_market * on32, jnp.int32),
+        "kind": (act.kind * on32, jnp.int32),
         "price": (act.price * ondt, dt),
         "volume": (act.volume * ondt, dt),
         "oid": (act.oid * ondt, dt),

@@ -312,7 +312,7 @@ def gen_ops(
     cols = {
         "action": action,
         "side": side * mask_i32,
-        "is_market": is_market * mask_i32,
+        "kind": is_market * mask_i32,  # LIMIT 0 / MARKET 1
         "price": price * mask_dt,
         "volume": volume * mask_dt,
         "oid": oid * mask_dt,

@@ -150,7 +150,7 @@ def grid_to_columns(ops: dict, drop_misses: bool = False) -> dict:
         n=len(action),
         action=action.astype(np.uint8),
         side=pick("side").astype(np.uint8),
-        kind=pick("is_market").astype(np.uint8),
+        kind=pick("kind").astype(np.uint8),
         price=pick("price").astype(np.int64),
         volume=pick("volume").astype(np.int64),
         symbol_idx=lane_idx.astype(np.uint32),

@@ -12,7 +12,7 @@ way):
     where a Poisson stream of the same rate gives ~1
 
 `sample_grids` provides the seeded sample: N generated grids' (action,
-side, is_market) layers, one device fetch at the end.
+side, kind) layers, one device fetch at the end.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ from .flow import FlowConfig, flow_init, gen_ops
 def _sample_grids_impl(
     config: FlowConfig, book_config: BookConfig, key, n_grids: int
 ):
-    """Stack n_grids of generated (action, side, is_market) layers
+    """Stack n_grids of generated (action, side, kind) layers
     [N, S, T] against a fixed empty book stack (pricing falls back to
     the reference band; cancels all miss — occurrence, type, and lane
     statistics do not depend on book state)."""
@@ -40,7 +40,7 @@ def _sample_grids_impl(
 
     def body(st, _):
         st2, ops = gen_ops(config, st, books)
-        return st2, (ops.action, ops.side, ops.is_market)
+        return st2, (ops.action, ops.side, ops.kind)
 
     _, layers = jax.lax.scan(body, state, None, length=n_grids)
     return layers
@@ -50,17 +50,17 @@ def sample_grids(
     config: FlowConfig, seed: int, n_grids: int,
     book_config: BookConfig | None = None,
 ) -> dict:
-    """Seeded sample as host numpy: {"action", "side", "is_market"},
+    """Seeded sample as host numpy: {"action", "side", "kind"},
     each [N, S, T] int32."""
     if book_config is None:
         book_config = BookConfig(cap=4, max_fills=1, dtype=jnp.int32)
-    action, side, is_market = jax.device_get(_sample_grids_impl(
+    action, side, kind = jax.device_get(_sample_grids_impl(
         config, book_config, jax.random.PRNGKey(seed), n_grids
     ))
     return {
         "action": np.asarray(action),
         "side": np.asarray(side),
-        "is_market": np.asarray(is_market),
+        "kind": np.asarray(kind),
     }
 
 

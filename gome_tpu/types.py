@@ -12,6 +12,8 @@ from __future__ import annotations
 import enum
 from dataclasses import dataclass, field, replace
 
+import numpy as np
+
 
 # Device execution strategies for one [S, T] op grid (single source of
 # truth for config validation and BatchEngine selection).
@@ -41,10 +43,44 @@ class Action(enum.IntEnum):
 class OrderType(enum.IntEnum):
     """Extension beyond the reference: the proto has no order-type field, so
     every reference order is implicitly a limit order (api/order.proto:10-17;
-    SURVEY §1 L5). MARKET is required by BASELINE.json config 5."""
+    SURVEY §1 L5). MARKET is required by BASELINE.json config 5.
+
+    IOC, FOK and POST_ONLY (PR 34) carry FIX 4.4's own numbers: tag 59
+    TimeInForce 3 = Immediate or Cancel, 4 = Fill or Kill; tag 18 ExecInst
+    6 = Participate don't initiate (post-only). 2 (tag 59 "at the opening":
+    this venue has no auction) and 5 stay unassigned and are rejected at the
+    gateway like any unknown kind. The rules of each kind are in
+    oracle/book.py's docstring. The number is the `kind` byte of the order
+    frame and the word the device op carries."""
 
     LIMIT = 0
     MARKET = 1
+    IOC = 3
+    FOK = 4
+    POST_ONLY = 6
+
+
+#: Every kind the program knows, ascending: the one list the gateway's
+#: columnar check, the order codecs and the fuzzers share.
+ORDER_KINDS = tuple(int(k) for k in OrderType)
+assert max(ORDER_KINDS) < 8  # known_kinds' table below has eight entries
+_KIND_KNOWN = np.isin(np.arange(8), ORDER_KINDS)
+
+
+def known_kinds(kind: np.ndarray) -> np.ndarray:
+    """Elementwise: is this entry of an integer array one of ORDER_KINDS?
+    Two compares and a take off an 8-entry table: np.isin costs ~15 us a
+    call whatever the size, which every admitted request would pay."""
+    return (kind >= 0) & (kind < 8) & _KIND_KNOWN[kind & 7]
+
+
+def may_rest(kind):
+    """True for the add kinds that can leave an order in the book: LIMIT
+    and POST_ONLY. A MARKET, IOC or FOK add never rests, so it neither
+    counts toward a lane's resting-count bound nor needs its price inside
+    the lane's admitted price envelope. Works on an int, an OrderType or a
+    numpy array of kinds (elementwise)."""
+    return (kind == OrderType.LIMIT) | (kind == OrderType.POST_ONLY)
 
 
 @dataclass(frozen=True)
@@ -125,6 +161,10 @@ class StepStats:
     dropped_no_prepool: int = 0
     cancels_missed: int = 0
     fills: int = 0
+    # Adds that expired by their kind's rule (oracle/book.py docstring).
+    expired_ioc: int = 0
+    fok_killed: int = 0
+    post_only_blocked: int = 0
 
 
 def snapshot_of(order: Order, volume: int | None = None) -> OrderSnapshot:

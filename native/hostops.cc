@@ -696,7 +696,7 @@ int64_t go_decode_compact(
 // field arrays): a frame that splits into a train of grids hands each
 // grid only the ops still alive at its time offset, so a G-grid train
 // costs O(sum of survivors), not O(G * frame). cols is [7, m] in
-// _GRID_FIELDS order (action, side, is_market, price, volume, oid, uid),
+// _GRID_FIELDS order (action, side, kind, price, volume, oid, uid),
 // int32 or int64 (val_itemsize). Meta outputs are int64 [m] where
 // m = |{j : t_off <= t[idx[j]] < t_off+t_grid}| (the caller sizes them
 // with one count pass); meta arrival carries the ORIGINAL frame index
@@ -728,9 +728,22 @@ int64_t go_pack_grid(
     if (r < 0 || r >= n_rows) return -1;
     int64_t flat = r * t_grid + tt;
     int64_t a = action[i];
-    bool is_mkt = kind[i] == market_val && a == add_val;
+    // The op's kind word is the wire's number on an ADD; a cancel ignores
+    // its kind (0 on the device).
+    int64_t k = a == add_val ? kind[i] : 0;
+    bool is_mkt = k == market_val && a == add_val;
     int64_t p_dev = is_mkt ? 0 : price[i] - bases[i];
-    int64_t vals[7] = {a,         side[i],     is_mkt ? 1 : 0, p_dev,
+    if (!wide) {
+      // An add that cannot rest (IOC, FOK) is no part of the lane's price
+      // envelope, so its limit may lie outside the rebased 32-bit window:
+      // clamp it one past the farthest representable resting price
+      // (step.TAKER_PRICE_MAX32), which crosses exactly what the true
+      // limit crosses. A price inside the envelope is untouched.
+      constexpr int64_t kTakerMax32 = 2147483647;
+      if (p_dev > kTakerMax32) p_dev = kTakerMax32;
+      if (p_dev < -kTakerMax32) p_dev = -kTakerMax32;
+    }
+    int64_t vals[7] = {a,         side[i],     k,          p_dev,
                        volume[i], oid_ids[i],  uid_ids[i]};
     if (wide) {
       auto* c = static_cast<int64_t*>(cols);

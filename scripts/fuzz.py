@@ -74,6 +74,9 @@ def run_case(seed: int) -> str:
     band = int(rng.choice([3, 50, 5_000]))
     n_orders = int(rng.choice([50, 200]))
     market_p = float(rng.choice([0.0, 0.15]))
+    # Share of the other adds that carry a time in force (IOC, FOK and
+    # POST_ONLY in equal parts); the rest are limit adds.
+    tif_p = float(rng.choice([0.0, 0.45]))
     cancel_p = float(rng.choice([0.0, 0.3]))
     chunk = int(rng.choice([1, 17, 64]))
 
@@ -94,17 +97,26 @@ def run_case(seed: int) -> str:
             )
             continue
         kind = OrderType.MARKET if rng.random() < market_p else OrderType.LIMIT
+        if kind is OrderType.LIMIT and rng.random() < tif_p:
+            kind = (OrderType.IOC, OrderType.FOK, OrderType.POST_ONLY)[
+                int(rng.integers(3))
+            ]
         side = Side(int(rng.integers(2)))
         price = (
             0 if (kind is OrderType.MARKET and rng.random() < 0.5)
             else base_price + int(rng.integers(-band, band + 1))
         )
+        if kind in (OrderType.IOC, OrderType.FOK) and rng.random() < 0.1:
+            # A taker's limit far outside the lane's price envelope (it
+            # feeds none): beyond what a rebased int32 price can hold.
+            price = max(base_price + int(rng.choice([-1, 1])) * (1 << 33), 1)
         orders.append(
             Order(uuid=f"u{int(rng.integers(3))}", oid=str(i), symbol=sym,
                   side=side, price=price, volume=int(rng.integers(1, 30)),
                   order_type=kind)
         )
-        if kind is OrderType.LIMIT:
+        if kind is not OrderType.MARKET:
+            # cancel targets; one aimed at an IOC or FOK add always misses
             live.append((sym, str(i), side, price))
 
     oracle = OracleEngine()
@@ -166,7 +178,7 @@ def run_case(seed: int) -> str:
         f"dtype={np.dtype(dtype).name} mode={mode}"
         f"{f'(depth={depth})' if depth else ''} "
         f"kernel={effective} base={base_price} band={band} n={n_orders} "
-        f"chunk={chunk}"
+        f"chunk={chunk} tif={tif_p}"
     )
     if got != expected:
         first = next(
@@ -180,10 +192,24 @@ def run_case(seed: int) -> str:
             f"{expected[first] if first < len(expected) else '<none>'}"
         )
     engine.verify_books()
+    expired = (
+        engine.stats.expired_ioc, engine.stats.fok_killed,
+        engine.stats.post_only_blocked,
+    )
+    want = (
+        oracle.stats.expired_ioc, oracle.stats.fok_killed,
+        oracle.stats.post_only_blocked,
+    )
+    if expired != want:
+        raise AssertionError(
+            f"DIVERGENCE [{desc}] expired (IOC, FOK, POST_ONLY) "
+            f"{expired} vs the oracle's {want}"
+        )
     return (
         f"OK [{desc}] events={len(got)} esc="
         f"{engine.stats.cap_escalations}"
         f"/{engine.stats.fill_record_escalations}"
+        f" expired={'/'.join(map(str, expired))}"
     )
 
 
@@ -279,10 +305,24 @@ def run_sim_case(seed: int) -> str:
             f"{expected[first] if first < len(expected) else '<none>'}"
         )
     engine.verify_books()
+    expired = (
+        engine.stats.expired_ioc, engine.stats.fok_killed,
+        engine.stats.post_only_blocked,
+    )
+    want = (
+        oracle.stats.expired_ioc, oracle.stats.fok_killed,
+        oracle.stats.post_only_blocked,
+    )
+    if expired != want:
+        raise AssertionError(
+            f"DIVERGENCE [{desc}] expired (IOC, FOK, POST_ONLY) "
+            f"{expired} vs the oracle's {want}"
+        )
     return (
         f"OK [{desc}] events={len(got)} esc="
         f"{engine.stats.cap_escalations}"
         f"/{engine.stats.fill_record_escalations}"
+        f" expired={'/'.join(map(str, expired))}"
     )
 
 

@@ -60,7 +60,7 @@ def part_a():
         shape = (rows, T)
         f["action"] = rng.integers(1, 2, shape)  # all ADDs
         f["side"] = rng.integers(0, 2, shape)
-        f["is_market"] = np.zeros(shape, np.int64)
+        f["kind"] = np.zeros(shape, np.int64)
         f["price"] = rng.integers(90, 110, shape)
         f["volume"] = rng.integers(1, 50, shape)
         f["oid"] = np.arange(rows * T).reshape(shape) + 1
@@ -251,7 +251,7 @@ def curve(out_path: str = "MULTICHIP_r06.json"):
         f = dict(
             action=np.ones(shape, np.int64),
             side=rng.integers(0, 2, shape),
-            is_market=np.zeros(shape, np.int64),
+            kind=np.zeros(shape, np.int64),
             price=rng.integers(90, 110, shape),
             volume=rng.integers(1, 50, shape),
             oid=np.arange(rows * T).reshape(shape) + 1,

@@ -6,7 +6,7 @@ script closes the compiled-lowering gap). Run on a TPU host:
     python scripts/tpu_parity_check.py --suite [S T CAP K G]
 
 Exit 0 on exact equality of every book leaf and every StepOutput leaf
-across chained grids of crossing flow (with cancels and market orders);
+across chained grids of crossing flow (with cancels and every order kind);
 1 on a mismatch or when JAX finds no TPU; 2 on a geometry the compiled
 kernel cannot block. `--suite` defaults to the served deployment's own
 geometry (DEPLOYMENT below); chip_smoke.py runs it before it starts the
@@ -32,6 +32,12 @@ DEPLOYMENT_DENSE = (
     (8, 1024, 256), (8, 1024, 512), (8, 1024, 1024), (8, 8192, 256),
     (256, 32, 64),
 )
+
+
+#: Order kinds of the random grids (types.OrderType's numbers: LIMIT,
+#: MARKET, IOC, FOK, POST_ONLY) and their shares.
+_KINDS = (0, 1, 3, 4, 6)
+_KIND_P = (0.5, 0.1, 0.15, 0.1, 0.15)
 
 
 def _block_or_fail(rows, cap, what, log):
@@ -76,7 +82,7 @@ def run_parity(S=512, T=16, CAP=128, K=16, G=4, log=print) -> int:
         return DeviceOp(
             action=action,
             side=r.integers(0, 2, (S, T)).astype(np.int32),
-            is_market=(r.random((S, T)) < 0.1).astype(np.int32),
+            kind=r.choice(_KINDS, size=(S, T), p=_KIND_P).astype(np.int32),
             price=r.integers(995_000, 1_005_000, (S, T)).astype(np.int32),
             volume=r.integers(1, 100, (S, T)).astype(np.int32),
             oid=(np.arange(S * T).reshape(S, T) % 97 + 1).astype(np.int32),
@@ -97,7 +103,7 @@ def run_parity(S=512, T=16, CAP=128, K=16, G=4, log=print) -> int:
         fills = int(np.asarray(jax.device_get(o_scan.n_fills)).sum())
         log(f"grid {g}: OK ({fills} fills)")
     log(f"PARITY OK: pallas == scan on {G} grids ({S}x{T} ops each at "
-        f"cap {CAP}, block_s {block_s}, cancels + markets included)")
+        f"cap {CAP}, block_s {block_s}, cancels and all five order kinds included)")
     return 0
 
 
@@ -136,7 +142,7 @@ def run_dense_parity(R=8, T=128, CAP=32, K=8, S=64, log=print) -> int:
         return DeviceOp(
             action=q.choice([1, 1, 1, 2], size=(R, T)).astype(np.int32),
             side=q.integers(0, 2, (R, T)).astype(np.int32),
-            is_market=(q.random((R, T)) < 0.1).astype(np.int32),
+            kind=q.choice(_KINDS, size=(R, T), p=_KIND_P).astype(np.int32),
             price=q.integers(995_000, 1_005_000, (R, T)).astype(np.int32),
             volume=q.integers(1, 100, (R, T)).astype(np.int32),
             oid=(np.arange(R * T).reshape(R, T) % 211 + 1).astype(np.int32),
@@ -178,7 +184,7 @@ def run_edge_price_parity(S=128, T=8, CAP=32, K=8, log=print) -> int:
         return DeviceOp(
             action=q.choice([1, 1, 1, 2], size=(S, T)).astype(np.int32),
             side=q.integers(0, 2, (S, T)).astype(np.int32),
-            is_market=np.zeros((S, T), np.int32),
+            kind=np.zeros((S, T), np.int32),
             price=(base + q.integers(-900, 900, (S, T))).astype(np.int32),
             volume=q.integers(1, 50, (S, T)).astype(np.int32),
             oid=(np.arange(S * T).reshape(S, T) % 97 + 1).astype(np.int32),

@@ -35,7 +35,7 @@ def _grid(config, s=2, t=4, seed=7):
     g = dict(
         action=np.ones((s, t), np.int32),  # all ADDs
         side=rng.integers(0, 2, (s, t)).astype(np.int32),
-        is_market=np.zeros((s, t), np.int32),
+        kind=np.zeros((s, t), np.int32),
         price=(100 + rng.integers(0, 5, (s, t))).astype(d),
         volume=(1 + rng.integers(0, 3, (s, t))).astype(d),
         oid=np.arange(1, s * t + 1, dtype=d).reshape(s, t),

@@ -20,7 +20,7 @@ def _grid(config, rows):
     g = dict(
         action=np.zeros((1, t), np.int32),
         side=np.zeros((1, t), np.int32),
-        is_market=np.zeros((1, t), np.int32),
+        kind=np.zeros((1, t), np.int32),
         price=np.zeros((1, t), d), volume=np.zeros((1, t), d),
         oid=np.zeros((1, t), d), uid=np.ones((1, t), d),
     )

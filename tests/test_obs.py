@@ -219,7 +219,7 @@ def test_frame_combo_detail_arithmetic():
     assert d["upload_bytes"] == 256 * (7 * 4 + 4)
     assert d["ops_grid_bytes"] == 128 * (3 * 4 + 4 * 4)
     assert d["record_bytes"] == 128 * 4 * 5 * 4
-    assert d["fetch_buffer_bytes"] == (7 * 512 + 2 * 64) * 4 + 8 * 4 * 4
+    assert d["fetch_buffer_bytes"] == (7 * 512 + 2 * 64) * 4 + 8 * 7 * 4
     assert d["dense"] is True
 
 

@@ -42,7 +42,7 @@ def test_grid_parity_with_cancels_markets_nops():
     grid = DeviceOp(
         action=rng.integers(0, 3, size=(S, T), dtype=np.int32),
         side=rng.integers(0, 2, size=(S, T), dtype=np.int32),
-        is_market=(rng.random((S, T)) < 0.2).astype(np.int32),
+        kind=(rng.random((S, T)) < 0.2).astype(np.int32),
         price=rng.integers(90, 111, size=(S, T)).astype(d),
         volume=rng.integers(1, 10, size=(S, T)).astype(d),
         oid=np.arange(S * T, dtype=d).reshape(S, T) % 7 + 1,

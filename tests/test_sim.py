@@ -75,7 +75,7 @@ class TestFlowGrid:
         dels = host.action == 2
         assert (host.volume[dels] == 0).all()
         assert (host.volume[adds] >= 1).all()
-        mkts = host.is_market == 1
+        mkts = host.kind == 1
         assert (host.price[mkts & adds] == 0).all()
         assert (host.price[adds & ~mkts] >= 1).all()
         # The intensity state advanced.
@@ -204,7 +204,7 @@ class TestEnv:
 
         def act(**kw):
             base = dict(
-                lane=z, action=z, side=z, is_market=z, price=z,
+                lane=z, action=z, side=z, kind=z, price=z,
                 volume=z, oid=z,
             )
             base.update({
@@ -222,7 +222,7 @@ class TestEnv:
         assert int(info.trades) == 0
         # Step 2: slot 0 market-SELLs 2 into the resting bid.
         state, obs, reward, info = env_step(config, state, act(
-            lane=[1, 0], action=[1, 0], side=[1, 0], is_market=[1, 0],
+            lane=[1, 0], action=[1, 0], side=[1, 0], kind=[1, 0],
             volume=[2, 0], oid=[oid + 1, 0],
         ))
         assert int(info.trades) == 1
