@@ -33,6 +33,7 @@ import dataclasses
 import functools
 import sys
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -317,6 +318,36 @@ full_kernel_step_donating = functools.partial(  # gomelint: disable=GL601 — ib
 )(_full_kernel_step_impl)
 
 
+class StepPlan(NamedTuple):
+    """Which step runs one grid, as BatchEngine._grid_plan decided it:
+    hashable, so a program traced round the step (frames._grid_program) is
+    keyed by it and shared by every engine that decides the same."""
+
+    cfg: BookConfig  # at the grid's cap class
+    dense: bool
+    block_s: int | None  # ops.kernel_plan's lane block; None = scan path
+    interpret: bool
+
+
+def step_in_program(plan: StepPlan, books: BookState, ops: DeviceOp, ids):
+    """The step BatchEngine._step dispatches for `plan` on one chip, for a
+    caller that is being traced itself: the same bodies, no program of
+    their own. ids are a dense grid's int32 lane ids, None on a full
+    grid."""
+    cfg = plan.cfg
+    if plan.dense:
+        if plan.block_s is not None:
+            return _dense_kernel_step_impl(
+                cfg, books, ids, ops, plan.block_s, plan.interpret
+            )
+        return _dense_batch_step_impl(cfg, books, ids, ops)
+    if plan.block_s is not None:
+        return _full_kernel_step_impl(
+            cfg, books, ops, plan.block_s, plan.interpret
+        )
+    return _batch_step_impl(cfg, books, ops)
+
+
 def _nop_grid(config: BookConfig, n_slots: int, t: int) -> dict[str, np.ndarray]:
     i32 = lambda: np.zeros((n_slots, t), np.int32)
     val = lambda: np.zeros((n_slots, t), np.dtype(config.dtype))
@@ -529,6 +560,11 @@ class EngineStats:
     fast_frames: int = 0
     fast_frames_reused: int = 0
     fast_frames_one_phase: int = 0
+    # Grids of one-phase frames, which cost the host ONE dispatch each:
+    # scatter, step, compaction and the count reduction in one program
+    # (frames._grid_program). Over device_calls: near 1 where small frames
+    # flow, 0 where large ones do.
+    fast_grids_one_program: int = 0
     cap_escalations: int = 0
     # Confined escalations: one GRID's cap class deepened (re-sliced from
     # the same storage) without growing the [S]-wide stack — the cheap
@@ -1416,6 +1452,20 @@ class BatchEngine:
                 st.scan_giveways[reason] = st.scan_giveways.get(reason, 0) + 1
         return block_s, interpret
 
+    def _grid_plan(self, n_rows: int, dense: bool, cap_g: int | None,
+                   n_ops: int | None) -> StepPlan:
+        """The step of one grid of n_rows (global) rows at cap class cap_g
+        (None/equal = storage cap), counted once in EngineStats
+        (_plan_step)."""
+        cfg = self.config
+        if cap_g is not None and cap_g != cfg.cap:
+            cfg = dataclasses.replace(cfg, cap=cap_g)
+        # Per-chip rows: under a mesh each chip blocks its own local slice
+        # (parallel.mesh makes the same kernel_plan call at trace time).
+        rows = n_rows // (1 if self.mesh is None else self.mesh.size)
+        block_s, interpret = self._plan_step(rows, cfg, dense, n_ops)
+        return StepPlan(cfg, dense, block_s, interpret)
+
     def _step(self, books: BookState, ops: DeviceOp, lane_ids=None,
               cap_g: int | None = None, n_ops: int | None = None):
         """Run one [R, T] grid with the configured kernel. lane_ids selects
@@ -1433,9 +1483,9 @@ class BatchEngine:
 
         n_ops: the grid's real op count, given by live dispatches so
         EngineStats attributes the grid to the kernel that ran it."""
-        cfg = self.config
-        if cap_g is not None and cap_g != cfg.cap:
-            cfg = dataclasses.replace(cfg, cap=cap_g)
+        dense = lane_ids is not None
+        plan = self._grid_plan(ops.action.shape[0], dense, cap_g, n_ops)
+        cfg, block_s, interpret = plan.cfg, plan.block_s, plan.interpret
         # Donation policy (GL6xx): a HOST-sourced grid (numpy)
         # re-transfers on every dispatch, so its device buffers are dead
         # after the call and the donating twins let XLA reuse them for
@@ -1447,13 +1497,6 @@ class BatchEngine:
         _dense = dense_batch_step_donating if donate else dense_batch_step
         _densek = dense_kernel_step_donating if donate else dense_kernel_step
         _fullk = full_kernel_step_donating if donate else full_kernel_step
-        dense = lane_ids is not None
-        # Per-chip rows: under a mesh each chip blocks its own local slice
-        # (parallel.mesh makes the same kernel_plan call at trace time).
-        rows = ops.action.shape[0] // (
-            1 if self.mesh is None else self.mesh.size
-        )
-        block_s, interpret = self._plan_step(rows, cfg, dense, n_ops)
         if dense and self.mesh is not None:
             from ..parallel.mesh import shard_batch, sharded_dense_step
 

@@ -26,16 +26,21 @@ Two ways to run a frame's grids, on the same BatchEngine state:
   * `apply_frame_fast` (submit_frame + resolve_frame; across frames,
     engine.pipeline.FramePipeline) — the production hot path: every grid
     of the frame is DISPATCHED back-to-back with a device-side
-    event-compaction kernel (compact_accum) appended, and the frame
+    event-compaction kernel (_compact_accum) appended, and the frame
     resolves from its own fetch. A frame costs the device one scatter,
     one step and one compaction per grid (and the count_ub reduction),
     nothing else: its event buffers are an earlier frame's, handed back
     at resolve and donated into the first compaction, which reads the
-    totals as zero (_take_buffers), so no op makes or clears them. It
-    costs the host ONE wait when its event matrices are small
-    (ONE_PHASE_MAX_BYTES): their copy starts with the totals' at submit;
-    a large frame fetches the totals first and then the used prefixes
-    (resolve_frame). The compaction reduces the transfer from O(S*T*K)
+    totals as zero (_take_buffers), so no op makes or clears them. When
+    its event matrices are small (_one_phase, ONE_PHASE_MAX_BYTES) it
+    costs the host ONE dispatch a grid, a program that holds all four
+    (_grid_program; on one chip: a mesh places every grid through
+    BatchEngine._step), and ONE wait: the matrices' copy starts with the
+    totals' at submit. A large frame dispatches scatter, step and
+    compaction apart, since a program keyed by its wandering buffer
+    classes would lower the kernel again for each, and fetches the totals
+    first and then the used prefixes (resolve_frame). The compaction
+    reduces the transfer from O(S*T*K)
     record tensors (~500 B/order) to O(events) (~30 B/order). If any
     device budget tripped (book overflow, record truncation, compaction
     buffer), the frame transactionally rolls back and re-runs on the
@@ -49,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +71,7 @@ from .batch import (
     _next_pow4,
     is_device_fault,
     splice_outs,
+    step_in_program,
 )
 from .book import GRID_I32_FIELDS, DeviceOp
 from .step import ACTION_ADD, LOT_MAX32, TAKER_PRICE_MAX32
@@ -267,32 +274,54 @@ def _frame_arrays(eng: BatchEngine, cols: dict) -> dict:
     )
 
 
+def _scatter_grid(dtype, n_rows: int, t_grid: int, cols, flat) -> DeviceOp:
+    """Packed op columns [7, m_pad] + flat positions [m_pad] -> a padded
+    [n_rows, t_grid] DeviceOp grid (traceable). Padding columns carry
+    flat == R*T and drop."""
+    rt = n_rows * t_grid
+    fields = {}
+    for i, name in enumerate(_GRID_FIELDS):
+        want = jnp.int32 if name in GRID_I32_FIELDS else dtype
+        fields[name] = (
+            jnp.zeros((rt,), want)
+            .at[flat]
+            .set(cols[i].astype(want), mode="drop")
+            .reshape(n_rows, t_grid)
+        )
+    return DeviceOp(**fields)
+
+
 @functools.lru_cache(maxsize=256)  # a cap-class train set (rows x depth
 # classes x caps) can exceed 64 live shapes; eviction = silent re-trace
 def _scatter_grid_fn(dtype_name: str, n_rows: int, t_grid: int):
-    """Jitted device-side grid builder for one (dtype, R, T) shape:
-    packed op columns [7, m_pad] + flat positions [m_pad] -> a padded
-    DeviceOp grid. The host uploads O(ops) bytes regardless of the
+    """Jitted device-side grid builder for one (dtype, R, T) shape
+    (_scatter_grid). The host uploads O(ops) bytes regardless of the
     grid's occupancy — a Zipf train's deep tail grids are ~1% occupied,
     and shipping their NOP padding over the device link cost more than
-    the matching itself. Padding columns carry flat == R*T and drop."""
+    the matching itself."""
     dtype = jnp.dtype(dtype_name)
-    rt = n_rows * t_grid
 
     @jax.jit
     def scatter(cols, flat):
-        fields = {}
-        for i, name in enumerate(_GRID_FIELDS):
-            want = jnp.int32 if name in GRID_I32_FIELDS else dtype
-            fields[name] = (
-                jnp.zeros((rt,), want)
-                .at[flat]
-                .set(cols[i].astype(want), mode="drop")
-                .reshape(n_rows, t_grid)
-            )
-        return DeviceOp(**fields)
+        return _scatter_grid(dtype, n_rows, t_grid, cols, flat)
 
     return scatter
+
+
+class HostGrid(NamedTuple):
+    """A grid still on the host, as the packer leaves it for a frame whose
+    grids run as one program each (_grid_program): the scatter's two
+    arguments and the shape it builds."""
+
+    cols: np.ndarray  # [7, m_pad] packed op columns
+    flat: np.ndarray  # [m_pad] flat (row, t) positions
+    n_rows: int
+    t_grid: int
+
+    def on_device(self, dtype) -> DeviceOp:
+        return _scatter_grid_fn(
+            np.dtype(dtype).name, self.n_rows, self.t_grid
+        )(self.cols, self.flat)
 
 
 def _class_partitions(eng: BatchEngine, a: dict, active_idx):
@@ -322,12 +351,16 @@ def _class_partitions(eng: BatchEngine, a: dict, active_idx):
     return out
 
 
-def pack_frame_grids(eng: BatchEngine, a: dict) -> list[tuple]:
+def pack_frame_grids(eng: BatchEngine, a: dict,
+                     on_device: bool = True) -> list[tuple]:
     """Stage 2: split the frame into per-cap-class grid trains (lanes
     deeper than a grid's time axis roll into the next grid — FIFO by
     construction), pack each grid's ops as columns, and DISPATCH the
     device-side scatter that rebuilds the padded grid on device. Returns
-    [(ops, meta, lane_ids, cap_g), ...] with ops already device-resident.
+    [(ops, meta, lane_ids, cap_g), ...] with ops already device-resident;
+    with on_device False nothing is dispatched and ops is the HostGrid
+    (submit_frame: a small frame's scatter is part of its grid's one
+    program).
 
     Each train's loop carries a SHRINKING active-op index set: each grid
     touches only the ops still alive at its time offset, so a G-grid
@@ -340,12 +373,14 @@ def pack_frame_grids(eng: BatchEngine, a: dict) -> list[tuple]:
     if not len(kept_idx):
         return grids
     for cap_g, part_idx in _class_partitions(eng, a, kept_idx):
-        _pack_class_train(eng, a, part_idx, t[part_idx], cap_g, grids)
+        _pack_class_train(
+            eng, a, part_idx, t[part_idx], cap_g, grids, on_device
+        )
     return grids
 
 
 def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
-                      cap_g: int, grids: list) -> None:
+                      cap_g: int, grids: list, on_device: bool) -> None:
     """Pack one cap class's grid train (the loop body of the original
     single-train pack_frame_grids, with geometry ratchets keyed by the
     class). Each grid's geometry is two decisions made apart, both on
@@ -432,9 +467,9 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
         # The events' symbol_id: the one-chip lane, wherever a mesh
         # engine's placement stores the symbol (the same array without one).
         meta["lane"] = eng._symbol_ids(meta["lane"])
-        ops = _scatter_grid_fn(
-            np.dtype(eng.config.dtype).name, n_rows, t_grid
-        )(cols, flat)
+        ops = HostGrid(cols, flat, n_rows, t_grid)
+        if on_device:
+            ops = ops.on_device(eng.config.dtype)
         meta["_m_pad"] = m_pad  # host-only: shape-combo recording
         grids.append((ops, meta, lane_ids, cap_g))
 
@@ -619,9 +654,9 @@ def _decode_compact(eng, meta, shape, fetched) -> dict:
     return {name: v[order] for name, v in columns.items()}
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3, 4))
-def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
-    """Append one grid's compacted events into the FRAME-level buffers.
+def _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g):
+    """Append one grid's compacted events into the FRAME-level buffers
+    (traceable; compact_accum is its own program, _grid_program holds it).
 
     Like compact_step_outputs, but events land at the frame's running
     offsets (the sums of earlier grids' counts in totals_acc) instead of
@@ -695,6 +730,41 @@ def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
     return fills_acc, cancels_acc, totals_acc
 
 
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3, 4))
+def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
+    """_compact_accum as a program of its own, after a large frame's
+    eng._step: the three buffers are donated."""
+    return _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g)
+
+
+@functools.partial(
+    jax.jit, static_argnums=(0, 1, 2), donate_argnums=(7, 8, 9)
+)
+def _grid_program(plan, n_rows, t_grid, books, cols, flat, ids,
+                  fills_acc, cancels_acc, totals_acc, g):
+    """One grid of a small frame (_one_phase, no mesh) as ONE program: the
+    scatter of the packed host columns (_scatter_grid), the step the
+    engine planned (batch.step_in_program), the compaction into the
+    frame's buffers (_compact_accum, donated as in compact_accum) and the
+    per-lane count reduction that re-anchors count_ub, in that order. The
+    op grid and the step's outputs never leave it; the books are not
+    donated (the checkpoint is the transaction). cols, flat and a dense
+    grid's int32 lane ids (None on a full grid) come as host arrays. Keyed
+    by the whole dispatch combo (COMBO_FIELDS): the statics and the
+    arguments' shapes."""
+    ops = _scatter_grid(jnp.dtype(plan.cfg.dtype), n_rows, t_grid, cols, flat)
+    books, outs = step_in_program(plan, books, ops, ids)
+    assert outs.fill_qty.shape[-1] == _k_rec(plan.cfg)
+    buffers = _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g)
+    return books, buffers, jnp.max(books.count, axis=-1)
+
+
+def _k_rec(cfg) -> int:
+    """The record axis K a step at cfg's cap class emits: with cap <
+    max_fills the record slice clamps to cap (step.py `rec`)."""
+    return min(cfg.max_fills, cfg.cap)
+
+
 class PendingFrame:
     """A frame whose grids are dispatched (device side in flight) but not
     yet resolved: everything resolve_frame needs, plus the checkpoint that
@@ -756,6 +826,12 @@ def export_metrics(eng: BatchEngine) -> None:
         ("gome_fast_frames_one_phase_total",
          "fast-path frames whose events came back with their totals",
          "fast_frames_one_phase"),
+        ("gome_fast_grids_one_program_total",
+         "grids of one-phase frames, dispatched as one program each",
+         "fast_grids_one_program"),
+        ("gome_device_calls_total",
+         "grids dispatched to the device, on the fast or the exact path",
+         "device_calls"),
     ):
         REGISTRY.callback_gauge(
             name, help_, lambda field=field: getattr(stats, field)
@@ -778,13 +854,19 @@ def export_metrics(eng: BatchEngine) -> None:
         )
 
 
+def _wide(eng: BatchEngine) -> np.dtype:
+    """The event buffers' dtype: wide enough for the books' and for int32
+    source indices."""
+    return np.promote_types(np.int32, np.dtype(eng.config.dtype))
+
+
 def _zero_buffers(eng: BatchEngine, e_fills: int, e_cancels: int,
                   totals_len: int):
     """A fresh set of event buffers (fills, cancels, totals): three host
     arrays put on the device as compact_accum returns them (replicated
     over a mesh), so a fresh set and a handed-back one run one program. A
     transfer, not a device op; a steady flow makes none (_take_buffers)."""
-    wide = np.promote_types(np.int32, np.dtype(eng.config.dtype))
+    wide = _wide(eng)
     where = None
     if eng.mesh is not None:
         where = jax.sharding.NamedSharding(
@@ -834,21 +916,24 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
     try:
         with span("frame_pack", orders=int(cols["n"])) as packed:
             a = _frame_arrays(eng, cols)
-            grids = pack_frame_grids(eng, a)
             books = eng.books
             items = []
             compact = None
             one_phase = False
             n_kept = int(np.count_nonzero(a["keep"]))
-            if grids:
-                e_fills, e_cancels = _compact_sizes(
+            if n_kept:  # the frame has grids
+                e_fills, e_cancels, one_phase = _compact_sizes(
                     eng, n_kept, a["dels_total"]
                 )
+            # A one-phase frame's grids stay on the host: each runs as one
+            # program, scatter included (_grid_program). Not under a mesh:
+            # there a grid is placed by eng._step (shard_put), large or
+            # small.
+            one_program = one_phase and eng.mesh is None
+            grids = pack_frame_grids(eng, a, on_device=not one_program)
+            if grids:
                 (fills_acc, cancels_acc, totals_acc), reused = _take_buffers(
                     eng, e_fills, e_cancels, max(_next_pow2(len(grids)), 8)
-                )
-                one_phase = _one_phase(
-                    fills_acc.dtype.itemsize, e_fills, e_cancels
                 )
                 eng.stats.fast_frames += 1
                 eng.stats.fast_frames_reused += int(reused)
@@ -858,29 +943,46 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
         for g_i, (ops, meta, lane_ids, cap_g) in enumerate(grids):
             t_disp = TRACER.clock() if TRACER.enabled else 0.0
             t_disp_j = JOURNAL.clock() if JOURNAL.enabled else 0.0
-            n_rows, t_grid = ops.action.shape
+            n_ops = len(meta["row"])
+            dense = lane_ids is not None
+            if one_program:
+                n_rows, t_grid = ops.n_rows, ops.t_grid
+            else:
+                n_rows, t_grid = ops.action.shape
             with span(
                 "grid_dispatch", rows=n_rows, t=t_grid, cap=int(cap_g),
-                n_ops=len(meta["row"]),
-                grid="full" if lane_ids is None else "dense",
+                n_ops=n_ops, grid="dense" if dense else "full",
+                program=1 if one_program else 3,
             ):
-                books, outs = eng._step(
-                    books, ops, lane_ids, cap_g, n_ops=len(meta["row"])
-                )
+                if one_program:
+                    plan = eng._grid_plan(n_rows, dense, cap_g, n_ops)
+                    books, buffers, counts_max = _grid_program(
+                        plan, n_rows, t_grid, books, ops.cols, ops.flat,
+                        lane_ids.astype(np.int32) if dense else None,
+                        fills_acc, cancels_acc, totals_acc, np.int32(g_i),
+                    )
+                    fills_acc, cancels_acc, totals_acc = buffers
+                    eng.stats.fast_grids_one_program += 1
+                    k_rec = _k_rec(plan.cfg)  # the program asserts it
+                else:
+                    books, outs = eng._step(
+                        books, ops, lane_ids, cap_g, n_ops=n_ops
+                    )
+                    fills_acc, cancels_acc, totals_acc = compact_accum(
+                        eng.config, outs, fills_acc, cancels_acc,
+                        totals_acc, np.int32(g_i),
+                    )
+                    k_rec = int(outs.fill_qty.shape[-1])
                 eng.stats.device_calls += 1
-                fills_acc, cancels_acc, totals_acc = compact_accum(
-                    eng.config, outs, fills_acc, cancels_acc, totals_acc,
-                    np.int32(g_i),
-                )
             meta["_n_rows"] = n_rows
-            # The record axis K comes from the ARRAY, never from
-            # config.max_fills: with cap < max_fills the step's record
-            # slice clamps to cap (step.py `rec`), so the decode's flat
-            # src arithmetic — and the truncation check in resolve_frame —
-            # must use the K the records were actually emitted with
-            # (fuzz-found: seed 9087, cap=4 K=8 mis-decoded fills and
-            # would have silently dropped records of >K-fill ops).
-            k_rec = int(outs.fill_qty.shape[-1])
+            # The record axis K comes from the ARRAY (or from the program
+            # that asserted it), never from config.max_fills: with cap <
+            # max_fills the step's record slice clamps to cap (step.py
+            # `rec`), so the decode's flat src arithmetic — and the
+            # truncation check in resolve_frame — must use the K the
+            # records were actually emitted with (fuzz-found: seed 9087,
+            # cap=4 K=8 mis-decoded fills and would have silently dropped
+            # records of >K-fill ops).
             items.append((meta, (t_grid, k_rec)))
             # Record the full dispatch combo (grid geometry x frame
             # buffers) for shape_manifest/precompile_combos: this tuple
@@ -925,8 +1027,12 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
             if len(_cap_ladder(eng.config.cap)) > 1:
                 # The count_ub re-anchor rides the frame's totals fetch —
                 # but only multi-class engines ever read it; single-class
-                # ones skip the [S]-wide reduction and transfer.
-                compact += (jnp.max(books.count, axis=-1),)
+                # ones skip the [S]-wide reduction and transfer. A small
+                # frame's last program made it.
+                compact += (
+                    counts_max if one_program
+                    else jnp.max(books.count, axis=-1),
+                )
             # The fetch starts now. Totals (+counts_max) are tiny and
             # resolve needs them FIRST; small event matrices come whole
             # with them (ONE_PHASE_MAX_BYTES), large ones are fetched as
@@ -1109,9 +1215,10 @@ def apply_frame_fast(eng: BatchEngine, cols: dict):
 
 
 # gomesurface: quantizer
-def _compact_sizes(eng, n_ops: int, n_dels: int) -> tuple[int, int]:
-    """Compaction buffer sizes for a grid of n_ops packed ops (n_dels of
-    them DELs). Sizes MUST be pow2-bucketed: every distinct size is a
+def _compact_sizes(eng, n_ops: int, n_dels: int) -> tuple[int, int, bool]:
+    """Compaction buffer sizes for a frame of n_ops packed ops (n_dels of
+    them DELs), and whether they put it under the one-phase rule
+    (_one_phase). Sizes MUST be pow2-bucketed: every distinct size is a
     fresh kernel compile. But the buffers are also the frame's device->
     host transfer — so they start TIGHT and ratchet up instead of paying
     2x+ headroom forever:
@@ -1135,15 +1242,26 @@ def _compact_sizes(eng, n_ops: int, n_dels: int) -> tuple[int, int]:
     floor, so that costs one slow frame per ratchet step, not a recurring
     tax; cancel events can never overflow (cancels <= n_dels by
     construction, step.py cancel_found). Deployments that know their flow
-    pre-warm the floors (BatchEngine.prewarm_geometry)."""
+    pre-warm the floors (BatchEngine.prewarm_geometry).
+
+    A frame under the one-phase rule takes a cancels buffer as wide as
+    its op class (n_dels <= n_ops, so the DEL count can never move it):
+    each of its grids is ONE program keyed by these widths too
+    (_grid_program), and a DEL count that crossed a power of two would
+    lower the kernel again where a large frame re-lowers a compaction. A
+    few hundred bytes more to fetch; the rule is decided on the widened
+    pair."""
     cls = eng._buf_class(n_ops)
     fills = max(cls, eng._fills_buf_floor.get(cls, 0))
     cancels = max(
         _next_pow2(max(n_dels, 64)), eng._cancels_buf_floor.get(cls, 0)
     )
+    one_phase = _one_phase(_wide(eng).itemsize, fills, max(cancels, cls))
+    if one_phase:
+        cancels = max(cancels, cls)
     eng._fills_buf_floor[cls] = fills
     eng._cancels_buf_floor[cls] = cancels
-    return fills, cancels
+    return fills, cancels, one_phase
 
 
 # gomesurface: combo(replay), precompile
@@ -1151,14 +1269,16 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
     """Replay recorded fast-path shape combos (BatchEngine.shape_manifest
     "combos") with ALL-PADDING inputs, forcing every jit trace+compile the
     live flow will need — scatter, step (dense or full, at the combo's cap
-    class), and frame-level compaction — before real traffic arrives.
+    class), and frame-level compaction: one program for a combo under the
+    one-phase rule (_grid_program), the three calls for one over it, as
+    submit_frame dispatches them — before real traffic arrives.
 
     All-padding means: scatter positions at the drop sentinel (R*T), so
     the DeviceOp grid is all NOPs; dense lane_ids at the n_slots sentinel
     (gathered as zero books, scattered nowhere). Book state is read but
-    results are DISCARDED — replay never mutates the engine (the step jits
-    don't donate their inputs; compact_accum donates only the dummy
-    buffers built here). Floors should be prewarmed first
+    results are DISCARDED — replay never mutates the engine (no program
+    donates the books; the compaction donates only the dummy buffers
+    built here). Floors should be prewarmed first
     (prewarm_geometry) so the live flow also CHOOSES these shapes.
 
     Returns the number of combos replayed. Cost: one compile each on a
@@ -1166,7 +1286,7 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
     vs ~0.3-1s of un-hideable host TRACE time per shape if it first
     appears mid-traffic (the XLA persistent cache covers compiles
     only; traces are per-process)."""
-    wide = jnp.result_type(jnp.int32, eng.config.dtype)
+    wide = _wide(eng)
     dt = np.dtype(eng.config.dtype)
     combos = sorted(set(map(tuple, combos)))
     replayed = 0
@@ -1189,18 +1309,31 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
                 # load_geometry does). Unreplayable as-is; skip rather
                 # than crash.
                 continue
-            cols = np.zeros((7, m_pad), dt)
-            flat = np.full(m_pad, n_rows * t_grid, np.int32)
-            ops = _scatter_grid_fn(dt.name, n_rows, t_grid)(cols, flat)
+            grid = HostGrid(
+                np.zeros((7, m_pad), dt),
+                np.full(m_pad, n_rows * t_grid, np.int32), n_rows, t_grid,
+            )
             lane_ids = (
                 np.full(n_rows, eng.n_slots, np.int64) if dense else None
             )
-            _books, outs = eng._step(eng.books, ops, lane_ids, cap_g)
-            out = compact_accum(
-                eng.config, outs,
-                *_zero_buffers(eng, e_fills, e_cancels, totals_len),
-                np.int32(0),
-            )
+            buffers = _zero_buffers(eng, e_fills, e_cancels, totals_len)
+            if eng.mesh is None and _one_phase(
+                wide.itemsize, e_fills, e_cancels
+            ):
+                # The live frame of this combo runs one program a grid.
+                out = _grid_program(
+                    eng._grid_plan(n_rows, dense, cap_g, None),
+                    n_rows, t_grid, eng.books, grid.cols, grid.flat,
+                    lane_ids.astype(np.int32) if dense else None,
+                    *buffers, np.int32(0),
+                )
+            else:
+                _books, outs = eng._step(
+                    eng.books, grid.on_device(dt), lane_ids, cap_g
+                )
+                out = compact_accum(
+                    eng.config, outs, *buffers, np.int32(0)
+                )
             # Serialize: each replay holds a transient books-sized output;
             # blocking frees it before the next combo allocates.
             jax.block_until_ready(out)

@@ -299,8 +299,12 @@ class EngineService:
         st = self.engine.stats
         (log.warning if tracing.slow() else log.info)(
             "fast-path frames: %d dispatched, %d on reused event buffers, "
-            "%d fetched in one phase",
+            "%d fetched in one phase; grids: %d dispatched, %d as one "
+            "program; %d dispatch combos over %d step geometries",
             st.fast_frames, st.fast_frames_reused, st.fast_frames_one_phase,
+            st.device_calls, st.fast_grids_one_program,
+            self.engine.batch.combo_count(),
+            len({c[:4] for c in self.engine.batch.combos()}),
         )
         (log.warning if tracing.slow() else log.info)(
             "orders: %d applied; adds by kind %s; expired: %d IOC "

@@ -186,7 +186,8 @@ def test_device_fault_is_never_bisected_by_the_poison_policy():
     assert _poisoned.value() == before
 
 
-def test_precompile_reraises_compile_error_and_skips_stale_combo(tmp_path):
+def test_precompile_reraises_compile_error_and_skips_stale_combo(
+        tmp_path, monkeypatch):
     from gome_tpu.engine import frames
     from gome_tpu.engine.orchestrator import MatchEngine
 
@@ -205,7 +206,9 @@ def test_precompile_reraises_compile_error_and_skips_stale_combo(tmp_path):
     ))
     assert engine.load_geometry(str(manifest)) == 1
 
-    eng._step = _refusing_step
+    # (the combo's buffers are under the one-phase rule: its replay is the
+    # one program of a small frame's grid, not eng._step)
+    monkeypatch.setattr(frames, "_grid_program", _refusing_step)
     with pytest.raises(jax.errors.JaxRuntimeError):
         frames.precompile_combos(eng, [stale, good])
     with pytest.raises(jax.errors.JaxRuntimeError):

@@ -183,9 +183,9 @@ def test_list_forms_are_the_frame_path(form, dtype, monkeypatch):
     packs = []
     real_pack = frames.pack_frame_grids
 
-    def counting_pack(eng, a):
+    def counting_pack(eng, a, **kw):
         packs.append(a["n"])
-        return real_pack(eng, a)
+        return real_pack(eng, a, **kw)
 
     monkeypatch.setattr(frames, "pack_frame_grids", counting_pack)
     if is_match:
@@ -694,16 +694,19 @@ def test_geometry_manifest_stale_or_oversized_is_best_effort(tmp_path):
 # --- the event buffers' life and the one-phase fetch (ISSUE 33) -------------
 
 
-def _engine(mesh_devices, **kw):
-    mesh = None
-    if mesh_devices:
-        from gome_tpu.parallel import make_mesh
+def _mesh(mesh_devices):
+    if not mesh_devices:
+        return None
+    from gome_tpu.parallel import make_mesh
 
-        mesh = make_mesh(mesh_devices)
+    return make_mesh(mesh_devices)
+
+
+def _engine(mesh_devices, **kw):
     kw.setdefault("n_slots", 16)
     kw.setdefault("max_t", 8)
     return BatchEngine(BookConfig(cap=128, max_fills=8, dtype=jnp.int32),
-                       mesh=mesh, **kw)
+                       mesh=_mesh(mesh_devices), **kw)
 
 
 @pytest.fixture
@@ -733,18 +736,60 @@ def device_work(monkeypatch):
     monitoring.unregister_event_duration_listener(on_event)
 
 
+@pytest.fixture
+def device_calls(monkeypatch):
+    """Two lists that fill with what the host asks of the device: the name
+    of every program executed, jitted or eager (the C++ fast path of a
+    jitted call is off meanwhile, so every execution passes
+    ExecuteReplicated.__call__; a signature that took the fast path before
+    the fixture stays unseen, so a test clears the caches of the programs
+    it counts), and the number of arrays of every explicit device_put
+    (jax.device_put, or the primitive bound eagerly; an eager jnp.asarray
+    of a host array is one of the two)."""
+    from jax._src import dispatch, pjit
+    from jax._src.interpreters import pxla
+
+    programs, puts = [], []
+    real_call = pxla.ExecuteReplicated.__call__
+
+    def counting_call(self, *args):
+        programs.append(self.name)
+        return real_call(self, *args)
+
+    monkeypatch.setattr(pxla.ExecuteReplicated, "__call__", counting_call)
+    monkeypatch.setattr(pjit, "_get_fastpath_data", lambda *a, **kw: None)
+    real_impl, real_put = dispatch.device_put_p.impl, jax.device_put
+
+    def counting_impl(*xs, **params):
+        puts.append(len(xs))
+        return real_impl(*xs, **params)
+
+    def counting_put(x, *a, **kw):
+        puts.append(len(jax.tree.leaves(x)))
+        return real_put(x, *a, **kw)
+
+    monkeypatch.setattr(dispatch.device_put_p, "impl", counting_impl)
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    return programs, puts
+
+
 @pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one_chip", "mesh4"])
 def test_steady_frames_make_no_eager_op_and_lower_nothing(
-    mesh_devices, device_work, monkeypatch
+    mesh_devices, device_work, device_calls, monkeypatch
 ):
     """After warm-up a steady stream's frames cost the device its
     programs and the host one wait: no primitive is dispatched eagerly by
     submit_frame or resolve_frame (the parent made six a frame, the three
     jnp.zeros of the event buffers), nothing is lowered, every frame's
     buffers are an earlier frame's and every frame resolves in one phase,
-    with one device_get (the parent made four)."""
+    with one device_get (the parent made four). On one chip a frame of
+    one grid executes ONE program and puts nothing on the device beside it
+    (the parent executed four: scatter, step, compaction, the count
+    reduction); under a mesh a small frame keeps the three calls and the
+    placement of its grid (BatchEngine._step), as a large one."""
     from collections import deque
 
+    from gome_tpu.engine import frames
     from gome_tpu.engine.frames import resolve_frame, submit_frame
 
     orders = multi_symbol_stream(
@@ -752,6 +797,8 @@ def test_steady_frames_make_no_eager_op_and_lower_nothing(
     )
     eng = _engine(mesh_devices)
     eager, lowered = device_work
+    programs, puts = device_calls
+    frames._grid_program.clear_cache()
     fetches = []
     real_get = jax.device_get
 
@@ -764,6 +811,7 @@ def test_steady_frames_make_no_eager_op_and_lower_nothing(
     for k in range(34):
         if k == 14:  # warm: every shape met, three sets in rotation
             eager.clear(), lowered.clear(), fetches.clear()
+            programs.clear(), puts.clear()
             before = dataclasses.replace(eng.stats)
         in_flight.append(
             submit_frame(eng, colwire.orders_to_cols(orders[k * 60:][:60]))
@@ -772,6 +820,16 @@ def test_steady_frames_make_no_eager_op_and_lower_nothing(
             resolve_frame(eng, in_flight.popleft())
     assert eager == []
     assert lowered == []
+    assert eng.stats.device_calls - before.device_calls == 20
+    one_program = (eng.stats.fast_grids_one_program
+                   - before.fast_grids_one_program)
+    if mesh_devices:
+        assert one_program == 0 and "jit(_grid_program)" not in programs
+        assert programs.count("jit(compact_accum)") == 20
+    else:
+        assert one_program == 20
+        assert programs == ["jit(_grid_program)"] * 20
+        assert puts == []
     # One blocking fetch a frame: totals, both matrices and counts_max.
     assert fetches == [4] * 20
     frames = eng.stats.fast_frames - before.fast_frames
@@ -886,3 +944,296 @@ def test_precompiled_manifest_leaves_a_live_run_nothing_to_lower(
     assert ev2 == ev1
     assert e2.stats.fast_frames_reused > 0
     assert e2.stats.frame_fallbacks == e1.stats.frame_fallbacks
+
+
+# --- a small frame's grid is one program (ISSUE 35) -------------------------
+
+
+def _hot_and_tail_frames(n_frames, seed=3):
+    """Frames of 56 to 60 orders for a venue of two cap classes: a listing rests
+    80 bids in `hot` (over the 64-slot class), then every frame adds four
+    bids there and cancels the four of the frame before, beside 52 orders
+    over ten tail symbols. With cap 256 and n_slots 64 each frame packs two
+    dense grids: the tail's at class 64 and hot's at class 256."""
+    def hot(oid, price, action=Action.ADD):
+        return Order(uuid="h", oid=oid, symbol="hot", side=Side.BUY,
+                     price=price, volume=5, action=action)
+
+    tail = multi_symbol_stream(
+        n=52 * n_frames, n_symbols=10, seed=seed, cancel_prob=0.3
+    )
+    out = [[hot(f"l{i}", 50_000_000 - i) for i in range(80)]]
+    for k in range(n_frames):
+        frame = [hot(f"f{k}.{j}", 40_000_000 - j) for j in range(4)]
+        if k:
+            frame += [hot(f"f{k - 1}.{j}", 40_000_000 - j, Action.DEL)
+                      for j in range(4)]
+        out.append(frame + tail[52 * k:][:52])
+    return out
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one_chip", "mesh4"])
+def test_a_small_frame_of_two_dense_grids_executes_two_programs(
+    mesh_devices, device_work, device_calls
+):
+    """Two dense grids a frame, of the 64-slot and of the 256-slot class:
+    after warm-up submit_frame executes one program a grid and puts nothing
+    on the device beside them, eager or explicit (the parent executed seven
+    programs a frame, two scatters, two steps, two compactions and the
+    count reduction, and uploaded each grid's lane ids before its step).
+    Under a mesh the frame keeps those calls: scatter, the sharded step
+    behind its shard_put, compaction."""
+    from gome_tpu.engine import frames
+
+    chunks = _hot_and_tail_frames(30)
+    eng = BatchEngine(
+        BookConfig(cap=256, max_fills=8, dtype=jnp.int32),
+        n_slots=64, max_t=16, mesh=_mesh(mesh_devices),
+    )
+    eager, lowered = device_work
+    programs, puts = device_calls
+    frames._grid_program.clear_cache()
+    for k, chunk in enumerate(chunks):
+        if k == 12:  # warm: both classes' floors settled, sets in rotation
+            eager.clear(), lowered.clear(), programs.clear(), puts.clear()
+            before = dataclasses.replace(
+                eng.stats, grids_by_kernel=dict(eng.stats.grids_by_kernel)
+            )
+        pend = frames.submit_frame(eng, colwire.orders_to_cols(chunk))
+        seen = len(programs)
+        frames.resolve_frame(eng, pend)
+        assert len(programs) == seen  # resolve executes nothing
+    n = len(chunks) - 12
+    assert eager == [] and lowered == []
+    assert eng.stats.device_calls - before.device_calls == 2 * n
+    one_program = (eng.stats.fast_grids_one_program
+                   - before.fast_grids_one_program)
+    if mesh_devices:
+        assert one_program == 0 and "jit(_grid_program)" not in programs
+        assert programs.count("jit(scatter)") == 2 * n
+        assert programs.count("jit(compact_accum)") == 2 * n
+        assert len(puts) == 4 * n  # a grid's ids and its ops, placed
+    else:
+        assert one_program == 2 * n
+        assert programs == ["jit(_grid_program)"] * (2 * n)
+        assert puts == []
+    assert (eng.stats.grids_by_kernel["scan_dense"]
+            - before.grids_by_kernel["scan_dense"]) == 2 * n
+    assert eng.stats.frame_fallbacks == 0
+    eng.verify_books()
+
+
+#: The Pallas kernel's code path where no compiled kernel runs (the
+#: interpreter): the step bodies the chip's programs hold.
+_INTERPRET = dict(kernel="pallas", pallas_interpret=True)
+
+
+def _case_under_and_over_the_rule():
+    """Full grids, frames of 64 and 65 kept ops: with the rule at a
+    64-wide buffer pair's bytes they alternate between one program a grid
+    and three calls."""
+    orders = multi_symbol_stream(
+        n=129 * 4, n_symbols=12, seed=11, cancel_prob=0.25
+    )
+    chunks, i = [], 0
+    while i < len(orders):
+        n = 64 if len(chunks) % 2 == 0 else 65
+        chunks.append(orders[i : i + n])
+        i += n
+    rule = (7 + 2) * 64 * 4
+    return dict(cap=128, n_slots=16, max_t=8), chunks, rule
+
+
+def _case_dense_grid():
+    orders = multi_symbol_stream(
+        n=60 * 8, n_symbols=6, seed=12, cancel_prob=0.3
+    )
+    chunks = [orders[i : i + 60] for i in range(0, len(orders), 60)]
+    return dict(cap=64, n_slots=64, max_t=16, **_INTERPRET), chunks, None
+
+
+def _case_two_classes():
+    return dict(cap=256, n_slots=64, max_t=16), _hot_and_tail_frames(8), None
+
+
+def _case_order_kinds():
+    from test_order_kinds import tif_flow
+
+    orders = tif_flow(seed=7, n=480, n_symbols=3)
+    chunks = [orders[i : i + 60] for i in range(0, len(orders), 60)]
+    return dict(cap=128, n_slots=32, max_t=8, **_INTERPRET), chunks, None
+
+
+def _case_fills_overflow_the_buffer():
+    """Twice: 16 asks of one lot rest in each of five symbols, then two
+    bids a symbol take eight each: 80 fills from a frame of 10 kept ops,
+    whose buffer class holds 64. The first such frame trips, rewinds to
+    the exact path and raises the class's floor; the second fits."""
+    def order(oid, sym, side, volume):
+        return Order(uuid="u", oid=oid, symbol=f"s{sym}", side=side,
+                     price=1_000, volume=volume)
+
+    chunks = []
+    for r in range(2):
+        chunks.append([order(f"a{r}.{s}.{i}", s, Side.SALE, 1)
+                       for s in range(5) for i in range(16)])
+        chunks.append([order(f"b{r}.{s}.{i}", s, Side.BUY, 8)
+                       for s in range(5) for i in range(2)])
+    return dict(cap=128, n_slots=16, max_t=8), chunks, None
+
+
+_ONE_PROGRAM_CASES = {
+    "under_and_over_the_rule": _case_under_and_over_the_rule,
+    "dense_grid": _case_dense_grid,
+    "two_classes": _case_two_classes,
+    "order_kinds": _case_order_kinds,
+    "fills_overflow_the_buffer": _case_fills_overflow_the_buffer,
+}
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one_chip", "mesh4"])
+@pytest.mark.parametrize("case", sorted(_ONE_PROGRAM_CASES))
+def test_one_program_frames_equal_the_exact_path(
+    case, mesh_devices, monkeypatch
+):
+    """A small frame's grids run as one program each (frames._grid_program;
+    under a mesh as three calls) and a large frame's as three calls:
+    either way the same EventBatch
+    columns, books, EngineStats (kinds and expiries included) and grids by
+    kernel as process_frame, frame for frame; a frame that trips is re-run
+    on the exact path and costs its grids once more."""
+    from gome_tpu.engine import frames
+
+    kw, chunks, rule = _ONE_PROGRAM_CASES[case]()
+    if rule is not None:
+        monkeypatch.setattr(frames, "ONE_PHASE_MAX_BYTES", rule)
+
+    def mk(kw):
+        cfg = BookConfig(cap=kw.pop("cap"), max_fills=8, dtype=jnp.int32)
+        return BatchEngine(cfg, mesh=_mesh(mesh_devices), **kw)
+
+    fast, exact = mk(dict(kw)), mk(dict(kw))
+    for k, chunk in enumerate(chunks):
+        got = frames.apply_frame_fast(fast, colwire.orders_to_cols(chunk))
+        want = process_frame(exact, colwire.orders_to_cols(chunk))
+        assert got.columns.keys() == want.columns.keys()
+        for name in want.columns:
+            np.testing.assert_array_equal(
+                got.columns[name], want.columns[name], err_msg=f"{k} {name}"
+            )
+    for name in ("orders", "fills", "cancels", "cancels_missed",
+                 "adds_by_kind", "expired_ioc", "fok_killed",
+                 "post_only_blocked", "scan_giveways"):
+        assert getattr(fast.stats, name) == getattr(exact.stats, name), name
+    assert fast.stats.fast_frames == len(chunks)
+    if mesh_devices:  # a mesh places every grid through BatchEngine._step
+        assert fast.stats.fast_grids_one_program == 0
+    # Grids by the kernel that ran them: process_frame's, and a tripped
+    # frame's once more (its one grid, dispatched and then re-run exactly).
+    tripped = int(case == "fills_overflow_the_buffer")
+    assert fast.stats.frame_fallbacks == tripped
+    for name in ("grids_by_kernel", "ops_by_kernel"):
+        ours, theirs = getattr(fast.stats, name), getattr(exact.stats, name)
+        assert ours.keys() == theirs.keys(), name
+        if not tripped:
+            assert ours == theirs, name
+    assert (sum(fast.stats.grids_by_kernel.values())
+            == sum(exact.stats.grids_by_kernel.values()) + tripped)
+    if case == "under_and_over_the_rule":
+        assert (0 < fast.stats.fast_frames_one_phase
+                < fast.stats.fast_frames)
+        if not mesh_devices:
+            assert (0 < fast.stats.fast_grids_one_program
+                    < fast.stats.device_calls)
+    elif not mesh_devices:
+        assert (fast.stats.fast_grids_one_program
+                == fast.stats.device_calls - tripped)  # the exact re-run's
+    if case == "two_classes":
+        assert fast.stats.device_calls >= 2 * (len(chunks) - 1)
+    if "kernel" in kw and not mesh_devices:
+        assert all(k.startswith("interpret_")
+                   for k in fast.stats.grids_by_kernel)
+    if case == "order_kinds":
+        assert set(fast.stats.adds_by_kind) == {0, 1, 3, 4, 6}
+        assert min(fast.stats.expired_ioc, fast.stats.fok_killed,
+                   fast.stats.post_only_blocked) > 0
+    if tripped:
+        assert fast.geometry_floors()["fills_buf"][64] == 128
+    fast.verify_books()
+    _assert_same_books(fast, exact)
+
+
+@pytest.mark.parametrize("kernel", ["scan", "interpret"])
+def test_precompile_replays_one_program_under_the_rule_and_three_calls_over_it(
+    kernel, device_work, device_calls
+):
+    """A manifest with combos on both sides of the one-phase rule, two
+    60-order frames' under it and a 1,100-order frame's over it: precompile_combos executes the
+    one program for each of the first and scatter, step and compaction for
+    the last, and the live frames after it lower nothing, on fresh buffers
+    and on reused ones."""
+    from gome_tpu.engine import frames
+
+    orders = multi_symbol_stream(
+        n=2320, n_symbols=12, seed=21, cancel_prob=0.3
+    )
+    chunks = [orders[:60], orders[60:1160], orders[1160:1220], orders[1220:]]
+
+    def mk():
+        return BatchEngine(
+            BookConfig(cap=64, max_fills=8, dtype=jnp.int32), n_slots=16,
+            max_t=8, **(_INTERPRET if kernel == "interpret" else {}),
+        )
+
+    def run(eng):
+        return [frames.apply_frame_fast(eng, colwire.orders_to_cols(c))
+                for c in chunks]
+
+    first = mk()
+    want = run(first)
+    manifest = first.shape_manifest()
+    small = [frames._one_phase(4, c[6], c[7]) for c in manifest["combos"]]
+    # (the second small frame meets the depth floor the large one raised)
+    assert small == [True, True, False]
+    assert first.stats.fast_grids_one_program == 2 < first.stats.device_calls
+
+    eng = mk()
+    eng.prewarm_geometry(**{
+        k: v for k, v in manifest["floors"].items() if k != "cap"
+    })
+    _eager, lowered = device_work
+    programs, _puts = device_calls
+    for fn in (frames._grid_program, frames.compact_accum):
+        fn.clear_cache()
+    programs.clear()
+    assert frames.precompile_combos(eng, manifest["combos"]) == 3
+    # (after them the count reduction and the large frame's prefix slices)
+    assert programs[:2] == ["jit(_grid_program)"] * 2
+    assert programs[2] == "jit(scatter)"  # then the large combo's step
+    assert programs[4] == "jit(compact_accum)"
+    assert "jit(_grid_program)" not in programs[2:]
+    assert eng.stats.grids_by_kernel == {}  # a replay counts no grid
+    lowered.clear()
+    got = run(eng)
+    assert lowered == []
+    assert eng.stats.fast_frames_reused > 0
+    for g, w in zip(got, want):
+        for name in w.columns:
+            np.testing.assert_array_equal(g.columns[name], w.columns[name])
+
+
+def test_the_one_program_counter_is_on_metrics():
+    """gome_fast_grids_one_program_total over gome_device_calls_total: 1
+    after small frames, under 1 once a large one went the three calls."""
+    from gome_tpu.engine import frames
+    from gome_tpu.utils.metrics import REGISTRY
+
+    eng = _engine(0)
+    frames.export_metrics(eng)
+    orders = multi_symbol_stream(n=1220, n_symbols=12, seed=4)
+    for chunk in (orders[:60], orders[60:120], orders[120:]):
+        frames.apply_frame_fast(eng, colwire.orders_to_cols(chunk))
+    text = REGISTRY.render()
+    assert "gome_fast_grids_one_program_total 2" in text
+    assert f"gome_device_calls_total {eng.stats.device_calls}" in text
+    assert eng.stats.device_calls > 2

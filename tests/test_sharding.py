@@ -663,9 +663,11 @@ def _packed_grid_digest():
     h = hashlib.sha256()
     inner = frames.pack_frame_grids
 
-    def pack(e, a):
-        grids = inner(e, a)
+    def pack(e, a, **kw):
+        grids = inner(e, a, **kw)
         for ops, meta, lane_ids, cap_g in grids:
+            if isinstance(ops, frames.HostGrid):  # a small frame's: the
+                ops = ops.on_device(e.config.dtype)  # program scatters it
             h.update(repr((tuple(ops.action.shape), int(cap_g),
                            lane_ids is None)).encode())
             for field in ops._fields:

@@ -117,3 +117,51 @@ def test_the_sharded_dense_step_compiles_for_four_v5e_chips_without_collectives(
     for collective in ("all-gather", "all-reduce", "all-to-all",
                        "collective-permute", "reduce-scatter"):
         assert collective not in text, collective
+
+
+@pytest.mark.parametrize(
+    "n_slots, store_cap, rows, t, cap, dense, m_pad, e_fills, name", [
+        # hotpair8.paced: 81 orders a frame, one full grid of the 1024-slot
+        # class at the shallowest depth
+        (8, 1024, 8, 32, 1024, False, 256, 128, "match_full_r8_t32_c1024"),
+        # spot10k.paced: 62 orders a frame, two dense grids: the tail's
+        # lanes at class 64 and the deep band's at class 256 (the cell's
+        # floors in a chip run: rows 64 and 32, depth 8)
+        (10240, 256, 64, 8, 64, True, 64, 64, "match_dense_r64_t8_c64"),
+        (10240, 256, 32, 8, 256, True, 64, 64, "match_dense_r32_t8_c256"),
+    ])
+def test_a_small_frames_grid_compiles_for_v5e_as_one_program(
+        one_chip, n_slots, store_cap, rows, t, cap, dense, m_pad, e_fills,
+        name):
+    """frames._grid_program at the paced cells' geometries: scatter, the
+    kernel's step, compaction and the count reduction lower and compile
+    for the chip as ONE module, with the kernel in it under its geometry's
+    name and the three event buffers aliased to their outputs."""
+    import numpy as np
+
+    from gome_tpu.engine import frames
+
+    store = BookConfig(cap=store_cap, max_fills=16, dtype=jnp.int32)
+    shape = lambda s, dt=jnp.int32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    books = jax.tree.map(lambda a: shape(a.shape, a.dtype), jax.eval_shape(
+        lambda: jax.vmap(lambda _: init_book(store))(jnp.arange(n_slots))
+    ))
+    block, reason = plan_block_s(rows, cap)
+    assert block is not None, reason
+    plan = B.StepPlan(
+        BookConfig(cap=cap, max_fills=16, dtype=jnp.int32), dense, block,
+        False,
+    )
+    with jax.enable_x64(False):  # the deployment's int32 process
+        text = frames._grid_program.lower(
+            plan, rows, t, books, shape((7, m_pad)), shape((m_pad,)),
+            shape((rows,)) if dense else None,
+            shape((len(frames._FILL_FIELDS), e_fills)),
+            shape((len(frames._CANCEL_FIELDS), e_fills)),  # the op class
+            shape((8, frames.N_TOTALS)), np.int32(0),
+        ).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and calls[0].startswith(f"%{name}."), calls
+    assert text.count("may-alias") + text.count("must-alias") >= 3
