@@ -92,14 +92,17 @@ class Queue(abc.ABC):
         return self.end_offset() - self.committed()
 
     def poll_batch(
-        self, max_n: int, max_wait_s: float, poll_interval_s: float = 0.001
+        self, max_n: int, max_wait_s: float, poll_interval_s: float = 0.001,
+        start: int | None = None,
     ) -> list[Message]:
-        """Micro-batch read from the committed offset: returns as soon as
-        max_n messages are available, else whatever arrived after max_wait_s
+        """Micro-batch read from the committed offset (or from `start`, for
+        a reader that runs ahead of its commits): returns as soon as max_n
+        messages are available, else whatever arrived after max_wait_s
         (possibly empty). Does NOT commit — the caller commits after the
         batch is fully processed (crash ⇒ replay, at-least-once)."""
         deadline = time.monotonic() + max_wait_s
-        start = self.committed()
+        if start is None:
+            start = self.committed()
         while True:
             msgs = self.read_from(start, max_n)
             if len(msgs) >= max_n or time.monotonic() >= deadline:

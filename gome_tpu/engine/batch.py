@@ -595,6 +595,40 @@ class EngineStats:
     scan_giveways: dict[str, int] = dataclasses.field(default_factory=dict)
 
 
+class BookCut:
+    """The engine's state at one instant between two dispatches
+    (BatchEngine.take_cut): what a snapshot holds of it.
+
+    The step never donates the book stack, so the stack as it stood right
+    after a frame's last dispatch IS the books after exactly that frame,
+    whatever is dispatched on top of it: the cut keeps that reference (one
+    more live version of the stack until the cut is dropped) and starts its
+    transfer to the host at once. The per-lane host vectors are copied at
+    the same instant. The interners are not part of it: they only grow, so
+    whoever reads their tables later reads a superset with the same ids.
+    `arrays()` waits for the transfer: the snapshot writer's thread calls
+    it, not the consumer's."""
+
+    __slots__ = ("books", "lanes", "rows", "meta", "rewinds")
+
+    def __init__(self, books, lanes, rows, meta, rewinds):
+        self.books = books
+        self.lanes = lanes
+        self.rows = rows
+        self.meta = meta
+        self.rewinds = rewinds
+
+    def arrays(self) -> dict[str, np.ndarray]:
+        """Books and per-lane vectors as host arrays in the one-chip order
+        (lane = interner id - 1), whatever row of whatever chip holds a
+        lane: a snapshot restores into any mesh, or none."""
+        out = {k: np.asarray(v) for k, v in self.books._asdict().items()}
+        out.update(self.lanes)
+        if self.rows is not None:
+            out = {k: v[self.rows] for k, v in out.items()}
+        return out
+
+
 class BatchEngine:
     """Host-side driver for the batched device engine.
 
@@ -766,6 +800,9 @@ class BatchEngine:
         # fits the int32 window under a new base, without a device scan.
         self._env_lo = np.zeros(n_slots, np.int64)
         self._env_hi = np.zeros(n_slots, np.int64)
+        # _restore() calls: a cut taken before one no longer says what the
+        # books were after its frame (cut_is_current).
+        self._rewinds = 0
 
     # Admission window around the current base; recenter when exceeded.
     REBASE_LIMIT = 1 << 30
@@ -1278,6 +1315,7 @@ class BatchEngine:
         self._env_hi = env_hi.copy()
         self._ub_base = ub_base.copy()
         self._ub_extra = ub_extra.copy()
+        self._rewinds += 1
 
     def process(self, orders: list[Order]) -> list[MatchResult]:
         """process_columnar() as MatchResult objects, in the reference's
@@ -1554,32 +1592,59 @@ class BatchEngine:
         return _batch(cfg, books, ops)
 
     # -- snapshot support ----------------------------------------------------
+    def take_cut(self) -> BookCut:
+        """The engine's state now, between two dispatches, without waiting
+        for the device: the current book stack (its transfer to the host
+        started) and copies of the per-lane host vectors. Called right after a frame's last dispatch it is the state
+        after exactly that frame, with later frames free to be packed and
+        dispatched meanwhile (BookCut)."""
+        books = self.books
+        for leaf in books:
+            leaf.copy_to_host_async()
+        return BookCut(
+            books=books,
+            lanes={
+                "price_base": self.price_base.copy(),
+                "base_set": self._base_set.copy(),
+                "env_lo": self._env_lo.copy(),
+                "env_hi": self._env_hi.copy(),
+            },
+            rows=(
+                None if self.mesh is None
+                else self._lane_of(np.arange(self.n_slots))
+            ),
+            meta={
+                "cap": self.config.cap,
+                "max_fills": self.config.max_fills,
+                "dtype": np.dtype(self.config.dtype).name,
+                "n_slots": self.n_slots,
+                "max_t": self.max_t,
+            },
+            rewinds=self._rewinds,
+        )
+
+    def cut_is_current(self, cut: BookCut) -> bool:
+        """False once the engine has been rewound since `cut` was taken: a
+        frame the cut counted on was re-run or dropped."""
+        return cut.rewinds == self._rewinds
+
     def export_state(self) -> dict:
         """Host-side copy of all mutable engine state (books + interners +
-        geometry) for the durability layer (gome_tpu.persist)."""
-        books = jax.device_get(self.books)
-        # The one-chip order, whatever row of whatever chip a lane is
-        # stored in: a snapshot restores into any mesh, or none.
-        by_arrival = np.asarray
-        if self.mesh is not None:
-            rows = self._lane_of(np.arange(self.n_slots))
-            by_arrival = lambda a: np.asarray(a)[rows]
+        geometry) for the durability layer (gome_tpu.persist): a cut,
+        waited for, with the interners' tables beside it."""
+        cut = self.take_cut()
+        arrays = cut.arrays()
         return {
-            "books": {k: by_arrival(v) for k, v in books._asdict().items()},
+            "books": {k: arrays[k] for k in BookState._fields},
             "symbols": self.symbols.to_list(),
             "oids": self.oids.to_list(),
             "uids": self.uids.to_list(),
-            "cap": self.config.cap,
-            "max_fills": self.config.max_fills,
-            "dtype": np.dtype(self.config.dtype).name,
-            "n_slots": self.n_slots,
-            "max_t": self.max_t,
-            # JSON-safe lists: the durability layer folds everything but
-            # "books" into its (JSON) manifest.
-            "price_base": by_arrival(self.price_base).tolist(),
-            "base_set": by_arrival(self._base_set).astype(int).tolist(),
-            "env_lo": by_arrival(self._env_lo).tolist(),
-            "env_hi": by_arrival(self._env_hi).tolist(),
+            **cut.meta,
+            # JSON-safe lists, as a version 1 snapshot's manifest has them.
+            "price_base": arrays["price_base"].tolist(),
+            "base_set": arrays["base_set"].astype(int).tolist(),
+            "env_lo": arrays["env_lo"].tolist(),
+            "env_hi": arrays["env_hi"].tolist(),
         }
 
     def import_state(self, state: dict) -> None:

@@ -57,6 +57,11 @@ class Interner:
         """All interned strings in id order (excluding the reserved 0)."""
         return list(self._to_str[1:])
 
+    def since(self, first: int) -> list[str]:
+        """The strings with ids first.. in id order: what a table that held
+        first - 1 strings lacks (the snapshot's append-only id files)."""
+        return self._to_str[first:]
+
     @classmethod
     def from_list(cls, strs: list[str]) -> "Interner":
         it = cls()

@@ -344,6 +344,11 @@ class NativeInterner:
         self._lib.gi_export(self._h, buf, need)
         return _parse_len_prefixed(buf.raw[:need], n)
 
+    def since(self, first: int) -> list[bytes]:
+        """The strings with ids first.. in id order, encoded (Interner.since;
+        one C gather, on the thread that interns)."""
+        return self.gather_padded(np.arange(first, len(self))).tolist()
+
     @classmethod
     def from_list(cls, strs: list[str]):
         self = cls()

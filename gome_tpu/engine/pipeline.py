@@ -36,8 +36,8 @@ class FramePipeline:
     """Depth-D pipelined ORDER-frame executor over one MatchEngine.
 
     feed(cols, token) submits a frame (admission included) and returns any
-    frames that resolved as a list of (token, EventBatch); flush() drains
-    the rest. Tokens let the caller (the consumer) commit each frame's bus
+    frames that resolved as a list of (token, EventBatch), or in two steps,
+    submit() then resolve_overflow(); flush() drains the rest. Tokens let the caller (the consumer) commit each frame's bus
     offset only after ITS events resolved and published."""
 
     def __init__(self, engine: MatchEngine, depth: int = 2):
@@ -48,6 +48,14 @@ class FramePipeline:
         self._q: deque = deque()  # (pending, consumed, token)
 
     def feed(self, cols: dict, token=None) -> list[tuple]:  # gomelint: hotpath
+        self.submit(cols, token)
+        return self.resolve_overflow()
+
+    def submit(self, cols: dict, token=None) -> None:  # gomelint: hotpath
+        """Admit and dispatch one frame, resolving nothing: on return the
+        engine's books are those after this frame (the instant the consumer
+        may cut them, service.consumer), and the pipeline may hold one
+        frame more than its depth until resolve_overflow()."""
         eng = self.engine.batch
         fcols, consumed = self.engine.admit_frame(cols)
         try:
@@ -58,6 +66,10 @@ class FramePipeline:
             self.engine.pre_pool |= consumed
             raise
         self._q.append((pend, consumed, token))
+
+    def resolve_overflow(self) -> list[tuple]:  # gomelint: hotpath
+        """Resolve the oldest frames until no more than `depth` are in
+        flight; returns them as (token, EventBatch)."""
         out = []
         while len(self._q) > self.depth:
             out.append(self._resolve_oldest())

@@ -265,6 +265,24 @@ class NativeConsumed:
             )
 
 
+class PoolDump:
+    """A NativePrePool's marks at one instant, length-prefixed as the C set
+    dumps them; iterating decodes the keys."""
+
+    __slots__ = ("raw",)
+
+    def __init__(self, raw: bytes):
+        self.raw = raw
+
+    def __iter__(self):
+        raw, pos = self.raw, 0
+        while pos < len(raw):
+            ln = int.from_bytes(raw[pos : pos + 4], "little")
+            pos += 4
+            yield tuple(raw[pos : pos + ln].decode().split(NativePrePool.SEP))
+            pos += ln
+
+
 class NativePrePool:
     """In-process marker store backed by the C++ set (native/hostops.cc):
     same semantics as LocalPrePool, but admission of a whole decoded ORDER
@@ -319,7 +337,9 @@ class NativePrePool:
     def __len__(self) -> int:
         return int(self._lib.gp_len(self._h))
 
-    def __iter__(self):
+    def frozen(self) -> "PoolDump":
+        """The marks now, as one C copy: decoded only when iterated, on
+        whatever thread does that (the snapshot writer's)."""
         import ctypes
 
         need = self._lib.gp_dump(self._h, None, 0)
@@ -331,13 +351,10 @@ class NativePrePool:
             # the set-mutated-during-iteration contract the snapshot layer
             # retries on (persist/snapshot.py) — never yield garbage.
             raise RuntimeError("pre-pool changed size during iteration")
-        pos = 0
-        raw = buf.raw
-        while pos < need:
-            ln = int.from_bytes(raw[pos : pos + 4], "little")
-            pos += 4
-            yield tuple(raw[pos : pos + ln].decode().split(self.SEP))
-            pos += ln
+        return PoolDump(buf.raw[:need])
+
+    def __iter__(self):
+        return iter(self.frozen())
 
     def clear(self) -> None:
         self._lib.gp_clear(self._h)

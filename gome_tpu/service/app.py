@@ -109,18 +109,17 @@ class EngineService:
                     st.host, st.port, exc,
                 )
         self.persist = persist  # gome_tpu.persist.Persister or None
-        on_batch = persist.on_batch if persist is not None else None
         self.feed = MatchFeed(self.bus)
         self.consumer = OrderConsumer(
             self.engine,
             self.bus,
             batch_n=e.max_t * max(1, e.n_slots // 8),
-            on_batch=on_batch,
             match_wire=self.config.bus.match_wire,
             pipeline_depth=e.pipeline_depth,
         )
         if persist is not None:
-            # The consumer rides along so snapshots carry the matchfeed
+            # attach() gives the consumer its two persist hooks; the
+            # consumer rides along so snapshots carry the matchfeed
             # seq at the cut and restore rebases it (exactly-once across
             # restarts); the durability gauges read from the Persister at
             # scrape time.
@@ -293,6 +292,8 @@ class EngineService:
             self._server = None
         self.consumer.stop()
         self.feed.stop()
+        if self.persist is not None and not self.persist.wait(60):
+            log.warning("a snapshot was still being written after 60 s")
         tracing.log_totals()
         # Beside the spans' totals, at their level: how the frames those
         # spans timed were handed their buffers and fetched.
@@ -313,6 +314,22 @@ class EngineService:
             {OrderType(k).name: n for k, n in sorted(st.adds_by_kind.items())},
             st.expired_ioc, st.fok_killed, st.post_only_blocked,
         )
+        if self.persist is not None:
+            kept = {
+                name: int(counter.value())
+                for name, counter in (
+                    ("order", self.gateway.order_log_bytes),
+                    ("match", self.consumer.match_log_bytes),
+                ) if counter is not None
+            }
+            (log.warning if tracing.slow() else log.info)(
+                "durability: %d snapshots taken, %d cadence ticks skipped "
+                "(writer busy), %d cuts discarded (engine rewound), last "
+                "snapshot %d bytes; log bytes appended %s",
+                self.persist.snapshots_taken, self.persist.snapshots_skipped,
+                self.persist.cuts_discarded,
+                self.persist.last_snapshot_bytes, kept,
+            )
         if self.ops is not None:
             self.ops.stop()
             if self.config.ops.timeline:
@@ -338,6 +355,8 @@ class EngineService:
         threads). Returns orders processed."""
         n = self.consumer.drain()
         self.feed.drain()
+        if self.persist is not None:
+            self.persist.wait()  # a cut taken on the way is on disk
         return n
 
 

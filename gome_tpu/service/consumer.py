@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import threading
 
-from ..bus import QueueBus, decode_orders_batch
+from ..bus import MemoryQueue, QueueBus, decode_orders_batch
 from ..engine.batch import is_device_fault
 from ..engine.orchestrator import MatchEngine
 from ..utils.faults import FAULTS
@@ -70,6 +70,7 @@ class OrderConsumer:
         batch_n: int = 256,
         batch_wait_s: float = 0.002,
         on_batch=None,
+        on_dispatch=None,
         poison_threshold: int = 3,
         match_wire: str = "json",
         pipeline_depth: int = 0,
@@ -97,6 +98,16 @@ class OrderConsumer:
         self.engine = engine
         self.bus = bus
         self.match_wire = match_wire
+        # A match queue that is kept (anything but the memory queue): its
+        # appends get a span of their own and are counted in bytes.
+        self.match_log_bytes = (
+            None if isinstance(bus.match_queue, MemoryQueue)
+            else REGISTRY.counter(
+                "gome_log_bytes_total",
+                "bytes appended to a queue that is kept on disk",
+                labels={"queue": getattr(bus.match_queue, "name", "matchOrder")},
+            )
+        )
         self.batch_n = batch_n
         self.batch_wait_s = batch_wait_s
         self.pipeline_depth = pipeline_depth
@@ -104,11 +115,15 @@ class OrderConsumer:
         # start()ed, or the sync run_once()/drain()/pump() caller; the
         # two modes never run concurrently (start() is the boundary).
         self._pipe = None  # single-writer: the consuming thread (lazy FramePipeline)
-        # Persist-hook counts deferred to the next pipeline-empty boundary
-        # (on_batch must only observe consistent cuts; see _emit_resolved).
-        self._hook_orders = 0  # single-writer: the consuming thread
-        self._hook_events = 0  # single-writer: the consuming thread
-        self.on_batch = on_batch  # callback(n_orders, n_events): persist hook
+        # The persist hooks (persist.Persister.attach sets both).
+        # on_dispatch(end_offset, in_flight): the unit whose commit will
+        # move the order queue's offset to end_offset has had its last
+        # dispatch — the books are those of every order below end_offset,
+        # whatever else is in flight — and in_flight units, this one among
+        # them, are dispatched and not committed. on_batch(n_orders,
+        # n_events): a unit's events are published and its offset committed.
+        self.on_batch = on_batch
+        self.on_dispatch = on_dispatch
         # Poison-batch policy: a deterministic per-batch error (e.g. a lane
         # CapacityError) would otherwise replay the same uncommitted offset
         # forever and halt matching engine-wide. After `poison_threshold`
@@ -213,18 +228,36 @@ class OrderConsumer:
             from ..bus.colwire import encode_event_frame
 
             if n:
-                mq = self.bus.match_queue
                 frame = encode_event_frame(batch, seq0=seq0)
-                if mq.supports_headers:
-                    # Alongside PR 2's x-trace: stringified per AMQP
-                    # header conventions (bus/amqp.py).
-                    mq.publish(frame, headers={"x-seq": str(seq0)})
-                else:
-                    mq.publish(frame)
+                self._append(len(frame), self._publish_frame, frame, seq0)
         else:
             # one write+fsync for the whole batch on the native backend
-            self.bus.match_queue.publish_batch(batch.to_json_lines(seq0=seq0))
+            lines = batch.to_json_lines(seq0=seq0)
+            self._append(
+                sum(map(len, lines)), self.bus.match_queue.publish_batch,
+                lines,
+            )
         self.match_seq = seq0 + n
+
+    def _publish_frame(self, frame: bytes, seq0: int) -> None:
+        mq = self.bus.match_queue
+        if mq.supports_headers:
+            # Alongside PR 2's x-trace: stringified per AMQP header
+            # conventions (bus/amqp.py).
+            mq.publish(frame, headers={"x-seq": str(seq0)})
+        else:
+            mq.publish(frame)
+
+    def _append(self, n_bytes: int, publish, *args) -> None:
+        """One append to the match queue (a frame's events): inside a
+        `match_log_append` span and counted where the queue is kept, bare on
+        the memory queue."""
+        if self.match_log_bytes is None:
+            publish(*args)
+            return
+        with span("match_log_append", bytes=n_bytes):
+            publish(*args)
+        self.match_log_bytes.inc(n_bytes)
 
     def run_once(self) -> int:  # gomelint: hotpath
         """Drain one micro-batch; returns the number of orders processed."""
@@ -271,6 +304,8 @@ class OrderConsumer:
             # processing and commit replays the batch (at-least-once;
             # recovery dedup lives in gome_tpu.persist's replay logic).
             FAULTS.fire("consumer.commit")
+            if self.on_dispatch is not None:
+                self.on_dispatch(msgs[-1].offset + 1, 1)
             self.bus.order_queue.commit(msgs[-1].offset + 1)
             self._seq_committed = self.match_seq
         for tid in done_tids:  # journeys are complete once committed
@@ -309,12 +344,11 @@ class OrderConsumer:
 
     def _emit_resolved(self, token, batch) -> int:
         """Publish one resolved frame's events and commit ITS offset —
-        frames resolve in FIFO order, so commits stay monotonic. The
-        persist hook (on_batch) is NOT called here: with frames in flight
-        the books are AHEAD of the committed offset, so a snapshot taken
-        now would double-apply the in-flight span on recovery; the counts
-        accumulate and the hook fires at the next pipeline-empty boundary
-        (a consistent cut)."""
+        frames resolve in FIFO order, so commits stay monotonic. With
+        frames in flight the books are AHEAD of the committed offset: what
+        a snapshot holds was cut behind this frame's own dispatch
+        (on_dispatch), and the persist hook called here only notes that the
+        frame has committed."""
         offset, n = token
         tids = self._pipe_tids.pop(offset, None) or []
         with TRACER.batch(tids), span("publish_events", events=len(batch)):
@@ -329,13 +363,12 @@ class OrderConsumer:
 
     def _account(self, n_orders: int, n_events: int) -> None:
         """Bookkeeping for one processed-and-committed unit in pipelined
-        mode: metrics now, persist hook deferred to the next consistent
-        cut."""
+        mode: the metrics and the persist hook."""
         _orders_total.inc(n_orders)
         _events_total.inc(n_events)
         _batch_size.observe(n_orders)
-        self._hook_orders += n_orders
-        self._hook_events += n_events
+        if self.on_batch is not None:
+            self.on_batch(n_orders, n_events)
 
     def _run_once_pipelined(self) -> int:
         """One consumer step with cross-frame pipelining: ORDER frames are
@@ -387,9 +420,12 @@ class OrderConsumer:
                             if tids:
                                 self._pipe_tids[m.offset] = tids
                         with annotate("pipeline_feed"), TRACER.batch(tids):
-                            resolved = pipe.feed(
+                            pipe.submit(
                                 cols, token=(m.offset, int(cols["n"]))
                             )
+                            if self.on_dispatch is not None:
+                                self.on_dispatch(m.offset + 1, len(pipe))
+                            resolved = pipe.resolve_overflow()
                         for token, batch in resolved:
                             n_orders += self._emit_resolved(token, batch)
                         i += 1
@@ -400,6 +436,8 @@ class OrderConsumer:
                                 break
                             n_orders += self._emit_resolved(*out)
                         j, n_o, n_e, jtids = self._process_json_run(msgs, i)
+                        if self.on_dispatch is not None:
+                            self.on_dispatch(msgs[j - 1].offset + 1, 1)
                         q.commit(msgs[j - 1].offset + 1)
                         self._seq_committed = self.match_seq
                         n_orders += n_o
@@ -422,16 +460,6 @@ class OrderConsumer:
         if n_orders and timer.elapsed > 0:
             inst = n_orders / timer.elapsed
             _throughput.set(0.8 * _throughput.value() + 0.2 * inst)
-        if (
-            len(pipe) == 0
-            and self.on_batch is not None
-            and (self._hook_orders or self._hook_events)
-        ):
-            # Consistent cut: books correspond exactly to the committed
-            # offset only when nothing is in flight — the persist hook
-            # (snapshot cadence) must only observe such states.
-            self.on_batch(self._hook_orders, self._hook_events)
-            self._hook_orders = self._hook_events = 0
         return n_orders
 
     def drain(self) -> int:
@@ -574,6 +602,8 @@ class OrderConsumer:
             ok, n_ok = self._bisect_apply(orders)
             if not ok:
                 return processed  # publish hiccup: leave offset for replay
+            if self.on_dispatch is not None:
+                self.on_dispatch(m.offset + 1, 1)
             self.bus.order_queue.commit(m.offset + 1)
             self._seq_committed = self.match_seq
             processed += n_ok

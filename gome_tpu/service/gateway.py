@@ -25,7 +25,7 @@ import numpy as np
 
 from ..api import order_pb2 as pb
 from ..api.service import add_order_servicer
-from ..bus import QueueBus, encode_order
+from ..bus import MemoryQueue, QueueBus, encode_order
 from ..bus.colwire import encode_order_block, encode_order_frame_blocks
 from ..config import Config
 from ..fixed import scale
@@ -34,6 +34,7 @@ from ..obs.placement import PLACEMENT
 from ..types import Action, Order, OrderType, Side, known_kinds
 from ..utils.faults import FAULTS
 from ..utils.logging import get_logger
+from ..utils.metrics import REGISTRY
 from ..utils.trace import TRACER
 from ..utils.tracing import span
 
@@ -217,6 +218,30 @@ class OrderGateway:
         self._max_volume = max_volume
         self._batcher = batcher
         self._admission = admission
+        # An order queue that is kept (anything but the memory queue): its
+        # appends get a span of their own and are counted in bytes.
+        # (A gateway that emits through a batcher alone is given no bus.)
+        queue = getattr(bus, "order_queue", None)
+        self.order_log_bytes = (
+            None if queue is None or isinstance(queue, MemoryQueue)
+            else REGISTRY.counter(
+                "gome_log_bytes_total",
+                "bytes appended to a queue that is kept on disk",
+                labels={"queue": getattr(queue, "name", "doOrder")},
+            )
+        )
+
+    def _publish(self, body: bytes, **kwargs) -> None:
+        """One append to the order queue (a request's frame on the columnar
+        path, a message on the scalar one): inside an `order_log_append`
+        span and counted where the queue is kept, bare on the memory
+        queue."""
+        if self.order_log_bytes is None:
+            self._bus.order_queue.publish(body, **kwargs)
+            return
+        with span("order_log_append", bytes=len(body)):
+            self._bus.order_queue.publish(body, **kwargs)
+        self.order_log_bytes.inc(len(body))
 
     def _emit(self, order: Order) -> None:
         # Fault point "gateway.emit": exit = gateway-kill, call-handler
@@ -230,11 +255,11 @@ class OrderGateway:
             # basic-properties headers (survives the broker hop even for
             # opaque bodies; the consumer adopts it when the body carries
             # none).
-            self._bus.order_queue.publish(
+            self._publish(
                 encode_order(order), headers={"x-trace": order.trace}
             )
         else:
-            self._bus.order_queue.publish(encode_order(order))
+            self._publish(encode_order(order))
 
     def _begin_trace(self):
         """(trace_id, t_ingress) for a new order journey, or (None, 0.0)
@@ -475,9 +500,7 @@ class OrderGateway:
         if self._batcher is not None:
             self._batcher.submit_block(block, m)
         else:
-            self._bus.order_queue.publish(
-                encode_order_frame_blocks([block])
-            )
+            self._publish(encode_order_frame_blocks([block]))
 
     def _apply_columnar(
         self, reqs: list, cancel: np.ndarray, resp, base: int = 0

@@ -9,6 +9,14 @@ The unit handed from the feed thread to a subscriber is the match message
 list of serialised MatchEvent messages, one per surviving event, put on the
 subscriber's queue once. The wire stays one MatchEvent per gRPC message; the
 handler's thread only yields bytes that are already made.
+
+The feed reads ahead of its cursor. A match message is committed on the
+match queue only when every live subscriber's handler has handed the whole
+of it to gRPC (it was asked for what follows the message's last event); with
+no subscriber, when it has been fanned out. So what a subscriber had not
+been handed when the process died lies above the cursor of a durable match
+queue, and the next process delivers it (again, where gRPC had it in flight:
+at-least-once across a death, each seq once within a process).
 """
 
 from __future__ import annotations
@@ -187,11 +195,30 @@ def match_result_to_pb(mr) -> pb.MatchEvent:
     return ev
 
 
+class _Chunk(list):
+    """One match message's serialised events for a subscriber, and the
+    match-queue offset that committing the message would move the cursor to."""
+
+    __slots__ = ("end",)
+
+
+class _Subscription(queue.Queue):
+    """One subscriber's queue of _Chunks, with how far it got: `given`, the
+    `end` of the last chunk put on it (the feed thread's), and `handed`, the
+    `end` of the last chunk whose every event its handler has handed to gRPC
+    (the handler thread's). Equal: it holds nothing it was given."""
+
+    def __init__(self):
+        super().__init__()
+        self.given = 0  # single-writer: the feed thread
+        self.handed = 0  # single-writer: the subscriber's handler thread
+
+
 class MatchFeed:
     def __init__(self, bus: QueueBus, log_events: bool = True):
         self.bus = bus
         self.log_events = log_events
-        self._subs: list[queue.Queue] = []  # guarded by self._lock
+        self._subs: list[_Subscription] = []  # guarded by self._lock
         self._lock = threading.Lock()
         self._life = threading.Lock()  # serializes start()/stop()
         self._stop = threading.Event()
@@ -204,10 +231,44 @@ class MatchFeed:
         self.seq = SeqTracker()
         self.suppressed = 0  # single-writer: the feed thread (run_once)
         self._poll = poll_span("feed_poll")  # owned by the feed thread
+        # The read cursor: the next match message to fan out, at or ahead of
+        # the queue's committed offset; and that offset as the feed last
+        # saw or set it (someone else moving it, a restore's rollback, sends
+        # the read cursor back to it).
+        self._next = 0  # single-writer: the feed thread (run_once)
+        self._committed: int | None = None  # single-writer: the feed thread
+
+    def _sync(self) -> int:
+        """The read cursor, after a look at the queue's committed offset."""
+        committed = self.bus.match_queue.committed()
+        if committed != self._committed:
+            self._next = self._committed = committed
+        return self._next
+
+    def _commit_handed(self) -> None:
+        """Move the queue's cursor up to the first message that some live
+        subscriber has not handed to gRPC whole (to the read cursor where
+        all have, or there is none)."""
+        if self._next <= self._committed:
+            return  # nothing fanned out that is not committed
+        with self._lock:
+            subs = list(self._subs)
+        upto = self._next
+        for sub in subs:
+            handed = sub.handed
+            if handed != sub.given:
+                upto = min(upto, handed)
+        if upto > self._committed:
+            self.bus.match_queue.commit(upto)
+            self._committed = upto
 
     def run_once(self) -> int:
-        msgs = self._poll(self.bus.match_queue.poll_batch, 256, 0.002)
+        start = self._sync()
+        msgs = self._poll(
+            self.bus.match_queue.poll_batch, 256, 0.002, 0.001, start
+        )
         if not msgs:
+            self._commit_handed()
             return 0
         from ..bus.colwire import decode_event_frame, is_frame
 
@@ -240,15 +301,16 @@ class MatchFeed:
                         seqs = [mr.seq for mr in results]
                 with span("feed_fanout", events=len(rows),
                           subscribers=len(subs)):
-                    self._fan_out(rows, seqs, subs)
+                    self._fan_out(rows, seqs, subs, msgs[j - 1].offset + 1)
                 i = j
-            self.bus.match_queue.commit(msgs[-1].offset + 1)
+            self._next = msgs[-1].offset + 1
+            self._commit_handed()
         return len(msgs)
 
-    def _fan_out(self, rows, seqs, subs) -> None:
+    def _fan_out(self, rows, seqs, subs, end: int) -> None:
         """One match message's events, as rows, to every subscriber as ONE
         queue item: the serialised MatchEvent of each event not seen before,
-        in order."""
+        in order. `end`: the match-queue offset past the message."""
         n = len(rows)
         if type(seqs) is range:
             rows = rows[self.seq.observe_run(seqs):]
@@ -272,13 +334,19 @@ class MatchFeed:
                     "match %s: taker=%s maker=%s qty=%d",
                     "FILL" if row[12] else "CANCEL", row[1], row[7], row[12],
                 )
-        chunk = [match_result_to_pb(row).SerializeToString() for row in rows]
-        for q in subs:
-            q.put(chunk)
+        chunk = _Chunk(
+            match_result_to_pb(row).SerializeToString() for row in rows
+        )
+        chunk.end = end
+        for sub in subs:
+            sub.put(chunk)
+            sub.given = end
 
     def drain(self) -> int:
+        """Fan out everything on the match queue (its cursor follows as the
+        subscribers take it, at once where there is none)."""
         total = 0
-        while self.bus.match_queue.committed() < self.bus.match_queue.end_offset():
+        while self._sync() < self.bus.match_queue.end_offset():
             total += self.run_once()
         return total
 
@@ -291,7 +359,8 @@ class MatchFeed:
         subscriber (the gateway's streaming handler sends them as they
         are). Ends when the gRPC context goes inactive or the feed stops;
         both are looked at once per queue item, not per event."""
-        q: queue.Queue = queue.Queue()
+        q = _Subscription()
+        q.given = q.handed = self._next  # owed nothing from before it came
         with self._lock:
             self._subs.append(q)
         try:
@@ -309,6 +378,9 @@ class MatchFeed:
                         except queue.Empty:
                             continue
                 yield from chunk
+                # Asked for what follows the chunk's last event: gRPC has
+                # the whole of it, and the feed may commit past it.
+                q.handed = chunk.end
         finally:
             with self._lock:
                 self._subs.remove(q)

@@ -134,22 +134,30 @@ class span:
         wall = self.wall_ns = _wall_ns() - self.t0_ns
         cpu = self.cpu_ns = _thread_cpu_ns() - self._c0
         self._ann.__exit__(exc_type, exc, tb)
-        with _lock:
-            row = _totals.get(self.name)
-            if row is None:
-                row = _totals[self.name] = [0, 0, 0, 0]
-                _export(self.name)
-            row[0] += 1
-            row[1] += wall
-            row[2] += cpu
-            if wall > row[3]:
-                row[3] = wall
+        record(self.name, wall, cpu)
         if wall >= SLOW_SPAN_NS:
             _note_slow(self.name, self.t0_ns, wall, cpu,
                        _process_cpu_ns() - self._p0)
         if self._stage is not None:
             TRACER.observe_span(self._stage, self._tr0, TRACER.clock())
         return False
+
+
+def record(name: str, wall_ns: int, cpu_ns: int = 0) -> None:
+    """Add one closed stretch to the table and /metrics: a span's exit, or a
+    stretch that no one thread held open (a boot's replay starts where one
+    thread's restore ends and ends at another's commit), timed by its owner;
+    such a stretch lies on no profile and is never a slow span."""
+    with _lock:
+        row = _totals.get(name)
+        if row is None:
+            row = _totals[name] = [0, 0, 0, 0]
+            _export(name)
+        row[0] += 1
+        row[1] += wall_ns
+        row[2] += cpu_ns
+        if wall_ns > row[3]:
+            row[3] = wall_ns
 
 
 class poll_span:

@@ -127,11 +127,10 @@ def run_worker(args) -> int:
     # batch_n=1: one message per step, commit per message — fault hit
     # counters then index individual frames, reproducibly.
     consumer = OrderConsumer(
-        engine, bus, batch_n=1, batch_wait_s=0.0,
-        on_batch=persist.on_batch, match_wire="frame",
+        engine, bus, batch_n=1, batch_wait_s=0.0, match_wire="frame",
     )
     feed = MatchFeed(bus, log_events=False)
-    persist.attach(engine, bus, consumer=consumer)
+    persist.attach(engine, bus, consumer=consumer)  # the consumer's hooks
 
     oq = bus.order_queue
     pre_committed = oq.committed()  # the crashed predecessor's position
@@ -171,6 +170,7 @@ def run_worker(args) -> int:
             result["recovery_s"] = time.monotonic() - t0
             write_result()
     feed.drain()
+    persist.wait()  # a cut on its way is on disk (or its fault has fired)
     result.update({
         "completed": True,
         "book_digest": book_digest(engine),

@@ -202,8 +202,7 @@ def run_consumer_worker(args) -> int:
         keep=SNAP_KEEP,
     ))
     consumer = OrderConsumer(
-        engine, bus, batch_n=4, batch_wait_s=0.02,
-        on_batch=persist.on_batch, match_wire="frame",
+        engine, bus, batch_n=4, batch_wait_s=0.02, match_wire="frame",
     )
     feed = MatchFeed(bus, log_events=False)
     persist.attach(engine, bus, consumer=consumer)
@@ -223,6 +222,7 @@ def run_consumer_worker(args) -> int:
     consumer.drain()  # any frames between the last poll and the stop
     feed.stop()
     feed.drain()
+    persist.wait()  # a cut on its way is on disk
     oq, mq = bus.order_queue, bus.match_queue
     result = {
         "role": "consumer",

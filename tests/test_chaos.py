@@ -563,6 +563,7 @@ def test_durability_payload_and_persist_telemetry(tmp_path):
         "gome_snapshot_age_seconds",
         "gome_snapshot_bytes",
         "gome_snapshots_taken_total",
+        "gome_snapshots_skipped_total",
         "gome_recovery_seconds",
         "gome_wal_replay_frames",
     ):
@@ -570,8 +571,9 @@ def test_durability_payload_and_persist_telemetry(tmp_path):
 
     probe = svc.persist.probe()
     assert set(probe) == {
-        "snapshots_taken", "snapshot_age_s", "snapshot_bytes",
-        "last_restore", "recovery_s", "wal_replay_frames",
+        "snapshots_taken", "snapshots_skipped", "snapshot_age_s",
+        "snapshot_bytes", "last_restore", "recovery_s", "replay_s",
+        "wal_replay_frames",
     }
 
 

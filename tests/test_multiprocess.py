@@ -298,15 +298,16 @@ if mesh_n:
 engine = MatchEngine(BookConfig(cap=64, max_fills=8), n_slots=8, mesh=mesh)
 engine.pre_pool = RespPrePool(RespClient(port={resp_port}))
 persist = Persister(PersistConfig(dir={snapdir!r}, every_n_batches=1))
-persist.attach(engine, bus)
 consumer = OrderConsumer(
     engine, bus, batch_n=1, batch_wait_s=0, match_wire="frame",
-    pipeline_depth=2, on_batch=persist.on_batch,
+    pipeline_depth=2,
 )
+persist.attach(engine, bus, consumer=consumer)
 phase = {phase!r}
 if phase == "crash":
-    # Drain the first span (2 frames) -> consistent cut -> snapshot.
+    # Drain the first span (2 frames) -> a cut behind a frame -> snapshot.
     consumer.drain()
+    persist.wait()
     assert persist.snapshots_taken >= 1, "no snapshot at the cut"
     print("SNAPSHOTTED", flush=True)
     # Now feed two more frames WITHOUT resolving (pipeline depth 2 keeps

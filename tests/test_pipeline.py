@@ -279,19 +279,24 @@ def test_checkpoint_restorable_twice_after_interim_mutation():
     np.testing.assert_array_equal(eng._base_set, set0)
 
 
-def test_pipelined_persist_hook_fires_only_at_consistent_cuts():
-    """on_batch (the persist snapshot hook) must only observe states where
-    the books correspond exactly to the committed offset — i.e. no frames
-    in flight; counts accumulate across the in-flight span."""
+def test_pipelined_persist_hooks_fire_per_frame_with_frames_in_flight():
+    """The persist hooks no longer wait for an empty pipeline: on_dispatch
+    follows every frame's dispatch with the offset its commit will reach and
+    the frames in flight (that one among them), and on_batch follows every
+    frame's commit, in order, whatever is still in flight
+    (tests/test_durable_cut.py holds what the Persister does with them)."""
     orders = multi_symbol_stream(n=200, n_symbols=4, seed=17, cancel_prob=0.1)
     engine = MatchEngine(**ENGINE_KW)
     bus = QueueBus(MemoryQueue("doOrder"), MemoryQueue("matchOrder"))
-    calls = []
+    calls, dispatched = [], []
     consumer = OrderConsumer(
         engine, bus, batch_n=4, batch_wait_s=0, match_wire="json",
         pipeline_depth=2,
         on_batch=lambda n, e: calls.append(
-            (n, e, len(consumer._pipe) if consumer._pipe else 0)
+            (n, e, len(consumer._pipe), bus.order_queue.committed())
+        ),
+        on_dispatch=lambda end, in_flight: dispatched.append(
+            (end, in_flight, bus.order_queue.committed())
         ),
     )
     for o in orders:
@@ -300,8 +305,14 @@ def test_pipelined_persist_hook_fires_only_at_consistent_cuts():
         bus.order_queue.publish(p)
     n = consumer.drain()
     assert n == len(orders)
-    assert sum(c[0] for c in calls) == len(orders)
-    assert all(c[2] == 0 for c in calls), calls
+    assert [c[0] for c in calls] == [25] * 8
+    assert [c[3] for c in calls] == list(range(1, 9))  # one commit a frame
+    assert max(c[2] for c in calls) == 2  # with frames still in flight
+    assert [d[0] for d in dispatched] == list(range(1, 9))
+    # in flight = dispatched and not committed, the frame itself among them
+    assert all(end - committed == in_flight
+               for end, in_flight, committed in dispatched)
+    assert max(d[1] for d in dispatched) == 3  # depth 2, before the resolve
 
 
 def test_pipeline_mixed_json_and_frames():
