@@ -188,7 +188,7 @@ def content_frames(
 # --- client --------------------------------------------------------------
 
 
-class AmqpQueue(Queue, _Waitable):
+class AmqpQueue(_Waitable, Queue):
     """One AMQP 0-9-1 queue behind the framework's offset/commit contract
     (module docstring). One TCP connection + one channel per instance."""
 

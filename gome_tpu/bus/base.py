@@ -5,15 +5,19 @@ delivery with explicit commit of consumer progress, vs the reference's
 auto-ack at-most-once (rabbitmq.go:102,148). `poll_batch` is the
 micro-batching primitive the TPU engine needs (SURVEY §7: N orders or T µs,
 whichever first) that the reference's one-message-at-a-time loop
-(rabbitmq.go:116-125) lacks.
+(rabbitmq.go:116-125) lacks; a message that is a batch already (a colwire
+frame) ends the wait at once.
 """
 
 from __future__ import annotations
 
 import abc
 import dataclasses
+import functools
 import threading
 import time
+
+from .colwire import is_frame
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,18 +100,60 @@ class Queue(abc.ABC):
         start: int | None = None,
     ) -> list[Message]:
         """Micro-batch read from the committed offset (or from `start`, for
-        a reader that runs ahead of its commits): returns as soon as max_n
-        messages are available, else whatever arrived after max_wait_s
-        (possibly empty). Does NOT commit — the caller commits after the
-        batch is fully processed (crash ⇒ replay, at-least-once)."""
+        a reader that runs ahead of its commits). The wait ends at the first
+        of: max_n messages readable ("full"); a message among them that is a
+        whole batch already, a colwire ORDER or EVENT frame, which no wait
+        can add company to since frames are never merged ("batch": whatever
+        is readable beside it comes with it, in order); max_wait_s run out
+        ("deadline": whatever arrived, possibly nothing). So the window is
+        paid by one-order and one-event messages alone, which it was made
+        for. Does NOT commit — the caller commits after the batch is fully
+        processed (crash ⇒ replay, at-least-once)."""
         deadline = time.monotonic() + max_wait_s
         if start is None:
             start = self.committed()
+        seen = 0  # messages of the last read already looked at for a frame
         while True:
             msgs = self.read_from(start, max_n)
-            if len(msgs) >= max_n or time.monotonic() >= deadline:
-                return msgs
-            self._wait_for_publish(poll_interval_s)
+            if len(msgs) >= max_n:
+                ended_by = "full"
+            elif any(is_frame(m.body) for m in msgs[seen:]):
+                ended_by = "batch"
+            elif time.monotonic() >= deadline:
+                ended_by = "deadline"
+            else:
+                seen = len(msgs)
+                self._wait_for_publish(poll_interval_s)
+                continue
+            self.poll_ended_by = ended_by
+            if msgs:  # once a poll, never per message
+                self._poll_counters[ended_by].inc()
+            return msgs
+
+    #: How this queue's last poll_batch ended ("full", "batch", "deadline"):
+    #: a note for the poll's span, written by the one thread that polls.
+    poll_ended_by: str | None = None
+
+    def poll_returns(self) -> dict[str, int]:
+        """poll_batch calls that brought messages, by what ended their wait
+        (gome_bus_poll_returns_total{queue=,ended_by=}; by the queue's name,
+        over the process)."""
+        return {end: c.value() for end, c in self._poll_counters.items()}
+
+    @functools.cached_property
+    def _poll_counters(self) -> dict:
+        # lazy metrics import, as export_queue_metrics
+        from ..utils.metrics import REGISTRY
+
+        return {
+            end: REGISTRY.counter(
+                "gome_bus_poll_returns_total",
+                "poll_batch calls that returned messages, by what ended "
+                "their wait: a whole-batch frame, max_n, or the deadline",
+                labels={"queue": self.name, "ended_by": end},
+            )
+            for end in ("batch", "full", "deadline")
+        }
 
     def _wait_for_publish(self, timeout_s: float) -> None:
         time.sleep(timeout_s)

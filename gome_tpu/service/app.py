@@ -308,6 +308,13 @@ class EngineService:
             len({c[:4] for c in self.engine.batch.combos()}),
         )
         (log.warning if tracing.slow() else log.info)(
+            "polls that brought messages, by what ended their wait: %s",
+            "; ".join(
+                f"{q.name} {q.poll_returns()}"
+                for q in (self.bus.order_queue, self.bus.match_queue)
+            ),
+        )
+        (log.warning if tracing.slow() else log.info)(
             "orders: %d applied; adds by kind %s; expired: %d IOC "
             "remainders dropped, %d FOK killed, %d POST_ONLY blocked",
             st.orders,

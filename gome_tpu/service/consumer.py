@@ -263,8 +263,8 @@ class OrderConsumer:
         """Drain one micro-batch; returns the number of orders processed."""
         if self.pipeline_depth > 0:
             return self._run_once_pipelined()
-        msgs = self._poll(
-            self.bus.order_queue.poll_batch, self.batch_n, self.batch_wait_s
+        msgs = self._poll.batch(
+            self.bus.order_queue, self.batch_n, self.batch_wait_s
         )
         if not msgs:
             return 0
@@ -391,9 +391,7 @@ class OrderConsumer:
         n_orders = 0
         try:
             if len(pipe) == 0:
-                msgs = self._poll(
-                    q.poll_batch, self.batch_n, self.batch_wait_s
-                )
+                msgs = self._poll.batch(q, self.batch_n, self.batch_wait_s)
                 if not msgs:
                     return 0
             else:

@@ -264,9 +264,7 @@ class MatchFeed:
 
     def run_once(self) -> int:
         start = self._sync()
-        msgs = self._poll(
-            self.bus.match_queue.poll_batch, 256, 0.002, 0.001, start
-        )
+        msgs = self._poll.batch(self.bus.match_queue, 256, 0.002, 0.001, start)
         if not msgs:
             self._commit_handed()
             return 0

@@ -164,11 +164,11 @@ class poll_span:
     """One span over a run of consecutive polls that came back empty: an
     idle loop polls every couple of milliseconds, and a span per poll costs
     a thread that has just woken ten times what it costs a running one
-    (PERF.md, PR 25). `poller(fn, *args)` makes one poll inside the span and
-    closes it when the poll brought something back or the span is
-    MERGE_POLLS_NS old, so it stays far under SLOW_SPAN_NS unless a single
-    poll overran: a slow poll span still means a stall. Owned by the one
-    thread that polls."""
+    (PERF.md, PR 25). `poller(fn, *args)`, or `poller.batch(queue, *args)` for
+    a queue's poll_batch, makes one poll inside the span and closes it when
+    the poll brought something back or the span is MERGE_POLLS_NS old, so it
+    stays far under SLOW_SPAN_NS unless a single poll overran: a slow poll
+    span still means a stall. Owned by the one thread that polls."""
 
     __slots__ = ("name", "_span", "_polls")
 
@@ -178,6 +178,22 @@ class poll_span:
         self._polls = 0
 
     def __call__(self, poll, *args):
+        got = self._poll(poll, args)
+        if got or _wall_ns() - self._span.t0_ns >= MERGE_POLLS_NS:
+            self.close()
+        return got
+
+    def batch(self, queue, *args):
+        """`queue.poll_batch(*args)` as one poll; the span that closes with
+        messages notes what ended the queue's wait (bus.base.Queue)."""
+        got = self._poll(queue.poll_batch, args)
+        if got:
+            self.close(ended_by=queue.poll_ended_by)
+        elif _wall_ns() - self._span.t0_ns >= MERGE_POLLS_NS:
+            self.close()
+        return got
+
+    def _poll(self, poll, args):
         if self._span is None:
             self._span = span(self.name).__enter__()
             self._polls = 0
@@ -187,15 +203,13 @@ class poll_span:
             self.close()
             raise
         self._polls += 1
-        if got or _wall_ns() - self._span.t0_ns >= MERGE_POLLS_NS:
-            self.close()
         return got
 
-    def close(self) -> None:
+    def close(self, **meta) -> None:
         """End the open span, if any (work follows, or the loop ends)."""
         open_, self._span = self._span, None
         if open_ is not None:
-            open_.note(polls=self._polls)
+            open_.note(polls=self._polls, **meta)
             open_.__exit__(None, None, None)
 
 

@@ -13,6 +13,8 @@ from gome_tpu.bus.amqp import AmqpQueue
 from gome_tpu.bus.fakebroker import FakeBroker
 from gome_tpu.config import BusConfig, load_config
 
+from test_bus import POLL_RULE_CASES
+
 
 @pytest.fixture
 def broker():
@@ -75,6 +77,29 @@ def test_poll_batch_wakes_on_publish(queue):
     msgs = queue.poll_batch(1, max_wait_s=5.0)
     t.join()
     assert [m.body for m in msgs] == [b"late"]
+
+
+@pytest.mark.parametrize("case", POLL_RULE_CASES, ids=lambda f: f.__name__)
+def test_poll_batch_wait_ends_by_rule(queue, case):
+    case(queue)
+
+
+@pytest.mark.parametrize(
+    "backend", ["MemoryQueue", "FileQueue", "NativeFileQueue", "AmqpQueue"]
+)
+def test_every_waitable_backend_waits_on_its_condition(backend):
+    """_Waitable comes before Queue in the bases, or the backend's
+    _notify_publish wakes nobody: poll_batch would sleep out Queue's
+    poll interval instead (AmqpQueue did, until ISSUE 37)."""
+    from gome_tpu.bus import amqp, filelog, memory, native
+
+    cls = {
+        "MemoryQueue": memory.MemoryQueue, "FileQueue": filelog.FileQueue,
+        "NativeFileQueue": native.NativeFileQueue,
+        "AmqpQueue": amqp.AmqpQueue,
+    }[backend]
+    assert cls._wait_for_publish.__qualname__ == "_Waitable._wait_for_publish"
+    assert cls.poll_batch.__qualname__ == "Queue.poll_batch"  # the one rule
 
 
 def test_large_bodies_split_into_frames(queue):

@@ -79,6 +79,164 @@ def test_poll_batch_wakes_on_publish(queue):
     assert [m.body for m in msgs] == [b"late"]
 
 
+# --- what ends a poll_batch wait (ISSUE 37) --------------------------------
+# A colwire frame is a whole batch already and ends the wait at once; one-order
+# and one-event messages keep the window (max_n or max_wait_s). Each case takes
+# a queue and runs under every backend here and under AMQP (test_amqp.py). The
+# only wall-clock bounds are the kept tests' own: under 1 s against a 5 s wait.
+
+
+def _order(oid):
+    return Order(
+        uuid="7", oid=oid, symbol="eth2usdt", side=Side.BUY,
+        price=99_500_000, volume=1_000_000,
+    )
+
+
+def _frame(oid="f1"):
+    from gome_tpu.bus.colwire import encode_orders, is_frame
+
+    body = encode_orders([_order(oid)])
+    assert is_frame(body)
+    return body
+
+
+def _single(oid):
+    return encode_order(_order(oid))
+
+
+def _poll_returns(queue) -> dict:
+    from gome_tpu.utils.metrics import REGISTRY
+
+    counts = {
+        end: REGISTRY.counter(
+            "gome_bus_poll_returns_total",
+            labels={"queue": queue.name, "ended_by": end},
+        ).value()
+        for end in ("batch", "full", "deadline")
+    }
+    assert queue.poll_returns() == counts  # the registry's, by name
+    return counts
+
+
+def _timed_poll(queue, max_n, max_wait_s):
+    """(bodies, seconds, which ending's counter moved) of one poll_batch."""
+    before = _poll_returns(queue)
+    t0 = time.monotonic()
+    msgs = queue.poll_batch(max_n, max_wait_s=max_wait_s)
+    took = time.monotonic() - t0
+    moved = {
+        end: n - before[end] for end, n in _poll_returns(queue).items()
+        if n != before[end]
+    }
+    return [m.body for m in msgs], took, moved
+
+
+def _publish_later(queue, *bodies):
+    queue.end_offset()  # (AMQP: start the consume loop first)
+
+    def later():
+        time.sleep(0.05)
+        for body in bodies:
+            queue.publish(body)
+
+    t = threading.Thread(target=later)
+    t.start()
+    return t
+
+
+def frame_published_into_a_wait_ends_it(queue):
+    frame = _frame()
+    t = _publish_later(queue, frame)
+    bodies, took, moved = _timed_poll(queue, 8, 5.0)
+    t.join()
+    assert bodies == [frame]
+    assert took < 1.0  # did not wait for the deadline, nor for seven more
+    assert moved == {"batch": 1} and queue.poll_ended_by == "batch"
+
+
+def frame_already_there_returns_at_once(queue):
+    frame = _frame()
+    queue.publish(frame)
+    bodies, took, moved = _timed_poll(queue, 8, 5.0)
+    assert bodies == [frame] and took < 1.0
+    assert moved == {"batch": 1}
+
+
+def single_messages_wait_out_the_window(queue):
+    queue.publish(_single("j0"))
+    bodies, took, moved = _timed_poll(queue, 8, 0.2)
+    assert bodies == [_single("j0")]
+    assert took >= 0.2  # the window is theirs: it was not cut short
+    assert moved == {"deadline": 1} and queue.poll_ended_by == "deadline"
+
+
+def single_messages_return_early_at_max_n(queue):
+    for i in range(4):
+        queue.publish(_single(f"j{i}"))
+    bodies, took, moved = _timed_poll(queue, 4, 5.0)
+    assert bodies == [_single(f"j{i}") for i in range(4)] and took < 1.0
+    assert moved == {"full": 1} and queue.poll_ended_by == "full"
+
+
+def mixed_read_returns_at_the_frame_in_order(queue):
+    sent = [_single("j0"), _single("j1"), _frame(), _single("j2")]
+    for body in sent:
+        queue.publish(body)
+    assert queue.end_offset() == 4  # (AMQP: all four have arrived)
+    bodies, took, moved = _timed_poll(queue, 8, 5.0)
+    assert bodies == sent and took < 1.0
+    assert moved == {"batch": 1}
+
+
+def frame_joins_the_single_messages_already_waiting(queue):
+    queue.publish(_single("j0"))
+    assert queue.end_offset() == 1
+    t = _publish_later(queue, _frame())
+    bodies, took, moved = _timed_poll(queue, 8, 5.0)
+    t.join()
+    assert bodies == [_single("j0"), _frame()] and took < 1.0
+    assert moved == {"batch": 1}
+
+
+def each_ending_counts_once_a_poll_and_empty_ones_never(queue):
+    before = _poll_returns(queue)
+    assert queue.poll_batch(8, max_wait_s=0.01) == []  # empty: not counted
+    for body in (_frame("a"), _frame("b"), _frame("c")):
+        queue.publish(body)
+    assert queue.end_offset() == 3
+    assert len(queue.poll_batch(8, max_wait_s=5.0)) == 3  # once, not thrice
+    queue.commit(3)
+    for i in range(5):
+        queue.publish(_single(f"j{i}"))
+    assert queue.end_offset() == 8
+    assert len(queue.poll_batch(2, max_wait_s=5.0)) == 2
+    queue.commit(5)
+    assert len(queue.poll_batch(8, max_wait_s=0.05)) == 3
+    queue.commit(8)
+    assert queue.poll_batch(8, max_wait_s=0) == []
+    after = _poll_returns(queue)
+    assert {end: after[end] - before[end] for end in after} == {
+        "batch": 1, "full": 1, "deadline": 1,
+    }
+
+
+POLL_RULE_CASES = [
+    frame_published_into_a_wait_ends_it,
+    frame_already_there_returns_at_once,
+    single_messages_wait_out_the_window,
+    single_messages_return_early_at_max_n,
+    mixed_read_returns_at_the_frame_in_order,
+    frame_joins_the_single_messages_already_waiting,
+    each_ending_counts_once_a_poll_and_empty_ones_never,
+]
+
+
+@pytest.mark.parametrize("case", POLL_RULE_CASES, ids=lambda f: f.__name__)
+def test_poll_batch_wait_ends_by_rule(queue, case):
+    case(queue)
+
+
 def test_file_queue_survives_reopen(tmp_path):
     base = str(tmp_path / "q")
     q = FileQueue("q", base)

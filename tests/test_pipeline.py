@@ -4,6 +4,10 @@ stream and book state as the synchronous frame path, including through
 budget escalations mid-pipeline, hard failures (at-least-once replay with
 pre-pool-mark restoration), and publish failures of resolved frames."""
 
+import dataclasses
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -490,5 +494,108 @@ def test_rewind_never_hands_out_a_held_or_donated_buffer_set(monkeypatch):
     # so do the two resubmitted after the rewind took three sets with it.
     assert handed[:3] == [False] * 3 and handed.count(False) <= 6
     assert st.fast_frames_reused == handed.count(True) >= 6
+    _assert_books_equal(engine, sync_eng)
+    engine.batch.verify_books()
+
+
+def _serve_two_frames(gap_s):
+    """An idle pipelined consumer and a live feed with one subscriber, on
+    the frame wire with the deployed 2 ms windows; two ORDER frames go onto
+    the order queue `gap_s` apart. Returns what left: the match messages'
+    (seq0, events), the order queue's commits in order, the chunks the
+    subscriber's queue was handed, how both queues' polls ended, and the
+    engine."""
+    from gome_tpu.bus.colwire import decode_event_frame
+    from gome_tpu.service.matchfeed import MatchFeed
+    from test_bus import _poll_returns
+
+    orders = multi_symbol_stream(n=120, n_symbols=4, seed=29, cancel_prob=0.2)
+    engine = MatchEngine(**ENGINE_KW)
+    bus = QueueBus(MemoryQueue("doOrder"), MemoryQueue("matchOrder"))
+    consumer = OrderConsumer(
+        engine, bus, batch_n=64, match_wire="frame", pipeline_depth=2
+    )
+    feed = MatchFeed(bus, log_events=False)
+    for o in orders:
+        engine.mark(o)
+    polls = [_poll_returns(q) for q in (bus.order_queue, bus.match_queue)]
+    commits = []
+    commit = bus.order_queue.commit
+    bus.order_queue.commit = lambda off: (commits.append(off), commit(off))
+
+    handed = []
+    sub = threading.Thread(
+        target=lambda: handed.extend(feed.subscribe()), daemon=True)
+    sub.start()
+    deadline = time.monotonic() + 60
+    while not feed._subs and time.monotonic() < deadline:
+        time.sleep(0.001)
+    chunks = []
+    put = feed._subs[0].put
+    feed._subs[0].put = lambda item: (chunks.append(item), put(item))
+
+    consumer.start()
+    feed.start()
+    try:
+        time.sleep(0.02)  # both loops are idle, inside their polls
+        first, second = _frames_for(orders, 60)
+        bus.order_queue.publish(first)
+        if gap_s:
+            time.sleep(gap_s)
+        bus.order_queue.publish(second)
+        while time.monotonic() < deadline and not (
+            bus.order_queue.committed() == 2
+            and bus.match_queue.committed() == bus.match_queue.end_offset()
+            and sum(map(len, chunks)) == len(handed) > 0
+        ):
+            time.sleep(0.001)
+    finally:
+        consumer.stop()
+        feed.stop()
+        sub.join(timeout=10)
+    assert not sub.is_alive()
+    messages = []
+    for m in bus.match_queue.read_from(0, 1 << 20):
+        batch = decode_event_frame(m.body)
+        messages.append((batch.seq0, batch.to_results()))
+    ended = {}
+    for q, before in zip((bus.order_queue, bus.match_queue), polls):
+        for end, n in _poll_returns(q).items():
+            ended[end] = ended.get(end, 0) + n - before[end]
+    return messages, commits, chunks, handed, ended, engine, orders
+
+
+@pytest.mark.parametrize("gap_s", [0.0005, 0.0], ids=["apart", "together"])
+def test_frames_apart_or_together_leave_the_same_way(gap_s):
+    """ISSUE 37: a poll that holds an ORDER frame returns at once, so two
+    frames half a millisecond apart may be read by two polls where one poll
+    used to read both after its window. Either way each frame is one submit,
+    one EVENT frame and one commit, and the feed hands each EVENT frame to
+    the subscriber as its own chunk: the events, seqs, commits and books are
+    those of the synchronous consumer (the parent's), whatever the timing."""
+    from gome_tpu.api import order_pb2 as pb
+    from gome_tpu.service import matchfeed
+
+    messages, commits, chunks, handed, ended, engine, orders = (
+        _serve_two_frames(gap_s))
+    sync_eng, _, _ = _run(ENGINE_KW, orders, 60, 0)
+    oracle = OracleEngine()
+    want = [[r for o in half for r in oracle.process(o)]
+            for half in (orders[:60], orders[60:])]
+    assert all(want)  # both frames make events
+    assert [seq0 for seq0, _ in messages] == [0, len(want[0])]
+    got = [[dataclasses.replace(r, seq=None) for r in results]
+           for _, results in messages]
+    assert got == want
+    assert commits == [1, 2]  # one commit a frame, in order
+    # no poll of either loop waited for company or for its window
+    assert ended["batch"] >= 2 and ended["full"] == ended["deadline"] == 0
+    assert [len(c) for c in chunks] == [len(w) for w in want]
+    assert handed == [raw for c in chunks for raw in c]
+    assert handed == [
+        matchfeed.match_result_to_pb(r).SerializeToString()
+        for _, results in messages for r in results
+    ]
+    assert pb.MatchEvent.FromString(handed[0]).node.oid
     _assert_books_equal(engine, sync_eng)
     engine.batch.verify_books()

@@ -160,6 +160,31 @@ def test_a_poll_span_covers_consecutive_empty_polls(clock):
     assert [r["span"] for r in tracing.slow()] == ["unit_idle"]
 
 
+def test_a_queue_poll_that_brings_messages_notes_what_ended_its_wait(
+    clock, monkeypatch
+):
+    """`poll_span.batch` polls a queue's poll_batch; the span that closes
+    with messages carries `ended_by` (bus.base.Queue.poll_batch: a frame is
+    a whole batch and ends the wait) beside `polls`."""
+    from gome_tpu.bus import MemoryQueue
+
+    notes = []
+    monkeypatch.setattr(
+        tracing.span, "note", lambda self, **meta: notes.append(meta))
+    queue = MemoryQueue("unit")
+    poller = tracing.poll_span("unit_queue_idle")
+    assert poller.batch(queue, 8, 0) == []
+    assert notes == []  # an empty poll: still open
+    queue.publish(b"GCO2 stands for a whole ORDER frame")
+    assert len(poller.batch(queue, 8, 5.0)) == 1
+    assert notes == [{"polls": 2, "ended_by": "batch"}]
+    queue.commit(1)
+    queue.publish(b'{"one": "order"}')
+    assert len(poller.batch(queue, 1, 5.0)) == 1
+    assert notes[1:] == [{"polls": 1, "ended_by": "full"}]
+    assert tracing.totals()["unit_queue_idle"]["count"] == 2
+
+
 def test_the_table_holds_under_many_threads():
     """More threads than cores, a short switch interval: every span of
     every thread is counted once and the families' children are made once."""
