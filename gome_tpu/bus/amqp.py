@@ -658,6 +658,10 @@ class AmqpQueue(_Waitable, Queue):
             if not self._confirm:
                 off = self._published
                 self._published += 1
+                # The hand-off's stamp (bus.base): keyed by the loopback
+                # offset, exact while this object is the queue's only
+                # publisher, as in a service that publishes and consumes.
+                self._stamps.put(off)
                 return off
             self._pub_seq += 1
             seq = self._pub_seq
@@ -682,6 +686,7 @@ class AmqpQueue(_Waitable, Queue):
         with self._lock:
             off = self._published
             self._published += 1
+            self._stamps.put(off)
             return off
 
     def read_from(self, offset: int, max_n: int) -> list[Message]:
@@ -956,6 +961,7 @@ class SupervisedAmqpQueue(Queue):
             with self._state:
                 off = self._published
                 self._published += 1
+            self._stamps.put(off)
             return off
 
     def read_from(self, offset: int, max_n: int) -> list[Message]:

@@ -16,8 +16,56 @@ import dataclasses
 import functools
 import threading
 import time
+from collections import deque
 
 from .colwire import is_frame
+
+#: utils.tracing's wall clock (the spans' and the benchmark client's), here so
+#: that the bus imports no profiler; a module global so a test can script it.
+_wall_ns = time.monotonic_ns
+#: Publish instants a queue keeps at most. A backlog deeper than this loses
+#: its oldest, and their hand-offs go unrecorded.
+PUBLISH_STAMPS = 4096
+
+
+class _PublishStamps:
+    """(offset, the instant its publish ended), oldest first, for the
+    messages that this queue object published and no read has returned yet:
+    one end of a queue hand-off's dwell (utils.tracing), kept by the queue so
+    that no wire carries it. Bounded: a queue nobody reads in this process (a
+    gateway's side of a file log) keeps the newest PUBLISH_STAMPS."""
+
+    __slots__ = ("_lock", "_ns")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._ns: deque = deque(maxlen=PUBLISH_STAMPS)  # guarded by self._lock
+
+    def put(self, first: int, n: int = 1) -> None:
+        now = _wall_ns()
+        with self._lock:
+            ns = self._ns
+            if ns and ns[-1][0] >= first:
+                # Offsets went back: a truncated tail is published anew (or
+                # two publishers' stamps crossed). What is kept is stale or
+                # out of order; a lost stamp is a reading not taken.
+                ns.clear()
+            if n == 1:  # a frame, or one JSON message: every publish but a batch's
+                ns.append((first, now))
+            else:
+                ns.extend((offset, now) for offset in range(first, first + n))
+
+    def take(self, first: int, last: int) -> int | None:
+        """The instant `first` was published, or None; forgets every message
+        through `last`, so a second read of them finds nothing."""
+        published = None
+        with self._lock:
+            ns = self._ns
+            while ns and ns[0][0] <= last:
+                offset, at = ns.popleft()
+                if offset == first:
+                    published = at
+        return published
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,6 +206,18 @@ class Queue(abc.ABC):
     def _wait_for_publish(self, timeout_s: float) -> None:
         time.sleep(timeout_s)
 
+    @functools.cached_property
+    def _stamps(self) -> _PublishStamps:
+        return _PublishStamps()
+
+    def publish_ns(self, first: int, last: int) -> int | None:
+        """A poll returned messages `first`..`last`: the instant (on
+        utils.tracing's wall clock) at which this queue object's publish of
+        `first` ended, or None where it published no such message (a replay
+        after a boot, another process's publish) or a read returned it
+        before (a rewind). Every backend's publish stamps its offsets."""
+        return self._stamps.take(first, last)
+
 
 @dataclasses.dataclass
 class QueueBus:
@@ -210,7 +270,11 @@ class _Waitable:
     def _init_wait(self):
         self._cond = threading.Condition()
 
-    def _notify_publish(self):
+    def _notify_publish(self, first: int | None = None, n: int = 1):
+        """Wake the pollers; `first`, `n`: the offsets a publish that has
+        just ended appended, stamped before anyone is woken to read them."""
+        if first is not None:
+            self._stamps.put(first, n)
         with self._cond:
             self._cond.notify_all()
 

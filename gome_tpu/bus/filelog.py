@@ -25,6 +25,7 @@ import struct
 import threading
 
 from ..utils.faults import FAULTS
+from ..utils.tracing import span
 from .base import Message, Queue, _Waitable
 
 _LEN = struct.Struct(">I")
@@ -140,7 +141,7 @@ class FileQueue(_Waitable, Queue):
             self._positions.append(pos)
             self._scan_end = pos + len(record)
             off = len(self._positions) - 1
-        self._notify_publish()
+        self._notify_publish(off)
         return off
 
     def read_from(self, offset: int, max_n: int) -> list[Message]:
@@ -151,11 +152,16 @@ class FileQueue(_Waitable, Queue):
                 return []
             start_pos = self._positions[offset]
         out: list[Message] = []
-        with open(self._log_path, "rb") as f:
-            f.seek(start_pos)
-            for i in range(offset, end):
-                (n,) = _LEN.unpack(f.read(_LEN.size))
-                out.append(Message(offset=i, body=f.read(n)))
+        # Only a read that returns messages opens a span: an empty one stays
+        # inside its caller's poll span, which counts it (polls=).
+        with span("log_read", queue=self.name,
+                  messages=end - offset) as reading:
+            with open(self._log_path, "rb") as f:
+                f.seek(start_pos)
+                for i in range(offset, end):
+                    (n,) = _LEN.unpack(f.read(_LEN.size))
+                    out.append(Message(offset=i, body=f.read(n)))
+                reading.note(bytes=f.tell() - start_pos)
         return out
 
     def end_offset(self) -> int:
@@ -215,11 +221,12 @@ class FileQueue(_Waitable, Queue):
                 f.write(text[: cut % (len(text) + 1)])
             FAULTS.hard_exit()
         tmp = self._off_path + ".tmp"
-        with open(tmp, "w") as f:
-            f.write(str(offset))
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, self._off_path)
+        with span("cursor_commit", queue=self.name, offset=offset):
+            with open(tmp, "w") as f:
+                f.write(str(offset))
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, self._off_path)
 
     def close(self) -> None:
         with self._lock:

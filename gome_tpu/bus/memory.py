@@ -26,7 +26,7 @@ class MemoryQueue(_Waitable, Queue):
             self._items.append(bytes(body))
             self._headers.append(headers)
             off = self._base + len(self._items) - 1
-        self._notify_publish()
+        self._notify_publish(off)
         return off
 
     def read_from(self, offset: int, max_n: int) -> list[Message]:

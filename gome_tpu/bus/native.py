@@ -18,6 +18,7 @@ import ctypes
 import os
 import threading
 
+from ..utils.tracing import span
 from .base import Message, Queue, _Waitable
 
 _lib = None
@@ -132,12 +133,21 @@ class NativeFileQueue(_Waitable, Queue):
         first = self._lib.gq_publish_batch(self._handle(), buf, lengths, n)
         if first < 0:
             raise OSError("native publish failed")
-        self._notify_publish()
+        self._notify_publish(int(first), n)
         return int(first)
 
     def read_from(self, offset: int, max_n: int) -> list[Message]:
-        if max_n <= 0:
+        # Only a read that returns messages opens a span (bus.filelog): the
+        # index says whether this one will.
+        if max_n <= 0 or self._lib.gq_end_offset(self._handle()) <= offset:
             return []
+        with span("log_read", queue=self.name) as reading:
+            out, payload = self._read(offset, max_n)
+            reading.note(messages=len(out), bytes=payload + 4 * len(out))
+        return out
+
+    def _read(self, offset: int, max_n: int) -> tuple[list[Message], int]:
+        """The messages and their bodies' bytes together."""
         cap = 1 << 16
         while True:
             bodies = (ctypes.c_ubyte * cap)()
@@ -162,7 +172,7 @@ class NativeFileQueue(_Waitable, Queue):
                         )
                     )
                     pos += ln
-                return out
+                return out, pos
             cap *= 4  # n == -1: caller buffer too small; grow and retry
             if cap > 1 << 30:
                 raise OSError("native read: record set exceeds 1 GiB buffer")
@@ -174,7 +184,8 @@ class NativeFileQueue(_Waitable, Queue):
         return int(self._lib.gq_committed(self._handle()))
 
     def commit(self, offset: int) -> None:
-        rc = self._lib.gq_commit(self._handle(), offset)
+        with span("cursor_commit", queue=self.name, offset=offset):
+            rc = self._lib.gq_commit(self._handle(), offset)
         if rc == -1:
             raise ValueError(
                 f"commit out of range: {offset} (committed={self.committed()},"
@@ -184,7 +195,8 @@ class NativeFileQueue(_Waitable, Queue):
             raise OSError("native commit failed")
 
     def rollback(self, offset: int) -> None:
-        rc = self._lib.gq_rollback(self._handle(), offset)
+        with span("cursor_commit", queue=self.name, offset=offset):
+            rc = self._lib.gq_rollback(self._handle(), offset)
         if rc == -1:
             raise ValueError(f"rollback going forwards: {offset}")
         if rc != 0:

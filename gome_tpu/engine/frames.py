@@ -527,7 +527,8 @@ def apply_frame(eng: BatchEngine, cols: dict):
     already applied."""
     from .events import decode_grid_columnar
 
-    with span("frame_pack"):
+    frame = cols.get("frame")  # the order-queue offset, where a consumer set it
+    with span("frame_pack", frame=frame):
         a = _frame_arrays(eng, cols)
         grids = pack_frame_grids(eng, a)
     batches = []
@@ -540,7 +541,7 @@ def apply_frame(eng: BatchEngine, cols: dict):
         a["expired"] += [
             int(np.count_nonzero(expired == k)) for k in _EXPIRING_KINDS
         ]
-        with span("frame_decode"):
+        with span("frame_decode", frame=frame):
             batches.append(
                 decode_grid_columnar(meta, splice_outs(outs, overrides))
             )
@@ -913,8 +914,10 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
     preserving sequential semantics. Raises (with rollback) only on
     host-side errors; device budget trips surface at resolve_frame."""
     cp = eng._checkpoint()
+    frame = cols.get("frame")  # the order-queue offset, where a consumer set it
     try:
-        with span("frame_pack", orders=int(cols["n"])) as packed:
+        with span("frame_pack", frame=frame,
+                  orders=int(cols["n"])) as packed:
             a = _frame_arrays(eng, cols)
             books = eng.books
             items = []
@@ -950,8 +953,9 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
             else:
                 n_rows, t_grid = ops.action.shape
             with span(
-                "grid_dispatch", rows=n_rows, t=t_grid, cap=int(cap_g),
-                n_ops=n_ops, grid="dense" if dense else "full",
+                "grid_dispatch", frame=frame, rows=n_rows, t=t_grid,
+                cap=int(cap_g), n_ops=n_ops,
+                grid="dense" if dense else "full",
                 program=1 if one_program else 3,
             ):
                 if one_program:
@@ -1094,7 +1098,8 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     # them. The totals fetch is the frame's completion barrier: blocking
     # there drains every dispatched grid, so this IS the device-execute
     # wait (an armed TRACER records it as that stage).
-    with span("frame_fetch", grids=len(pend.items),
+    frame = pend.cols.get("frame")
+    with span("frame_fetch", frame=frame, grids=len(pend.items),
               phases=1 if pend.one_phase else 2) as fetched:
         t0 = time.perf_counter()
         totals_dev, fills_dev, cancels_dev = pend.compact[:3]
@@ -1168,7 +1173,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     off_f = np.concatenate(([0], np.cumsum(nf_g)))
     off_c = np.concatenate(([0], np.cumsum(nc_g)))
     batches = []
-    with span("frame_decode", grids=g):
+    with span("frame_decode", frame=frame, grids=g):
         for i, (meta, shape) in enumerate(pend.items):
             fills = {
                 f: fills_mat[j, off_f[i] : off_f[i + 1]]

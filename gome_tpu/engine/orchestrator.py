@@ -171,7 +171,8 @@ class MatchEngine:
         — the caller restores `consumed` (pre_pool |= consumed) if the
         batch later fails (at-least-once replay must not drop re-admitted
         ADDs)."""
-        with span("frame_admit", orders=int(cols["n"])):
+        with span("frame_admit", frame=cols.get("frame"),
+                  orders=int(cols["n"])):
             return self._admit_frame(cols)
 
     def _admit_frame(self, cols: dict) -> tuple[dict, set]:

@@ -217,47 +217,53 @@ class OrderConsumer:
             tids.append(tid)
         return tids
 
-    def _publish(self, batch) -> None:
+    def _publish(self, batch) -> int | None:
+        """Publish a batch's events; returns the match-queue offset of the
+        (first) message, None where the batch has no event on a frame wire."""
         # Every event is stamped with the next matchfeed seq (GCE2 header
         # / JSON "Seq" / AMQP x-seq); match_seq only advances once the
         # publish SUCCEEDED, so a failed publish replays with the same
         # seqs.
         seq0 = self.match_seq
         n = len(batch)
+        match = None
         if self.match_wire == "frame":
             from ..bus.colwire import encode_event_frame
 
             if n:
                 frame = encode_event_frame(batch, seq0=seq0)
-                self._append(len(frame), self._publish_frame, frame, seq0)
+                match = self._append(
+                    len(frame), self._publish_frame, frame, seq0
+                )
         else:
             # one write+fsync for the whole batch on the native backend
             lines = batch.to_json_lines(seq0=seq0)
-            self._append(
+            match = self._append(
                 sum(map(len, lines)), self.bus.match_queue.publish_batch,
                 lines,
             )
         self.match_seq = seq0 + n
+        return match
 
-    def _publish_frame(self, frame: bytes, seq0: int) -> None:
+    def _publish_frame(self, frame: bytes, seq0: int) -> int:
         mq = self.bus.match_queue
         if mq.supports_headers:
             # Alongside PR 2's x-trace: stringified per AMQP header
             # conventions (bus/amqp.py).
-            mq.publish(frame, headers={"x-seq": str(seq0)})
-        else:
-            mq.publish(frame)
+            return mq.publish(frame, headers={"x-seq": str(seq0)})
+        return mq.publish(frame)
 
-    def _append(self, n_bytes: int, publish, *args) -> None:
+    def _append(self, n_bytes: int, publish, *args) -> int:
         """One append to the match queue (a frame's events): inside a
         `match_log_append` span and counted where the queue is kept, bare on
-        the memory queue."""
+        the memory queue. Returns what the publish returned: the offset."""
         if self.match_log_bytes is None:
-            publish(*args)
-            return
-        with span("match_log_append", bytes=n_bytes):
-            publish(*args)
+            return publish(*args)
+        with span("match_log_append", bytes=n_bytes) as appended:
+            match = publish(*args)
+            appended.note(match=match)
         self.match_log_bytes.inc(n_bytes)
+        return match
 
     def run_once(self) -> int:  # gomelint: hotpath
         """Drain one micro-batch; returns the number of orders processed."""
@@ -281,16 +287,19 @@ class OrderConsumer:
             while i < len(msgs):
                 FAULTS.fire("consumer.frame")
                 if is_frame(msgs[i].body):
-                    with span("frame_unpack"):
+                    frame = msgs[i].offset
+                    with span("frame_unpack", frame=frame):
                         cols = decode_order_frame(msgs[i].body)
                         tids = self._consume_traces(cols, msgs[i].headers)
-                    with annotate("engine_process_frame"), \
+                        cols["frame"] = frame  # the frame's spans note it
+                    with annotate("engine_process_frame", frame=frame), \
                             TRACER.batch(tids):
                         batch = self.engine.process_frame(cols)
                     count = int(cols["n"])
                     with TRACER.batch(tids), \
-                            span("publish_events", events=len(batch)):
-                        self._publish(batch)
+                            span("publish_events", frame=frame,
+                                 events=len(batch)) as published:
+                        published.note(match=self._publish(batch))
                     done_tids += tids
                     n_orders += count
                     n_events += len(batch)
@@ -333,13 +342,16 @@ class OrderConsumer:
         j = i
         while j < len(msgs) and not is_frame(msgs[j].body):
             j += 1
-        with span("decode_orders", orders=j - i):  # frame_unpack's JSON twin
+        # frame_unpack's JSON twin; a run's identifier is its first message's
+        frame = msgs[i].offset
+        with span("decode_orders", frame=frame, orders=j - i):
             orders = decode_orders_batch([m.body for m in msgs[i:j]])
             tids = self._json_traces(orders, msgs[i:j])
-        with annotate("engine_process"), TRACER.batch(tids):
+        with annotate("engine_process", frame=frame), TRACER.batch(tids):
             batch = self.engine.process_columnar(orders)
-        with TRACER.batch(tids), span("publish_events", events=len(batch)):
-            self._publish(batch)
+        with TRACER.batch(tids), span("publish_events", frame=frame,
+                                      events=len(batch)) as published:
+            published.note(match=self._publish(batch))
         return j, len(orders), len(batch), tids
 
     def _emit_resolved(self, token, batch) -> int:
@@ -351,8 +363,9 @@ class OrderConsumer:
         frame has committed."""
         offset, n = token
         tids = self._pipe_tids.pop(offset, None) or []
-        with TRACER.batch(tids), span("publish_events", events=len(batch)):
-            self._publish(batch)
+        with TRACER.batch(tids), span("publish_events", frame=offset,
+                                      events=len(batch)) as published:
+            published.note(match=self._publish(batch))
         FAULTS.fire("consumer.commit")
         self.bus.order_queue.commit(offset + 1)
         self._seq_committed = self.match_seq
@@ -397,8 +410,8 @@ class OrderConsumer:
             else:
                 # Read cursor: committed offset + one message per in-flight
                 # frame (only whole ORDER-frame messages stay in flight).
-                msgs = self._poll(
-                    q.read_from, q.committed() + len(pipe), self.batch_n
+                msgs = self._poll.ahead(
+                    q, q.committed() + len(pipe), self.batch_n
                 )
                 self._poll.close()  # empty or not, the oldest frame resolves
             with _batch_latency.time() as timer:
@@ -412,12 +425,14 @@ class OrderConsumer:
                     FAULTS.fire("consumer.frame")
                     m = msgs[i]
                     if is_frame(m.body):
-                        with span("frame_unpack"):
+                        with span("frame_unpack", frame=m.offset):
                             cols = decode_order_frame(m.body)
                             tids = self._consume_traces(cols, m.headers)
                             if tids:
                                 self._pipe_tids[m.offset] = tids
-                        with annotate("pipeline_feed"), TRACER.batch(tids):
+                            cols["frame"] = m.offset  # its spans note it
+                        with annotate("pipeline_feed", frame=m.offset), \
+                                TRACER.batch(tids):
                             pipe.submit(
                                 cols, token=(m.offset, int(cols["n"]))
                             )

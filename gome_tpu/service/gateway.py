@@ -231,17 +231,19 @@ class OrderGateway:
             )
         )
 
-    def _publish(self, body: bytes, **kwargs) -> None:
+    def _publish(self, body: bytes, **kwargs) -> int:
         """One append to the order queue (a request's frame on the columnar
         path, a message on the scalar one): inside an `order_log_append`
         span and counted where the queue is kept, bare on the memory
-        queue."""
+        queue. Returns the message's offset: the frame's identifier on every
+        span of its way (utils.tracing)."""
         if self.order_log_bytes is None:
-            self._bus.order_queue.publish(body, **kwargs)
-            return
-        with span("order_log_append", bytes=len(body)):
-            self._bus.order_queue.publish(body, **kwargs)
+            return self._bus.order_queue.publish(body, **kwargs)
+        with span("order_log_append", bytes=len(body)) as appended:
+            frame = self._bus.order_queue.publish(body, **kwargs)
+            appended.note(frame=frame)
         self.order_log_bytes.inc(len(body))
+        return frame
 
     def _emit(self, order: Order) -> None:
         # Fault point "gateway.emit": exit = gateway-kill, call-handler
@@ -482,7 +484,9 @@ class OrderGateway:
             if order.action is Action.ADD:
                 self._unmark(order)
 
-    def _emit_cols(self, cols: dict, m: int) -> None:  # gomelint: hotpath
+    def _emit_cols(self, cols: dict, m: int) -> int | None:  # gomelint: hotpath
+        """Returns the frame's order-queue offset; None where a batcher
+        publishes it later."""
         FAULTS.fire("gateway.emit")  # same point as the scalar funnel
         block = encode_order_block(
             m,
@@ -499,18 +503,20 @@ class OrderGateway:
         )
         if self._batcher is not None:
             self._batcher.submit_block(block, m)
-        else:
-            self._publish(encode_order_frame_blocks([block]))
+            return None
+        return self._publish(encode_order_frame_blocks([block]))
 
     def _apply_columnar(
-        self, reqs: list, cancel: np.ndarray, resp, base: int = 0
+        self, reqs: list, cancel: np.ndarray, resp, base: int = 0,
+        admit=None,
     ) -> int:  # gomelint: hotpath
         """Array-native admission of one batch: validates + interns +
         marks + emits the accepted rows as ONE wire block, appending
         per-row rejects to resp. Returns accepted count. Emission is
         all-or-nothing per block: on emit failure every mark is undone,
         zero rows are accepted, and resp carries the scalar loop's abort
-        code/message anchored at the block's first accepted entry."""
+        code/message anchored at the block's first accepted entry. `admit`:
+        the request's span, which notes the frame's offset (frame=)."""
         n = len(reqs)
         if n == 0:
             return 0
@@ -583,7 +589,7 @@ class OrderGateway:
         }
         self._mark_cols(cols)  # pre-pool before queueing (main.go:44-45)
         try:
-            self._emit_cols(cols, m)
+            frame = self._emit_cols(cols, m)
         except (RuntimeError, ConnectionError, OSError) as e:
             self._unmark_cols(cols)
             resp.code = (
@@ -594,6 +600,8 @@ class OrderGateway:
             first = base if keep is None else base + int(keep[0])
             resp.message = f"batch aborted at entry {first}: {e}"
             return 0
+        if admit is not None:
+            admit.note(frame=frame)
         HOSTPROF.note_admit(m)  # one locked add per block
         # Symbol-flow sketch (obs.placement): the armed hook bincounts
         # the already-interned columns; disabled it is one attr check.
@@ -609,12 +617,12 @@ class OrderGateway:
         # One span per request: admission verdict, columnar apply, emit
         # to the bus.
         with span("gateway_admit", orders=len(request.orders)) as admit:
-            resp = self._do_order_batch(request, context)
+            resp = self._do_order_batch(request, context, admit)
             admit.note(accepted=resp.accepted)
         return resp
 
     def _do_order_batch(
-        self, request: pb.OrderBatchRequest, context
+        self, request: pb.OrderBatchRequest, context, admit
     ) -> pb.OrderBatchResponse:
         n = len(request.orders)
         if request.cancel and len(request.cancel) != n:
@@ -643,7 +651,7 @@ class OrderGateway:
             else:
                 cancel = np.zeros(n, np.bool_)
             resp.accepted = self._apply_columnar(
-                list(request.orders), cancel, resp
+                list(request.orders), cancel, resp, admit=admit
             )
             return resp
         cancels = request.cancel or (False,) * n

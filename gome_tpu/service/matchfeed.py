@@ -33,7 +33,8 @@ from ..fixed import unscale
 from ..types import MatchResult
 from ..utils.logging import get_logger
 from ..utils.metrics import REGISTRY
-from ..utils.tracing import annotate, poll_span, span
+from ..utils import tracing
+from ..utils.tracing import poll_span, span
 
 log = get_logger("matchfeed")
 
@@ -196,10 +197,13 @@ def match_result_to_pb(mr) -> pb.MatchEvent:
 
 
 class _Chunk(list):
-    """One match message's serialised events for a subscriber, and the
-    match-queue offset that committing the message would move the cursor to."""
+    """One match message's serialised events for a subscriber: `match`, the
+    message's match-queue offset (the first's, of a run of JSON messages);
+    `end`, the offset that committing it would move the cursor to; `put_ns`,
+    the instant the feed put it on the subscribers' queues (the handler's
+    get ends the hand-off: subscriber_queue_dwell, utils.tracing)."""
 
-    __slots__ = ("end",)
+    __slots__ = ("match", "end", "put_ns")
 
 
 class _Subscription(queue.Queue):
@@ -270,45 +274,46 @@ class MatchFeed:
             return 0
         from ..bus.colwire import decode_event_frame, is_frame
 
-        with annotate("feed_run_once"):
-            with self._lock:
-                subs = list(self._subs)
-            i = 0
-            while i < len(msgs):
-                # One decode and one fan-out span per EVENT frame (one
-                # message = a whole batch of MatchResults, bus.colwire) or
-                # per run of JSON messages (one event each): never a span
-                # per event. `seqs` is a range for a stamped frame, whose
-                # duplicates and gaps are decided on its ends.
-                j = i + 1
-                with span("feed_decode"):
-                    if is_frame(msgs[i].body):
-                        batch = decode_event_frame(msgs[i].body)
-                        rows = _frame_rows(batch)
-                        seqs = (
-                            None if batch.seq0 is None
-                            else range(batch.seq0, batch.seq0 + len(rows))
-                        )
-                    else:
-                        while j < len(msgs) and not is_frame(msgs[j].body):
-                            j += 1
-                        results = [
-                            decode_match_result(m.body) for m in msgs[i:j]
-                        ]
-                        rows = [_row_of(mr) for mr in results]
-                        seqs = [mr.seq for mr in results]
-                with span("feed_fanout", events=len(rows),
-                          subscribers=len(subs)):
-                    self._fan_out(rows, seqs, subs, msgs[j - 1].offset + 1)
-                i = j
-            self._next = msgs[-1].offset + 1
-            self._commit_handed()
+        with self._lock:
+            subs = list(self._subs)
+        i = 0
+        while i < len(msgs):
+            # One decode and one fan-out span per EVENT frame (one message =
+            # a whole batch of MatchResults, bus.colwire) or per run of JSON
+            # messages (one event each): never a span per event. `seqs` is a
+            # range for a stamped frame, whose duplicates and gaps are
+            # decided on its ends.
+            j = i + 1
+            match = msgs[i].offset
+            with span("feed_decode", match=match):
+                if is_frame(msgs[i].body):
+                    batch = decode_event_frame(msgs[i].body)
+                    rows = _frame_rows(batch)
+                    seqs = (
+                        None if batch.seq0 is None
+                        else range(batch.seq0, batch.seq0 + len(rows))
+                    )
+                else:
+                    while j < len(msgs) and not is_frame(msgs[j].body):
+                        j += 1
+                    results = [
+                        decode_match_result(m.body) for m in msgs[i:j]
+                    ]
+                    rows = [_row_of(mr) for mr in results]
+                    seqs = [mr.seq for mr in results]
+            with span("feed_fanout", match=match, events=len(rows),
+                      subscribers=len(subs)):
+                self._fan_out(rows, seqs, subs, match, msgs[j - 1].offset + 1)
+            i = j
+        self._next = msgs[-1].offset + 1
+        self._commit_handed()
         return len(msgs)
 
-    def _fan_out(self, rows, seqs, subs, end: int) -> None:
+    def _fan_out(self, rows, seqs, subs, match: int, end: int) -> None:
         """One match message's events, as rows, to every subscriber as ONE
         queue item: the serialised MatchEvent of each event not seen before,
-        in order. `end`: the match-queue offset past the message."""
+        in order. `match`: the message's match-queue offset; `end`: the
+        offset past it."""
         n = len(rows)
         if type(seqs) is range:
             rows = rows[self.seq.observe_run(seqs):]
@@ -335,7 +340,8 @@ class MatchFeed:
         chunk = _Chunk(
             match_result_to_pb(row).SerializeToString() for row in rows
         )
-        chunk.end = end
+        chunk.match, chunk.end = match, end
+        chunk.put_ns = tracing._wall_ns()
         for sub in subs:
             sub.put(chunk)
             sub.given = end
@@ -365,23 +371,48 @@ class MatchFeed:
             while not self._stop.is_set():
                 if context is not None and not context.is_active():
                     return
+                # The handler's two leaves: stream_wait while its queue is
+                # empty, stream_send while gRPC takes a chunk from it. The
+                # hand-off's dwell rides on whichever closes with the chunk.
                 try:
                     chunk = q.get_nowait()
                 except queue.Empty:
-                    # Only an empty queue opens a span: time outside
-                    # stream_wait is gRPC sending.
-                    with span("stream_wait"):
+                    with span("stream_wait") as waited:
                         try:
                             chunk = q.get(timeout=0.1)
                         except queue.Empty:
                             continue
-                yield from chunk
+                        waited.note(match=chunk.match,
+                                    dwell_us=self._picked_up(chunk))
+                    dwell = None
+                else:
+                    dwell = self._picked_up(chunk)
+                # One span per queue item, never per event: it closes when
+                # the generator is resumed after the chunk's last event (gRPC
+                # has the whole of it), or when the subscriber goes away.
+                with span("stream_send", match=chunk.match,
+                          events=len(chunk)) as sent:
+                    if dwell is not None:
+                        sent.note(dwell_us=dwell)
+                    events = iter(chunk)
+                    yield next(events)  # a chunk holds at least one event
+                    first = tracing._wall_ns() - sent.t0_ns
+                    yield from events
+                    sent.note(first_us=first // 1000)
                 # Asked for what follows the chunk's last event: gRPC has
                 # the whole of it, and the feed may commit past it.
                 q.handed = chunk.end
         finally:
             with self._lock:
                 self._subs.remove(q)
+
+    @staticmethod
+    def _picked_up(chunk: _Chunk) -> int:
+        """A handler has taken `chunk` off its queue: the hand-off's dwell,
+        recorded once per chunk and subscriber; returns it in microseconds."""
+        dwell = tracing._wall_ns() - chunk.put_ns
+        tracing.record("subscriber_queue_dwell", dwell)
+        return dwell // 1000
 
     # -- background loop -----------------------------------------------------
     def start(self) -> None:

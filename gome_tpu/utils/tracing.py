@@ -13,9 +13,13 @@
                        process CPU time, and hands a closed span to an armed
                        TRACER under the taxonomy's stage name (STAGE_OF_SPAN),
                        so per-order journeys keep their batch-scoped stages.
-  poll_span(name)    — one span over consecutive empty polls of an idle loop.
-  annotate(name)     — a bare TraceAnnotation: the parents that only group
-                       leaves on the trace (pipeline_feed, feed_run_once).
+  poll_span(name)    — one span over consecutive empty polls of an idle loop;
+                       the poll that brings messages back is the pick-up of a
+                       queue hand-off (below).
+  record(name, ns)   — a stretch that no one thread held open, into the same
+                       table: a hand-off between two threads, a boot's replay.
+  annotate(name)     — a bare TraceAnnotation: the parent that only groups
+                       leaves on the trace (pipeline_feed).
   trace(dir), maybe_trace(dir) — jax.profiler.trace around a block.
 
 Granularity rule: a span is opened per request, per frame, per grid or per
@@ -31,9 +35,29 @@ gap of the device, and a stall, has a name per thread:
   consumer                     consumer_poll, frame_unpack, [pipeline_feed:]
                                frame_admit, frame_pack, grid_dispatch (one per
                                grid), frame_fetch, frame_decode, publish_events
-  match feed                   feed_poll, [feed_run_once:] feed_decode,
-                               feed_fanout (one per match message)
-  gRPC handler, SubscribeMatches   stream_wait (only while its queue is empty)
+  match feed                   feed_poll, feed_decode, feed_fanout (one each
+                               per match message)
+  gRPC handler, SubscribeMatches   stream_wait (while its queue is empty),
+                               stream_send (one per queue item, while gRPC
+                               takes a match message's events from it)
+
+A frame changes threads three times, and each hand-off is timed where the
+frame is picked up (HANDOFF_OF_POLL, service.matchfeed): the queue keeps the
+instant of its publish (bus.base.Queue), the poll that first returns the
+message records pick-up minus publish under the hand-off's name and notes it
+on the span that closes with the message (dwell_us=):
+
+  order_queue_dwell        gateway's publish  -> consumer's read
+  match_queue_dwell        consumer's publish -> feed's read
+  subscriber_queue_dwell   feed's put         -> handler's get
+
+Once per poll that brought messages, the oldest message's; nothing for a
+message this process did not publish or reads a second time. The spans of
+one frame note frame=<order-queue offset>, the feed's and the handler's
+match=<match-queue offset>, publish_events both: a reader of the profile
+follows one frame across the four threads. On a file-backed queue a read that
+returns messages is a log_read span and a cursor's write a cursor_commit span
+(bus.filelog, bus.native), inside whichever leaf asked for them.
 
 Wall minus thread CPU is the time a thread held a span open without running:
 the interpreter lock, a blocking call, or the scheduler. A slow-span line
@@ -66,6 +90,18 @@ SLOW_SPAN_NS = 250_000_000
 SLOW_RING = 256
 #: A poll_span over empty polls closes at the first poll that ends this late.
 MERGE_POLLS_NS = 100_000_000
+#: Spans that are long by arithmetic, not by a stall: the handler hands a
+#: 4,096-order frame's ~2,200 events to gRPC in 300 ms. They never enter the
+#: slow ring and never log (a slow line reads the cgroup's files, on the
+#: thread that sets the pace); a stall inside one still shows as its longest
+#: and in wall minus thread CPU.
+LONG_BY_SIZE = frozenset({"stream_send"})
+#: Poll span -> the hand-off its pick-up ends, and the identifier that the
+#: queue's offsets are on a frame's spans (module docstring).
+HANDOFF_OF_POLL = {
+    "consumer_poll": ("order_queue_dwell", "frame"),
+    "feed_poll": ("match_queue_dwell", "match"),
+}
 
 #: Span name -> the order-lifecycle taxonomy's stage (utils.trace.STAGES) an
 #: armed TRACER records it under. A span without an entry is not forwarded
@@ -135,7 +171,7 @@ class span:
         cpu = self.cpu_ns = _thread_cpu_ns() - self._c0
         self._ann.__exit__(exc_type, exc, tb)
         record(self.name, wall, cpu)
-        if wall >= SLOW_SPAN_NS:
+        if wall >= SLOW_SPAN_NS and self.name not in LONG_BY_SIZE:
             _note_slow(self.name, self.t0_ns, wall, cpu,
                        _process_cpu_ns() - self._p0)
         if self._stage is not None:
@@ -145,9 +181,10 @@ class span:
 
 def record(name: str, wall_ns: int, cpu_ns: int = 0) -> None:
     """Add one closed stretch to the table and /metrics: a span's exit, or a
-    stretch that no one thread held open (a boot's replay starts where one
-    thread's restore ends and ends at another's commit), timed by its owner;
-    such a stretch lies on no profile and is never a slow span."""
+    stretch that no one thread held open (a queue hand-off from its publish
+    to its pick-up; a boot's replay, which starts where one thread's restore
+    ends and ends at another's commit), timed by its owner; such a stretch
+    lies on no profile and is never a slow span."""
     with _lock:
         row = _totals.get(name)
         if row is None:
@@ -164,22 +201,26 @@ class poll_span:
     """One span over a run of consecutive polls that came back empty: an
     idle loop polls every couple of milliseconds, and a span per poll costs
     a thread that has just woken ten times what it costs a running one
-    (PERF.md, PR 25). `poller(fn, *args)`, or `poller.batch(queue, *args)` for
-    a queue's poll_batch, makes one poll inside the span and closes it when
-    the poll brought something back or the span is MERGE_POLLS_NS old, so it
-    stays far under SLOW_SPAN_NS unless a single poll overran: a slow poll
-    span still means a stall. Owned by the one thread that polls."""
+    (PERF.md, PR 25). `poller(fn, *args)`, or `poller.batch(queue, *args)` /
+    `poller.ahead(queue, *args)` for a queue's poll_batch / read_from, makes
+    one poll inside the span and closes it when the poll brought something
+    back or the span is MERGE_POLLS_NS old, so it stays far under
+    SLOW_SPAN_NS unless a single poll overran: a slow poll span still means a
+    stall. A queue's poll that brings messages back is the pick-up of the
+    hand-off HANDOFF_OF_POLL names for the span (module docstring). Owned by
+    the one thread that polls."""
 
-    __slots__ = ("name", "_span", "_polls")
+    __slots__ = ("name", "_span", "_polls", "_handoff")
 
     def __init__(self, name: str):
         self.name = name
         self._span = None
         self._polls = 0
+        self._handoff = HANDOFF_OF_POLL.get(name)
 
     def __call__(self, poll, *args):
         got = self._poll(poll, args)
-        if got or _wall_ns() - self._span.t0_ns >= MERGE_POLLS_NS:
+        if got:
             self.close()
         return got
 
@@ -188,9 +229,15 @@ class poll_span:
         messages notes what ended the queue's wait (bus.base.Queue)."""
         got = self._poll(queue.poll_batch, args)
         if got:
-            self.close(ended_by=queue.poll_ended_by)
-        elif _wall_ns() - self._span.t0_ns >= MERGE_POLLS_NS:
-            self.close()
+            self._picked_up(queue, got, ended_by=queue.poll_ended_by)
+        return got
+
+    def ahead(self, queue, *args):
+        """`queue.read_from(*args)` as one poll: a reader that runs ahead of
+        its commits and does not wait."""
+        got = self._poll(queue.read_from, args)
+        if got:
+            self._picked_up(queue, got)
         return got
 
     def _poll(self, poll, args):
@@ -203,7 +250,23 @@ class poll_span:
             self.close()
             raise
         self._polls += 1
+        if not got and _wall_ns() - self._span.t0_ns >= MERGE_POLLS_NS:
+            self.close()  # an idle stretch: closed by its age
         return got
+
+    def _picked_up(self, queue, msgs, **meta) -> None:
+        """Close with a queue's messages. Once per poll, never per message:
+        the oldest message's dwell, where the queue has the instant of its
+        publish (this process published it and no read returned it before)."""
+        if self._handoff is not None:
+            name, key = self._handoff
+            meta[key] = msgs[0].offset
+            published = queue.publish_ns(msgs[0].offset, msgs[-1].offset)
+            if published is not None:
+                dwell = _wall_ns() - published
+                record(name, dwell)
+                meta["dwell_us"] = dwell // 1000
+        self.close(**meta)
 
     def close(self, **meta) -> None:
         """End the open span, if any (work follows, or the loop ends)."""
@@ -343,8 +406,8 @@ def reset() -> None:
         _process_sample[:] = 0, 0
 
 
-def annotate(name: str):
-    return TraceAnnotation(name)
+def annotate(name: str, **meta):
+    return TraceAnnotation(name, **meta)
 
 
 @contextlib.contextmanager

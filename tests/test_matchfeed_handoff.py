@@ -243,8 +243,12 @@ def test_json_messages_that_come_one_by_one_are_hand_offs_of_one():
         mq.publish(encode_match_result(mr))
         assert feed.run_once() == 1
     assert REGISTRY.counter("gome_matchfeed_handoffs_total").value() - h0 == 5
+    deadline = time.monotonic() + 10  # the subscriber's thread takes the last
+    while len(got) < len(results) and time.monotonic() < deadline:
+        time.sleep(0.001)
     feed._stop.set()
     t.join(timeout=10)
+    assert not t.is_alive()
     assert [pb.MatchEvent.FromString(r) for r in got] == [
         matchfeed.match_result_to_pb(mr) for mr in results]
 

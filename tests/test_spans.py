@@ -329,7 +329,8 @@ ALLOWED_LOOPS = {
     "gome_tpu/service/gateway.py": set(),
     "gome_tpu/service/matchfeed.py": {
         "while i < len(msgs)",  # one EVENT frame or one run of JSON messages
-        "while not self._stop.is_set()",  # the subscriber's wait, when empty
+        # the subscriber's wait, when empty, and its send: one per queue item
+        "while not self._stop.is_set()",
     },
     "gome_tpu/engine/frames.py": {
         "for (g_i, (ops, meta, lane_ids, cap_g)) in enumerate(grids)",
@@ -397,9 +398,9 @@ LEAVES = {
                  "grid_dispatch", "frame_fetch", "frame_decode",
                  "publish_events"},
     "feed": {"feed_poll", "feed_decode", "feed_fanout"},
-    "stream": {"stream_wait"},
+    "stream": {"stream_wait", "stream_send"},
 }
-PARENTS = {"pipeline_feed", "feed_run_once"}
+PARENTS = {"pipeline_feed"}
 
 
 @pytest.fixture(scope="module")
@@ -501,3 +502,130 @@ def test_the_leaves_of_a_thread_do_not_overlap(served_profile):
         (rows,) = kinds[kind]
         covered = sum(e - s for s, e, _n in rows)
         assert covered / (rows[-1][1] - rows[0][0]) > 0.8, kind
+
+
+# --- one frame across the four threads (ISSUE 38) -------------------------
+
+FRAME_LEAVES = {"gateway_admit", "frame_unpack", "frame_admit", "frame_pack",
+                "grid_dispatch", "frame_fetch", "frame_decode",
+                "publish_events"}
+MATCH_LEAVES = {"feed_decode", "feed_fanout", "stream_send"}
+
+
+def _noted(served_profile):
+    """[(span name, its metadata)] of every named event of the profile."""
+    from jax.profiler import ProfileData
+
+    every = set().union(*LEAVES.values()) | PARENTS
+    return [
+        (e.name, dict(e.stats))
+        for plane in ProfileData.from_file(served_profile).planes
+        for line in plane.lines for e in line.events if e.name in every
+    ]
+
+
+def test_every_leaf_of_one_frame_carries_the_same_frame(served_profile):
+    """frame= is the order-queue offset on the gateway's and the consumer's
+    spans, match= the match-queue offset on the feed's and the handler's,
+    and publish_events has both: one frame can be followed across threads."""
+    noted = _noted(served_profile)
+    frames = {}
+    for name, meta in noted:
+        if name in FRAME_LEAVES | PARENTS:
+            assert "frame" in meta, (name, meta)
+            frames.setdefault(meta["frame"], set()).add(name)
+    assert frames == {k: FRAME_LEAVES | PARENTS for k in range(3)}
+    match_of = {meta["frame"]: meta["match"] for name, meta in noted
+                if name == "publish_events"}
+    assert match_of == {0: 0, 1: 1, 2: 2}  # a frame of fills, each of them
+    matches = {}
+    for name, meta in noted:
+        if name in MATCH_LEAVES:
+            matches.setdefault(meta["match"], set()).add(name)
+    assert matches == {k: MATCH_LEAVES for k in range(3)}
+    # the polls that picked a frame up say which, and how long it had lain
+    for poll, key in (("consumer_poll", "frame"), ("feed_poll", "match")):
+        picked = [meta for name, meta in noted
+                  if name == poll and key in meta]
+        # (the poll that brought the first may have begun before the trace)
+        assert {1, 2} <= {meta[key] for meta in picked} <= {0, 1, 2}, poll
+        assert all(meta["dwell_us"] >= 0 for meta in picked)
+    sends = [meta for name, meta in noted if name == "stream_send"]
+    assert all(meta["events"] == 8 and "first_us" in meta for meta in sends)
+
+
+def test_the_handler_is_inside_one_of_its_two_leaves_all_the_time(
+        served_profile):
+    """stream_wait while its queue is empty, stream_send while gRPC takes a
+    chunk: between its first and last span nothing else."""
+    from jax.profiler import ProfileData
+
+    lines = []
+    for plane in ProfileData.from_file(served_profile).planes:
+        for line in plane.lines:
+            rows = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                          for e in line.events if e.name in LEAVES["stream"])
+            if rows:
+                lines.append(rows)
+    (rows,) = lines  # one subscriber, one thread
+    assert {name for _s, _e, name in rows} == LEAVES["stream"]
+    covered = sum(e - s for s, e, _n in rows)
+    assert covered / (rows[-1][1] - rows[0][0]) > 0.95
+
+
+@pytest.mark.parametrize("backend", ["memory", "file"])
+def test_a_served_request_puts_the_new_names_on_metrics(backend, tmp_path):
+    """gome_span_seconds_total / gome_span_count carry the stream's span and
+    the three hand-offs after one request; on the file bus the log's reads
+    and the cursors' writes too."""
+    from gome_tpu.config import BusConfig, Config, EngineConfig, GrpcConfig
+    from gome_tpu.service import EngineService
+    from gome_tpu.utils.metrics import REGISTRY
+
+    tracing.reset()
+    svc = EngineService(Config(
+        grpc=GrpcConfig(host="127.0.0.1", port=0),
+        engine=EngineConfig(cap=32, n_slots=8, max_t=8, pipeline_depth=2),
+        bus=BusConfig(backend=backend, dir=str(tmp_path / "bus"),
+                      match_wire="frame"),
+    ))
+    svc.feed.log_events = False
+    svc.start()
+    try:
+        events = svc.feed.subscribe()
+        got = []
+        reader = threading.Thread(
+            target=lambda: got.extend(itertools.islice(events, 9)),
+            daemon=True)
+        reader.start()
+        deadline = time.monotonic() + 30
+        while not svc.feed._subs and time.monotonic() < deadline:
+            time.sleep(0.01)
+        for k in range(2):  # the second frame's first event closes the first
+            reqs = [
+                pb.OrderRequest(
+                    uuid="u", oid=f"o{k}-{i}", symbol=f"s{i // 2 % 4}",
+                    transaction=pb.SALE if i % 2 else pb.BUY,
+                    price=1.0, volume=1.0)
+                for i in range(16)
+            ]
+            resp = svc.gateway.DoOrderBatch(
+                pb.OrderBatchRequest(orders=reqs), None)
+            assert (resp.code, resp.accepted) == (0, 16)
+        reader.join(timeout=60)
+        assert len(got) == 9
+    finally:
+        svc.stop()
+    names = ["stream_send", "order_queue_dwell", "match_queue_dwell",
+             "subscriber_queue_dwell"]
+    if backend == "file":
+        names += ["log_read", "cursor_commit"]
+    text = REGISTRY.render()
+    rows = tracing.totals()
+    for name in names:
+        assert rows[name]["count"] >= 1, name
+        for family in ("gome_span_seconds_total", "gome_span_count"):
+            assert f'{family}{{span="{name}"}}' in text, (family, name)
+    if backend == "memory":
+        assert not {"log_read", "cursor_commit"} & {
+            name for name, row in rows.items() if row["count"]}
