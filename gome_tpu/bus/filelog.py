@@ -5,11 +5,29 @@ Format: length-prefixed records in one log file per queue
 sidecar ``<name>.offset`` holding the committed consumer offset as ASCII.
 Publishes fsync per append batch; commits rewrite the sidecar atomically
 (tmp + rename). A torn final record (crash mid-append) is detected on open
-and truncated away. Readers TAIL the log across processes: read_from/
-end_offset re-scan for records another process appended since the last
-look (single writer per queue; an incomplete tail record is the live
-writer mid-append and is skipped, not truncated) — the split
-gateway/consumer fleet topology runs on exactly this.
+and truncated away.
+
+The log has a single writer per queue, and a FileQueue object is one of two
+things, told apart by its own history and by nothing else:
+
+  the writer — the last thing this object did to the log was append a record
+      (publish). Its index (_positions, _scan_end) is the log's end by
+      construction, so read_from, end_offset and depth answer from it with
+      no system call. The single-process venue is this case on both queues:
+      gateway, consumer and match feed share one object per queue
+      (make_bus), whose publish also wakes the pollers, so an idle
+      poll_batch never asks the filesystem anything.
+  a reader — an object that has not appended since it was opened, or since
+      its last truncate_to (recovery's; the object proves itself again at
+      its next append). It TAILS the log across processes: every read_from
+      and end_offset looks at the file (_refresh_locked: one stat, then a
+      scan of what another process appended; an incomplete tail record is
+      the live writer mid-append and is skipped, not truncated). Nobody
+      notifies it, so it looks at every read — the split gateway/consumer
+      fleet topology runs on exactly this.
+
+Record bodies are read from the file by either kind. How often either kind
+looked is counted: gome_bus_log_looks_total{queue=} (log_looks()).
 
 This is the durability the reference lacks on its bus (non-durable queues +
 auto-ack, rabbitmq.go:64,102 — SURVEY §2.3.6): with a FileQueue, the order
@@ -25,6 +43,7 @@ import struct
 import threading
 
 from ..utils.faults import FAULTS
+from ..utils.metrics import REGISTRY
 from ..utils.tracing import span
 from .base import Message, Queue, _Waitable
 
@@ -52,6 +71,16 @@ class FileQueue(_Waitable, Queue):
         # cross-process tail point (_refresh_locked resumes scanning
         # here when ANOTHER process appended since we last looked).
         self._scan_end = 0  # guarded by self._lock
+        # True while this object's last write to the log was an append: it
+        # is then the log's single writer and its index is the log's end
+        # (module docstring). Nothing sets it but what the object did.
+        self._wrote = False  # guarded by self._lock
+        self._looks = REGISTRY.counter(
+            "gome_bus_log_looks_total",
+            "stats of the log made by this queue's reads: a file queue "
+            "that has not appended looks for another process's records",
+            labels={"queue": name},
+        )
         with self._lock:
             self._scan_existing_locked()
         self._f = open(self._log_path, "ab")
@@ -85,8 +114,12 @@ class FileQueue(_Waitable, Queue):
         in-memory index must tail the writer's appends. Only complete
         records are indexed — an incomplete tail is a record the live
         writer is mid-append on, so (unlike the open-time scan) it is
-        left alone, never truncated. One stat per call when nothing
-        changed."""
+        left alone, never truncated. A reader pays one stat per call when
+        nothing changed; the log's writer returns at once (its own publish
+        indexed everything there is)."""
+        if self._wrote:
+            return
+        self._looks.inc()
         try:
             size = os.path.getsize(self._log_path)
         except OSError:
@@ -140,6 +173,7 @@ class FileQueue(_Waitable, Queue):
                 os.fsync(self._f.fileno())
             self._positions.append(pos)
             self._scan_end = pos + len(record)
+            self._wrote = True
             off = len(self._positions) - 1
         self._notify_publish(off)
         return off
@@ -168,6 +202,12 @@ class FileQueue(_Waitable, Queue):
         with self._lock:
             self._refresh_locked()
             return len(self._positions)
+
+    def log_looks(self) -> int:
+        """Stats of the log that reads of this queue made, over the process
+        and by the queue's name (gome_bus_log_looks_total{queue=}): stands
+        still while the object is the log's writer."""
+        return self._looks.value()
 
     def committed(self) -> int:
         with self._lock:
@@ -209,6 +249,7 @@ class FileQueue(_Waitable, Queue):
             self._f.seek(pos)
             del self._positions[offset:]
             self._scan_end = pos
+            self._wrote = False
 
     def _write_offset(self, offset: int) -> None:
         cut = FAULTS.fire("filelog.offset")

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import os
 
-from ..bus import make_bus
+from ..bus import FileQueue, make_bus
 from ..config import Config
 from ..engine.orchestrator import MatchEngine
 from ..types import OrderType
@@ -307,10 +307,14 @@ class EngineService:
             self.engine.batch.combo_count(),
             len({c[:4] for c in self.engine.batch.combos()}),
         )
+        # A file queue also says how often its reads looked at the log
+        # (bus/filelog.py): the count stops at the queue's first append.
         (log.warning if tracing.slow() else log.info)(
             "polls that brought messages, by what ended their wait: %s",
             "; ".join(
                 f"{q.name} {q.poll_returns()}"
+                + (f", looks at the log {q.log_looks()}"
+                   if isinstance(q, FileQueue) else "")
                 for q in (self.bus.order_queue, self.bus.match_queue)
             ),
         )

@@ -1,5 +1,7 @@
 """Bus backends + codec tests (gome_tpu.bus vs rabbitmq.go topology)."""
 
+import os
+import sys
 import threading
 import time
 
@@ -264,6 +266,192 @@ def test_file_queue_truncates_torn_tail(tmp_path):
     q2 = FileQueue("q", base)
     assert q2.end_offset() == 1
     assert q2.read_from(0, 9)[0].body == b"whole"
+
+
+# --- which file queue looks at its log (ISSUE 39) ---------------------------
+# The log has one writer per queue. An object whose last write to the log was
+# an append is that writer and answers from its index with no system call; an
+# object that has not appended tails another process's log and looks (one
+# stat) at every read. gome_bus_log_looks_total{queue=} counts the looks.
+
+
+def _log_looks(queue) -> int:
+    from gome_tpu.utils.metrics import REGISTRY
+
+    n = REGISTRY.counter(
+        "gome_bus_log_looks_total", labels={"queue": queue.name}
+    ).value()
+    assert queue.log_looks() == n  # the registry's, by name
+    return n
+
+
+class _StatCount:
+    """Counts os.stat (so os.path.getsize / exists too) of one path and every
+    os.fstat, through a patched os."""
+
+    def __init__(self, monkeypatch, path):
+        self.n = 0
+        stat, fstat = os.stat, os.fstat
+
+        def counted_stat(p, *a, **kw):
+            if p == path:
+                self.n += 1
+            return stat(p, *a, **kw)
+
+        def counted_fstat(fd):
+            self.n += 1
+            return fstat(fd)
+
+        monkeypatch.setattr(os, "stat", counted_stat)
+        monkeypatch.setattr(os, "fstat", counted_fstat)
+
+    def during(self, call) -> int:
+        before = self.n
+        call()
+        return self.n - before
+
+
+def _written_log(tmp_path, n=3):
+    """A FileQueue opened on a log of `n` records that another object wrote,
+    everything committed (so a poll of it is idle)."""
+    base = str(tmp_path / "q")
+    first = FileQueue("q", base)
+    for i in range(n):
+        first.publish(f"msg-{i}".encode())
+    first.commit(n)
+    first.close()
+    return FileQueue("q", base), base + ".log"
+
+
+LOOK_CASES = {
+    "read_from_empty": lambda q: q.read_from(q.committed(), 8),
+    "read_from_nonempty": lambda q: q.read_from(0, 2),
+    "end_offset": lambda q: q.end_offset(),
+    "depth": lambda q: q.depth(),
+    "idle_poll_batch": lambda q: q.poll_batch(8, max_wait_s=0.01),
+}
+
+
+@pytest.mark.parametrize("case", LOOK_CASES)
+def test_file_queue_looks_at_its_log_only_until_it_writes(
+    tmp_path, monkeypatch, case
+):
+    q, log_path = _written_log(tmp_path)
+    stats = _StatCount(monkeypatch, log_path)
+    call = lambda: LOOK_CASES[case](q)
+    # It has not appended: the log may be another process's, so it looks,
+    # once a read (a poll makes several reads).
+    looks = _log_looks(q)
+    made = stats.during(call)
+    assert made == _log_looks(q) - looks
+    if case == "idle_poll_batch":
+        assert made >= 1
+    else:
+        assert made == 1
+    # Its first append makes it the log's writer: no read asks again.
+    q.publish(b"mine")
+    q.commit(q.end_offset())
+    looks = _log_looks(q)
+    assert stats.during(call) == 0
+    assert _log_looks(q) == looks
+    # And the answers are still the log's.
+    assert q.end_offset() == 4 and q.depth() == 0
+    assert [m.body for m in q.read_from(2, 8)] == [b"msg-2", b"mine"]
+    assert stats.n == made
+
+
+def test_file_queue_writer_truncated_and_publishing_again(tmp_path, monkeypatch):
+    base = str(tmp_path / "q")
+    q = FileQueue("q", base)
+    for i in range(5):
+        q.publish(f"old-{i}".encode())
+    q.commit(2)
+    stats = _StatCount(monkeypatch, base + ".log")
+    q.truncate_to(3)  # recovery's: the tail is published anew by the replay
+    assert q.end_offset() == 3 and q.depth() == 1
+    assert [q.publish(f"new-{i}".encode()) for i in range(2)] == [3, 4]
+    looks, made = _log_looks(q), stats.n
+    assert [m.body for m in q.read_from(0, 99)] == [
+        b"old-0", b"old-1", b"old-2", b"new-0", b"new-1",
+    ]
+    assert q.end_offset() == 5 and q.read_from(5, 9) == []
+    assert _log_looks(q) == looks and stats.n == made  # the writer again
+    q.close()
+    # What it left on the disk is what it said it held.
+    q2 = FileQueue("q", base)
+    assert [m.body for m in q2.read_from(0, 99)] == [
+        b"old-0", b"old-1", b"old-2", b"new-0", b"new-1",
+    ]
+    assert q2.committed() == 2
+
+
+def test_file_queue_torn_tail_then_writer(tmp_path, monkeypatch):
+    q, log_path = _written_log(tmp_path, n=2)
+    q.close()
+    whole = os.path.getsize(log_path)
+    with open(log_path, "ab") as f:
+        f.write(b"\x00\x00\x00\xff partial")  # length says 255, body short
+    q2 = FileQueue("q", log_path[: -len(".log")])
+    assert os.path.getsize(log_path) == whole  # truncated at open
+    stats = _StatCount(monkeypatch, log_path)
+    looks = _log_looks(q2)
+    assert q2.end_offset() == 2  # not yet the writer: it looks
+    assert stats.n == 1 and _log_looks(q2) == looks + 1
+    assert q2.publish(b"after") == 2  # lands where the torn record was cut
+    assert q2.end_offset() == 3
+    assert [m.body for m in q2.read_from(1, 9)] == [b"msg-1", b"after"]
+    assert q2.poll_batch(8, max_wait_s=0.01, start=3) == []
+    assert stats.n == 1 and _log_looks(q2) == looks + 1  # stopped looking
+
+
+def test_file_queue_pollers_race_the_first_append(tmp_path):
+    """The one-process venue: two threads poll one object while a third makes
+    it the log's writer. No record is missed or read twice at the change from
+    looking to knowing, and once it is the writer nobody looks again."""
+    q = FileQueue("q", str(tmp_path / "q"))
+    n, seen, stop = 200, {"a": [], "b": []}, threading.Event()
+
+    def poller(name):
+        at = 0
+        while at < n and not stop.is_set():
+            for m in q.poll_batch(8, max_wait_s=0.002, start=at):
+                seen[name].append((m.offset, m.body))
+                at = m.offset + 1
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=poller, args=(k,)) for k in seen]
+        for t in threads:
+            t.start()
+        time.sleep(0.02)  # both are looking at an empty log
+        assert q.publish(b"m-0") == 0
+        looks = _log_looks(q)
+        for i in range(1, n):
+            q.publish(f"m-{i}".encode())
+        for t in threads:
+            t.join(timeout=30)
+        stop.set()
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    want = [(i, f"m-{i}".encode()) for i in range(n)]
+    assert seen == {"a": want, "b": want}
+    assert _log_looks(q) == looks
+
+
+def test_file_queue_reader_sees_another_objects_appends(tmp_path):
+    """The split topology: one object appends, another (a second process's)
+    only reads. Nobody notifies the reader, so it looks at every read and
+    finds each append at its next one."""
+    base = str(tmp_path / "q")
+    writer, reader = FileQueue("q", base), FileQueue("q", base)
+    for i in range(3):
+        assert reader.end_offset() == i
+        writer.publish(f"w-{i}".encode())
+        looks = _log_looks(reader)
+        assert [m.body for m in reader.read_from(i, 8)] == [f"w-{i}".encode()]
+        assert _log_looks(reader) == looks + 1
 
 
 def test_make_bus_topology(tmp_path):
