@@ -279,7 +279,7 @@ def _entry_records_x64_scoped(dtype: str):
     })
     fills_acc = jnp.zeros((len(fr._FILL_FIELDS), 64), wide)
     cancels_acc = jnp.zeros((len(fr._CANCEL_FIELDS), 64), wide)
-    totals_acc = jnp.zeros((8, fr.N_TOTALS), jnp.int32)
+    totals_acc = jnp.zeros((8, fr.n_totals(config)), jnp.int32)
     yield dict(
         context="engine/frames.py:compact_accum",
         closed=jax.make_jaxpr(

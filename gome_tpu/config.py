@@ -117,6 +117,12 @@ class EngineConfig:
     # under shard_map, zero-collective dense grids (SURVEY §5.8). 0 = no
     # mesh (single chip). n_slots must be a multiple of mesh_devices.
     mesh_devices: int = 0
+    # The venue's self-trade prevention rule (types.SELF_TRADE_RULES):
+    # "none", the reference's, or "expire_taker": an add stops at its
+    # owner's first resting order and what is left of it expires. Decided
+    # inside the match step on the device. A rule of the venue, as the
+    # accuracy is, not a tuning knob: the two give different events.
+    self_trade: str = "none"
 
     def __post_init__(self) -> None:
         if not 0 <= self.accuracy <= 18:
@@ -131,12 +137,13 @@ class EngineConfig:
             )
         if self.dtype not in ("int32", "int64"):
             raise ValueError(f"engine.dtype must be int32|int64, got {self.dtype}")
-        from .types import KERNELS
+        from .types import KERNELS, check_self_trade
 
         if self.kernel not in KERNELS:
             raise ValueError(
                 f"engine.kernel must be one of {KERNELS}, got {self.kernel}"
             )
+        check_self_trade(self.self_trade)
 
     def book_config(self) -> "BookConfig":
         import jax.numpy as jnp
@@ -147,6 +154,7 @@ class EngineConfig:
             cap=self.cap,
             max_fills=self.max_fills,
             dtype=jnp.int32 if self.dtype == "int32" else jnp.int64,
+            self_trade=self.self_trade,
         )
 
 

@@ -581,6 +581,10 @@ class EngineStats:
     expired_ioc: int = 0
     fok_killed: int = 0
     post_only_blocked: int = 0
+    # Adds of any kind that stopped at their owner's resting order with
+    # volume left and expired there (BookConfig.self_trade "expire_taker";
+    # 0 on a venue without the rule). Counted like the three above.
+    stp_expired: int = 0
     lane_growths: int = 0
     # Grids dispatched and the real (non-padding) ops through them, keyed
     # by the kernel that ACTUALLY ran (BatchEngine._step): "pallas_full" /
@@ -1617,6 +1621,7 @@ class BatchEngine:
                 "cap": self.config.cap,
                 "max_fills": self.config.max_fills,
                 "dtype": np.dtype(self.config.dtype).name,
+                "self_trade": self.config.self_trade,
                 "n_slots": self.n_slots,
                 "max_t": self.max_t,
             },
@@ -1650,7 +1655,20 @@ class BatchEngine:
     def import_state(self, state: dict) -> None:
         """Restore a state exported by export_state (snapshot recovery).
         Replaces books, interners, and geometry; stats are NOT restored
-        (counters describe a process lifetime, not book state)."""
+        (counters describe a process lifetime, not book state). The
+        venue's self-trade rule is the one piece of the BookConfig that is
+        not taken from the state: it is compared, and a state written
+        under another rule is refused (one from before the rule existed was
+        written under "none"). The books would load, but whoever replays
+        the frames behind a snapshot has to make the events the first
+        process made, and the other rule makes others."""
+        rule = state.get("self_trade", "none")
+        if rule != self.config.self_trade:
+            raise ValueError(
+                f"snapshot was written under engine.self_trade {rule!r}, "
+                f"this engine runs {self.config.self_trade!r}: restore "
+                "under the rule it was written under"
+            )
         self.config = dataclasses.replace(
             self.config,
             cap=int(state["cap"]),

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..types import check_self_trade
+
 BUY = 0
 SALE = 1
 
@@ -50,11 +52,22 @@ class BookConfig:
     dtype    — lot/price dtype. int64 (default) matches the reference's
                exact-integer envelope at accuracy=8 (SURVEY §2.2); int32 is
                available when tick/lot ranges allow, halving HBM traffic.
+    self_trade — the venue's self-trade prevention rule
+               (types.SELF_TRADE_RULES; step._match has it): "none", the
+               reference's, lets an account trade with itself;
+               "expire_taker" stops an add at its owner's first resting
+               order and expires what is left. A rule of the venue, as the
+               accuracy is: the two give different events. Static, so a
+               venue without the rule traces the step it always traced.
     """
 
     cap: int = 256
     max_fills: int = 16
     dtype: jnp.dtype = jnp.int64
+    self_trade: str = "none"
+
+    def __post_init__(self) -> None:
+        check_self_trade(self.self_trade)
 
     @property
     def seq_dtype(self):
@@ -121,8 +134,10 @@ class StepOutput(NamedTuple):
     cancel_found: jax.Array  # i32 bool: DEL matched a resting order
     cancel_volume: jax.Array  # lots remaining at cancel (engine.go:100)
     # i32: 0, or the kind of an add that expired by its kind's rule (IOC:
-    # a remainder was dropped; FOK: killed; POST_ONLY: blocked). The kind
-    # and not a flag, so that the frame's totals count each by a compare.
+    # a remainder was dropped; FOK: killed; POST_ONLY: blocked), or
+    # step.EXPIRED_STP for an add of any kind that stopped at its owner's
+    # resting order with volume left (BookConfig.self_trade). A code and
+    # not a flag, so that the frame's totals count each by a compare.
     expired: jax.Array
 
 

@@ -74,7 +74,7 @@ from .batch import (
     step_in_program,
 )
 from .book import GRID_I32_FIELDS, DeviceOp
-from .step import ACTION_ADD, LOT_MAX32, TAKER_PRICE_MAX32
+from .step import ACTION_ADD, EXPIRED_STP, LOT_MAX32, TAKER_PRICE_MAX32
 
 #: Cumulative wall-clock seconds apply_frame_fast spent BLOCKED on the
 #: device->host fetch of compacted events. Blocking there also drains the
@@ -84,16 +84,35 @@ FETCH_SECONDS = 0.0
 
 ACTION_DEL = int(Action.DEL)
 MARKET = int(OrderType.MARKET)
-#: Kinds whose expiry the device counts, in the order of the totals'
-#: columns 4.. (compact_accum) and of a frame's `expired` array, and the
-#: EngineStats field that counts each.
-_EXPIRING_KINDS = (
-    int(OrderType.IOC), int(OrderType.FOK), int(OrderType.POST_ONLY)
+#: What StepOutput.expired can say, in the order of the totals' columns 4..
+#: (compact_accum) and of a frame's `expired` array: the code, the
+#: EngineStats field that counts it, and its label on /metrics
+#: gome_orders_expired_total. The kinds' three, then the one of a venue's
+#: self-trade rule, which only such a venue's totals hold (expired_codes),
+#: so that a venue without a rule runs the programs it always ran.
+_EXPIRED = (
+    (int(OrderType.IOC), "expired_ioc", "ioc"),
+    (int(OrderType.FOK), "fok_killed", "fok"),
+    (int(OrderType.POST_ONLY), "post_only_blocked", "post_only"),
+    (EXPIRED_STP, "stp_expired", "stp"),
 )
-_EXPIRED_FIELDS = ("expired_ioc", "fok_killed", "post_only_blocked")
 #: Columns of a frame's per-grid totals: fills, cancels, book overflows,
-#: max n_fills, then the expired adds of each of _EXPIRING_KINDS.
-N_TOTALS = 4 + len(_EXPIRING_KINDS)
+#: max n_fills, then the expired adds of each of expired_codes(config):
+#: n_totals(config) in all.
+_N_COUNTS = 4
+
+
+def expired_codes(config) -> tuple:
+    """The codes of StepOutput.expired that a step under `config` can emit,
+    in the order of the totals' columns 4.. and of a frame's `expired`."""
+    codes = tuple(code for code, _field, _label in _EXPIRED)
+    return codes[:-1] if config.self_trade == "none" else codes
+
+
+def n_totals(config) -> int:
+    """The width of the per-grid totals of an engine under `config`."""
+    return _N_COUNTS + len(expired_codes(config))
+
 
 _GRID_FIELDS = DeviceOp._fields  # one canonical field list + order
 
@@ -266,11 +285,11 @@ def _frame_arrays(eng: BatchEngine, cols: dict) -> dict:
         dels_total=int((action == ACTION_DEL).sum()),
         add_counts=add_counts,
         # EngineStats upkeep (_assemble): kept adds by kind, of them those
-        # that cannot rest, and the expired adds per _EXPIRING_KINDS, which
+        # that cannot rest, and the expired adds per expired_codes, which
         # the frame's totals (fast path) or outputs (exact path) add up.
         adds_by_kind=adds_by_kind,
         takers=int(kept_add.sum()) - int(add_counts.sum()),
-        expired=np.zeros(len(_EXPIRING_KINDS), np.int64),
+        expired=np.zeros(len(expired_codes(eng.config)), np.int64),
     )
 
 
@@ -502,7 +521,7 @@ def _assemble(eng, a, batches):
         st.adds_by_kind[k] = st.adds_by_kind.get(k, 0) + int(
             a["adds_by_kind"][k]
         )
-    for field, n in zip(_EXPIRED_FIELDS, a["expired"].tolist()):
+    for (_code, field, _label), n in zip(_EXPIRED, a["expired"].tolist()):
         setattr(st, field, getattr(st, field) + n)
     if not batches:
         eng.stats.cancels_missed += a["dels_total"]
@@ -539,7 +558,8 @@ def apply_frame(eng: BatchEngine, cols: dict):
         outs, overrides = eng._run_exact(ops, contexts, lane_ids, cap_g)
         expired = np.asarray(outs.expired)[meta["row"], meta["t"]]
         a["expired"] += [
-            int(np.count_nonzero(expired == k)) for k in _EXPIRING_KINDS
+            int(np.count_nonzero(expired == k))
+            for k in expired_codes(eng.config)
         ]
         with span("frame_decode", frame=frame):
             batches.append(
@@ -655,7 +675,7 @@ def _decode_compact(eng, meta, shape, fetched) -> dict:
     return {name: v[order] for name, v in columns.items()}
 
 
-def _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g):
+def _compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
     """Append one grid's compacted events into the FRAME-level buffers
     (traceable; compact_accum is its own program, _grid_program holds it).
 
@@ -667,7 +687,7 @@ def _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g):
     accumulators are donated, so the train appends in place with no
     host sync; totals_acc[g] records this grid's TRUE
     fill/cancel counts (+ overflow flag + max n_fills + the adds that
-    expired, per kind of _EXPIRING_KINDS), which is also
+    expired, per code of expired_codes(config)), which is also
     how the host later splits the flat buffers back into grids. The
     grid with g == 0 opens the frame: it is handed whatever buffers of
     the right shapes the engine holds and reads the totals as zero."""
@@ -724,7 +744,7 @@ def _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g):
             ]
             + [
                 jnp.sum((outs.expired == k).astype(jnp.int32))
-                for k in _EXPIRING_KINDS
+                for k in expired_codes(config)
             ]
         ).astype(jnp.int32)  # x64 promotes int32 sums to int64
     )
@@ -735,7 +755,9 @@ def _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g):
 def compact_accum(config, outs, fills_acc, cancels_acc, totals_acc, g):
     """_compact_accum as a program of its own, after a large frame's
     eng._step: the three buffers are donated."""
-    return _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g)
+    return _compact_accum(
+        config, outs, fills_acc, cancels_acc, totals_acc, g
+    )
 
 
 @functools.partial(
@@ -756,7 +778,9 @@ def _grid_program(plan, n_rows, t_grid, books, cols, flat, ids,
     ops = _scatter_grid(jnp.dtype(plan.cfg.dtype), n_rows, t_grid, cols, flat)
     books, outs = step_in_program(plan, books, ops, ids)
     assert outs.fill_qty.shape[-1] == _k_rec(plan.cfg)
-    buffers = _compact_accum(outs, fills_acc, cancels_acc, totals_acc, g)
+    buffers = _compact_accum(
+        plan.cfg, outs, fills_acc, cancels_acc, totals_acc, g
+    )
     return books, buffers, jnp.max(books.count, axis=-1)
 
 
@@ -845,13 +869,15 @@ def export_metrics(eng: BatchEngine) -> None:
             lambda k=int(kind): stats.adds_by_kind.get(k, 0),
             labels={"kind": kind.name.lower()},
         )
-    for kind, field in zip(_EXPIRING_KINDS, _EXPIRED_FIELDS):
+    for _code, field, label in _EXPIRED:
         REGISTRY.callback_gauge(
             "gome_orders_expired_total",
-            "adds that expired by their kind's rule: an IOC remainder "
-            "dropped, a FOK add killed, a POST_ONLY add blocked",
+            "adds that expired by their kind's rule (an IOC remainder "
+            "dropped, a FOK add killed, a POST_ONLY add blocked) or, "
+            "kind=\"stp\", by the venue's self-trade rule (an add of any "
+            "kind stopped at its owner's resting order with volume left)",
             lambda field=field: getattr(stats, field),
-            labels={"kind": OrderType(kind).name.lower()},
+            labels={"kind": label},
         )
 
 
@@ -876,7 +902,7 @@ def _zero_buffers(eng: BatchEngine, e_fills: int, e_cancels: int,
     return tuple(jax.device_put((
         np.zeros((len(_FILL_FIELDS), e_fills), wide),
         np.zeros((len(_CANCEL_FIELDS), e_cancels), wide),
-        np.zeros((totals_len, N_TOTALS), np.int32),
+        np.zeros((totals_len, n_totals(eng.config)), np.int32),
     ), where))
 
 
@@ -1019,7 +1045,8 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
                     "frame_dispatch", combo,
                     JOURNAL.clock() - t_disp_j,
                     detail=frame_combo_detail(
-                        np.dtype(eng.config.dtype).name, combo
+                        np.dtype(eng.config.dtype).name, combo,
+                        n_totals(eng.config),
                     ),
                 )
             eng.record_combo(combo)
@@ -1077,7 +1104,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
     the used prefix is sliced on the host. A large one keeps the TWO-phase
     device->host fetch:
 
-      1. the [G, N_TOTALS] totals (+ the [S] count_ub re-anchor), tiny and
+      1. the [G, n_totals] totals (+ the [S] count_ub re-anchor), tiny and
          already in flight since submit;
       2. the USED PREFIX of the fill/cancel event matrices, pow2-bucketed
          from the totals — a margined mixed-flow buffer is 7-8x its
@@ -1116,7 +1143,7 @@ def resolve_frame(eng: BatchEngine, pend: PendingFrame):
         nc_g = totals[:g, 1].astype(np.int64)
         total_f = int(nf_g.sum())
         total_c = int(nc_g.sum())
-        expired = totals[:g, 4:].sum(axis=0, dtype=np.int64)
+        expired = totals[:g, _N_COUNTS:].sum(axis=0, dtype=np.int64)
         fetched.note(expired=int(expired.sum()))
         # A fills-buffer overflow ratchets the grow-only floor (keyed by
         # the FRAME's kept-op class) BEFORE the exact fallback, so the

@@ -56,6 +56,9 @@ KIND_MARKET = int(OrderType.MARKET)
 KIND_IOC = int(OrderType.IOC)
 KIND_FOK = int(OrderType.FOK)
 KIND_POST_ONLY = int(OrderType.POST_ONLY)
+# StepOutput.expired of an add that self-trade prevention stopped: a number
+# no kind has (2 and 5 may yet be kinds; 7 lies past FIX's tables).
+EXPIRED_STP = 7
 
 
 def _bsel(c, a, b):
@@ -166,10 +169,10 @@ class _Side(NamedTuple):
 
 
 def _match(
-    config: BookConfig, opp: _Side, opp_count, side, price, volume, kind
+    config: BookConfig, opp: _Side, opp_count, side, price, volume, kind, uid
 ):
     """Fill the crossing prefix of the opposing side, as the add's kind
-    allows.
+    and the venue's self-trade rule allow.
 
     Crossing rule (nodepool.go:86-115): BUY taker hits asks with price <=
     limit; SALE taker hits bids with price >= limit; MARKET (extension)
@@ -182,6 +185,20 @@ def _match(
     that would take anything, leave the book as it was. Both are decided
     from the fill itself and blended in as an i32 mask (as _bsel does): no
     branch, no new reduction. Returns `killed` (bool) beside the usual.
+
+    Who may trade with whom (config.self_trade, static): under "none", the
+    reference's, the owners are never compared and an account fills its own
+    resting order like any other. Under "expire_taker" the crossing prefix
+    is cut at its first slot whose uid is the taker's: the add fills the
+    slots ahead of it as it always did, and if volume is left when it
+    arrives there, what is left expires (`stp`: it does not trade with
+    that order, does not pass it and does not rest; step_rows_impl drops
+    it). The own order is never touched. A FOK add counts only the lots
+    ahead of the cut; a POST_ONLY add is blocked by the uncut prefix,
+    whoever owns its first order. The cut prefix is still a prefix, so the
+    fill records, fill_overflow and the compaction below do not change;
+    the cost is one compare and one masked integer minimum over [cap].
+    Returns `stp` (bool, or None where the venue has no rule).
     """
     cap = config.cap
     k = config.max_fills
@@ -195,6 +212,16 @@ def _match(
     mkt = (kind == KIND_MARKET).astype(jnp.int32)
     crosses = jnp.maximum(_bsel(side == BUY, le, ge), mkt)
     crossing = active & (crosses != 0)
+    own_in_c = None
+    if config.self_trade == "expire_taker":
+        # The first own slot of C, cap where C holds none. An integer
+        # minimum, as _remove's reductions are integer sums (Mosaic lowers
+        # a boolean reduction through a float).
+        first_own = jnp.min(
+            jnp.where(crossing & (opp.uid == uid), idx, cap)
+        ).astype(jnp.int32)
+        own_in_c = first_own < cap
+        crossing = crossing & (idx < first_own)
 
     clots = jnp.where(crossing, opp.lots, 0)
     # Exclusive prefix = inclusive prefix of the shifted array — computed
@@ -211,13 +238,21 @@ def _match(
     # FOK: avail >= volume  <=>  total == volume. POST_ONLY: C is not
     # empty  <=>  total > 0 (resting lots and volumes are positive, so the
     # first crossing slot always fills something).
-    killed = ((kind == KIND_FOK) & (total < volume)) | (
-        (kind == KIND_POST_ONLY) & (total > 0)
-    )
+    fok_short = (kind == KIND_FOK) & (total < volume)
+    is_post_only = kind == KIND_POST_ONLY
+    took = total > 0
+    if own_in_c is not None:
+        # The uncut C is not empty where the cut one is not, or where it
+        # held an own order at all (at its head, the cut one is empty).
+        took = took | own_in_c
+    killed = fok_short | (is_post_only & took)
     live = 1 - killed.astype(fill.dtype)
     fill = fill * live
     total = total * live
     remaining = volume - total
+    stp = None
+    if own_in_c is not None:
+        stp = own_in_c & (remaining > 0) & ~killed
 
     new_lots = opp.lots - fill
     fully_filled = (fill > 0) & (new_lots == 0)  # a prefix of the array
@@ -240,7 +275,7 @@ def _match(
     )
 
     compacted = opp._replace(lots=new_lots).shift_left(n_removed, cap)
-    return compacted, opp_count - n_removed, remaining, killed, out
+    return compacted, opp_count - n_removed, remaining, killed, stp, out
 
 
 def _insert(config: BookConfig, own: _Side, own_count, entry: _Side, side):
@@ -268,7 +303,9 @@ def _insert(config: BookConfig, own: _Side, own_count, entry: _Side, side):
 def _remove(config: BookConfig, own: _Side, own_count, oid, price):
     """Cancel lookup + unlink (engine.go:87-116): requires the exact resting
     price (SURVEY §2.3.2 — the reference looks up S:link:P by price); no
-    ownership check (uid is deliberately not compared)."""
+    ownership check (uid is deliberately not compared, under either
+    self-trade rule: the rule is about who trades with whom, and a cancel
+    trades with no one)."""
     cap = config.cap
     idx = jnp.arange(cap, dtype=jnp.int32)
     active = idx < own_count
@@ -320,18 +357,22 @@ def step_rows_impl(
     opp_count0 = jnp.where(is_buy, sale_count, buy_count)
 
     # --- ADD: match against the opposing side -------------------------------
-    opp1, opp_count1, remaining, killed, fills = _match(
-        config, opp0, opp_count0, s, op.price, op.volume, op.kind
+    opp1, opp_count1, remaining, killed, stp, fills = _match(
+        config, opp0, opp_count0, s, op.price, op.volume, op.kind, op.uid
     )
 
     # --- ADD: rest the remainder: a LIMIT add's, or a POST_ONLY add that
     # took nothing (types.may_rest); a MARKET or IOC remainder is dropped
     # and a killed FOK leaves nothing to rest (extensions: the reference
-    # has limit orders only) ------------------------------------------------
+    # has limit orders only); nor does what self-trade prevention stopped --
     do_rest = is_add & (remaining > 0) & may_rest(op.kind) & ~killed
     expired = is_add & (
         killed | ((op.kind == KIND_IOC) & (remaining > 0))
     )
+    if stp is not None:
+        # Stopped at its owner's order with volume left: whatever the
+        # kind, the remainder does not rest.
+        do_rest = do_rest & ~stp
     entry = _Side(
         price=op.price,
         lots=remaining,
@@ -396,6 +437,12 @@ def step_rows_impl(
         cancel_volume=jnp.where(is_del, cancel_volume, zero),
         expired=jnp.where(expired, op.kind, 0).astype(jnp.int32),
     )
+    if stp is not None:
+        # Such an add counts under the venue's rule alone, whatever its
+        # kind (a killed add did nothing and keeps its kind's count).
+        out = out._replace(
+            expired=jnp.where(is_add & stp, EXPIRED_STP, out.expired)
+        )
     return new_buy, new_sale, new_buy_count, new_sale_count, new_next_seq, out
 
 

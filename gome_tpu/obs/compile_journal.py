@@ -194,12 +194,13 @@ def _scatter_eqn_count(dtype_name: str, n_rows: int, t_grid: int) -> int:
 
 
 # gomesurface: combo(replay)
-def frame_combo_detail(dtype_name: str, combo: tuple) -> dict:
+def frame_combo_detail(dtype_name: str, combo: tuple, n_totals: int) -> dict:
     """Analytic cost block for one frame dispatch combo
     (engine.frames.submit_frame records tuples of (n_rows, t_grid, cap_g,
     dense, m_pad, k_rec, e_fills, e_cancels, totals_len)): grid cell
     count, host->device op-grid bytes, the step's [R, T, K] record-tensor
-    bytes, the frame-level fetch-buffer bytes, and the scatter jaxpr's op
+    bytes, the frame-level fetch-buffer bytes (its totals n_totals wide:
+    frames.n_totals of the engine's config), and the scatter jaxpr's op
     count. Pure arithmetic plus one memoized abstract trace — called only
     on an enabled-journal compile MISS, which already paid a full
     trace+compile."""
@@ -230,10 +231,9 @@ def frame_combo_detail(dtype_name: str, combo: tuple) -> dict:
         # step record tensors [R, T, K] x 5 (dominant step output)
         "record_bytes": int(cells * k_rec * _RECORD_TENSORS * itemsize),
         # frame-level compaction buffers (fills[7, e_f] + cancels[2, e_c]
-        # + totals[len, 7]: frames.N_TOTALS) — the device->host fetch
-        # ceiling
+        # + totals[len, n_totals]) — the device->host fetch ceiling
         "fetch_buffer_bytes": int(
-            (7 * e_fills + 2 * e_cancels) * wide + totals_len * 7 * 4
+            (7 * e_fills + 2 * e_cancels) * wide + totals_len * n_totals * 4
         ),
         "scatter_jaxpr_eqns": _scatter_eqn_count(
             dtype_name, int(n_rows), int(t_grid)

@@ -15,7 +15,8 @@ Deliberate behavioral choices (SURVEY §2.3):
     (engine.go:92-98); a miss emits nothing.
   * cancel-before-consume race: a DEL clears the pre-pool marker, so the
     queued ADD is dropped at consume time (engine.go:58-62,88-90).
-  * no self-trade prevention (engine.go:138-198 never compares uuids).
+  * no self-trade prevention (engine.go:138-198 never compares uuids),
+    unless the venue states a rule (an extension, below).
   * event field semantics per types.MatchResult docstring.
   * the middle-delete hash leak (nodelink.go:151-164, SURVEY §2.3.1) is
     unobservable in the event stream and is not replicated.
@@ -40,6 +41,29 @@ Extensions beyond the reference (flagged explicitly):
     A cancel ignores the kind; a cancel aimed at an order that never rested
     misses. StepStats counts what expired: expired_ioc (an IOC remainder
     dropped), fok_killed, post_only_blocked.
+  * Self-trade prevention (PR 41; OracleEngine's `self_trade`, the
+    config's engine.self_trade, types.SELF_TRADE_RULES). Under "none", the
+    default and the reference's, the owners are never compared. Under
+    "expire_taker" (Binance spot's EXPIRE_TAKER, CME's cancel newest,
+    Coinbase's `cn`), with C as above and j the first order of C whose
+    uuid is the add's own:
+      - the add fills down C ahead of j exactly as without the rule (same
+        events). If volume is left when it arrives at j, the remainder
+        expires: it does not trade with j, does not pass j, does not rest,
+        makes no event and is no cancel target. If the volume runs out
+        ahead of j, or C holds no own order, nothing differs. j is never
+        touched.
+      - LIMIT, MARKET, IOC: as above (a MARKET or IOC remainder was
+        dropped anyway: only the stop at j differs).
+      - FOK: `avail` counts only the lots ahead of j; less than the volume
+        kills it as before (nothing happens).
+      - POST_ONLY: a non-empty C blocks it, whoever owns C's first order
+        (a post-only add whose only crossing order is its owner's must not
+        rest into a crossed book).
+    Own means equal uuid. A cancel takes no notice of the owner.
+    StepStats.stp_expired counts the adds, of any kind, that stopped at j
+    with volume left; such an IOC add is not in expired_ioc too, and a
+    killed FOK or a blocked POST_ONLY add keeps its kind's count.
 
 Out-of-contract inputs (deliberate divergences on degenerate streams):
   * volume <= 0 ADDs: the reference emits a MatchVolume=0 pseudo-event when
@@ -65,6 +89,7 @@ from ..types import (
     OrderType,
     Side,
     StepStats,
+    check_self_trade,
     may_rest,
     snapshot_of,
 )
@@ -188,7 +213,9 @@ class OracleEngine:
     Events accumulate in `self.events` in emission order — the parity stream.
     """
 
-    def __init__(self) -> None:
+    def __init__(self, self_trade: str = "none") -> None:
+        check_self_trade(self_trade)
+        self.self_trade = self_trade
         self.books: dict[str, SymbolBook] = {}
         self.pre_pool: set[tuple[str, str, str]] = set()
         self.queue: collections.deque[Order] = collections.deque()
@@ -248,19 +275,30 @@ class OracleEngine:
         if kind is OrderType.POST_ONLY and crossing:
             self.stats.post_only_blocked += 1  # would take: nothing happens
             return
+        stp = self.self_trade == "expire_taker"
         if kind is OrderType.FOK:
-            opp = order.side.opposite
-            avail = sum(book.level_volume(opp, p) for p in crossing)
+            avail = 0
+            for maker in self._crossing_orders(book, order, crossing):
+                if stp and maker.uuid == order.uuid:
+                    break  # only the lots ahead of its owner's order count
+                avail += maker.volume
             if avail < order.volume:
-                self.stats.fok_killed += 1  # all of C cannot fill it
+                self.stats.fok_killed += 1  # C, as cut, cannot fill it
                 return
         remaining = order.volume
+        stopped = False
         for level_price in crossing:
-            remaining = self._match_level(book, order, level_price, remaining)
-            if remaining <= 0:
+            remaining, stopped = self._match_level(
+                book, order, level_price, remaining, stp
+            )
+            if remaining <= 0 or stopped:
                 break
 
-        if remaining > 0 and may_rest(kind):
+        if stopped:
+            # Arrived at its owner's order with volume left: the remainder
+            # expires, whatever the kind. No rest, no event.
+            self.stats.stp_expired += 1
+        elif remaining > 0 and may_rest(kind):
             # Remainder rests at its own limit price (engine.go:69-83).
             book.rest(order, remaining)
         elif remaining > 0 and kind is OrderType.IOC:
@@ -268,14 +306,32 @@ class OracleEngine:
         # A MARKET or IOC remainder is dropped (extensions; the reference
         # has neither): no event.
 
+    @staticmethod
+    def _crossing_orders(book: SymbolBook, taker: Order, crossing):
+        """The resting orders of the crossing levels, in priority order."""
+        opp = book.levels[taker.side.opposite]
+        for level_price in crossing:
+            yield from opp[level_price]
+
     def _match_level(
-        self, book: SymbolBook, taker: Order, level_price: int, remaining: int
-    ) -> int:
+        self,
+        book: SymbolBook,
+        taker: Order,
+        level_price: int,
+        remaining: int,
+        stp: bool = False,
+    ) -> tuple[int, bool]:
         """MatchOrder's FIFO walk at one price level (engine.go:138-198),
-        iterative where the reference recurses (engine.go:161)."""
+        iterative where the reference recurses (engine.go:161). Returns the
+        taker's remaining volume and, under `stp`, whether the walk stopped
+        at a resting order of the taker's own uuid with volume left."""
         queue = book.levels[taker.side.opposite].get(level_price)
+        stopped = False
         while remaining > 0 and queue:
             maker = queue[0]
+            if stp and maker.uuid == taker.uuid:
+                stopped = True
+                break
             if remaining >= maker.volume:
                 # Full maker fill (engine.go:145-175; diff>0 and diff==0
                 # branches are identical observably).
@@ -312,7 +368,7 @@ class OracleEngine:
                     match_volume=match_volume,
                 )
         book.remove_empty_level(taker.side.opposite, level_price)
-        return remaining
+        return remaining, stopped
 
     # -- cancellation (engine.go:87-116) -----------------------------------
     def delete_order(self, order: Order) -> None:

@@ -263,6 +263,7 @@ def restore_from_redis(engine, store, symbols: list[str] | None = None) -> int:
         "uids": uid_strings,
         "cap": cap,
         "max_fills": batch.config.max_fills,
+        "self_trade": batch.config.self_trade,
         "dtype": val_dtype,
         "n_slots": n_slots,
         "max_t": batch.max_t,

@@ -319,10 +319,12 @@ class EngineService:
             ),
         )
         (log.warning if tracing.slow() else log.info)(
-            "orders: %d applied; adds by kind %s; expired: %d IOC "
+            "orders: %d applied; adds by kind %s; %d stopped at their "
+            "owner's order (self_trade %s); expired: %d IOC "
             "remainders dropped, %d FOK killed, %d POST_ONLY blocked",
             st.orders,
             {OrderType(k).name: n for k, n in sorted(st.adds_by_kind.items())},
+            st.stp_expired, self.engine.batch.config.self_trade,
             st.expired_ioc, st.fok_killed, st.post_only_blocked,
         )
         if self.persist is not None:

@@ -19,6 +19,21 @@ import numpy as np
 # truth for config validation and BatchEngine selection).
 KERNELS = ("scan", "pallas")
 
+# The self-trade prevention rules a venue can state (engine.self_trade in
+# the config, BookConfig.self_trade on the device, OracleEngine's argument):
+# "none", the reference's, lets an account trade with itself;
+# "expire_taker" stops an add at its owner's first resting order and
+# expires what is left of it (oracle/book.py's docstring has the rule).
+SELF_TRADE_RULES = ("none", "expire_taker")
+
+
+def check_self_trade(rule) -> None:
+    if rule not in SELF_TRADE_RULES:
+        raise ValueError(
+            f"engine.self_trade must be one of {SELF_TRADE_RULES}, "
+            f"got {rule!r}"
+        )
+
 
 class Side(enum.IntEnum):
     """api/order.proto:4-7 — TransactionType {BUY=0, SALE=1}."""
@@ -165,6 +180,9 @@ class StepStats:
     expired_ioc: int = 0
     fok_killed: int = 0
     post_only_blocked: int = 0
+    # Adds of any kind that stopped at their owner's resting order with
+    # volume left (self-trade prevention, rule expire_taker).
+    stp_expired: int = 0
 
 
 def snapshot_of(order: Order, volume: int | None = None) -> OrderSnapshot:

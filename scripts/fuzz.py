@@ -6,7 +6,11 @@ run a batch through the one frame packer: the list form (exact), the
 encoded frame on the compacted fast path, and ORDER frames through
 MatchEngine admission + the cross-frame device pipeline).
 
-    python scripts/fuzz.py [n_cases] [seed0] [--tpu]
+    python scripts/fuzz.py [n_cases] [seed0] [--tpu] [--stp]
+
+--stp runs every case under the venue rule engine.self_trade
+"expire_taker", engine and oracle alike (the flow has 3 users, so it
+self-crosses without steering).
 
 Prints one line per case; exits nonzero on the first divergence with a
 reproducer description. Runs on CPU by default — the fuzz target is
@@ -45,7 +49,18 @@ def configure(tpu: bool = False) -> None:
     jax.config.update("jax_enable_x64", True)
 
 
-def run_case(seed: int) -> str:
+def _expired(stats) -> tuple:
+    return (
+        stats.expired_ioc, stats.fok_killed, stats.post_only_blocked,
+        stats.stp_expired,
+    )
+
+
+def run_case(seed: int, self_trade: str = "none") -> str:
+    """One case. `self_trade` is the venue's rule (types.SELF_TRADE_RULES)
+    for the engine and the oracle alike: the flow draws its owners from 3
+    users, so it self-crosses without steering. It is an argument and not
+    a draw, so that a seed's flow is the same under both rules."""
     import jax.numpy as jnp
 
     from gome_tpu.engine import BatchEngine, BookConfig
@@ -119,7 +134,7 @@ def run_case(seed: int) -> str:
             # cancel targets; one aimed at an IOC or FOK add always misses
             live.append((sym, str(i), side, price))
 
-    oracle = OracleEngine()
+    oracle = OracleEngine(self_trade=self_trade)
     expected = []
     for o in orders:
         expected.extend(oracle.process(o))
@@ -141,7 +156,8 @@ def run_case(seed: int) -> str:
 
         depth = int(rng.choice([1, 2, 3]))
         meng = MatchEngine(
-            config=BookConfig(cap=cap, max_fills=max_fills, dtype=dtype),
+            config=BookConfig(cap=cap, max_fills=max_fills, dtype=dtype,
+                              self_trade=self_trade),
             n_slots=n_slots, max_t=max_t, kernel=kernel,
         )
         engine = meng.batch
@@ -157,7 +173,8 @@ def run_case(seed: int) -> str:
             got.extend(batch.to_results())
     else:
         engine = BatchEngine(
-            BookConfig(cap=cap, max_fills=max_fills, dtype=dtype),
+            BookConfig(cap=cap, max_fills=max_fills, dtype=dtype,
+                       self_trade=self_trade),
             n_slots=n_slots, max_t=max_t, kernel=kernel,
         )
         got = []
@@ -178,7 +195,7 @@ def run_case(seed: int) -> str:
         f"dtype={np.dtype(dtype).name} mode={mode}"
         f"{f'(depth={depth})' if depth else ''} "
         f"kernel={effective} base={base_price} band={band} n={n_orders} "
-        f"chunk={chunk} tif={tif_p}"
+        f"chunk={chunk} tif={tif_p} self_trade={self_trade}"
     )
     if got != expected:
         first = next(
@@ -192,17 +209,11 @@ def run_case(seed: int) -> str:
             f"{expected[first] if first < len(expected) else '<none>'}"
         )
     engine.verify_books()
-    expired = (
-        engine.stats.expired_ioc, engine.stats.fok_killed,
-        engine.stats.post_only_blocked,
-    )
-    want = (
-        oracle.stats.expired_ioc, oracle.stats.fok_killed,
-        oracle.stats.post_only_blocked,
-    )
+    expired = _expired(engine.stats)
+    want = _expired(oracle.stats)
     if expired != want:
         raise AssertionError(
-            f"DIVERGENCE [{desc}] expired (IOC, FOK, POST_ONLY) "
+            f"DIVERGENCE [{desc}] expired (IOC, FOK, POST_ONLY, STP) "
             f"{expired} vs the oracle's {want}"
         )
     return (
@@ -305,17 +316,11 @@ def run_sim_case(seed: int) -> str:
             f"{expected[first] if first < len(expected) else '<none>'}"
         )
     engine.verify_books()
-    expired = (
-        engine.stats.expired_ioc, engine.stats.fok_killed,
-        engine.stats.post_only_blocked,
-    )
-    want = (
-        oracle.stats.expired_ioc, oracle.stats.fok_killed,
-        oracle.stats.post_only_blocked,
-    )
+    expired = _expired(engine.stats)
+    want = _expired(oracle.stats)
     if expired != want:
         raise AssertionError(
-            f"DIVERGENCE [{desc}] expired (IOC, FOK, POST_ONLY) "
+            f"DIVERGENCE [{desc}] expired (IOC, FOK, POST_ONLY, STP) "
             f"{expired} vs the oracle's {want}"
         )
     return (
@@ -329,10 +334,13 @@ def run_sim_case(seed: int) -> str:
 def main():
     configure(tpu="--tpu" in sys.argv)
     sim = "--sim" in sys.argv
-    args = [a for a in sys.argv[1:] if a not in ("--tpu", "--sim")]
+    stp = "--stp" in sys.argv  # the venue's rule expire_taker (run_case)
+    args = [a for a in sys.argv[1:] if a not in ("--tpu", "--sim", "--stp")]
     n = int(args[0]) if len(args) > 0 else 30
     seed0 = int(args[1]) if len(args) > 1 else 1000
     case = run_sim_case if sim else run_case
+    if stp and not sim:
+        case = lambda s: run_case(s, self_trade="expire_taker")
     for s in range(seed0, seed0 + n):
         print(case(s), flush=True)
     print(f"ALL {n} CASES PASSED")

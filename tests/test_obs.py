@@ -214,7 +214,7 @@ def test_disabled_journal_allocates_nothing():
 
 def test_frame_combo_detail_arithmetic():
     combo = (8, 16, 64, True, 256, 4, 512, 64, 8)
-    d = frame_combo_detail("int32", combo)
+    d = frame_combo_detail("int32", combo, 7)
     assert d["grid_cells"] == 128
     assert d["upload_bytes"] == 256 * (7 * 4 + 4)
     assert d["ops_grid_bytes"] == 128 * (3 * 4 + 4 * 4)

@@ -509,9 +509,10 @@ def test_expired_in_the_totals_is_the_oracles_count_and_buffers_are_reused(
         int(k): sum(o.order_type is k for o in adds) for k in OrderType}
     # PR 33's book-keeping: a frame's buffers are handed back and taken again
     assert st.fast_frames == 10 and st.fast_frames_reused >= 6
-    assert frames.N_TOTALS == 7
+    width = frames.n_totals(meng.batch.config)
+    assert width == 7
     held = [s for sets in meng.batch._event_buffers.values() for s in sets]
-    assert held and all(s[2].shape[1] == frames.N_TOTALS for s in held)
+    assert held and all(s[2].shape[1] == width for s in held)
 
 
 def test_the_order_kind_counters_are_on_metrics():

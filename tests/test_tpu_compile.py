@@ -38,8 +38,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_text(one_chip, n_slots, rows, t, cap, dense):
-    cfg = BookConfig(cap=cap, max_fills=16, dtype=jnp.int32)
+def _compiled_text(one_chip, n_slots, rows, t, cap, dense,
+                   self_trade="none"):
+    cfg = BookConfig(cap=cap, max_fills=16, dtype=jnp.int32,
+                     self_trade=self_trade)
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     books = jax.tree.map(place, jax.eval_shape(
         lambda: jax.vmap(lambda _: init_book(cfg))(jnp.arange(n_slots))
@@ -73,6 +75,26 @@ def test_the_kernel_compiles_for_v5e_under_its_geometrys_name(
         one_chip, n_slots, rows, t, cap, dense, name):
     calls = [ln.strip() for ln in
              _compiled_text(one_chip, n_slots, rows, t, cap, dense).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert calls[0].startswith(f"%{name}."), calls[0][:120]
+
+
+@pytest.mark.parametrize("n_slots, rows, t, cap, dense, name", [
+    # spot10k_stp: the wide class-64 dense grid, the deep band's class-256
+    # grid, and the widest class a lane can grow into, as a full grid
+    (10240, 2048, 256, 64, True, "match_dense_r2048_t256_c64"),
+    (10240, 32, 512, 256, True, "match_dense_r32_t512_c256"),
+    (8, 8, 256, 1024, False, "match_full_r8_t256_c1024"),
+])
+def test_the_kernel_compiles_for_v5e_under_self_trade_prevention(
+        one_chip, n_slots, rows, t, cap, dense, name):
+    """The venue rule expire_taker (step._match: one compare against the
+    op's uid and one masked integer minimum over [cap]) lowers in Mosaic at
+    every slot width in use, in the one kernel, under the same name."""
+    text = _compiled_text(one_chip, n_slots, rows, t, cap, dense,
+                          self_trade="expire_taker")
+    calls = [ln.strip() for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1
     assert calls[0].startswith(f"%{name}."), calls[0][:120]
@@ -159,7 +181,7 @@ def test_a_small_frames_grid_compiles_for_v5e_as_one_program(
             shape((rows,)) if dense else None,
             shape((len(frames._FILL_FIELDS), e_fills)),
             shape((len(frames._CANCEL_FIELDS), e_fills)),  # the op class
-            shape((8, frames.N_TOTALS)), np.int32(0),
+            shape((8, frames.n_totals(plan.cfg))), np.int32(0),
         ).compile().as_text()
     calls = [ln.strip() for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
