@@ -26,6 +26,9 @@ _wall_ns = time.monotonic_ns
 #: Publish instants a queue keeps at most. A backlog deeper than this loses
 #: its oldest, and their hand-offs go unrecorded.
 PUBLISH_STAMPS = 4096
+#: The timed look: how long an idle reader sleeps between two looks at a queue
+#: object that does not hear its publisher (Queue.wait_idle).
+IDLE_LOOK_S = 0.001
 
 
 class _PublishStamps:
@@ -204,7 +207,51 @@ class Queue(abc.ABC):
         }
 
     def _wait_for_publish(self, timeout_s: float) -> None:
+        """The timed look of a backend with no condition: nobody can wake
+        its reader, so it sleeps the interval out and looks again."""
         time.sleep(timeout_s)
+
+    def wait_idle(self, start: int, bound_s: float) -> str:
+        """An idle reader's sleep between two polls: until a message stands
+        at or past `start`, or wake(), or `bound_s` has passed; returns which
+        ("publish", "wake", "timer"). How long it may sleep follows what this
+        queue object itself has observed: one that hears every publish it can
+        be asked to read (_Waitable._hears_publisher) sleeps on the condition
+        that the publish notifies, up to the bound; any other sleeps one
+        timed look, IDLE_LOOK_S, and leaves the look to its caller's next
+        poll. This backend has no condition, so it is the second kind."""
+        self._wait_for_publish(min(bound_s, IDLE_LOOK_S))
+        return self._count_wakeup("timer")
+
+    def wake(self) -> None:
+        """End this queue's wait_idle now, or the next one at once: whoever
+        has work for a sleeping reader calls it (a stop, a subscriber's
+        hand-over). Nothing to do where the reader sleeps timed looks."""
+
+    def idle_wakeups(self) -> dict[str, int]:
+        """wait_idle calls that slept or took a wake(), by what ended them
+        (gome_bus_idle_wakeups_total{queue=,woken_by=}; by the queue's name,
+        over the process). "timer" is a reader that woke for nothing."""
+        return {by: c.value() for by, c in self._idle_counters.items()}
+
+    def _count_wakeup(self, by: str) -> str:
+        self._idle_counters[by].inc()
+        return by
+
+    @functools.cached_property
+    def _idle_counters(self) -> dict:
+        from ..utils.metrics import REGISTRY  # lazy, as _poll_counters
+
+        return {
+            by: REGISTRY.counter(
+                "gome_bus_idle_wakeups_total",
+                "wake-ups of an idle reader's wait, by what ended it: a "
+                "publish, a wake() (stop, a subscriber's hand-over), or "
+                "its timer (once a wake-up, never per message)",
+                labels={"queue": self.name, "woken_by": by},
+            )
+            for by in ("publish", "wake", "timer")
+        }
 
     @functools.cached_property
     def _stamps(self) -> _PublishStamps:
@@ -264,11 +311,15 @@ def export_queue_metrics(queue: Queue, registry=None) -> None:
 
 
 class _Waitable:
-    """Mixin: condition-variable publish notification so poll_batch wakes
-    immediately instead of sleeping the full poll interval."""
+    """Mixin: a condition that every publish notifies. poll_batch's wait
+    inside its window ends at the publish instead of at the poll interval,
+    and an idle reader of a queue object that hears its publisher sleeps on
+    it until there is something to read (wait_idle)."""
 
     def _init_wait(self):
         self._cond = threading.Condition()
+        with self._cond:
+            self._woken = False  # guarded by self._cond: a wake() not yet taken
 
     def _notify_publish(self, first: int | None = None, n: int = 1):
         """Wake the pollers; `first`, `n`: the offsets a publish that has
@@ -281,3 +332,46 @@ class _Waitable:
     def _wait_for_publish(self, timeout_s: float) -> None:
         with self._cond:
             self._cond.wait(timeout_s)
+
+    def _hears_publisher(self) -> bool:
+        """Whether every message this object can be asked to read comes
+        through its own publish, which notifies the condition: told from
+        what the object has done, by nothing else. Not so by default (an
+        AmqpQueue's reads ask the broker)."""
+        return False
+
+    def wait_idle(self, start: int, bound_s: float) -> str:
+        # Queue.wait_idle has the contract. The look at the end offset and
+        # the wait happen under the lock that the publisher's notify takes:
+        # a publish between the two finds the reader waiting and wakes it.
+        # An object that does not hear its publisher is not asked for its
+        # end here (an AMQP look waits for the reader thread, which notifies
+        # under this lock): it sleeps one timed look.
+        hears = self._hears_publisher()
+        deadline = time.monotonic() + (
+            bound_s if hears else min(bound_s, IDLE_LOOK_S)
+        )
+        slept = False
+        with self._cond:
+            while True:
+                if self._woken:
+                    self._woken = False
+                    by = "wake"
+                    break
+                if hears and self.end_offset() > start:
+                    if not slept:
+                        return "publish"  # it stood there: nobody was woken
+                    by = "publish"
+                    break
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    by = "timer"
+                    break
+                self._cond.wait(left)
+                slept = True
+        return self._count_wakeup(by)
+
+    def wake(self) -> None:
+        with self._cond:
+            self._woken = True
+            self._cond.notify_all()

@@ -16,15 +16,17 @@ things, told apart by its own history and by nothing else:
       no system call. The single-process venue is this case on both queues:
       gateway, consumer and match feed share one object per queue
       (make_bus), whose publish also wakes the pollers, so an idle
-      poll_batch never asks the filesystem anything.
+      poll_batch never asks the filesystem anything, and an idle reader
+      sleeps on that publish (Queue.wait_idle) instead of on a timer.
   a reader — an object that has not appended since it was opened, or since
       its last truncate_to (recovery's; the object proves itself again at
       its next append). It TAILS the log across processes: every read_from
       and end_offset looks at the file (_refresh_locked: one stat, then a
       scan of what another process appended; an incomplete tail record is
       the live writer mid-append and is skipped, not truncated). Nobody
-      notifies it, so it looks at every read — the split gateway/consumer
-      fleet topology runs on exactly this.
+      notifies it, so it looks at every read, and its idle reader sleeps one
+      timed look between polls — the split gateway/consumer fleet topology
+      runs on exactly this.
 
 Record bodies are read from the file by either kind. How often either kind
 looked is counted: gome_bus_log_looks_total{queue=} (log_looks()).
@@ -177,6 +179,10 @@ class FileQueue(_Waitable, Queue):
             off = len(self._positions) - 1
         self._notify_publish(off)
         return off
+
+    def _hears_publisher(self) -> bool:
+        with self._lock:
+            return self._wrote  # the log's writer: nobody else appends
 
     def read_from(self, offset: int, max_n: int) -> list[Message]:
         with self._lock:

@@ -29,6 +29,9 @@ class MemoryQueue(_Waitable, Queue):
         self._notify_publish(off)
         return off
 
+    def _hears_publisher(self) -> bool:
+        return True  # the list is this object's: only its publish appends
+
     def read_from(self, offset: int, max_n: int) -> list[Message]:
         with self._lock:
             if offset < self._base:

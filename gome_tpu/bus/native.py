@@ -104,7 +104,13 @@ class NativeFileQueue(_Waitable, Queue):
         if not self._h:
             raise RuntimeError(f"gq_open failed for {path_base}")
         self._lock = threading.Lock()
+        # FileQueue's rule (bus.filelog): True while this object's last
+        # write to the log was an append, so that it is the log's writer.
+        self._wrote = False  # single-writer: the publisher, or recovery
         self._init_wait()
+
+    def _hears_publisher(self) -> bool:
+        return self._wrote
 
     def _handle(self):
         """The open native handle; raises (instead of passing NULL into C,
@@ -133,6 +139,7 @@ class NativeFileQueue(_Waitable, Queue):
         first = self._lib.gq_publish_batch(self._handle(), buf, lengths, n)
         if first < 0:
             raise OSError("native publish failed")
+        self._wrote = True
         self._notify_publish(int(first), n)
         return int(first)
 
@@ -203,6 +210,8 @@ class NativeFileQueue(_Waitable, Queue):
             raise OSError("native rollback failed")
 
     def truncate_to(self, offset: int) -> None:
+        if offset < self.end_offset():  # a tail goes: as FileQueue
+            self._wrote = False  # proves itself again at its next append
         rc = self._lib.gq_truncate_to(self._handle(), offset)
         if rc == -1:
             raise ValueError(f"cannot truncate below committed: {offset}")

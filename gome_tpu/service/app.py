@@ -307,12 +307,15 @@ class EngineService:
             self.engine.batch.combo_count(),
             len({c[:4] for c in self.engine.batch.combos()}),
         )
-        # A file queue also says how often its reads looked at the log
-        # (bus/filelog.py): the count stops at the queue's first append.
+        # Beside them what woke each queue's idle reader (bus/base.py:
+        # "timer" is a reader that woke for nothing). A file queue also says
+        # how often its reads looked at the log (bus/filelog.py): the count
+        # stops at the queue's first append.
         (log.warning if tracing.slow() else log.info)(
             "polls that brought messages, by what ended their wait: %s",
             "; ".join(
-                f"{q.name} {q.poll_returns()}"
+                f"{q.name} {q.poll_returns()}, its idle reader woken by "
+                f"{q.idle_wakeups()}"
                 + (f", looks at the log {q.log_looks()}"
                    if isinstance(q, FileQueue) else "")
                 for q in (self.bus.order_queue, self.bus.match_queue)

@@ -504,8 +504,18 @@ class OrderConsumer:
         # busy-spinning against a dead dependency; any success resets.
         delays = None
         self.device_fault = None  # an explicit restart is a new attempt
+        q = self.bus.order_queue
         while not self._stop.is_set():
-            self.step_with_policy()
+            n = self.step_with_policy()
+            if n == 0 and not self._last_step_failed and not self._pipe:
+                # Nothing read and no frame in flight: asleep until the
+                # queue has a message past the cursor (at once if one stands
+                # there), stop() wakes it, or the span's bound has passed.
+                try:
+                    self._poll.idle(q, q.committed())
+                except Exception:  # the bus, as in a step: back off
+                    log.exception("the order queue's idle wait failed")
+                    self._last_step_failed = True
             if self._last_step_failed:
                 if delays is None:
                     delays = backoff_delays(FAULT_BACKOFF)
@@ -680,6 +690,7 @@ class OrderConsumer:
         # deadlock; concurrent stop()s serialize harmlessly.
         with self._life:
             self._stop.set()
+            self.bus.order_queue.wake()  # the loop may sleep on its queue
             if self._thread is not None:
                 self._thread.join(timeout=10)
                 self._thread = None

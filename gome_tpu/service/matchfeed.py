@@ -400,8 +400,10 @@ class MatchFeed:
                     yield from events
                     sent.note(first_us=first // 1000)
                 # Asked for what follows the chunk's last event: gRPC has
-                # the whole of it, and the feed may commit past it.
+                # the whole of it, and the feed may commit past it; it may
+                # be asleep on its queue (_loop).
                 q.handed = chunk.end
+                self.bus.match_queue.wake()
         finally:
             with self._lock:
                 self._subs.remove(q)
@@ -435,9 +437,18 @@ class MatchFeed:
         from .consumer import FAULT_BACKOFF
 
         delays = None  # backoff across consecutive failures (dead bus)
+        q = self.bus.match_queue
         while not self._stop.is_set():
             try:
-                self.run_once()
+                # Looked up on the instance at every pass: a harness may
+                # have put its own run_once there. One that brought nothing
+                # is followed by a sleep until the queue has a message past
+                # the read cursor (at once if one stands there: a wrapper
+                # may hold them back), a handler's hand-over or stop() wakes
+                # the feed, or the span's bound has passed; the next pass
+                # commits what was handed over meanwhile.
+                if self.run_once() == 0:
+                    self._poll.idle(q, self._sync())
                 delays = None
             except Exception:
                 log.exception("match feed batch failed")
@@ -451,6 +462,7 @@ class MatchFeed:
         # deadlock; concurrent stop()s serialize harmlessly.
         with self._life:
             self._stop.set()
+            self.bus.match_queue.wake()  # the loop may sleep on its queue
             if self._thread is not None:
                 self._thread.join(timeout=10)
                 self._thread = None

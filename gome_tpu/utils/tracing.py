@@ -13,9 +13,10 @@
                        process CPU time, and hands a closed span to an armed
                        TRACER under the taxonomy's stage name (STAGE_OF_SPAN),
                        so per-order journeys keep their batch-scoped stages.
-  poll_span(name)    — one span over consecutive empty polls of an idle loop;
-                       the poll that brings messages back is the pick-up of a
-                       queue hand-off (below).
+  poll_span(name)    — one span over consecutive empty polls of an idle loop
+                       and its sleep between them; the poll that brings
+                       messages back is the pick-up of a queue hand-off
+                       (below).
   record(name, ns)   — a stretch that no one thread held open, into the same
                        table: a hand-off between two threads, a boot's replay.
   annotate(name)     — a bare TraceAnnotation: the parent that only groups
@@ -88,7 +89,8 @@ log = get_logger("tracing")
 #: timeout on the served path (2 ms polls, the stream's 100 ms get).
 SLOW_SPAN_NS = 250_000_000
 SLOW_RING = 256
-#: A poll_span over empty polls closes at the first poll that ends this late.
+#: A poll_span over empty polls closes at the first poll that ends this late;
+#: the longest an idle reader sleeps before it looks again (poll_span.idle).
 MERGE_POLLS_NS = 100_000_000
 #: Spans that are long by arithmetic, not by a stall: the handler hands a
 #: 4,096-order frame's ~2,200 events to gRPC in 300 ms. They never enter the
@@ -198,24 +200,29 @@ def record(name: str, wall_ns: int, cpu_ns: int = 0) -> None:
 
 
 class poll_span:
-    """One span over a run of consecutive polls that came back empty: an
-    idle loop polls every couple of milliseconds, and a span per poll costs
-    a thread that has just woken ten times what it costs a running one
+    """One span over an idle stretch of a loop that polls: a poll that came
+    back empty, the loop's sleep until its queue has something for it
+    (`poller.idle(queue, start)`: the queue's wait_idle, at most
+    MERGE_POLLS_NS long), the next poll, and so on; a span per poll costs a
+    thread that has just woken ten times what it costs a running one
     (PERF.md, PR 25). `poller(fn, *args)`, or `poller.batch(queue, *args)` /
     `poller.ahead(queue, *args)` for a queue's poll_batch / read_from, makes
     one poll inside the span and closes it when the poll brought something
     back or the span is MERGE_POLLS_NS old, so it stays far under
-    SLOW_SPAN_NS unless a single poll overran: a slow poll span still means a
-    stall. A queue's poll that brings messages back is the pick-up of the
-    hand-off HANDOFF_OF_POLL names for the span (module docstring). Owned by
-    the one thread that polls."""
+    SLOW_SPAN_NS unless a single poll or sleep overran: a slow poll span
+    still means a stall. The span that closes notes `polls=` and, where the
+    loop slept in it, what ended its last sleep (`woken_by=`). A queue's poll
+    that brings messages back is the pick-up of the hand-off HANDOFF_OF_POLL
+    names for the span (module docstring). Owned by the one thread that
+    polls."""
 
-    __slots__ = ("name", "_span", "_polls", "_handoff")
+    __slots__ = ("name", "_span", "_polls", "_woken_by", "_handoff")
 
     def __init__(self, name: str):
         self.name = name
         self._span = None
         self._polls = 0
+        self._woken_by = None
         self._handoff = HANDOFF_OF_POLL.get(name)
 
     def __call__(self, poll, *args):
@@ -240,10 +247,34 @@ class poll_span:
             self._picked_up(queue, got)
         return got
 
-    def _poll(self, poll, args):
+    def idle(self, queue, start: int) -> str:
+        """The loop's sleep after a poll that brought nothing, inside the
+        span: `queue.wait_idle(start, ...)` for what is left of the span's
+        MERGE_POLLS_NS (a span that old already is closed, and the sleep
+        opens the next); returns what ended it. Not a poll: the loop polls
+        next, and that poll closes the span with its messages or by its
+        age."""
+        self._open()
+        left = MERGE_POLLS_NS - (_wall_ns() - self._span.t0_ns)
+        if left <= 0:
+            self.close()
+            self._open()
+            left = MERGE_POLLS_NS
+        try:
+            self._woken_by = queue.wait_idle(start, left / 1e9)
+        except BaseException:
+            self.close()
+            raise
+        return self._woken_by
+
+    def _open(self) -> None:
         if self._span is None:
             self._span = span(self.name).__enter__()
             self._polls = 0
+            self._woken_by = None
+
+    def _poll(self, poll, args):
+        self._open()
         try:
             got = poll(*args)
         except BaseException:
@@ -272,6 +303,8 @@ class poll_span:
         """End the open span, if any (work follows, or the loop ends)."""
         open_, self._span = self._span, None
         if open_ is not None:
+            if self._woken_by is not None:
+                meta["woken_by"] = self._woken_by
             open_.note(polls=self._polls, **meta)
             open_.__exit__(None, None, None)
 

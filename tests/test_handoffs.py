@@ -404,3 +404,192 @@ def test_a_300ms_stream_send_is_neither_kept_nor_logged(clock, caplog):
     assert [r["span"] for r in tracing.slow()] == ["frame_fetch"]
     assert len(caplog.records) == 1
     assert "span=frame_fetch" in caplog.records[0].getMessage()
+
+
+# --- the two loops asleep on their queues (ISSUE 42) -----------------------
+
+
+def _service(backend, tmp_path):
+    from gome_tpu.config import BusConfig, Config, EngineConfig, GrpcConfig
+    from gome_tpu.service import EngineService
+
+    svc = EngineService(Config(
+        grpc=GrpcConfig(host="127.0.0.1", port=0),
+        engine=EngineConfig(cap=32, n_slots=8, max_t=8, pipeline_depth=2),
+        bus=BusConfig(backend=backend, dir=str(tmp_path / "bus"),
+                      match_wire="frame"),
+    ))
+    svc.feed.log_events = False
+    return svc
+
+
+def _timers(svc) -> list[int]:
+    return [q.idle_wakeups()["timer"]
+            for q in (svc.bus.order_queue, svc.bus.match_queue)]
+
+
+def _until(done, timeout_s=30.0, step_s=0.0005) -> float:
+    """Seconds until done() held, looked at every half millisecond."""
+    t0 = time.monotonic()
+    while not done():
+        assert time.monotonic() - t0 < timeout_s
+        time.sleep(step_s)
+    return time.monotonic() - t0
+
+
+def test_an_idle_service_sleeps_and_its_poll_spans_cover_the_second(
+        tmp_path, caplog):
+    """A started service with nothing to do: each loop wakes on its timer
+    about ten times in a second (a thousand before ISSUE 42), the consumer's
+    and the feed's poll spans still cover the idle stretch, and the request
+    that ends it is picked up on the publish's own wake-up, its two queue
+    hand-offs recorded once each."""
+    from gome_tpu.api import order_pb2 as pb
+
+    svc = _service("memory", tmp_path)
+    svc.consumer.run_once()  # the first step's imports, outside the second
+    svc.consumer._poll.close()
+    svc.start()
+    try:
+        time.sleep(0.15)  # both loops asleep
+        tracing.reset()
+        before, t0 = _timers(svc), time.monotonic()
+        time.sleep(1.0)
+        woken = [b - a for a, b in zip(before, _timers(svc))]
+        assert all(n <= 15 for n in woken), woken
+        for name in ("consumer_poll", "feed_poll"):  # never a slow span
+            assert tracing.totals()[name]["longest_s"] < 0.25, name
+        reqs = [
+            pb.OrderRequest(
+                uuid="u", oid=f"o{i}", symbol="s", price=1.0, volume=1.0,
+                transaction=pb.SALE if i % 2 else pb.BUY)
+            for i in range(8)
+        ]
+        resp = svc.gateway.DoOrderBatch(pb.OrderBatchRequest(orders=reqs), None)
+        assert (resp.code, resp.accepted) == (0, 8)
+        _until(lambda: svc.feed.events_seen >= 4, timeout_s=120)
+        idle = time.monotonic() - t0
+    finally:
+        with caplog.at_level(logging.INFO, logger="gome_tpu"):
+            svc.stop()
+    (polls,) = [r.getMessage() for r in caplog.records
+                if "polls that brought messages" in r.getMessage()]
+    for q in (svc.bus.order_queue, svc.bus.match_queue):  # beside the polls
+        assert f"its idle reader woken by {q.idle_wakeups()}" in polls
+    rows = tracing.totals()
+    # The spans open at the reset count whole, those the request closed end
+    # with it: both loops were inside their poll span for the idle second.
+    for name in ("consumer_poll", "feed_poll"):
+        assert 0.95 < rows[name]["wall_s"] / 1.0 and \
+            rows[name]["wall_s"] < idle + 0.3, (name, rows[name], idle)
+    for name in ("order_queue_dwell", "match_queue_dwell"):
+        assert rows[name]["count"] == 1, (name, rows[name])
+        assert rows[name]["wall_s"] < 0.05, (name, rows[name])
+
+
+def test_stop_wakes_an_idle_consumer_and_feed(tmp_path, monkeypatch):
+    """With the sleep's bound at 30 s a stop that did not wake its sleeper
+    would wait out the join's 10 s: the best of three stops of each loop
+    returns in under 50 ms."""
+    monkeypatch.setattr(tracing, "MERGE_POLLS_NS", 30_000_000_000)
+    svc = _service("memory", tmp_path)
+    svc.consumer.run_once()
+    svc.consumer._poll.close()
+    took = {"consumer": [], "feed": []}
+    for _ in range(3):
+        for name, loop in (("consumer", svc.consumer), ("feed", svc.feed)):
+            loop.start()
+            time.sleep(0.05)  # asleep on its queue
+            t0 = time.monotonic()
+            loop.stop()
+            took[name].append(time.monotonic() - t0)
+    assert min(took["consumer"]) < 0.05 and max(took["consumer"]) < 2, took
+    assert min(took["feed"]) < 0.05 and max(took["feed"]) < 2, took
+
+
+@pytest.mark.parametrize("backend", ["memory", "file"])
+def test_a_hand_over_is_committed_at_once_with_no_further_publish(
+        backend, tmp_path, monkeypatch):
+    """The handler that has handed a chunk to gRPC whole wakes the feed, so
+    the match queue's cursor follows within milliseconds, not at the feed's
+    next timer (30 s here) nor at the next publish (there is none)."""
+    monkeypatch.setattr(tracing, "MERGE_POLLS_NS", 30_000_000_000)
+    if backend == "file":
+        make = lambda name: FileQueue(name, str(tmp_path / name), fsync=False)
+    else:
+        make = MemoryQueue
+    bus = QueueBus(make("doOrder"), make("matchOrder"))
+    feed = MatchFeed(bus, log_events=False)
+    events = _batch()
+    n = len(events)
+    got, last_at = [], []
+
+    def handler():
+        for ev in feed.subscribe():
+            got.append(ev)
+            if len(got) % n == 0:
+                last_at.append(time.monotonic())  # it asks for the next now
+
+    feed.start()
+    reader = threading.Thread(target=handler, daemon=True)
+    reader.start()
+    try:
+        _until(lambda: feed._subs)
+        took = []
+        for k in range(3):
+            bus.match_queue.publish(encode_event_frame(events, seq0=k * n))
+            _until(lambda: len(last_at) == k + 1)
+            _until(lambda: bus.match_queue.committed() == k + 1)
+            took.append(time.monotonic() - last_at[k])
+            time.sleep(0.02)  # the feed is asleep again
+            if k == 0:  # a file queue hears from its first append on
+                timers = bus.match_queue.idle_wakeups()["timer"]
+        assert min(took) < 0.01 and max(took) < 2, took
+        assert bus.match_queue.idle_wakeups()["timer"] == timers
+    finally:
+        feed.stop()
+        reader.join(timeout=5)
+
+
+def test_a_feed_held_by_its_harness_neither_sleeps_on_messages_nor_skips_them(
+        monkeypatch):
+    """benchmark/serve.py's hold-until-subscribed: run_once replaced on the
+    instance by one that returns 0 while messages wait in the match queue.
+    The loop calls what the instance holds, does not sleep on a queue that
+    is not empty at the read cursor (the sleep's bound is 30 s here), and
+    fans out every message once the wrapper lets go."""
+    monkeypatch.setattr(tracing, "MERGE_POLLS_NS", 30_000_000_000)
+    bus = QueueBus(MemoryQueue("doOrder"), MemoryQueue("matchOrder"))
+    feed = MatchFeed(bus, log_events=False)
+    events = _batch()
+    n = len(events)
+    for k in range(2):
+        bus.match_queue.publish(encode_event_frame(events, seq0=k * n))
+    inner, held, calls = feed.run_once, threading.Event(), [0]
+    held.set()
+
+    def run_once():
+        calls[0] += 1
+        if held.is_set():
+            time.sleep(0.002)
+            return 0
+        return inner()
+
+    feed.run_once = run_once
+    feed.start()
+    try:
+        time.sleep(0.3)
+        assert calls[0] >= 20, calls  # ~150: a pass every 2 ms, no sleep
+        assert feed.events_seen == 0 and bus.match_queue.committed() == 0
+        held.clear()
+        _until(lambda: bus.match_queue.committed() == 2, timeout_s=5)
+        assert feed.events_seen == 2 * n
+        assert feed.seq_state()["gaps"] == 0 == feed.seq_state()["dupes"]
+        # and with the queue empty at the cursor it sleeps, held or not
+        held.set()
+        time.sleep(0.05)
+        calls[0] = 0
+        time.sleep(0.3)
+        assert calls[0] <= 1, calls
+    finally:
+        feed.stop()

@@ -185,6 +185,51 @@ def test_a_queue_poll_that_brings_messages_notes_what_ended_its_wait(
     assert tracing.totals()["unit_queue_idle"]["count"] == 2
 
 
+def test_a_poll_spans_sleep_lies_inside_it_and_is_cut_to_its_age(
+    clock, monkeypatch
+):
+    """`poll_span.idle` is the loop's sleep between two polls (ISSUE 42):
+    inside the span, for what is left of the span's 100 ms, so that the span
+    still closes by its age; the span that closes notes what ended its last
+    sleep beside `polls`, which counts polls and not sleeps."""
+    notes = []
+    monkeypatch.setattr(
+        tracing.span, "note", lambda self, **meta: notes.append(meta))
+
+    class Sleeper:
+        name, asked = "unit", []
+
+        def wait_idle(self, start, bound_s):
+            self.asked.append((start, bound_s))
+            clock.advance(bound_s * 1e3)
+            return "timer"
+
+        def poll_batch(self, *args):
+            clock.advance(2.0)
+            return []
+
+    queue, poller = Sleeper(), tracing.poll_span("unit_sleep")
+    assert poller.batch(queue, 8, 0.002) == []  # opens the span: 2 ms
+    assert poller.idle(queue, 7) == "timer"  # the 98 ms that are left
+    assert queue.asked == [(7, pytest.approx(0.098))]
+    assert "unit_sleep" not in tracing.totals()  # still open
+    assert poller.batch(queue, 8, 0.002) == []  # 102 ms old: closed by age
+    assert notes == [{"polls": 2, "woken_by": "timer"}]
+    assert poller.idle(queue, 7) == "timer"  # opens the next: the whole 100
+    assert poller.idle(queue, 7) == "timer"  # that one is old: and the next
+    assert [b for _s, b in queue.asked[1:]] == [pytest.approx(0.1)] * 2
+    assert notes[1:] == [{"polls": 0, "woken_by": "timer"}]
+    poller.close()
+    rows = tracing.totals()["unit_sleep"]
+    assert (rows["count"], rows["wall_s"]) == (3, pytest.approx(0.302))
+    assert rows["longest_s"] == pytest.approx(0.102)
+    assert tracing.slow() == []
+    queue.wait_idle = lambda *a: (_ for _ in ()).throw(OSError("bus down"))
+    with pytest.raises(OSError):  # a sleep that raises closes its span
+        poller.idle(queue, 7)
+    assert tracing.totals()["unit_sleep"]["count"] == 4
+
+
 def test_the_table_holds_under_many_threads():
     """More threads than cores, a short switch interval: every span of
     every thread is counted once and the families' children are made once."""
