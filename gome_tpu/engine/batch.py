@@ -51,6 +51,7 @@ from .book import (
     BookState,
     DeviceOp,
     StepOutput,
+    ensure_dtype_usable,
     grow_books,
     init_books,
 )
@@ -623,9 +624,10 @@ class BookCut:
         self.rewinds = rewinds
 
     def arrays(self) -> dict[str, np.ndarray]:
-        """Books and per-lane vectors as host arrays in the one-chip order
-        (lane = interner id - 1), whatever row of whatever chip holds a
-        lane: a snapshot restores into any mesh, or none."""
+        """Books and per-lane vectors of the venue's lanes as host arrays
+        in the one-chip order (lane = interner id - 1), whatever row of
+        whatever chip holds a lane and however many rows the stack was
+        provisioned to: a snapshot restores into any mesh, or none."""
         out = {k: np.asarray(v) for k, v in self.books._asdict().items()}
         out.update(self.lanes)
         if self.rows is not None:
@@ -703,13 +705,26 @@ class BatchEngine:
         (engine.placement), so a Zipf head that arrives first spreads
         evenly. What leaves the engine keeps the one-chip order, lane =
         interner id - 1: the events' symbol_id, export_state / import_state,
-        lane_books and symbol_lane."""
+        lane_books and symbol_lane.
+
+        n_slots is the venue's lanes: one a symbol, what the interner,
+        growth, count_ub, snapshots and every view count in. Under
+        kernel="pallas" the device's lane axis (the book stack, a full
+        grid) is provisioned to lane_rows, the compiled kernel's row floor
+        at or over it (ops.blockable_rows; under a mesh per shard), so
+        every n_slots has a compiled full grid. The rows past the venue's
+        lanes hold empty books and never take an op; where n_slots is
+        itself blockable (8, 10,240) lane_rows is n_slots, as it is for an
+        engine that never runs the kernel."""
         if kernel not in KERNELS:
             raise ValueError(f"kernel must be one of {KERNELS}, got {kernel!r}")
         if config.cap > max_cap:
             raise ValueError(f"cap {config.cap} exceeds max_cap {max_cap}")
         if n_slots > max_slots:
             raise ValueError(f"n_slots {n_slots} exceeds max_slots {max_slots}")
+        # (before the row floor below brings the kernel's module in: see
+        # ensure_dtype_usable)
+        ensure_dtype_usable(config.dtype)
         self.config = config
         self.n_slots = n_slots
         self.max_t = max_t
@@ -736,8 +751,9 @@ class BatchEngine:
         # nothing else ever raises a count, so base+extra is provably an
         # upper bound). It is a PERFORMANCE hint only: an underestimate is
         # caught on device by _guard_capped and re-run deeper.
-        self._ub_base = np.zeros(n_slots, np.int64)
-        self._ub_extra = np.zeros(n_slots, np.int64)
+        span = self.lane_span
+        self._ub_base = np.zeros(span, np.int64)
+        self._ub_extra = np.zeros(span, np.int64)
         # Compaction-buffer ratchets (frames._compact_sizes): grow-only
         # fetch-buffer sizes, keyed by the grid's pow2 op-count class. A
         # frame can contain grids of wildly different sizes (a Zipf flow
@@ -777,7 +793,7 @@ class BatchEngine:
                     )
         self._sharded_steppers: dict = {}  # BookConfig -> jitted step
         self._sharded_dense_steppers: dict = {}  # BookConfig -> dense step
-        self.books = self._place(init_books(config, n_slots))
+        self.books = self._place(init_books(config, self.lane_rows))
         from .nativehost import make_interner
 
         from ..utils.cache import IdentityCache
@@ -797,13 +813,13 @@ class BatchEngine:
         # windowed-ladder re-centering of SURVEY §5.7, done at the host
         # boundary where it costs one subtract. int64 books keep base 0.
         self._rebase = jnp.dtype(config.dtype).itemsize <= 4
-        self.price_base = np.zeros(n_slots, np.int64)
-        self._base_set = np.zeros(n_slots, bool)
+        self.price_base = np.zeros(span, np.int64)
+        self._base_set = np.zeros(span, bool)
         # Conservative absolute-price envelope per lane (grows only): the
         # recenter check proves every price the lane has EVER admitted still
         # fits the int32 window under a new base, without a device scan.
-        self._env_lo = np.zeros(n_slots, np.int64)
-        self._env_hi = np.zeros(n_slots, np.int64)
+        self._env_lo = np.zeros(span, np.int64)
+        self._env_hi = np.zeros(span, np.int64)
         # _restore() calls: a cut taken before one no longer says what the
         # books were after its frame (cut_is_current).
         self._rewinds = 0
@@ -811,6 +827,43 @@ class BatchEngine:
     # Admission window around the current base; recenter when exceeded.
     REBASE_LIMIT = 1 << 30
     _INT32_SAFE = (1 << 31) - 2
+
+    def _rows_for(self, n_slots: int) -> int:
+        """Rows of the device's lane axis that hold n_slots lanes: where the
+        engine runs the kernel, its row floor (ops.blockable_rows), under a
+        mesh for each shard's block."""
+        if self.kernel != "pallas":
+            return n_slots
+        from ..ops import blockable_rows
+
+        d = 1 if self.mesh is None else self.mesh.size
+        return d * blockable_rows(n_slots // d)
+
+    def _span_for(self, n_slots: int) -> int:
+        """Length of the per-lane host vectors of n_slots lanes: what a
+        lane indexes. Without a mesh a lane is its symbol's place in
+        arrival order, under one its row of the stack (_lane_of)."""
+        return n_slots if self.mesh is None else self._rows_for(n_slots)
+
+    @property
+    def lane_rows(self) -> int:
+        """Provisioned rows of the book stack and of a full grid."""
+        return self._rows_for(self.n_slots)
+
+    @property
+    def lane_span(self) -> int:
+        """Length of price_base, count_ub and the other per-lane host
+        vectors (_span_for the venue's lanes)."""
+        return self._span_for(self.n_slots)
+
+    def _venue_rows(self):
+        """The stack's rows that hold the venue's lanes, in the one-chip
+        order (lane = interner id - 1): what a cut, a view or a restore
+        indexes the stack by. None where the stack is just those, in that
+        order (no mesh, n_slots its own row floor)."""
+        if self.mesh is None and self.lane_rows == self.n_slots:
+            return None
+        return self._lane_of(np.arange(self.n_slots))
 
     def _place(self, books: BookState) -> BookState:
         """Pin the lane axis across the mesh (no-op without one)."""
@@ -826,37 +879,39 @@ class BatchEngine:
         array; the identity without a mesh."""
         if self.mesh is None:
             return arrival
-        return placement.lane_of(arrival, self.n_slots, self.mesh.size)
+        return placement.lane_of(arrival, self.lane_rows, self.mesh.size)
 
     def _symbol_ids(self, lanes):
         """Inverse of _lane_of: the one-chip lane (interner id - 1) that
         events and snapshots name a symbol by."""
         if self.mesh is None:
             return lanes
-        return placement.arrival_of(lanes, self.n_slots, self.mesh.size)
+        return placement.arrival_of(lanes, self.lane_rows, self.mesh.size)
 
-    def _relayout(self, a, n_slots: int, xp=np):
-        """A per-lane array laid out for len(a) lanes, laid out for n_slots:
-        padded (or cut) at the end without a mesh; under one every shard's
-        block widens, so every lane moves, through arrival order. Host
-        arrays, or with xp=jnp a leaf of the device book stack."""
+    def _relayout(self, a, rows: int, xp=np):
+        """A per-lane array laid out for a stack of len(a) rows, laid out
+        for one of `rows` (the same array where they are equal: a venue
+        that grows inside its row floor moves nothing): padded (or cut) at
+        the end without a mesh; under one every shard's block widens, so
+        every lane moves, through arrival order. Host arrays, or with
+        xp=jnp a leaf of the device book stack."""
         old = len(a)
-        if old == n_slots:
+        if old == rows:
             return a
         if self.mesh is not None:
             d = self.mesh.size
             a = a[placement.lane_of(np.arange(old), old, d)]
-        a = a[:n_slots] if old > n_slots else xp.pad(
-            a, [(0, n_slots - old)] + [(0, 0)] * (a.ndim - 1)
+        a = a[:rows] if old > rows else xp.pad(
+            a, [(0, rows - old)] + [(0, 0)] * (a.ndim - 1)
         )
         if self.mesh is not None:
-            a = a[placement.arrival_of(np.arange(n_slots), n_slots, d)]
+            a = a[placement.arrival_of(np.arange(rows), rows, d)]
         return a
 
-    def _grow_base_arrays(self, new_slots: int) -> None:
+    def _grow_base_arrays(self, rows: int) -> None:
         for name in ("price_base", "_base_set", "_env_lo", "_env_hi",
                      "_ub_base", "_ub_extra"):
-            setattr(self, name, self._relayout(getattr(self, name), new_slots))
+            setattr(self, name, self._relayout(getattr(self, name), rows))
 
     # -- resting-count upper bound (cap-class selection) -------------------
     def count_ub(self) -> np.ndarray:
@@ -866,7 +921,7 @@ class BatchEngine:
     def note_packed_adds(self, add_counts: np.ndarray) -> None:
         """Record a packed batch's per-lane limit-ADD counts (each may rest
         at most once, keeping count_ub an upper bound). add_counts is
-        [n_slots] at pack time; callers keep it for _note_exact_counts."""
+        [lane_span] at pack time; callers keep it for _note_exact_counts."""
         self._ub_extra[: len(add_counts)] += add_counts
 
     def _note_exact_counts(self, counts_max, resolved_adds=None) -> None:
@@ -876,9 +931,10 @@ class BatchEngine:
         on top (the frame pipeline resolves FIFO, so extra minus B's share
         is exactly the still-in-flight sum); None asserts nothing is in
         flight and zeroes extra."""
-        n = self.n_slots
+        n = self.lane_span
         # (a fetch or a pack from before a lane growth is laid out for the
-        # stack as it was then)
+        # stack as it was then; a fetch has the stack's rows, of which the
+        # venue's lanes come first in every shard's block)
         self._ub_base = self._relayout(np.array(counts_max, np.int64), n)
         if resolved_adds is None:
             self._ub_extra = np.zeros(n, np.int64)
@@ -1059,7 +1115,8 @@ class BatchEngine:
         to powers of two (min 8 — the Pallas kernel's sublane floor;
         sentinel padding rows are free) to bound compile shapes. Once the
         row bucket reaches n_slots the gather buys nothing and the grid is
-        the full one, row == lane — which says nothing about its depth.
+        the full one, row == lane, at the stack's lane_rows — which says
+        nothing about its depth.
 
         `first` marks the first dense grid of a frame's train. Only it
         consults/advances the grow-only row ratchet: the train's DEEPER
@@ -1084,25 +1141,26 @@ class BatchEngine:
         cap class (the single-class behavior).
 
         Returns (use_dense, n_rows, lane_ids, row_of): lane_ids [n_rows]
-        GLOBAL lane ids with sentinel n_slots on padding rows (the device
-        step localizes under a mesh); row_of [n_slots] maps live lane ->
+        GLOBAL lane ids with sentinel lane_rows on padding rows (the device
+        step localizes under a mesh); row_of [lane_rows] maps live lane ->
         row (valid only at live positions). Both None for full grids."""
+        rows = self.lane_rows
         if not (self.dense and len(live) > 0):
-            return False, self.n_slots, None, None
+            return False, rows, None, None
         cls = self.config.cap if cls is None else cls
         floor = self._dense_rows_floor.get(cls, 8) if first else 8
         bucket = _next_pow2 if first else _next_pow4
         if self.mesh is None:
             n_rows = max(8, bucket(len(live)), floor)
             if n_rows >= self.n_slots:
-                return False, self.n_slots, None, None
+                return False, rows, None, None
             # Grow-only row bucket ("ratchet"): live-lane counts hovering
             # at a pow2 boundary would otherwise flip the compiled grid
             # shape frame to frame — and one fresh XLA compile costs more
             # than thousands of frames of matching.
             if first:
                 self._dense_rows_floor[cls] = n_rows
-            lane_ids = np.full(n_rows, self.n_slots, np.int64)
+            lane_ids = np.full(n_rows, rows, np.int64)
             lane_ids[: len(live)] = live
             rows_for_live = np.arange(len(live), dtype=np.int64)
             # Occupancy ledger (obs.placement): dispatched-vs-live rows
@@ -1110,7 +1168,7 @@ class BatchEngine:
             PLACEMENT.note_dispatch(n_rows, live)
         else:
             d = self.mesh.size
-            local = self.n_slots // d
+            local = rows // d
             shard = live // local  # live is sorted (np.unique upstream)
             counts = np.bincount(shard, minlength=d)
             # Uniform R_s = global max is structural for now: shard_map's
@@ -1119,11 +1177,11 @@ class BatchEngine:
             # Per-shard geometry is ROADMAP item 2's refactor.
             r_s = max(8, bucket(int(counts.max())), floor)  # gomelint: disable=GL802 — owning workstream: ROADMAP item 2 (per-shard geometry)
             if r_s * d >= self.n_slots:
-                return False, self.n_slots, None, None
+                return False, rows, None, None
             if first:
                 self._dense_rows_floor[cls] = r_s
             n_rows = r_s * d
-            lane_ids = np.full(n_rows, self.n_slots, np.int64)
+            lane_ids = np.full(n_rows, rows, np.int64)
             starts = np.zeros(d, np.int64)
             np.cumsum(counts[:-1], out=starts[1:])
             rank = np.arange(len(live), dtype=np.int64) - starts[shard]
@@ -1134,7 +1192,7 @@ class BatchEngine:
             _dense_shard_skew.observe(int(counts.max()) * d / len(live))
             PROFILER.note_shard_dispatch(d, r_s, counts)
             PLACEMENT.note_dispatch(n_rows, live, counts, r_s)
-        row_of = np.empty(self.n_slots, np.int64)
+        row_of = np.empty(rows, np.int64)
         row_of[live] = rows_for_live
         # Skew telemetry: what row padding (pow2 bucket, grow-only floor,
         # and per-shard MAX bucketing under a mesh) costs THIS dispatch.
@@ -1277,11 +1335,15 @@ class BatchEngine:
         return k
 
     def _grow_lanes(self, new_slots: int) -> None:
-        # (under a mesh the one gather across chips the engine ever does)
-        self.books = self._place(jax.tree.map(
-            lambda a: self._relayout(a, new_slots, jnp), self.books
-        ))
-        self._grow_base_arrays(new_slots)
+        """The venue's lanes double; the stack is provisioned anew only
+        where the new count passes its row floor."""
+        rows = self._rows_for(new_slots)
+        if rows != self.lane_rows:
+            # (under a mesh the one gather across chips the engine ever does)
+            self.books = self._place(jax.tree.map(
+                lambda a: self._relayout(a, rows, jnp), self.books
+            ))
+        self._grow_base_arrays(self._span_for(new_slots))
         self.n_slots = new_slots
         self.stats.lane_growths += 1
 
@@ -1381,7 +1443,7 @@ class BatchEngine:
             with span("grid_dispatch", rows=ops.action.shape[0],
                       t=ops.action.shape[1], cap=int(cap_g),
                       n_ops=len(contexts),
-                      grid="full" if lane_ids is None else "dense"):
+                      **self.grid_note(lane_ids is not None)):
                 new_books, outs = self._step(
                     books_before, ops, lane_ids, cap_g, n_ops=len(contexts)
                 )
@@ -1468,6 +1530,13 @@ class BatchEngine:
             lane_overrides[row] = jax.device_get(lane_out)
         return outs, lane_overrides
 
+    def grid_note(self, dense: bool) -> dict:
+        """What a grid_dispatch span notes of the grid's kind: under a full
+        grid's rows (the provisioned lane_rows) the venue's lanes."""
+        if dense:
+            return {"grid": "dense"}
+        return {"grid": "full", "lanes": self.n_slots}
+
     def _plan_step(self, rows: int, cfg: BookConfig, dense: bool,
                    n_ops: int | None):
         """Choose the kernel for one grid of `rows` (per-chip) lanes and
@@ -1546,10 +1615,11 @@ class BatchEngine:
             # block names only its own lanes, so lane % local IS the
             # local index); sentinel rows map to `local` (out of range on
             # every chip: gathered as zero books, dropped by the scatter).
-            local = self.n_slots // self.mesh.size
+            rows = self.lane_rows
+            local = rows // self.mesh.size
             ids_np = np.asarray(lane_ids)
             ids_local = np.where(
-                ids_np >= self.n_slots, local, ids_np % local
+                ids_np >= rows, local, ids_np % local
             ).astype(np.int32)
             stepper = self._sharded_dense_steppers.get(cfg)
             if stepper is None:
@@ -1565,7 +1635,7 @@ class BatchEngine:
             with span(
                 "shard_put", rows=len(ids_np) // self.mesh.size,
                 live="/".join(map(str, (
-                    ids_np.reshape(self.mesh.size, -1) < self.n_slots
+                    ids_np.reshape(self.mesh.size, -1) < rows
                 ).sum(axis=1))),
             ):
                 ids_local = shard_batch(self.mesh, jnp.asarray(ids_local))
@@ -1613,10 +1683,7 @@ class BatchEngine:
                 "env_lo": self._env_lo.copy(),
                 "env_hi": self._env_hi.copy(),
             },
-            rows=(
-                None if self.mesh is None
-                else self._lane_of(np.arange(self.n_slots))
-            ),
+            rows=self._venue_rows(),
             meta={
                 "cap": self.config.cap,
                 "max_fills": self.config.max_fills,
@@ -1689,13 +1756,20 @@ class BatchEngine:
                 "engine or re-snapshot from a mesh-aligned one"
             )
         self.max_t = int(state["max_t"])
-        # A snapshot is in the one-chip order (export_state): each lane
-        # goes to the row this engine's placement gives it.
-        placed = np.array  # (a copy: the state stays the caller's)
-        if self.mesh is not None:
-            rows = self._symbol_ids(np.arange(self.n_slots))
-            placed = lambda a: np.asarray(a)[rows]
-        b = {k: placed(v) for k, v in state["books"].items()}
+        # A snapshot holds the venue's n_slots lanes in the one-chip order
+        # (export_state): each goes to the row this engine's placement
+        # gives it, and the rows past them (the row floor's) stay empty.
+        rows = self._venue_rows()
+
+        def placed(a, n=self.lane_span):
+            if rows is None:
+                return np.array(a)  # (a copy: the state stays the caller's)
+            a = np.asarray(a)
+            out = np.zeros((n,) + a.shape[1:], a.dtype)
+            out[rows] = a
+            return out
+
+        b = {k: placed(v, self.lane_rows) for k, v in state["books"].items()}
         books = BookState(**b)
         # _place device_puts with the mesh sharding directly from host
         # arrays; an inner device_put first would materialize the whole
@@ -1711,10 +1785,11 @@ class BatchEngine:
         self.oids = make_interner(from_list=list(state["oids"]))
         self.uids = Interner.from_list(list(state["uids"]))
         self._rebase = jnp.dtype(self.config.dtype).itemsize <= 4
-        n = self.n_slots
+        n = self.lane_span
         # count_ub restarts exact from the restored books (nothing in
         # flight after a restore).
-        self._ub_base = np.asarray(b["count"], np.int64).max(axis=1)
+        counts = np.asarray(state["books"]["count"])  # [n_slots, 2]
+        self._ub_base = placed(counts.astype(np.int64).max(axis=1))
         self._ub_extra = np.zeros(n, np.int64)
         if "price_base" in state:
             self.price_base = placed(np.asarray(state["price_base"], np.int64))
@@ -1728,21 +1803,20 @@ class BatchEngine:
             # relative to it while the restored book stays absolute (silent
             # non-matching). Envelope from the restored books themselves.
             self.price_base = np.zeros(n, np.int64)
-            counts = np.asarray(b["count"])  # [S, 2]
             occupied = counts.sum(axis=1) > 0
-            self._base_set = occupied.copy()
-            prices = np.asarray(b["price"]).astype(np.int64)  # [S, 2, cap]
-            cap = prices.shape[-1]
+            self._base_set = placed(occupied)
+            prices = np.asarray(state["books"]["price"]).astype(np.int64)
+            cap = prices.shape[-1]  # [n_slots, 2, cap]
             slot = np.arange(cap)
             active = slot[None, None, :] < counts[:, :, None]
-            self._env_lo = np.where(
+            self._env_lo = placed(np.where(
                 occupied,
                 np.where(active, prices, np.iinfo(np.int64).max).min((1, 2)),
                 0,
-            )
-            self._env_hi = np.where(
+            ))
+            self._env_hi = placed(np.where(
                 occupied, np.where(active, prices, 0).max((1, 2)), 0
-            )
+            ))
 
     def verify_books(self) -> None:
         """Check every lane against the book invariants (priority-sorted
@@ -1790,13 +1864,14 @@ class BatchEngine:
         (symbol_lane), under a mesh too. Consumers of raw device state use
         export_state instead."""
         books = jax.device_get(self.books)
+        base = self.price_base
+        rows = self._venue_rows()
+        if rows is not None:
+            books = jax.tree.map(lambda a: np.asarray(a)[rows], books)
+            base = base[rows]
         if self._rebase and self._base_set.any():
             price = np.asarray(books.price).astype(np.int64)
-            price = price + self.price_base[:, None, None]
-            books = books._replace(price=price)
-        if self.mesh is not None:
-            rows = self._lane_of(np.arange(self.n_slots))
-            books = jax.tree.map(lambda a: np.asarray(a)[rows], books)
+            books = books._replace(price=price + base[:, None, None])
         return books
 
     def symbol_lane(self, symbol: str) -> int:

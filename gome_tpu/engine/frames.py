@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import time
+import weakref
 from typing import NamedTuple
 
 import jax
@@ -133,7 +134,7 @@ MAX_FRAME_OPS = 1 << 20
 #: adding a dimension means updating every site in one commit, and lint
 #: fails until they line up.
 COMBO_FIELDS = (
-    "n_rows",      # grid rows (live-lane bucket or full n_slots)
+    "n_rows",      # grid rows (live-lane bucket or the full lane_rows)
     "t_grid",      # grid time-axis depth (packed-train class)
     "cap_g",       # book capacity class dispatched against
     "dense",       # full-grid (False) vs compact gather/scatter (True=
@@ -248,7 +249,7 @@ def _frame_arrays(eng: BatchEngine, cols: dict) -> dict:
 
     if nativehost.available():
         t = nativehost.occurrences(
-            lanes, None if keep.all() else keep, eng.n_slots
+            lanes, None if keep.all() else keep, eng.lane_rows
         )
     else:
         t = np.full(n, -1, np.int64)
@@ -273,7 +274,7 @@ def _frame_arrays(eng: BatchEngine, cols: dict) -> dict:
     kept_add = keep & is_add
     rest_mask = kept_add & may_rest(kind)
     add_counts = np.bincount(
-        lanes[rest_mask], minlength=eng.n_slots
+        lanes[rest_mask], minlength=eng.lane_span
     ).astype(np.int64)
     eng.note_packed_adds(add_counts)
     adds_by_kind = np.bincount(kind[kept_add])
@@ -420,7 +421,7 @@ def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
         )
         if not use_dense:
             # Full grid: row == lane (identity map).
-            row_of = np.arange(eng.n_slots, dtype=np.int64)
+            row_of = np.arange(n_rows, dtype=np.int64)
         t_grid = eng._grid_depth(
             n_rows, int(t_sub.max()) - t_off + 1, cap_g, first, use_dense
         )
@@ -861,6 +862,22 @@ def export_metrics(eng: BatchEngine) -> None:
         REGISTRY.callback_gauge(
             name, help_, lambda field=field: getattr(stats, field)
         )
+    # The venue's lanes and the rows the device's lane axis was provisioned
+    # to for them (BatchEngine.lane_rows: the compiled kernel's row floor).
+    ref = weakref.ref(eng)
+    for name, help_, field in (
+        ("gome_engine_lanes",
+         "lanes of the venue (engine.n_slots, doubled as symbols arrive)",
+         "n_slots"),
+        ("gome_engine_lane_rows",
+         "rows of the book stack and of a full grid: the lanes padded to a "
+         "row count the compiled kernel can block",
+         "lane_rows"),
+    ):
+        REGISTRY.callback_gauge(
+            name, help_,
+            lambda field=field: getattr(ref(), field, 0),
+        )
     # Adds applied and adds expired, by kind (types.OrderType's names).
     for kind in OrderType:
         REGISTRY.callback_gauge(
@@ -980,8 +997,7 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
                 n_rows, t_grid = ops.action.shape
             with span(
                 "grid_dispatch", frame=frame, rows=n_rows, t=t_grid,
-                cap=int(cap_g), n_ops=n_ops,
-                grid="dense" if dense else "full",
+                cap=int(cap_g), n_ops=n_ops, **eng.grid_note(dense),
                 program=1 if one_program else 3,
             ):
                 if one_program:
@@ -1306,7 +1322,7 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
     submit_frame dispatches them — before real traffic arrives.
 
     All-padding means: scatter positions at the drop sentinel (R*T), so
-    the DeviceOp grid is all NOPs; dense lane_ids at the n_slots sentinel
+    the DeviceOp grid is all NOPs; dense lane_ids at the lane_rows sentinel
     (gathered as zero books, scattered nowhere). Book state is read but
     results are DISCARDED — replay never mutates the engine (no program
     donates the books; the compaction donates only the dummy buffers
@@ -1346,7 +1362,7 @@ def precompile_combos(eng: BatchEngine, combos) -> int:
                 np.full(m_pad, n_rows * t_grid, np.int32), n_rows, t_grid,
             )
             lane_ids = (
-                np.full(n_rows, eng.n_slots, np.int64) if dense else None
+                np.full(n_rows, eng.lane_rows, np.int64) if dense else None
             )
             buffers = _zero_buffers(eng, e_fills, e_cancels, totals_len)
             if eng.mesh is None and _one_phase(
@@ -1503,8 +1519,8 @@ def _prepare_bases_vec(eng, lanes, action, kind, price) -> np.ndarray:
             viol = ~inside
             al, ap = al[viol], ap[viol]
             uniq = np.unique(al)
-            lo = np.full(eng.n_slots, np.iinfo(np.int64).max)
-            hi = np.full(eng.n_slots, np.iinfo(np.int64).min)
+            lo = np.full(eng.lane_span, np.iinfo(np.int64).max)
+            hi = np.full(eng.lane_span, np.iinfo(np.int64).min)
             np.minimum.at(lo, al, ap)
             np.maximum.at(hi, al, ap)
             # Vectorized widen for lanes that only need their envelope

@@ -1,6 +1,7 @@
 """Custom TPU kernels (Pallas) for the matching hot path."""
 
 from .pallas_match import (
+    blockable_rows,
     default_block_s,
     kernel_plan,
     pallas_available,
@@ -8,6 +9,7 @@ from .pallas_match import (
 )
 
 __all__ = [
+    "blockable_rows",
     "default_block_s",
     "kernel_plan",
     "pallas_available",

@@ -84,25 +84,33 @@ def interpret_block_s(s: int) -> int:
     return next(b for b in (8, 4, 2, 1) if s % b == 0)
 
 
+def blockable_rows(s: int) -> int:
+    """The smallest row count at or over `s` the compiled kernel can block
+    (Mosaic's lane-dim rule, enforced in pallas_batch_step): a multiple of 8
+    up to 256 (one sublane-aligned whole-axis block; s % 8 != 0 hits
+    unsupported relayouts), of 128 above (128-row blocks). The row rule is
+    written here and nowhere else: plan_block_s refuses every other count,
+    and BatchEngine provisions the device's lane axis to this floor, so a
+    venue states its lanes and still runs every full grid compiled."""
+    step = 8 if s <= 256 else 128
+    return -(-s // step) * step
+
+
 def plan_block_s(s: int, cap: int = 256) -> tuple[int | None, str | None]:
     """The compiled kernel's lane-blocking policy, in ONE place. Returns
     (block_s, None), or (None, reason) when no blocking is valid and the
     caller gives way to the scan path:
 
-      "unblockable_rows" — Mosaic's lane-dim rule (enforced in
-          pallas_batch_step): blocks are 128-multiples, or one
-          sublane-aligned whole-axis block for modest s (s % 8 != 0 hits
-          unsupported relayouts);
+      "unblockable_rows" — s is not a row count blockable_rows gives:
+          blocks are 128-multiples, or one sublane-aligned whole-axis
+          block for modest s;
       "tile_over_budget" — the legal block's resident book tiles
           (~10 x block x 2*cap x 4 B, in/out aliased at ~2x) do not fit
           the 6 MB share of Mosaic's 16 MB scoped-VMEM stack: cap=1024 at
           block 128 is a compile-time VMEM OOM."""
-    if s % 128 == 0:
-        block = 128
-    elif s <= 256 and s % 8 == 0:
-        block = s
-    else:
+    if s != blockable_rows(s):
         return None, "unblockable_rows"
+    block = 128 if s % 128 == 0 else s
     if 10 * block * 2 * cap * 4 > 6 << 20:
         return None, "tile_over_budget"
     return block, None

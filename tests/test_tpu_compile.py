@@ -11,6 +11,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from gome_tpu.engine import batch as B
@@ -187,3 +188,95 @@ def test_a_small_frames_grid_compiles_for_v5e_as_one_program(
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1 and calls[0].startswith(f"%{name}."), calls
     assert text.count("may-alias") + text.count("must-alias") >= 3
+
+
+def _engine_on_the_chip(monkeypatch, n_slots, cap):
+    """A BatchEngine as a configuration file builds it (kernel pallas, the
+    lanes the venue states), planning its grids as on the chip: kernel_plan
+    asks the default backend, which is the CPU here, so the test answers
+    for the described chip."""
+    from gome_tpu.ops import pallas_match
+
+    monkeypatch.setattr(pallas_match, "pallas_available",
+                        lambda dtype=jnp.int32: True)
+    return B.BatchEngine(
+        BookConfig(cap=cap, max_fills=16, dtype=jnp.int32), n_slots=n_slots,
+        kernel="pallas")
+
+
+def _lowered_full_grid(one_chip, eng, t, cap_g):
+    """The full grid the engine dispatches at depth t and cap class cap_g,
+    lowered for the chip from the engine's own stack and plan."""
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    use_dense, rows, _ids, _row_of = eng._grid_geometry(
+        np.arange(eng.n_slots))
+    assert not use_dense
+    plan = eng._grid_plan(rows, False, cap_g, None)
+    assert plan.block_s is not None and not plan.interpret
+    cell = jax.ShapeDtypeStruct((rows, t), jnp.int32, sharding=one_chip)
+    ops = DeviceOp(**{f: cell for f in DeviceOp._fields})
+    with jax.enable_x64(False):  # the deployment's int32 process
+        return B.full_kernel_step.lower(
+            plan.cfg, jax.tree.map(place, eng.books), ops, plan.block_s,
+            plan.interpret)
+
+
+# The two tests below loop over their cases inside one test each, and do not
+# parametrise: pytest-xdist hands out files largest first (loadscopereorder),
+# and this file has to stay smaller than tests/test_hostprof.py (15 tests), so
+# that no worker runs that file's signal sampler after this one has loaded the
+# TPU's library into the process: the sampler then kills the worker (the
+# parent's tree too; ROADMAP C).
+
+
+def test_a_venue_of_one_lane_compiles_for_v5e_at_the_kernels_row_floor(
+        one_chip, monkeypatch):
+    """n_slots 1 as hotpair1's file says it: the engine's own stack and
+    plan give grids Mosaic takes, at the deepest class an 8-row block's
+    VMEM budget admits: one lane provisioned to the kernel's 8-row floor,
+    full grids of the 4096-slot class at the three depth classes (a
+    4,096-order frame runs as four of the deepest)."""
+    eng = _engine_on_the_chip(monkeypatch, 1, 4096)
+    assert (eng.n_slots, eng.lane_rows) == (1, 8)
+    for t in (32, 256, 1024):
+        text = _lowered_full_grid(one_chip, eng, t, 4096).compile().as_text()
+        calls = [ln.strip() for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert len(calls) == 1
+        assert calls[0].startswith(f"%match_full_r8_t{t}_c4096."), (
+            calls[0][:120])
+
+
+def test_where_the_lanes_are_blockable_the_program_is_the_parents(
+        one_chip, monkeypatch):
+    """The floor is the identity at the accepted venues' lane counts: what
+    the engine lowers from its own stack and plan is, letter for letter,
+    what the parent lowered from a stack of n_slots rows."""
+    for case in (
+        (8, 1024, 1024, 1024),   # hotpair8's deepest full grid
+        (10240, 256, 32, 64),    # spot10k's full grid at the class-64 slice
+    ):
+        _the_program_is_the_parents(one_chip, monkeypatch, *case)
+
+
+def _the_program_is_the_parents(one_chip, monkeypatch, n_slots, store_cap, t,
+                                cap_g):
+    eng = _engine_on_the_chip(monkeypatch, n_slots, store_cap)
+    assert eng.lane_rows == eng.lane_span == n_slots
+    mine = _lowered_full_grid(one_chip, eng, t, cap_g).as_text()
+    # the parent: books of n_slots rows, a grid of n_slots rows
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    store = BookConfig(cap=store_cap, max_fills=16, dtype=jnp.int32)
+    books = jax.tree.map(place, jax.eval_shape(
+        lambda: jax.vmap(lambda _: init_book(store))(jnp.arange(n_slots))
+    ))
+    cell = jax.ShapeDtypeStruct((n_slots, t), jnp.int32, sharding=one_chip)
+    ops = DeviceOp(**{f: cell for f in DeviceOp._fields})
+    block, reason = plan_block_s(n_slots, cap_g)
+    assert block is not None, reason
+    with jax.enable_x64(False):
+        parents = B.full_kernel_step.lower(
+            BookConfig(cap=cap_g, max_fills=16, dtype=jnp.int32), books, ops,
+            block, False).as_text()
+    assert mine == parents
+    assert f"match_full_r{n_slots}_t{t}_c{cap_g}" in mine
