@@ -392,6 +392,18 @@ def _cap_ladder(cap: int) -> list[int]:
     return out
 
 
+def merged_floor_key(cls: int) -> int:
+    """The key, in the rows and depth floors, of a merged small frame's
+    grid at cap class `cls` (frames.pack_frame_grids): the class negated.
+    Such a grid carries every lane of its frame, a few dozen rows at the
+    deepest class present, where that class's own train in a large frame
+    carries its deep lanes alone; under one key the one would pad the
+    other for the life of the process. An int like the classes, so a
+    saved manifest's JSON keys read back the same way
+    (orchestrator.load_geometry)."""
+    return -cls
+
+
 def _slice_books_cap(books: BookState, cap: int) -> BookState:
     """Restrict the slot axis to the leading `cap` slots (no-op at the
     storage width). Exact for every lane whose resting count <= cap —
@@ -561,6 +573,12 @@ class EngineStats:
     fast_frames: int = 0
     fast_frames_reused: int = 0
     fast_frames_one_phase: int = 0
+    # One-phase frames whose lanes spanned more than one cap class and were
+    # packed as ONE grid at the deepest class present
+    # (frames.pack_frame_grids). Over fast_frames: near 1 where small frames
+    # mix deep and shallow lanes, 0 where a frame's lanes are of one class
+    # or frames are large.
+    fast_frames_merged: int = 0
     # Grids of one-phase frames, which cost the host ONE dispatch each:
     # scatter, step, compaction and the count reduction in one program
     # (frames._grid_program). Over device_calls: near 1 where small frames
@@ -741,7 +759,8 @@ class BatchEngine:
         # Keyed by CAP CLASS (_cap_ladder): each class runs its own grid
         # train with its own row/depth profile — the tail class's 10K-row
         # floor must never inflate the hot class's 8-row grids (and vice
-        # versa for depth).
+        # versa for depth). A small frame's merged grid has a profile of
+        # its own again, under merged_floor_key(class).
         self._dense_rows_floor: dict[int, int] = {}
         self._dense_t_floor: dict[int, int] = {}
         # Per-lane resting-count upper bound, the host-side input to cap-
@@ -973,7 +992,8 @@ class BatchEngine:
 
         rows_floor/t_floor accept an int (a floor for the storage-cap
         class — the pre-cap-class behavior) or a {cap class: floor} dict
-        as returned by geometry_floors()."""
+        as returned by geometry_floors() (merged small frames' floors under
+        merged_floor_key(class))."""
 
         def merge(dst: dict, src, cap: int) -> None:
             """Merge grow-only, clamped to `cap`: a floor beyond the
@@ -1048,7 +1068,8 @@ class BatchEngine:
         """The current grow-only shape ratchets (see prewarm_geometry) —
         what a warmup loop watches to decide the flow's compiled shapes
         have stabilized, and what a deployment records to pre-warm the
-        next process. rows_floor/t_floor are {cap class: floor} dicts, the
+        next process. rows_floor/t_floor are {cap class: floor} dicts (a
+        merged small frame's grids under merged_floor_key(class)), the
         buffer floors {pow2 op-class: slots} dicts; everything is copied
         (safe to hold across further frames)."""
         return dict(
@@ -1137,7 +1158,8 @@ class BatchEngine:
         shard — which is the true cost surface on hardware.
 
         `cls` keys the grow-only floors by the grid's cap class (per-class
-        trains have independent row/depth profiles); None = the storage
+        trains have independent row/depth profiles; a merged small frame's
+        grid comes with merged_floor_key of its class); None = the storage
         cap class (the single-class behavior).
 
         Returns (use_dense, n_rows, lane_ids, row_of): lane_ids [n_rows]

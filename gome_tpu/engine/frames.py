@@ -71,6 +71,7 @@ from .batch import (
     _next_pow2,
     _next_pow4,
     is_device_fault,
+    merged_floor_key,
     splice_outs,
     step_in_program,
 )
@@ -371,8 +372,8 @@ def _class_partitions(eng: BatchEngine, a: dict, active_idx):
     return out
 
 
-def pack_frame_grids(eng: BatchEngine, a: dict,
-                     on_device: bool = True) -> list[tuple]:
+def pack_frame_grids(eng: BatchEngine, a: dict, on_device: bool = True,
+                     small: bool = False) -> list[tuple]:
     """Stage 2: split the frame into per-cap-class grid trains (lanes
     deeper than a grid's time axis roll into the next grid — FIFO by
     construction), pack each grid's ops as columns, and DISPATCH the
@@ -382,48 +383,72 @@ def pack_frame_grids(eng: BatchEngine, a: dict,
     (submit_frame: a small frame's scatter is part of its grid's one
     program).
 
+    `small` is the one-phase rule's verdict on the frame (_compact_sizes,
+    taken by submit_frame before the pack). A small frame whose lanes span
+    more than one class packs ONE train at the deepest class present: any
+    class at or above a lane's own is exact for it (_slice_books_cap; a
+    lane's class is only the smallest that covers its count_ub), and at a
+    few dozen orders a second grid's dispatch, gather, scatter-back and
+    copy of the book stack cost far more than the shallow rows' share of
+    a deeper kernel (MERGE_MAX_CELLS). Its floors are keyed apart from the
+    per-class trains' (batch.merged_floor_key); a["merged"] is the class
+    it ran at, 0 for a frame packed class by class.
+
     Each train's loop carries a SHRINKING active-op index set: each grid
     touches only the ops still alive at its time offset, so a G-grid
     train (a Zipf flow draining hot lanes) costs O(sum of survivors), not
     O(G * frame) — with 27 grids per frame the latter was the consumer's
     dominant host cost."""
     keep, t = a["keep"], a["t"]
+    a["merged"] = 0
     grids: list[tuple] = []
     kept_idx = np.nonzero(keep)[0]
     if not len(kept_idx):
         return grids
-    for cap_g, part_idx in _class_partitions(eng, a, kept_idx):
+    parts = _class_partitions(eng, a, kept_idx)
+    if small and len(parts) > 1:
+        deepest = parts[-1][0]
+        lanes = len(np.unique(a["lanes"][kept_idx]))
+        depth = int(t[kept_idx].max()) + 1
+        if _next_pow2(lanes) * _next_pow2(depth) * deepest <= MERGE_MAX_CELLS:
+            a["merged"] = deepest
+            parts = [(deepest, kept_idx)]
+    for cap_g, part_idx in parts:
         _pack_class_train(
-            eng, a, part_idx, t[part_idx], cap_g, grids, on_device
+            eng, a, part_idx, t[part_idx], cap_g, grids, on_device,
+            merged_floor_key(cap_g) if a["merged"] else cap_g,
         )
     return grids
 
 
 def _pack_class_train(eng: BatchEngine, a: dict, active_idx, t_sub,
-                      cap_g: int, grids: list, on_device: bool) -> None:
+                      cap_g: int, grids: list, on_device: bool,
+                      floor_key: int) -> None:
     """Pack one cap class's grid train (the loop body of the original
-    single-train pack_frame_grids, with geometry ratchets keyed by the
-    class). Each grid's geometry is two decisions made apart, both on
-    BatchEngine: _grid_geometry picks the ROWS (a dense grid over the
-    live lanes, or the full grid with row == lane once the row bucket
-    reaches n_slots) and _grid_depth picks the DEPTH from the row count
-    and the deepest lane still to carry, whichever kind the rows are. So
-    a venue provisioned with exactly its live lanes runs a hot lane's
-    frame as a couple of deep full grids, not as a train of max_t-deep
-    ones; max_t is only the shallowest depth class."""
+    single-train pack_frame_grids, with geometry ratchets keyed by
+    `floor_key`: the class, or a merged small frame's key for it). Each
+    grid's geometry is two decisions made apart, both on BatchEngine:
+    _grid_geometry picks the ROWS (a dense grid over the live lanes, or
+    the full grid with row == lane once the row bucket reaches n_slots)
+    and _grid_depth picks the DEPTH from the row count and the deepest
+    lane still to carry, whichever kind the rows are. So a venue
+    provisioned with exactly its live lanes runs a hot lane's frame as a
+    couple of deep full grids, not as a train of max_t-deep ones; max_t
+    is only the shallowest depth class."""
     lanes, t = a["lanes"], a["t"]
     t_off = 0
     while len(active_idx):
         live = np.unique(lanes[active_idx])
         first = t_off == 0
         use_dense, n_rows, lane_ids, row_of = eng._grid_geometry(
-            live, first=first, cls=cap_g
+            live, first=first, cls=floor_key
         )
         if not use_dense:
             # Full grid: row == lane (identity map).
             row_of = np.arange(n_rows, dtype=np.int64)
         t_grid = eng._grid_depth(
-            n_rows, int(t_sub.max()) - t_off + 1, cap_g, first, use_dense
+            n_rows, int(t_sub.max()) - t_off + 1, floor_key, first,
+            use_dense,
         )
 
         from . import nativehost
@@ -826,6 +851,24 @@ class PendingFrame:
 ONE_PHASE_MAX_BYTES = 1 << 15
 
 
+#: The most cells (rows x depth x cap class, rows and depth rounded up to
+#: their powers of two) a small frame's merged grid may hold
+#: (pack_frame_grids); over it the frame packs class by class. Merging
+#: saves a grid's host dispatch and its gather, scatter-back and copy of
+#: the book stack; it costs the shallow lanes' rows at the deepest class.
+#: scripts/merge_cost.py on a TPU v5 lite (PR 44; submit to resolve of a
+#: lone small frame, one deep lane and the rest shallow, merged / class by
+#: class, ms): class 256, 64 rows x 8: 3.46 / 5.18; class 1024, 16, 64
+#: and 128 rows: 3.45 / 5.22, 3.53 / 5.04, 4.12 / 5.92; class 4096, 16,
+#: 64 and 128 rows (2**19, 2**21, 2**22 cells): 4.06 / 5.64, 4.71 / 5.91,
+#: 5.52 / 6.98. The merge is 1.2-1.8 ms ahead at every point read, and its
+#: own cost grows by 0.4 ns a cell at class 4096 (the kernel's 6.09 us a
+#: step of 8 rows, PERF_LEDGER.jsonl PR 43, and the rows' copies): the
+#: bound stands where the readings end, not where the merge was seen to
+#: lose. spot10k.paced's merged grid (64 x 8 x 256) has 2**17 cells.
+MERGE_MAX_CELLS = 1 << 22
+
+
 def _one_phase(itemsize: int, e_fills: int, e_cancels: int) -> bool:
     """The one-phase rule on a frame's buffer widths."""
     return (
@@ -838,7 +881,9 @@ def export_metrics(eng: BatchEngine) -> None:
     scrape time (nothing on the frame's way; registering again rebinds to
     the newest engine's, as services are rebuilt across tests). Reused over
     frames and one-phase over frames are both near 1 where small frames
-    flow steadily; a large-frame flow reads reuse near 1 and one-phase 0."""
+    flow steadily; a large-frame flow reads reuse near 1 and one-phase 0.
+    Merged over frames is near 1 where small frames mix lanes of more than
+    one cap class, 0 where every lane is of one class or frames are large."""
     from ..utils.metrics import REGISTRY
 
     stats = eng.stats  # the counters only: a gauge outlives its engine
@@ -852,6 +897,10 @@ def export_metrics(eng: BatchEngine) -> None:
         ("gome_fast_frames_one_phase_total",
          "fast-path frames whose events came back with their totals",
          "fast_frames_one_phase"),
+        ("gome_fast_frames_classes_merged_total",
+         "one-phase frames whose lanes spanned more than one cap class and "
+         "were packed as one grid at the deepest",
+         "fast_frames_merged"),
         ("gome_fast_grids_one_program_total",
          "grids of one-phase frames, dispatched as one program each",
          "fast_grids_one_program"),
@@ -976,7 +1025,12 @@ def submit_frame(eng: BatchEngine, cols: dict) -> PendingFrame:
             # there a grid is placed by eng._step (shard_put), large or
             # small.
             one_program = one_phase and eng.mesh is None
-            grids = pack_frame_grids(eng, a, on_device=not one_program)
+            grids = pack_frame_grids(
+                eng, a, on_device=not one_program, small=one_phase
+            )
+            if a["merged"]:
+                eng.stats.fast_frames_merged += 1
+                packed.note(merged=1, cap=a["merged"])
             if grids:
                 (fills_acc, cancels_acc, totals_acc), reused = _take_buffers(
                     eng, e_fills, e_cancels, max(_next_pow2(len(grids)), 8)

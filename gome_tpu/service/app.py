@@ -300,11 +300,12 @@ class EngineService:
         st = self.engine.stats
         (log.warning if tracing.slow() else log.info)(
             "fast-path frames: %d dispatched, %d on reused event buffers, "
-            "%d fetched in one phase; grids: %d dispatched, %d as one "
+            "%d fetched in one phase, %d with their cap classes merged into "
+            "one grid; grids: %d dispatched, %d as one "
             "program; %d dispatch combos over %d step geometries; the "
             "book stack holds %d rows for the venue's %d lanes",
             st.fast_frames, st.fast_frames_reused, st.fast_frames_one_phase,
-            st.device_calls, st.fast_grids_one_program,
+            st.fast_frames_merged, st.device_calls, st.fast_grids_one_program,
             self.engine.batch.combo_count(),
             len({c[:4] for c in self.engine.batch.combos()}),
             self.engine.batch.lane_rows, self.engine.batch.n_slots,

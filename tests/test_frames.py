@@ -973,18 +973,25 @@ def _hot_and_tail_frames(n_frames, seed=3):
 
 
 @pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one_chip", "mesh4"])
-def test_a_small_frame_of_two_dense_grids_executes_two_programs(
-    mesh_devices, device_work, device_calls
+@pytest.mark.parametrize("size", ["small", "large"])
+def test_a_frame_of_two_classes_is_one_grid_when_small_and_two_when_large(
+    size, mesh_devices, device_work, device_calls, monkeypatch
 ):
-    """Two dense grids a frame, of the 64-slot and of the 256-slot class:
-    after warm-up submit_frame executes one program a grid and puts nothing
-    on the device beside them, eager or explicit (the parent executed seven
-    programs a frame, two scatters, two steps, two compactions and the
-    count reduction, and uploaded each grid's lane ids before its step).
-    Under a mesh the frame keeps those calls: scatter, the sharded step
-    behind its shard_put, compaction."""
+    """Lanes of the 64-slot and of the 256-slot class in every frame. A
+    SMALL frame (under the one-phase rule) packs them as one dense grid at
+    class 256 (ISSUE 44): after warm-up submit_frame executes ONE program a
+    frame and puts nothing on the device beside it, eager or explicit
+    (until ISSUE 44 one program a class, two a frame; until ISSUE 35 seven
+    programs a frame and an upload of each grid's lane ids). Under a mesh
+    the one grid keeps a large frame's calls: scatter, the sharded step
+    behind its shard_put, compaction. A LARGE frame (the same frames with
+    the rule set under them) packs one grid a class as it always did, three
+    calls each, and counts as neither one-phase nor merged."""
     from gome_tpu.engine import frames
 
+    if size == "large":
+        monkeypatch.setattr(frames, "ONE_PHASE_MAX_BYTES", 0)
+    per_frame = 1 if size == "small" else 2
     chunks = _hot_and_tail_frames(30)
     eng = BatchEngine(
         BookConfig(cap=256, max_fills=8, dtype=jnp.int32),
@@ -994,31 +1001,42 @@ def test_a_small_frame_of_two_dense_grids_executes_two_programs(
     programs, puts = device_calls
     frames._grid_program.clear_cache()
     for k, chunk in enumerate(chunks):
-        if k == 12:  # warm: both classes' floors settled, sets in rotation
+        if k == 12:  # warm: the floors settled, sets in rotation
             eager.clear(), lowered.clear(), programs.clear(), puts.clear()
             before = dataclasses.replace(
                 eng.stats, grids_by_kernel=dict(eng.stats.grids_by_kernel)
             )
         pend = frames.submit_frame(eng, colwire.orders_to_cols(chunk))
+        assert len(pend.items) == (per_frame if k else 1)  # k 0: the listing
         seen = len(programs)
         frames.resolve_frame(eng, pend)
-        assert len(programs) == seen  # resolve executes nothing
+        if size == "small":
+            assert len(programs) == seen  # resolve executes nothing
     n = len(chunks) - 12
-    assert eager == [] and lowered == []
-    assert eng.stats.device_calls - before.device_calls == 2 * n
+    assert lowered == []
+    assert eng.stats.device_calls - before.device_calls == per_frame * n
     one_program = (eng.stats.fast_grids_one_program
                    - before.fast_grids_one_program)
-    if mesh_devices:
-        assert one_program == 0 and "jit(_grid_program)" not in programs
+    merged = eng.stats.fast_frames_merged - before.fast_frames_merged
+    if size == "large":
+        assert one_program == 0 and merged == 0
+        assert eng.stats.fast_frames_one_phase == 0
+        assert "jit(_grid_program)" not in programs
         assert programs.count("jit(scatter)") == 2 * n
         assert programs.count("jit(compact_accum)") == 2 * n
-        assert len(puts) == 4 * n  # a grid's ids and its ops, placed
+    elif mesh_devices:
+        assert eager == [] and merged == n
+        assert one_program == 0 and "jit(_grid_program)" not in programs
+        assert programs.count("jit(scatter)") == n
+        assert programs.count("jit(compact_accum)") == n
+        assert len(puts) == 2 * n  # the grid's ids and its ops, placed
     else:
-        assert one_program == 2 * n
-        assert programs == ["jit(_grid_program)"] * (2 * n)
+        assert eager == [] and merged == n
+        assert one_program == n
+        assert programs == ["jit(_grid_program)"] * n
         assert puts == []
     assert (eng.stats.grids_by_kernel["scan_dense"]
-            - before.grids_by_kernel["scan_dense"]) == 2 * n
+            - before.grids_by_kernel["scan_dense"]) == per_frame * n
     assert eng.stats.frame_fallbacks == 0
     eng.verify_books()
 
@@ -1132,13 +1150,17 @@ def test_one_program_frames_equal_the_exact_path(
     # frame's once more (its one grid, dispatched and then re-run exactly).
     tripped = int(case == "fills_overflow_the_buffer")
     assert fast.stats.frame_fallbacks == tripped
+    # (A small frame of two classes is one grid where process_frame packs
+    # one a class: every frame of that case but the listing.)
+    merged = len(chunks) - 1 if case == "two_classes" else 0
+    assert fast.stats.fast_frames_merged == merged
     for name in ("grids_by_kernel", "ops_by_kernel"):
         ours, theirs = getattr(fast.stats, name), getattr(exact.stats, name)
         assert ours.keys() == theirs.keys(), name
-        if not tripped:
+        if not tripped and not (merged and name == "grids_by_kernel"):
             assert ours == theirs, name
     assert (sum(fast.stats.grids_by_kernel.values())
-            == sum(exact.stats.grids_by_kernel.values()) + tripped)
+            == sum(exact.stats.grids_by_kernel.values()) + tripped - merged)
     if case == "under_and_over_the_rule":
         assert (0 < fast.stats.fast_frames_one_phase
                 < fast.stats.fast_frames)
@@ -1149,7 +1171,7 @@ def test_one_program_frames_equal_the_exact_path(
         assert (fast.stats.fast_grids_one_program
                 == fast.stats.device_calls - tripped)  # the exact re-run's
     if case == "two_classes":
-        assert fast.stats.device_calls >= 2 * (len(chunks) - 1)
+        assert fast.stats.device_calls == len(chunks)
     if "kernel" in kw and not mesh_devices:
         assert all(k.startswith("interpret_")
                    for k in fast.stats.grids_by_kernel)

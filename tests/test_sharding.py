@@ -646,11 +646,13 @@ def test_lane_growth_under_a_mesh_moves_every_lane_and_loses_nothing():
     four.batch.verify_books()
 
 
-def _packed_grid_digest():
+def _packed_grid_digest(merge=True):
     """sha256 over every grid the frame packer makes of a seeded frame train
     on an engine without a mesh: each grid's op fields, row -> lane ids, cap
     class and decode metadata, frame after frame through the fast path (so
-    count_ub, the floors and the cap classes evolve as they do when served)."""
+    count_ub, the floors and the cap classes evolve as they do when served).
+    With `merge` off the packer is not told which frames are small, so every
+    frame packs one train a cap class, as all did until ISSUE 44."""
     import hashlib
 
     from gome_tpu.engine import frames
@@ -664,6 +666,8 @@ def _packed_grid_digest():
     inner = frames.pack_frame_grids
 
     def pack(e, a, **kw):
+        if not merge:
+            kw.pop("small")
         grids = inner(e, a, **kw)
         for ops, meta, lane_ids, cap_g in grids:
             if isinstance(ops, frames.HostGrid):  # a small frame's: the
@@ -689,14 +693,25 @@ def _packed_grid_digest():
     return h.hexdigest(), n_events, eng.stats.device_calls
 
 
-def test_without_a_mesh_the_packed_grids_are_the_parents():
-    """The timed path of the one-chip cells: the same grids, byte for byte,
-    as the tree before the placement (digest taken from an unpacked
-    `git archive a4fbb9d` with this same function)."""
-    assert _packed_grid_digest() == PARENT_PACKED_GRIDS
+@pytest.mark.parametrize("merge", [False, True],
+                         ids=["class_by_class", "as_served"])
+def test_without_a_mesh_the_packed_grids_are_the_parents(merge):
+    """The timed path of the one-chip cells: packed one train a cap class,
+    the same grids, byte for byte, as the tree before the placement (digest
+    taken from an unpacked `git archive a4fbb9d` with this same function).
+    As served since ISSUE 44 the train's 512-order frames are under the
+    one-phase rule and those whose lanes span both classes pack one grid at
+    the deeper: the same events from five grids where there were nine."""
+    want = MERGED_PACKED_GRIDS if merge else PARENT_PACKED_GRIDS
+    assert _packed_grid_digest(merge) == want
 
 
 #: (digest, events, device calls) of _packed_grid_digest() on commit a4fbb9d.
 PARENT_PACKED_GRIDS = (
     "1c3097caf37ec952395aa5d09105bc6d77f1c6f03fd07482225d2e0cd91a2a08", 728, 9
+)
+#: The same of the train as it is served since ISSUE 44 (small frames whose
+#: lanes span two classes merged): taken on this PR's tree.
+MERGED_PACKED_GRIDS = (
+    "d950433df165c5e53c0f42fdb1cee01da54fe6eeee2e63e411e101f793b8107f", 728, 5
 )

@@ -147,11 +147,17 @@ def test_the_sharded_dense_step_compiles_for_four_v5e_chips_without_collectives(
         # hotpair8.paced: 81 orders a frame, one full grid of the 1024-slot
         # class at the shallowest depth
         (8, 1024, 8, 32, 1024, False, 256, 128, "match_full_r8_t32_c1024"),
-        # spot10k.paced: 62 orders a frame, two dense grids: the tail's
+        # spot10k.paced: 62 orders a frame; until ISSUE 44 (and for a frame
+        # of one class since) two dense grids: the tail's
         # lanes at class 64 and the deep band's at class 256 (the cell's
         # floors in a chip run: rows 64 and 32, depth 8)
         (10240, 256, 64, 8, 64, True, 64, 64, "match_dense_r64_t8_c64"),
         (10240, 256, 32, 8, 256, True, 64, 64, "match_dense_r32_t8_c256"),
+        # ... and since ISSUE 44 ONE: every lane of the frame, up to 63, at
+        # the deepest class present, at the depths its floor can settle on
+        (10240, 256, 64, 8, 256, True, 64, 64, "match_dense_r64_t8_c256"),
+        (10240, 256, 64, 16, 256, True, 64, 64, "match_dense_r64_t16_c256"),
+        (10240, 256, 64, 32, 256, True, 64, 64, "match_dense_r64_t32_c256"),
     ])
 def test_a_small_frames_grid_compiles_for_v5e_as_one_program(
         one_chip, n_slots, store_cap, rows, t, cap, dense, m_pad, e_fills,
